@@ -187,8 +187,6 @@ def _sweep_one_sync_leg() -> dict:
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import gc
     import statistics
 

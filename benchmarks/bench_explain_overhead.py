@@ -184,8 +184,6 @@ def _run_leg(port: int, rows, n_requests: int, explain: bool):
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import tempfile
 
     import numpy as np

@@ -160,8 +160,6 @@ def _pctl(samples: list, p: float) -> float:
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import numpy as np
 
     import jax

@@ -107,8 +107,6 @@ def _measure_interleaved(run_once, configs: dict) -> dict[str, float]:
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import jax
 
     from transmogrifai_tpu.utils.profiling import profiler

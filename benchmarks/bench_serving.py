@@ -160,8 +160,6 @@ def _pump(server, rows, results, start_evt, idx0, step):
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import jax
 
     platform = jax.devices()[0].platform

@@ -188,8 +188,6 @@ def _train_zoo(root: str) -> dict:
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import tempfile
 
     import numpy as np

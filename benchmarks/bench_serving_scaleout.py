@@ -226,8 +226,6 @@ def _percentiles(samples, lo=None, hi=None):
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import tempfile
 
     import jax

@@ -171,8 +171,6 @@ def _drive(server, rows, mint) -> float:
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import gc
     import statistics
 

@@ -269,8 +269,6 @@ def _run_binary_leg(port: int, rows, n_frames: int):
 
 
 def main() -> int:
-    from transmogrifai_tpu.utils.platform import respect_jax_platforms
-    respect_jax_platforms()
     import tempfile
 
     import numpy as np

@@ -1,0 +1,734 @@
+"""chip_smoke.py — the quickest proof that the AutoML main path still starts,
+and is right, on the attached TPU.
+
+    python chip_smoke.py            # on a machine with a TPU; anything else fails
+
+Drives ``ingest -> transmogrify -> SanityChecker -> 3-fold default-zoo
+ModelSelector -> refit -> save/load -> bulk score -> serve`` once through the
+public API (``Workflow`` / ``ModelSelector`` / ``FleetServer``), in ONE process
+(a chip belongs to the process that opened it; this script starts no child),
+and fails if any leg fails or any of the product's safety nets fired on the
+way (a stacked sweep that fell back to the loop, a degradation rung, a device
+retry, a lazy serving compile, a degraded serving entry).
+
+Legs: ``titanic`` (mixed schema, committed fixture), ``higgs`` (bench.py's own
+``run_pipeline``: 28 numeric columns, the un-cut default binary zoo, 3 folds),
+``serve`` (the higgs winner behind a real localhost endpoint, JSON + binary
+frames against the row-path oracle), ``kernels`` (each Pallas kernel through
+its public stage, compiled, against its XLA twin), ``mesh`` (the higgs leg
+under two mesh shapes; runs when >= 4 devices are visible).
+
+A bare run asserts ``jax.default_backend() == "tpu"`` before any work and exits
+non-zero otherwise. ``--rehearsal`` is the ONLY way onto another backend: it
+stamps ``"rehearsal": true`` into the summary and exists so every command can
+be rehearsed at a tiny size on the CPU before chip time is spent on it.
+
+Walls in the summary are SMOKE walls (cold compile included, one run): they
+say the program ran, not how fast it is. The full summary goes to
+``<out>/summary.json`` (default ``chiprun_out/chip_smoke/``); the last line of
+stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import http.client
+import importlib.metadata
+import json
+import os
+import shutil
+import sys
+import time
+import traceback
+import warnings
+from unittest import mock
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+LEGS = ("titanic", "higgs", "serve", "kernels", "mesh")
+TITANIC_CSV = os.path.join(HERE, "tests", "fixtures",
+                           "TitanicPassengersTrainData.csv")
+
+#: rows of the higgs leg. 400,000 is the floor ISSUE 21 allows; the reason it
+#: is not the 1,000,000 of the one old chip record is in PERF.md ("Bring-up
+#: on the v5e"): cold compile of the stacked tree programs, not their run
+#: time, is what the 1200 s window of this script pays for.
+HIGGS_ROWS = 400_000
+BIG_ROWS = 1_000_000
+FRAME_SIZES = (1, 7, 64, 256)
+
+#: serving replies vs the ``score_function`` row oracle, max abs difference
+#: over every numeric field of the score doc (raw margins included). The
+#: compiled lane and the row path run the same f32 program shapes at
+#: different batch sizes; the only legitimate difference is the TPU's default
+#: matmul precision (f32 operands take bf16 passes on the MXU: ~2^-8
+#: relative per product, on margins of magnitude <= ~8 over 28-56 columns).
+#: Tree winners involve no matmul and agree to ~1e-6. The leg also checks
+#: that this tolerance REJECTS a reply paired with another row's oracle.
+SERVE_TOL = 5e-2
+
+#: sorted-histogram Pallas kernel vs the einsum engine, per-feature split
+#: gains: identical bf16 one-hot operands and f32 accumulation on the TPU, so
+#: split structure must match exactly and the histogram sums differ by f32
+#: accumulation order only (the kernel sums a block's 256 rows inside one
+#: MXU dot, the einsum inside XLA's tiling; both then cumsum ~4k blocks).
+#: Gains are differences of squared sums, so allow 1e-3: four orders over
+#: f32 epsilon, two under bf16's.
+HIST_RTOL = 1e-3
+
+
+class Leg:
+    """One leg's record: what ran, what was asserted, its smoke wall."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self.asserted: list[str] = []
+        self.info: dict = {}
+
+    def check(self, cond, what: str) -> None:
+        if what not in self.asserted:
+            self.asserted.append(what)
+        if not cond:
+            raise AssertionError(what)
+
+
+def _counters_clean(leg: Leg) -> None:
+    """The product's safety nets, read after a leg's work: none may have
+    fired."""
+    from transmogrifai_tpu.utils.profiling import run_counters
+    from transmogrifai_tpu.utils.resources import resource_counters
+    res = resource_counters.to_json()
+    leg.info["resource_counters"] = res
+    leg.info["run_counters"] = run_counters.to_json()
+    leg.check(res["degradationsBySite"] == {},
+              "resource_counters.degradationsBySite == {}")
+    leg.check(run_counters.retries == 0, "RunCounters.retries == 0")
+
+
+def _peaks() -> list[dict]:
+    from transmogrifai_tpu.utils.devicewatch import device_memory_census
+    return [{"device": d["device"], "peak_bytes_in_use": d["peakBytesInUse"]}
+            for d in device_memory_census()["devices"]]
+
+
+# -- titanic ------------------------------------------------------------------
+
+def leg_titanic(leg: Leg, out: str, ctx: dict) -> None:
+    """The flagship flow of examples/op_titanic.py on the committed fixture."""
+    import numpy as np
+    import titanic  # tests/titanic.py: the shared feature definitions
+    from transmogrifai_tpu import dsl  # noqa: F401 — installs feature DSL
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.readers import CSVReader
+    from transmogrifai_tpu.selector import BinaryClassificationModelSelector
+    from transmogrifai_tpu.utils.profiling import profiler
+    from transmogrifai_tpu.utils.resources import resource_counters
+    from transmogrifai_tpu.workflow import Workflow, load_model
+
+    def reader():
+        return CSVReader(TITANIC_CSV, schema=titanic.SCHEMA, header=False,
+                         columns=titanic.COLUMNS, key_col="id")
+
+    profiler.reset(app_name="chip_smoke.titanic")
+    resource_counters.reset()
+    survived, predictors = titanic.titanic_features()
+    features = transmogrify(predictors, min_support=5)
+    checked = survived.sanity_check(features)
+    selector = BinaryClassificationModelSelector.with_cross_validation(
+        n_folds=3, seed=42)
+    prediction = survived.transform_with(selector, checked)
+    model = (Workflow().set_reader(reader())
+             .set_result_features(prediction, checked).train())
+    s = model.selector_summary()
+    auroc = float(s.holdout_evaluation["binary classification"]["au_roc"])
+    leg.info.update(best=s.best_model_name, holdout_auroc=round(auroc, 4),
+                    candidates=len(s.validation_results))
+    leg.check(s.failures == [], "summary.failures == []")
+    leg.check(auroc >= 0.88, "holdout AuROC >= 0.88")
+    _counters_clean(leg)
+
+    path = os.path.join(out, "titanic_model")
+    model.save(path)
+    scored = load_model(path).score(reader())
+    leg.check(scored.n_rows == 891,
+              "save -> load_model -> score(reader) returns 891 rows")
+    doc = model.score_function()({"pclass": "1", "sex": "female",
+                                  "age": 30.0})
+    p1 = doc[prediction.name]["probability_1"]
+    leg.check(np.isfinite(p1) and 0.0 <= p1 <= 1.0,
+              "score_function row: finite probability_1 in [0, 1]")
+    leg.info["score_function_probability_1"] = round(float(p1), 4)
+
+
+# -- higgs --------------------------------------------------------------------
+
+def _fold_metric_counts(ckpt_dir: str) -> tuple[dict, list]:
+    """``({family index: finite per-fold metric values}, degradations)`` from
+    the sweep checkpoint. Keys: ``ci:stacked:...`` / ``ci:treestack:...``
+    (fold-major k x G vectors) or ``fold:ci:...`` (one fold's G values)."""
+    import math
+    with open(os.path.join(ckpt_dir, "sweep.json")) as fh:
+        doc = json.load(fh)
+    counts: dict[int, int] = {}
+    for key, vals in doc["entries"].items():
+        parts = key.split(":")
+        ci = int(parts[0] if parts[1] in ("stacked", "treestack")
+                 else parts[1])
+        counts[ci] = counts.get(ci, 0) + sum(
+            1 for v in vals if math.isfinite(v))
+    return counts, doc.get("degradations", [])
+
+
+def _run_higgs(leg: Leg, rows: int, ckpt_dir: str, on_tpu: bool) -> dict:
+    """One run of bench.run_pipeline with every sweep assertion of the
+    higgs leg (shared with the mesh leg)."""
+    import bench
+    from transmogrifai_tpu.models.trees import (
+        _SORT_MIN_ROWS, _sorted_acc_default, _sorted_engine_default,
+        _TreePredictor,
+    )
+    from transmogrifai_tpu.parallel.mesh import current_mesh
+    from transmogrifai_tpu.selector import factories
+    from transmogrifai_tpu.utils.resources import resource_counters
+
+    shutil.rmtree(ckpt_dir, ignore_errors=True)
+    resource_counters.reset()
+    res = bench.run_pipeline(rows, checkpoint_dir=ckpt_dir)
+    model = res["model"]
+    s = model.selector_summary()
+    zoo = factories._default_binary_candidates()
+    n_points = sum(len(g) for _, g in zoo)
+    k = 3
+    n_tr = int(rows * 0.9) * (k - 1) // k
+    modes = {f: c["mode"] for f, c in res["sweep_counters"].items()}
+    acc = _sorted_acc_default()
+    if acc == "auto":
+        acc = "bf16" if on_tpu else "f32"
+    hist = _TreePredictor._tree_stack_hist_mode(n_tr)
+    leg.info.update(
+        rows=rows, smoke_wall_s=round(res["wall"], 1), best=res["best"],
+        holdout_auroc=round(res["auroc"], 4), grid_points=n_points,
+        sweep_modes=modes, sweep_run_counters=res["sweep_run_counters"],
+        sweep_counters=res["sweep_counters"],
+        tree_hist_engine=hist, tree_sorted_engine=_sorted_engine_default(),
+        tree_accumulate_dtype=acc if hist == "sorted" else "f32",
+        fold_train_rows=n_tr,
+        phases_wall_s={p: v["wall_s"] for p, v in res["phases"].items()},
+        cv_metrics={r.model_name: list(r.metric_values.values())[0]
+                    for r in s.validation_results},
+        peak_bytes_in_use=_peaks())
+
+    leg.check(s.failures == [], "summary.failures == []")
+    leg.check(len(s.validation_results) == n_points,
+              f"all {n_points} grid points of the default zoo have a "
+              "validation result")
+    if on_tpu:
+        leg.check(n_points == 16, "the default binary zoo is un-cut: "
+                                  "16 grid points")
+    counts, degradations = _fold_metric_counts(ckpt_dir)
+    leg.check(all(counts.get(ci) == k * len(g)
+                  for ci, (_e, g) in enumerate(zoo)),
+              "every grid point has a finite metric per fold "
+              "(sweep checkpoint: k x grid values per family)")
+    leg.check(degradations == [], "sweep checkpoint records no degradation")
+    if on_tpu:
+        leg.check(all(m in ("fold_stacked", "tree_stacked")
+                      for m in modes.values()) and len(modes) == len(zoo),
+                  "every family took its stacked sweep mode (none fell "
+                  "back to the per-fold loop)")
+        leg.check(res["sweep_run_counters"]["sweepHostSyncs"] == 1,
+                  "sweepHostSyncs == 1 (one-sync async sweep)")
+        if current_mesh() is None and n_tr >= _SORT_MIN_ROWS:
+            leg.check(hist == "sorted" and acc == "bf16",
+                      "trees took the sorted engine with bf16 accumulate "
+                      f"(fold rows {n_tr} >= {_SORT_MIN_ROWS})")
+    floor = 0.80 if rows >= 400_000 else 0.60
+    leg.check(res["auroc"] >= floor, f"holdout AuROC >= {floor}")
+    _counters_clean(leg)
+    return res
+
+
+def leg_higgs(leg: Leg, out: str, ctx: dict) -> None:
+    ctx["higgs"] = _run_higgs(leg, ctx["rows"],
+                              os.path.join(out, "ckpt", "higgs"),
+                              ctx["on_tpu"])
+    ctx["single_cv_metrics"] = leg.info["cv_metrics"]
+
+
+# -- serve --------------------------------------------------------------------
+
+def _post(conn, path: str, body, ctype: str = "application/json"):
+    conn.request("POST", path, body, {"Content-Type": ctype})
+    resp = conn.getresponse()
+    return resp.status, resp.read()
+
+
+def leg_serve(leg: Leg, out: str, ctx: dict) -> None:
+    """The higgs winner behind FleetServer's real localhost endpoint."""
+    import numpy as np
+    from transmogrifai_tpu.local.scoring import required_raw_keys
+    from transmogrifai_tpu.serving import FleetServer
+    from transmogrifai_tpu.serving import wireformat as wf
+    from transmogrifai_tpu.serving.fleet import score_diff
+
+    if "higgs" not in ctx:
+        raise RuntimeError("needs the higgs leg's fitted model")
+    model, frame = ctx["higgs"]["model"], ctx["higgs"]["frame"]
+    n = frame.n_rows
+    root = os.path.join(out, "models")
+    model.save(os.path.join(root, "higgs", "v1"))
+
+    keys = required_raw_keys(model)
+    rng = np.random.default_rng(7)
+    take = rng.choice(n, size=64 + sum(FRAME_SIZES), replace=False)
+    rows = [{k_: float(frame[k_].values[i]) for k_ in keys} for i in take]
+    oracle_fn = model.score_function()
+    oracle = [oracle_fn(r) for r in rows]
+
+    fleet = FleetServer(metrics_port=0)
+    fleet.register_dir(root)
+    try:
+        with warnings.catch_warnings():
+            # a failed warm-up is a warning in the product (lazy compile);
+            # here it is an error
+            warnings.simplefilter("error", RuntimeWarning)
+            fleet.start(warmup_rows={"higgs": rows[0]})
+        conn = http.client.HTTPConnection(
+            "127.0.0.1", fleet.bound_metrics_port, timeout=120)
+        replies = []
+        for r in rows[:64]:
+            status, body = _post(conn, "/score/higgs", json.dumps(r))
+            leg.check(status == 200, "POST /score/higgs (JSON) -> 200")
+            doc = json.loads(body)
+            lineage = doc.pop("lineage", None)
+            doc.pop("traceId", None)
+            leg.check(bool(lineage) and lineage.get("modelId") == "higgs",
+                      "JSON reply carries lineage for model 'higgs'")
+            replies.append(doc)
+        at = 64
+        for size in FRAME_SIZES:
+            chunk = rows[at:at + size]
+            status, body = _post(conn, "/score/higgs",
+                                 wf.encode_rows("higgs", chunk),
+                                 ctype=wf.CONTENT_TYPE_FRAME)
+            leg.check(status == 200,
+                      "POST /score/higgs (binary frame) -> 200")
+            reply = wf.decode_frame(body)
+            leg.check(reply.n_rows == size
+                      and reply.meta["lineage"]["modelId"] == "higgs",
+                      "frame reply: row count + lineage")
+            replies.extend(wf.reply_to_rows(reply))
+            at += size
+        diffs = [score_diff(a, b) for a, b in zip(replies, oracle)]
+        cross = [score_diff(a, b)
+                 for a, b in zip(replies, oracle[1:] + oracle[:1])]
+        rejected = sum(1 for d in cross if d > SERVE_TOL) / len(cross)
+        leg.info.update(json_requests=64, frame_sizes=list(FRAME_SIZES),
+                        max_abs_diff_vs_oracle=float(max(diffs)),
+                        tolerance=SERVE_TOL,
+                        wrong_row_rejected_share=round(rejected, 3))
+        leg.check(max(diffs) <= SERVE_TOL,
+                  f"every reply within {SERVE_TOL} of the score_function "
+                  "row oracle")
+        leg.check(rejected >= 0.9,
+                  "the tolerance rejects >= 90% of replies paired with "
+                  "another row's oracle")
+
+        lane = fleet.active_lanes()["higgs"]
+        leg.check(lane.post_warmup_compiles() == {},
+                  "lane.post_warmup_compiles() == {}")
+        leg.check(lane.metrics.degraded_entries == 0
+                  and lane.metrics.degraded_batches == 0,
+                  "degraded serving entries == 0")
+        conn.request("GET", "/healthz")
+        resp = conn.getresponse()
+        health = json.loads(resp.read())
+        leg.check(resp.status == 200 and health["ready"] is True
+                  and health["models"]["higgs"]["state"] == "ready",
+                  "GET /healthz: ready, lane 'higgs' ready")
+        conn.close()
+    finally:
+        fleet.stop()
+
+    # bulk scoring, the reference's primary mode: a fresh frame of the big
+    # size, whose numeric matrix is past the 96 MB upload chunk at 1M rows
+    import bench
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu import pipeline_data
+    from transmogrifai_tpu.types import feature_types as ft
+    big = ctx["big_rows"]
+    X, _y = bench.make_data(big, seed=3)
+    bulk = fr.HostFrame({f"f{i}": fr.HostColumn(
+        ft.Real, X[:, i].astype(np.float64), np.ones(big, bool))
+        for i in range(bench.D)})
+    scored = model.score(bulk)
+    leg.check(scored.n_rows == big,
+              f"bulk model.score(frame) returns {big} rows")
+    chunked = pipeline_data._fill_rows._cache_size() > 0
+    if X.nbytes > 96 << 20:
+        leg.check(chunked, "the bulk frame's upload took the chunked path "
+                           "(> 96 MB)")
+    leg.info.update(bulk_rows=scored.n_rows, bulk_upload_chunked=chunked,
+                    peak_bytes_in_use=_peaks())
+
+
+# -- kernels ------------------------------------------------------------------
+
+def _lowers_to_custom_call(jitted, *args, **kw) -> bool:
+    """True when the program lowered FOR THE TPU holds a Mosaic kernel (a
+    ``tpu_custom_call``), i.e. the Pallas call is compiled, not
+    interpreted."""
+    lowered = jitted.trace(*args, **kw).lower(lowering_platforms=("tpu",))
+    return "tpu_custom_call" in lowered.as_text()
+
+
+def leg_kernels(leg: Leg, out: str, ctx: dict) -> None:
+    """Each Pallas kernel through its public stage at > 1 block with a
+    ragged tail, against its XLA twin."""
+    import bench
+    import jax.numpy as jnp
+    import numpy as np
+    from transmogrifai_tpu import dsl  # noqa: F401 — installs feature DSL
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.features.builder import FeatureBuilder
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.ops import hashing_pallas as hp
+    from transmogrifai_tpu.ops import quantile_bin_pallas as qb
+    from transmogrifai_tpu.ops import sorted_hist_pallas as sh
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.types import feature_types as ft
+    from transmogrifai_tpu.workflow import Workflow
+
+    n = ctx["big_rows"] + 3             # never a whole number of blocks
+    on_tpu = ctx["on_tpu"]
+    rng = np.random.default_rng(11)
+    leg.info["rows"] = n
+
+    def stage_output(frame, make_feature, engine_var, engine):
+        with mock.patch.dict(os.environ, {engine_var: engine}):
+            feat = make_feature(FeatureBuilder.from_frame(frame))
+            model = (Workflow().set_input_frame(frame)
+                     .set_result_features(feat).train())
+            return model.transform(frame).device_col(feat.name).values
+
+    # 1. NumericBucketizer -> ops/quantile_bin_pallas.py
+    x = rng.normal(size=n)
+    mask = rng.uniform(size=n) > 0.05
+    num_frame = fr.HostFrame({"x": fr.HostColumn(ft.Real, x, mask)})
+    splits = [float("-inf"), -1.0, -0.25, 0.0, 0.5, 1.5, float("inf")]
+
+    def bucketized(feats):
+        return feats["x"].bucketize(splits, track_nulls=True,
+                                    track_invalid=True)
+
+    if on_tpu:
+        leg.check(qb.bucket_engine() == "pallas",
+                  "bucketizer engine defaults to pallas on the TPU")
+    got = stage_output(num_frame, bucketized,
+                       "TRANSMOGRIFAI_BUCKET_ENGINE", "pallas")
+    ref = stage_output(num_frame, bucketized,
+                       "TRANSMOGRIFAI_BUCKET_ENGINE", "xla")
+    leg.check(got.shape == ref.shape == (n, len(splits) + 1),
+              "bucketizer output shape [rows, buckets + invalid + null]")
+    leg.check(bool(jnp.array_equal(got, ref)),
+              "bucketizer kernel bitwise equal to its XLA twin")
+    leg.check(_lowers_to_custom_call(
+        qb._bucketize_pallas, jnp.zeros(n), jnp.ones(n),
+        jnp.asarray(splits, jnp.float32), k=len(splits) - 1,
+        track_invalid=True, track_nulls=True, interpret=False),
+        "bucketizer kernel lowers to a tpu_custom_call")
+    leg.info["bucketizer_blocks"] = -(-n // qb._BLOCK_ROWS)
+    del got, ref
+
+    # 2. transmogrify(text_vectorizer="hash_device") -> ops/hashing_pallas.py
+    vocab = np.array([f"id{v:05d}" for v in range(20_000)], dtype=object)
+    cat = vocab[rng.integers(0, len(vocab), n)]
+    cat[rng.uniform(size=n) < 0.03] = None
+    txt_frame = fr.HostFrame({"c": fr.HostColumn(ft.Text, cat)})
+
+    def hashed(feats):
+        return transmogrify([feats["c"]], text_vectorizer="hash_device")
+
+    if on_tpu:
+        leg.check(hp.hash_engine() == "pallas",
+                  "hash engine defaults to pallas on the TPU")
+    got = stage_output(txt_frame, hashed,
+                       "TRANSMOGRIFAI_HASH_ENGINE", "pallas")
+    ref = stage_output(txt_frame, hashed, "TRANSMOGRIFAI_HASH_ENGINE", "xla")
+    leg.check(got.shape == ref.shape and got.shape[0] == n
+              and got.shape[1] >= 512,
+              "hash_device output shape [rows, >= 512 hash bins]")
+    leg.check(bool(jnp.array_equal(got, ref)),
+              "hashing kernel bitwise equal to its XLA twin")
+    leg.check(_lowers_to_custom_call(
+        hp._segment_onehot_pallas, jnp.zeros((n, 1), jnp.int32),
+        n_bins=512, interpret=False),
+        "hashing kernel lowers to a tpu_custom_call")
+    leg.info["hashing_blocks"] = -(-n // hp._BLOCK_ROWS)
+    leg.info["hashing_width"] = int(got.shape[1])
+    del got, ref
+
+    # 3. train_ensemble(hist="sorted", sorted_engine="pallas")
+    #    -> ops/sorted_hist_pallas.py
+    X, y = bench.make_data(n, seed=5)
+    edges = trees.quantile_bin_edges(X, 64)
+    Xb = trees.bin_data(jnp.asarray(X), jnp.asarray(edges))
+    kw = dict(n_rounds=2, max_depth=3, n_bins=64, n_out=1, loss="logistic",
+              learning_rate=jnp.float32(0.3), reg_lambda=jnp.float32(1.0),
+              gamma=jnp.float32(0.0), min_child_weight=jnp.float32(1.0),
+              subsample=1.0, colsample=1.0, base_score=jnp.float32(0.0),
+              bootstrap=False, seed=5, hist="sorted",
+              sorted_acc="bf16" if on_tpu else "f32")
+    yj, wj = jnp.asarray(y, jnp.float32), jnp.ones(n, jnp.float32)
+    (f_k, b_k, _), g_k = trees.train_ensemble(Xb, yj, wj,
+                                              sorted_engine="pallas", **kw)
+    (f_e, b_e, _), g_e = trees.train_ensemble(Xb, yj, wj,
+                                              sorted_engine="einsum", **kw)
+    same = all(bool(jnp.array_equal(a, b))
+               for a, b in zip((*f_k, *b_k), (*f_e, *b_e)))
+    leg.check(same, "histogram kernel: split structure (feature, bin per "
+                    "node) identical to the einsum engine")
+    # split gains are computed from the histograms (leaf values are not:
+    # they come from segment sums of the row partition). Off the TPU the
+    # einsum reference runs f32 operands against the kernel's bf16 ones
+    # (XLA:CPU has no bf16 dot), so the rehearsal bound is bf16's
+    rtol = HIST_RTOL if on_tpu else 5e-2
+    gain_err = float(jnp.max(jnp.abs(g_k - g_e) / (1e-6 + jnp.abs(g_e))))
+    leg.info["hist_gain_max_rel_err"] = gain_err
+    leg.check(gain_err <= rtol,
+              f"histogram kernel: per-feature split gains within rtol "
+              f"{rtol} of the einsum engine")
+    leg.check(_lowers_to_custom_call(trees.train_ensemble, Xb, yj, wj,
+                                     sorted_engine="pallas", **kw)
+              if on_tpu else _lowers_to_custom_call(
+                  sh.sorted_block_hist, jnp.zeros((5, 256, 28), jnp.int8),
+                  jnp.zeros((5, 2, 256), jnp.float32), n_bins=64,
+                  interpret=False),
+              "histogram kernel lowers to a tpu_custom_call (on the TPU: "
+              "inside train_ensemble's own program)")
+    leg.info["hist_shape"] = [n, 28, 64]
+    leg.info["interpret"] = not on_tpu
+    leg.info["peak_bytes_in_use"] = _peaks()
+
+
+# -- mesh ---------------------------------------------------------------------
+
+def leg_mesh(leg: Leg, out: str, ctx: dict) -> None:
+    """The higgs leg under two mesh shapes on >= 4 devices, against a
+    single-device run of the same rows, plus the multi-chip dryrun."""
+    import jax
+    import __graft_entry__ as graft
+    from transmogrifai_tpu.parallel import make_mesh, use_mesh
+    from transmogrifai_tpu.pipeline_data import PipelineData
+
+    devices = jax.devices()[:4]
+    rows = ctx["rows"]
+    leg.info["rows"] = rows
+
+    def run(label: str, ckpt: str) -> dict:
+        """One _run_higgs as a sub-leg; its record lands under ``label``
+        (and on disk at once: a call cut short keeps what it learned)."""
+        sub = Leg(f"mesh.{label}")
+        try:
+            return _run_higgs(sub, rows, os.path.join(out, "ckpt", ckpt),
+                              ctx["on_tpu"])
+        finally:
+            leg.asserted += [f"[{label}] {a}" for a in sub.asserted]
+            leg.info[label] = sub.info
+            with open(os.path.join(out, f"mesh_{label}.json"), "w") as fh:
+                json.dump(sub.info, fh, indent=1, default=str)
+
+    # where the single-device CV metrics come from: the higgs leg of this
+    # process, a one-chip run's summary, or (last) a run of their own
+    base = ctx.get("single_cv_metrics")
+    if base is None and ctx["single_summary"]:
+        with open(ctx["single_summary"]) as fh:
+            ref = json.load(fh)["legs"]["higgs"]
+        leg.check(ref["ok"] and ref["rows"] == rows,
+                  "--single-summary holds a passed higgs leg at the mesh "
+                  "leg's rows")
+        base = ref["cv_metrics"]
+    # the cheap part first; its verdict is held until the sweeps have run
+    # so one call to the chips still says everything it can
+    try:
+        graft.dryrun_multichip(4)
+        dryrun_error = None
+    except Exception as e:  # noqa: BLE001 — re-raised through leg.check below
+        traceback.print_exc()
+        dryrun_error = f"{type(e).__name__}: {e}"
+    leg.info["dryrun_multichip"] = dryrun_error or "ok"
+
+    shapes = {"data2_model2": dict(n_data=2, n_model=2),
+              "data4": dict(n_data=4, n_model=1)}
+    for label, shape in shapes.items():
+        with use_mesh(make_mesh(devices=devices, **shape)):
+            res = run(label, f"mesh_{label}")
+            placed = PipelineData.from_host(
+                res["frame"]).device_col("f0").values
+        on = {d.id for d in placed.sharding.device_set}
+        leg.check(on == {d.id for d in devices},
+                  f"[{label}] the training matrix's shards sit on four "
+                  "distinct devices")
+    peaks = _peaks()[:4]
+    leg.info["peak_bytes_in_use_after_mesh_runs"] = peaks
+    # devices 1..3 are touched by mesh work only; device 0 also carries
+    # whatever single-device legs ran before in this process
+    others = [p["peak_bytes_in_use"] for p in peaks[1:]]
+    if ctx["on_tpu"]:
+        leg.check(min(others) > 0 and min(others) >= 0.25 * max(others),
+                  "every device's peak_bytes_in_use is a comparable share "
+                  "(devices 1-3 within 4x of each other, none idle)")
+
+    if base is None:
+        run("single", "mesh_single")
+        base = leg.info["single"]["cv_metrics"]
+    parity: dict[str, dict] = {}
+    for label in shapes:
+        got = leg.info[label]["cv_metrics"]
+        leg.check(set(got) == set(base),
+                  f"[{label}] same candidates as the single-device run")
+        worst: dict[str, float] = {}
+        for name, v in got.items():
+            fam = name.split("_")[0]
+            worst[fam] = max(worst.get(fam, 0.0), abs(v - base[name]))
+        parity[label] = {f: float(f"{e:.3g}") for f, e in worst.items()}
+        # the bounds __graft_entry__.dryrun_multichip uses: 5e-3 for the
+        # linear families, 3e-2 for tree CV metrics at 4 devices
+        for fam, err in worst.items():
+            bound = 5e-3 if fam in ("OpLogisticRegression",
+                                    "OpLinearSVC") else 3e-2
+            leg.check(err < bound, f"[{label}] {fam} CV-metric parity with "
+                                   f"the single-device run < {bound}")
+    leg.info["cv_metric_parity_vs_single"] = parity
+
+    leg.check(dryrun_error is None,
+              "__graft_entry__.dryrun_multichip(4) on the attached devices")
+
+
+LEG_FNS = {"titanic": leg_titanic, "higgs": leg_higgs, "serve": leg_serve,
+           "kernels": leg_kernels, "mesh": leg_mesh}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="run on whatever backend jax has (CPU rehearsal "
+                         "of the commands); stamps rehearsal: true")
+    ap.add_argument("--legs", default=None,
+                    help=f"comma list of {','.join(LEGS)} (default: every "
+                         "single-device leg, plus mesh on >= 4 devices)")
+    ap.add_argument("--rows", type=int, default=HIGGS_ROWS,
+                    help="rows of the higgs leg and of the mesh leg's runs")
+    ap.add_argument("--big-rows", type=int, default=BIG_ROWS,
+                    help="rows of the single-pass work: each kernel's "
+                         "column and the bulk score")
+    ap.add_argument("--single-summary", default=None,
+                    help="mesh leg: take the single-device CV metrics from "
+                         "the higgs leg of this summary.json (a one-chip "
+                         "run at the same --rows) instead of re-running it")
+    ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
+                                                  "chip_smoke"))
+    args = ap.parse_args(argv)
+
+    t_start = time.time()
+    import jax
+    platform = jax.default_backend()
+    if platform != "tpu" and not args.rehearsal:
+        print(f"chip_smoke: jax.default_backend() is {platform!r}, not "
+              "'tpu' — no accelerator, nothing to prove. (--rehearsal "
+              "rehearses the commands on this backend.)", file=sys.stderr)
+        return 2
+    try:
+        sys.path[:0] = [HERE, os.path.join(HERE, "tests")]
+        from transmogrifai_tpu import native
+        from transmogrifai_tpu.scaleout.supervisor import host_tpu_chips
+        from transmogrifai_tpu.utils import devicewatch
+        from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
+    except ModuleNotFoundError as e:
+        print(f"chip_smoke: the repository is not beside this script "
+              f"({e})", file=sys.stderr)
+        return 2
+    cache_dir = enable_compile_cache()
+    devices = jax.devices()
+    on_tpu = platform == "tpu"
+    legs = (args.legs.split(",") if args.legs else
+            [name for name in LEGS if name != "mesh" or len(devices) >= 4])
+    unknown = set(legs) - set(LEGS)
+    if unknown:
+        ap.error(f"unknown legs {sorted(unknown)}")
+    out = os.path.abspath(args.out)
+    shutil.rmtree(out, ignore_errors=True)
+    os.makedirs(out)
+    devicewatch.configure(incident_dir=os.path.join(out, "incidents"))
+
+    native_states = native.library_states()
+    if "python" in native_states.values():
+        print("chip_smoke: NATIVE LIBRARIES MISSING — "
+              f"{native_states}: no working g++ here, host-side encoders "
+              "run their Python twins", file=sys.stderr)
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    summary: dict = {
+        "ok": False, "rehearsal": bool(args.rehearsal),
+        "platform": platform, "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "versions": {"python": sys.version.split()[0],
+                     "jax": jax.__version__,
+                     "jaxlib": importlib.metadata.version("jaxlib"),
+                     "libtpu": libtpu},
+        "compile_cache_dir": cache_dir,
+        "native_libraries": native_states,
+        "host_tpu_chips_from_device_files": host_tpu_chips(),
+        "legs": {}}
+    ctx = {"rows": args.rows, "big_rows": args.big_rows, "on_tpu": on_tpu,
+           "single_summary": args.single_summary}
+
+    def write_summary() -> None:
+        with open(os.path.join(out, "summary.json"), "w") as fh:
+            json.dump(summary, fh, indent=1, default=str)
+
+    for name in [n for n in LEGS if n in legs]:
+        leg = Leg(name)
+        t0 = time.time()
+        try:
+            LEG_FNS[name](leg, out, ctx)
+            ok, err = True, None
+        except Exception as e:  # noqa: BLE001 — a failed leg is recorded, later legs still run
+            ok = False
+            err = f"{type(e).__name__}: {e}"
+            traceback.print_exc()
+        doc = {"ok": ok, "smoke_wall_s": round(time.time() - t0, 1),
+               **leg.info, "asserted": leg.asserted}
+        if err:
+            doc["error"] = err[:2000]
+        summary["legs"][name] = doc
+        write_summary()     # a run cut short still leaves what it learned
+        print(f"# leg {name}: {'ok' if ok else 'FAILED'} in "
+              f"{doc['smoke_wall_s']}s ({len(leg.asserted)} assertions)"
+              + (f" — {err[:300]}" if err else ""), file=sys.stderr)
+
+    summary["watchdog_stalls"] = devicewatch.watchdog.stalls
+    summary["peak_bytes_in_use"] = _peaks()
+    summary["smoke_wall_s"] = round(time.time() - t_start, 1)
+    summary["ok"] = bool(summary["legs"]) and all(
+        d["ok"] for d in summary["legs"].values())
+    summary["claim"] = None
+    write_summary()
+    print(json.dumps({k: v for k, v in summary.items() if k != "legs"},
+                     default=str))
+    for name, doc in summary["legs"].items():
+        print(json.dumps({"leg": name, **{
+            k: v for k, v in doc.items()
+            if k not in ("asserted", "sweep_counters", "cv_metrics")}},
+            default=str))
+    if not summary["ok"]:
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -27,7 +27,7 @@ from transmogrifai_tpu import dsl  # noqa: F401 — installs the feature DSL
 from transmogrifai_tpu.features.builder import FeatureBuilder
 from transmogrifai_tpu.readers import DataReaders
 from transmogrifai_tpu.types import feature_types as ft
-from transmogrifai_tpu.utils.platform import respect_jax_platforms
+from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
 
 _RES = "/root/reference/helloworld/src/main/resources"
 WEB_VISITS_CSV = f"{_RES}/WebVisitsDataset/WebVisits.csv"
@@ -129,7 +129,7 @@ def joins_and_aggregates():
 
 
 def main() -> int:
-    respect_jax_platforms()
+    enable_compile_cache()
     cond = conditional_aggregation()
     print("ConditionalAggregation:")
     for i in range(cond.n_rows):

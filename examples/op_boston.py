@@ -17,7 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 
-from transmogrifai_tpu.utils.platform import respect_jax_platforms
+from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
 from transmogrifai_tpu import dsl  # noqa: F401
 from transmogrifai_tpu import frame as fr
 from transmogrifai_tpu.features.builder import FeatureBuilder
@@ -87,7 +87,7 @@ def boston_frame_real(path: str = BOSTON_CSV) -> fr.HostFrame:
 
 
 def main(n: int = 506) -> int:
-    respect_jax_platforms()
+    enable_compile_cache()
     if os.path.exists(BOSTON_CSV):
         frame = boston_frame_real()
         columns = BOSTON_COLUMNS

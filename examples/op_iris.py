@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 
-from transmogrifai_tpu.utils.platform import respect_jax_platforms
+from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
 from transmogrifai_tpu import dsl  # noqa: F401
 from transmogrifai_tpu import frame as fr
 from transmogrifai_tpu.features.builder import FeatureBuilder
@@ -71,7 +71,7 @@ def iris_frame_real(path: str = IRIS_CSV) -> fr.HostFrame:
 
 
 def main(n: int = 450) -> int:
-    respect_jax_platforms()
+    enable_compile_cache()
     frame = iris_frame_real() if os.path.exists(IRIS_CSV) else iris_frame(n)
     feats = FeatureBuilder.from_frame(frame, response="species")
     label = feats["species"].index_string()

@@ -16,7 +16,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tests"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from transmogrifai_tpu.utils.platform import respect_jax_platforms
+from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
 from transmogrifai_tpu import dsl  # noqa: F401 — installs feature DSL
 from transmogrifai_tpu.evaluators import OpBinaryClassificationEvaluator
 from transmogrifai_tpu.ops.transmogrifier import transmogrify
@@ -27,7 +27,7 @@ from titanic import titanic_features, titanic_reader
 
 
 def main() -> int:
-    respect_jax_platforms()
+    enable_compile_cache()
     survived, predictors = titanic_features()
     features = transmogrify(predictors, min_support=5)
     checked = survived.sanity_check(features)

@@ -6,15 +6,14 @@ and docs rely on, should fail CI loudly instead of silently reading as a
 measurement. Required of every artifact:
 
 - ``metric`` — what was measured (string)
-- ``platform`` — where (``cpu``/``tpu``/...; the CPU guard in ``bench.py``
-  and ``bench_serving.py`` depends on artifacts being truthful here)
+- ``platform`` — where (``cpu``/``tpu``/...; every reader of a committed
+  number depends on artifacts being truthful here)
 - a size: ``rows`` or ``requests`` (positive int)
 - a timing: ``wall_s``, ``value``, any ``*_s`` key, or a latency block
 - accelerator artifacts (``platform`` != ``cpu``) must carry a
   ``code_fingerprint`` — an accel number without provenance against the
-  code that produced it is unverifiable (CPU baselines are exempt,
-  matching ``bench.py._load_bench_artifact``'s contract: hand-committed
-  CPU walls tolerate code drift).
+  code that produced it is unverifiable (CPU baselines are exempt:
+  hand-committed CPU walls tolerate code drift).
 
 Library use: ``validate_artifact(doc) -> [errors]``; CLI: exits 1 listing
 every violation. Wired into tier-1 via ``tests/test_bench_artifacts.py``.
@@ -91,8 +90,6 @@ def validate_artifact(doc: object) -> list[str]:
         errors.extend(_validate_continuous_loop(doc))
     if doc.get("metric") == "resource_resilience":
         errors.extend(_validate_resource_resilience(doc))
-    if doc.get("metric") == "accel_probe_autopsy":
-        errors.extend(_validate_accel_autopsy(doc))
     if doc.get("metric") == "devicewatch_overhead":
         errors.extend(_validate_devicewatch_overhead(doc))
     if doc.get("metric") == "ingest_fe_fusion":
@@ -833,59 +830,6 @@ def _validate_devicewatch_overhead(doc: dict) -> list[str]:
                 f"one-sync contract violated under the armed watchdog: "
                 f"{syncs} blocking host syncs (must be exactly 1 — the "
                 "watchdog may add zero syncs)")
-    return errors
-
-
-def _validate_accel_autopsy(doc: dict) -> list[str]:
-    """The ``benchmarks/ACCEL_AUTOPSY.json`` contract: a fully-hung accel
-    probe ladder commits its evidence — an escalating (non-decreasing)
-    per-attempt timeout ledger where every attempt records an outcome,
-    at least one attempt HUNG, and every hung attempt names its stall
-    site (from the probe child's self-autopsy; 'unknown' when the child
-    hung before arming is honest and allowed)."""
-    errors = []
-
-    def num(v) -> bool:
-        return isinstance(v, (int, float)) and not isinstance(v, bool)
-
-    if not (num(doc.get("probe_wall_s")) and doc["probe_wall_s"] > 0):
-        errors.append("accel-autopsy artifact: missing positive "
-                      "'probe_wall_s'")
-    attempts = doc.get("attempts")
-    if not (isinstance(attempts, list) and attempts
-            and all(isinstance(a, dict) for a in attempts)):
-        errors.append("accel-autopsy artifact: 'attempts' must be a "
-                      "non-empty list of per-attempt records")
-        return errors
-    prev_timeout = None
-    any_hung = False
-    for i, a in enumerate(attempts):
-        if not (isinstance(a.get("label"), str) and a.get("label")):
-            errors.append(f"accel-autopsy attempt {i}: missing 'label'")
-        if not (num(a.get("timeout_s")) and a["timeout_s"] > 0):
-            errors.append(f"accel-autopsy attempt {i}: missing positive "
-                          "'timeout_s'")
-        else:
-            if prev_timeout is not None and a["timeout_s"] < prev_timeout:
-                errors.append(
-                    f"accel-autopsy attempt {i}: timeout {a['timeout_s']}"
-                    f"s < attempt {i - 1}'s {prev_timeout}s — the retry "
-                    "ladder must ESCALATE, not burn identical windows")
-            prev_timeout = a["timeout_s"]
-        outcome = a.get("outcome")
-        if not (isinstance(outcome, str) and outcome):
-            errors.append(f"accel-autopsy attempt {i}: missing 'outcome'")
-            continue
-        if outcome == "hung":
-            any_hung = True
-            if not isinstance(a.get("stall_site"), str):
-                errors.append(
-                    f"accel-autopsy attempt {i}: hung attempt lacks "
-                    "'stall_site' (the probe child's self-autopsy digest "
-                    "— 'unknown' is allowed, absence is not)")
-    if not any_hung:
-        errors.append("accel-autopsy artifact: no attempt hung — this "
-                      "artifact exists to commit hang evidence")
     return errors
 
 

@@ -1,11 +1,8 @@
-"""bench.py measurement-artifact machinery (pure host logic, no jax).
+"""Committed benchmark artifacts (pure host logic, no jax).
 
-The driver depends on bench.py's always-print-JSON contract; these pin
-the artifact loaders' validation (rows/models match, malformed content
-tolerated, stale code fingerprints rejected) and the atomic saver. The
-second half wires ``scripts/check_artifacts.py`` into tier-1: every
-COMMITTED ``benchmarks/*.json`` must pass schema validation, so a "cited
-but never committed" (or key-starved) artifact fails loudly.
+Wires ``scripts/check_artifacts.py`` into tier-1: every COMMITTED
+``benchmarks/*.json`` must pass schema validation, so a "cited but never
+committed" (or key-starved) artifact fails loudly.
 """
 
 import importlib.util
@@ -34,92 +31,6 @@ def benchmod():
 @pytest.fixture()
 def checker():
     return _load_script("scripts/check_artifacts.py")
-
-
-def _accel_art(m, **over):
-    art = {"metric": "x", "rows": m.N_ROWS, "models": m.MODELS,
-           "platform": "tpu", "wall_s": 1234.5, "holdout_auroc": 0.82,
-           "best_model": "g", "phases": {}, "scaling_curve": [],
-           "code_fingerprint": m._code_fingerprint(),
-           "measured_at": "2026-07-31T00:00:00Z"}
-    art.update(over)
-    return art
-
-
-def test_accel_artifact_roundtrip_and_rejections(benchmod, tmp_path,
-                                                 monkeypatch):
-    m = benchmod
-    path = str(tmp_path / "ACCEL.json")
-    monkeypatch.setattr(m, "_accel_artifact_path", lambda: path)
-
-    # save is atomic and loads back
-    m._save_accel_artifact({"wall": 1234.5, "platform": "tpu",
-                            "auroc": 0.82, "best": "g"}, [])
-    got = m._load_accel_artifact()
-    assert got is not None and got["wall_s"] == 1234.5
-    assert got["code_fingerprint"] == m._code_fingerprint()
-
-    # stale code fingerprint -> rejected
-    json.dump(_accel_art(m, code_fingerprint="deadbeef0000"),
-              open(path, "w"))
-    assert m._load_accel_artifact() is None
-    # CPU platform -> rejected (accel artifact must be an accel wall)
-    json.dump(_accel_art(m, platform="cpu"), open(path, "w"))
-    assert m._load_accel_artifact() is None
-    # rows mismatch -> rejected
-    json.dump(_accel_art(m, rows=m.N_ROWS + 1), open(path, "w"))
-    assert m._load_accel_artifact() is None
-    # malformed content must never raise (always-print-JSON contract)
-    open(path, "w").write("{not json")
-    assert m._load_accel_artifact() is None
-    json.dump(["not", "a", "dict"], open(path, "w"))
-    assert m._load_accel_artifact() is None
-    json.dump(_accel_art(m, wall_s=None), open(path, "w"))
-    assert m._load_accel_artifact() is None
-    os.remove(path)
-    assert m._load_accel_artifact() is None
-
-
-def test_cpu_artifact_validation(benchmod, tmp_path):
-    m = benchmod
-    path = str(tmp_path / "CPU.json")
-    art = {"rows": m.N_ROWS, "models": m.MODELS, "wall_s": 4253.89,
-           "platform": "cpu"}
-    json.dump(art, open(path, "w"))
-    got = m._load_bench_artifact(path, accel_only=False)
-    assert got is not None and got["wall_s"] == 4253.89
-    # the CPU loader does NOT demand a fingerprint (hand-committed,
-    # code drift is acceptable for the baseline side) but still
-    # validates rows/models
-    json.dump({**art, "models": "lr"}, open(path, "w"))
-    assert m._load_bench_artifact(path, accel_only=False) is None
-
-
-def test_code_fingerprint_tracks_sources(benchmod):
-    m = benchmod
-    fp = m._code_fingerprint()
-    assert isinstance(fp, str) and len(fp) == 12
-    assert fp == m._code_fingerprint()  # deterministic
-
-
-def test_cpu_artifact_requires_cpu_platform(benchmod, tmp_path):
-    """The vs_baseline DENOMINATOR must be a real CPU measurement: an
-    accelerator artifact (or one missing the platform field) dropped into
-    the CPU slot is rejected (ADVICE r5)."""
-    m = benchmod
-    path = str(tmp_path / "CPU.json")
-    art = {"rows": m.N_ROWS, "models": m.MODELS, "wall_s": 4253.89,
-           "platform": "cpu"}
-    json.dump(art, open(path, "w"))
-    assert m._load_bench_artifact(path, accel_only=False,
-                                  require_platform="cpu") is not None
-    json.dump({**art, "platform": "tpu"}, open(path, "w"))
-    assert m._load_bench_artifact(path, accel_only=False,
-                                  require_platform="cpu") is None
-    art.pop("platform")
-    json.dump(art, open(path, "w"))
-    assert m._load_bench_artifact(path, accel_only=False,
-                                  require_platform="cpu") is None
 
 
 def test_committed_artifacts_pass_schema(checker):

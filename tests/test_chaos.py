@@ -812,7 +812,7 @@ def test_transient_classification_walks_cause_chain():
     # implicit chaining (__context__) also walks
     try:
         try:
-            raise XlaRuntimeError("ABORTED: tunnel reset")
+            raise XlaRuntimeError("ABORTED: connection reset")
         except XlaRuntimeError:
             raise KeyError("raised while handling")
     except KeyError as implicit:
@@ -850,7 +850,7 @@ def test_wrapped_transient_error_is_retried():
         calls["n"] += 1
         if calls["n"] == 1:
             try:
-                raise XlaRuntimeError("UNAVAILABLE: flaky tunnel")
+                raise XlaRuntimeError("UNAVAILABLE: flaky device")
             except XlaRuntimeError as e:
                 raise ValueError("wrapped") from e
         return "ok"

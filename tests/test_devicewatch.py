@@ -586,46 +586,6 @@ def test_registry_carries_device_and_compile_series():
 
 # -- artifact schemas ---------------------------------------------------------
 
-def _good_autopsy_doc():
-    return {
-        "metric": "accel_probe_autopsy", "platform": "unknown",
-        "rows": 4_000_000, "models": "full", "probe_wall_s": 1620.5,
-        "code_fingerprint": "abc123def456",
-        "attempts": [
-            {"label": "accel attempt 1", "timeout_s": 240,
-             "outcome": "hung", "stall_site": "bench.probe",
-             "wall_s": 240.1},
-            {"label": "accel attempt 2", "timeout_s": 480,
-             "outcome": "hung", "stall_site": "unknown",
-             "wall_s": 480.2},
-            {"label": "accel attempt 3", "timeout_s": 900,
-             "outcome": "error", "wall_s": 12.0},
-        ],
-    }
-
-
-def test_accel_autopsy_schema_accepts_and_rejects():
-    checker = _load_script("scripts/check_artifacts.py")
-    assert checker.validate_artifact(_good_autopsy_doc()) == []
-    # identical (non-escalating) windows are the r05 failure mode
-    burn = _good_autopsy_doc()
-    burn["attempts"][1]["timeout_s"] = 240
-    burn["attempts"][2]["timeout_s"] = 120
-    assert any("ESCALATE" in e for e in checker.validate_artifact(burn))
-    # a hung attempt without its stall-site digest is a stderr line again
-    bare = _good_autopsy_doc()
-    del bare["attempts"][0]["stall_site"]
-    assert any("stall_site" in e for e in checker.validate_artifact(bare))
-    # no hang -> this artifact has no reason to exist
-    clean = _good_autopsy_doc()
-    for a in clean["attempts"]:
-        a["outcome"] = "error"
-    assert any("no attempt hung" in e
-               for e in checker.validate_artifact(clean))
-    empty = dict(_good_autopsy_doc(), attempts=[])
-    assert any("attempts" in e for e in checker.validate_artifact(empty))
-
-
 def _good_overhead_doc():
     return {
         "metric": "devicewatch_overhead", "platform": "cpu",

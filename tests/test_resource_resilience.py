@@ -136,7 +136,7 @@ def test_oom_classifier_walks_cause_chain():
     assert not resources.is_resource_exhausted(
         NotImplementedError("Out of memory"))
     # transient stays transient, OOM stays OOM — disjoint marker sets
-    transient = XlaRuntimeError("UNAVAILABLE: flaky tunnel")
+    transient = XlaRuntimeError("UNAVAILABLE: flaky device")
     assert is_transient_device_error(transient)
     assert not resources.is_resource_exhausted(transient)
 

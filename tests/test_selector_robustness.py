@@ -152,7 +152,7 @@ def test_with_device_retry_passes_through_real_errors():
     with pytest.raises(ValueError):
         with_device_retry(broken, backoff_s=0.0)
     assert not is_transient_device_error(ValueError("UNAVAILABLE"))
-    assert is_transient_device_error(RuntimeError("ABORTED: tunnel reset"))
+    assert is_transient_device_error(RuntimeError("ABORTED: connection reset"))
 
 
 def test_checkpointed_sweep_restarts(tmp_path):

@@ -73,6 +73,14 @@ def main(argv=None) -> int:
                         "holders, pending dispatches, event tail)"))
     args = ap.parse_args(argv)
 
+    if args.command in ("shell", "serve", "explain", "continuous",
+                        "profile"):
+        # the commands that compile in THIS process (scaleout's workers
+        # enable the cache themselves; gen/slo/autopsy never touch jax)
+        from transmogrifai_tpu.utils.compile_cache import (
+            enable_compile_cache,
+        )
+        enable_compile_cache()
     if args.command == "shell":
         from transmogrifai_tpu.cli.shell import run_shell
         return run_shell()

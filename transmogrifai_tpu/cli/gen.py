@@ -210,15 +210,15 @@ def _run_py(name, kind) -> str:
 import sys
 
 from transmogrifai_tpu.evaluators import {evaluator}
-from transmogrifai_tpu.utils.platform import respect_jax_platforms
 from transmogrifai_tpu.params import OpParams
 from transmogrifai_tpu.runner import RunTypes, WorkflowRunner
+from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
 
 from workflow import make_reader, make_workflow
 
 
 def main() -> int:
-    respect_jax_platforms()  # honor JAX_PLATFORMS over site plugins
+    enable_compile_cache()
     run_type = sys.argv[1] if len(sys.argv) > 1 else RunTypes.TRAIN
     params = OpParams.from_file("params.json")
     runner = WorkflowRunner(

@@ -78,8 +78,8 @@ def _binary_curves(y, score, yhat, w):
 @jax.jit
 def _binary_scalars(y, score, yhat, w):
     """All scalar metrics as ONE [6] vector so the host pays a single
-    device->host sync (scalar-by-scalar pulls round-trip per value on
-    tunneled devices)."""
+    device->host sync (scalar-by-scalar pulls block the host once per
+    value)."""
     c = _binary_curves(y, score, yhat, w)
     return jnp.stack([c["au_roc"], c["au_pr"], c["tp"], c["fp"], c["tn"],
                       c["fn"]])

@@ -10,10 +10,10 @@ is a bin-edge search over the fitted splits followed by a one-hot expand:
 The XLA path materializes the searchsorted gather + one-hot as separate
 HLOs; at Criteo widths (13 numeric columns x ~34-bucket tree splits inside
 one fused FE program) the one-hot scatter is pure VPU work that this kernel
-keeps entirely in VMEM: one grid step = one row block, the split vector
-(tiny, <= a few hundred f32) replicated into VMEM, bin index by comparison
-count and the one-hot written as a single iota-compare — no intermediate
-index array ever reaches HBM.
+keeps entirely in VMEM: one grid step = one row block laid out with rows on
+the lane axis, the split vector (tiny, <= a few hundred f32) in SMEM, bin
+index by comparison count and the one-hot written as a single iota-compare
+— no intermediate index array ever reaches HBM.
 
 Engine selection mirrors the sorted-histogram kernel
 (``ops/sorted_hist_pallas.py``): ``TRANSMOGRIFAI_BUCKET_ENGINE`` picks
@@ -74,26 +74,25 @@ def bucketize_block_xla(values, mask, splits: np.ndarray,
     return jax.nn.one_hot(slot, width, dtype=jnp.float32)
 
 
-def _kernel(v_ref, m_ref, sp_ref, out_ref, *, k: int, width: int,
+def _kernel(sp_ref, v_ref, m_ref, out_ref, *, k: int, width: int,
             invalid_slot: int, null_slot: int):
-    """One grid step = one row block, fully VMEM-resident.
+    """One grid step = one row block, rows on the LANE axis.
 
     The bin-edge search is a comparison COUNT against the inner splits
     (sum over j of v >= inner[j] == searchsorted side="right"), the
     range/null slot rules match the XLA path exactly, and the one-hot is
-    a single [R, width] iota compare — all VPU element-wise work."""
-    v = v_ref[0]                      # [R] f32
-    m = m_ref[0]                      # [R] f32
-    sp = sp_ref[...]                  # [k+1] f32 (fitted splits, +-inf ends)
-    R = v.shape[0]
-    idx = jnp.zeros((R,), jnp.int32)
+    a single [width, R] sublane-iota compare — all VPU element-wise work
+    on lane-dense tiles. The splits are scalars read from SMEM."""
+    v = v_ref[...]                    # [1, R] f32
+    m = m_ref[...]                    # [1, R] f32
+    idx = jnp.zeros(v.shape, jnp.int32)
     for j in range(1, k):             # static unroll over the inner splits
-        idx = idx + (v >= sp[j]).astype(jnp.int32)
-    in_range = (v >= sp[0]) & (v <= sp[k])
+        idx = idx + (v >= sp_ref[j]).astype(jnp.int32)
+    in_range = (v >= sp_ref[0]) & (v <= sp_ref[k])
     slot = jnp.where(in_range, idx, invalid_slot)
     slot = jnp.where(m > 0, slot, null_slot)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (R, width), 1)
-    out_ref[0] = (lanes == slot[:, None]).astype(jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (width, v.shape[1]), 0)
+    out_ref[...] = (rows == slot).astype(jnp.float32)
 
 
 @functools.partial(
@@ -105,31 +104,32 @@ def _bucketize_pallas(values, mask, splits, *, k: int, track_invalid: bool,
     width = k + int(track_invalid) + int(track_nulls)
     invalid_slot = k if track_invalid else width
     null_slot = k + int(track_invalid) if track_nulls else width
-    R = min(_BLOCK_ROWS, max(int(n), 1))
-    n_pad = int(np.ceil(max(n, 1) / R) * R)
+    R = _BLOCK_ROWS
+    nb = max(-(-n // R), 1)
+    n_pad = nb * R
     # padded rows carry mask 0 -> null_slot (or all-zeros): harmless, and
-    # sliced back off below
+    # sliced back off below. The kernel computes the TRANSPOSED block
+    # [width, n_pad] so every tile is lane-dense (Mosaic's (8, 128) block
+    # rule: a (1, R) block's sublane dim equals the array's, R % 128 == 0)
     v = jnp.pad(values.astype(jnp.float32), (0, n_pad - n))
     m = jnp.pad(mask.astype(jnp.float32), (0, n_pad - n))
-    nb = n_pad // R
-    out = pl.pallas_call(
+    out_t = pl.pallas_call(
         functools.partial(_kernel, k=k, width=width,
                           invalid_slot=invalid_slot, null_slot=null_slot),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, R), lambda i: (i, 0),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, R), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, R), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k + 1,), lambda i: (0,),
+            pl.BlockSpec((1, R), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, R, width), lambda i: (i, 0, 0),
+        out_specs=pl.BlockSpec((width, R), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((nb, R, width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((width, n_pad), jnp.float32),
         interpret=interpret,
-    )(v.reshape(nb, R), m.reshape(nb, R), splits)
-    return out.reshape(n_pad, width)[:n]
+    )(splits, v.reshape(1, n_pad), m.reshape(1, n_pad))
+    return out_t[:, :n].T
 
 
 def bucketize_block(values, mask, splits: np.ndarray, track_invalid: bool,

@@ -47,7 +47,7 @@ def _native():
     if not _native_tried:
         _native_tried = True
         from transmogrifai_tpu.native import build_and_load
-        lib = build_and_load("text_hashing.cpp", "texthash")
+        lib = build_and_load("texthash")
         if lib is not None:
             import ctypes
             i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")

@@ -27,9 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from transmogrifai_tpu.parallel.mesh import (
-    DATA_AXIS, MeshContext, shard_map_compat,
-)
+from transmogrifai_tpu.parallel.mesh import DATA_AXIS, MeshContext
 
 __all__ = ["tree_psum", "tree_pmax", "tree_pmin", "mesh_reduce_stats",
            "reduce_host_metrics", "CollectiveTimeoutError",
@@ -175,8 +173,8 @@ def mesh_reduce_stats(ctx: MeshContext,
     def shard_fn(*args):
         return combine(local_stats_fn(*args))
 
-    fn = shard_map_compat(shard_fn, mesh=ctx.mesh, in_specs=in_specs,
-                          out_specs=P())
+    fn = jax.shard_map(shard_fn, mesh=ctx.mesh, in_specs=in_specs,
+                       out_specs=P())
     if jax.process_count() <= 1:
         return fn(*row_sharded_args)
     # block inside the deadline: jit dispatch is async, so only a

@@ -28,7 +28,7 @@ __all__ = [
     "MeshContext", "make_mesh", "use_mesh", "current_mesh", "row_sharding",
     "replicated", "pad_rows", "shard_rows", "num_data_shards",
     "pad_and_shard_rows", "shard_training_rows", "fold_axis_on_model",
-    "shard_stacked_training_rows", "shard_map_compat",
+    "shard_stacked_training_rows",
 ]
 
 DATA_AXIS = "data"
@@ -176,26 +176,6 @@ def pad_and_shard_rows(arr, pad_value=0.0):
             import jax.numpy as jnp
             arr = jnp.pad(arr, width, constant_values=pad_value)
     return shard_rows(arr)
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: >= 0.5 exposes it top-level
-    with ``check_vma``; older releases ship it as
-    ``jax.experimental.shard_map.shard_map`` with the equivalent knob named
-    ``check_rep``. Every explicit-collective program in the framework (tree
-    histogram all-reduce, monoid stats reduction) routes through here so
-    the distributed substrate works on both."""
-    kw = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
 
 
 def fold_axis_on_model(k: int) -> bool:

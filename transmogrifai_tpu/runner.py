@@ -577,6 +577,8 @@ def main(argv=None):
                          "timeline) here")
     args = ap.parse_args(argv)
     import importlib
+    from transmogrifai_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mod, _, attr = args.workflow.partition(":")
     runner: WorkflowRunner = getattr(importlib.import_module(mod), attr)
     result = runner.run(args.run_type, OpParams.from_file(args.params),

@@ -8,9 +8,13 @@ identical in every replica that loaded the same bytes — so the compile
 work is shareable. Two cooperating mechanisms:
 
 1. **shared XLA compilation cache** (the heavy lifting):
-   :meth:`ArtifactStore.enable_shared_compilation_cache` points jax's
-   persistent compilation cache at ``<root>/_artifacts/xla_cache``
-   (thresholds dropped so every serving program caches). The FIRST
+   :meth:`ArtifactStore.enable_shared_compilation_cache` turns on jax's
+   persistent compilation cache through ``utils/compile_cache.py`` — the
+   one directory every process of this checkout shares (or the one
+   ``JAX_COMPILATION_CACHE_DIR`` names), thresholds dropped so every
+   serving program caches. A cache under the model root would move with
+   every ``mkdtemp`` model dir and never hit: the path is part of the
+   key. The FIRST
    process to compile a ``(fingerprint, layer, bucket)`` program pays
    XLA; every other replica's warmup **maps** the serialized executable
    from disk. This is AOT serialization by the backend's own format —
@@ -56,7 +60,6 @@ class ArtifactStore:
         #: subdir so ``register_dir`` scans never mistake it for a model
         self.root = root
         self.dir = os.path.join(root, ARTIFACTS_DIRNAME)
-        self.cache_dir = os.path.join(self.dir, "xla_cache")
         self._cache_enabled = False
 
     # -- manifests -----------------------------------------------------------
@@ -107,40 +110,22 @@ class ArtifactStore:
             return []
 
     # -- shared XLA compilation cache ----------------------------------------
-    def enable_shared_compilation_cache(self) -> bool:
-        """Point jax's persistent compilation cache at the shared
-        artifact dir (idempotent). Must run before the process's first
-        serving compile to be effective. Returns False (with a warning)
-        when this jax build refuses — the stack still works, each
-        replica just compiles for itself."""
-        if self._cache_enabled:
-            return True
-        try:
-            import jax
-            os.makedirs(self.cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", self.cache_dir)
-            # serving programs are small and compile fast — cache them
-            # all (the default thresholds exist for interactive use)
-            for knob, value in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-                try:
-                    jax.config.update(knob, value)
-                except Exception:  # noqa: BLE001 — knob absent on this jax (failure-ok)
-                    pass
-            self._cache_enabled = True
-            return True
-        except Exception as e:  # noqa: BLE001 — cache is an optimization, not a dependency
-            warnings.warn(
-                f"artifact store: shared compilation cache unavailable "
-                f"({type(e).__name__}: {e}); every replica compiles for "
-                "itself", RuntimeWarning)
-            return False
+    def enable_shared_compilation_cache(self) -> str:
+        """Turn on jax's persistent compilation cache for this replica
+        (idempotent), every serving program cached. Must run before the
+        process's first serving compile to be effective. Returns the
+        cache directory (``utils/compile_cache.py`` decides it)."""
+        from transmogrifai_tpu.utils.compile_cache import (
+            enable_compile_cache,
+        )
+        self._cache_enabled = True
+        return enable_compile_cache(cache_everything=True)
 
     def to_json(self) -> dict:
+        from transmogrifai_tpu.utils.compile_cache import compile_cache_dir
         cache_entries = 0
         try:
-            cache_entries = sum(1 for n in os.listdir(self.cache_dir)
+            cache_entries = sum(1 for n in os.listdir(compile_cache_dir())
                                 if n.endswith("-cache"))
         except OSError:
             pass

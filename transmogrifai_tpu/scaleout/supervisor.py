@@ -35,6 +35,7 @@ table; this module wires the two together:
 
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import subprocess
@@ -49,7 +50,21 @@ from transmogrifai_tpu.scaleout.wire import AdminError, ReplicaStates
 from transmogrifai_tpu.utils.events import events
 from transmogrifai_tpu.utils.faults import fault_point
 
-__all__ = ["ReplicaSupervisor", "RollingSwapError", "ScaleoutMetrics"]
+__all__ = ["ReplicaSupervisor", "RollingSwapError", "ScaleoutMetrics",
+           "ChipCapacityError", "host_tpu_chips"]
+
+
+class ChipCapacityError(RuntimeError):
+    """The host cannot give every requested replica process a chip of
+    its own (or this process already holds the chips)."""
+
+
+def host_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files.
+    JAX-free on purpose: the supervisor must never open the chips its
+    workers need."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 class RollingSwapError(RuntimeError):
@@ -171,6 +186,42 @@ class ReplicaSupervisor:
             cmd += ["--model-dir", self.model_dir]
         return cmd + self.worker_args
 
+    def _check_chip_capacity(self, replicas: int) -> None:
+        """One process per chip: a TPU belongs to the process that opened
+        it, and a worker is given no chip assignment of its own — each
+        opens every chip on the host. So on a TPU host, workers that run
+        on the chip are refused (a) from a parent that has itself
+        initialized the TPU backend (it holds the chips; every worker
+        would fail or hang) and (b) in any number above one. CPU-pinned
+        workers (``JAX_PLATFORMS=cpu``) and hosts without a TPU are not
+        constrained. Raises before anything is spawned."""
+        platforms = self.worker_env.get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+        chips = host_tpu_chips()
+        if platforms == "cpu" or chips == 0:
+            return
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            from jax._src import xla_bridge
+            if xla_bridge.backends_are_initialized() \
+                    and jax.default_backend() == "tpu":
+                raise ChipCapacityError(
+                    "scaleout: this process has initialized the TPU "
+                    "backend and holds the host's chips; replica workers "
+                    "started from it would fail or hang at start-up. "
+                    "Start the supervisor from a process that stays off "
+                    "jax (e.g. `python -m transmogrifai_tpu.cli scaleout "
+                    "serve`), or pin the workers to the CPU "
+                    "(worker_env={'JAX_PLATFORMS': 'cpu'}).")
+        if replicas > 1:
+            raise ChipCapacityError(
+                f"scaleout: {replicas} chip replicas requested on a host "
+                f"with {chips} TPU chip(s), but workers get no per-process "
+                "chip assignment — each opens every chip, so the host can "
+                "give a chip to ONE worker process. Use replicas=1 (one "
+                "process can drive all chips), or pin the workers to the "
+                "CPU (worker_env={'JAX_PLATFORMS': 'cpu'}).")
+
     def _spawn(self, replica_id: str, respawn_of: bool = False) -> _Proc:
         log_dir = os.path.join(self.state_dir, wire.HEARTBEAT_DIRNAME)
         os.makedirs(log_dir, exist_ok=True)
@@ -243,6 +294,7 @@ class ReplicaSupervisor:
 
     # -- lifecycle ------------------------------------------------------------
     def start(self, wait_ready: bool = True) -> "ReplicaSupervisor":
+        self._check_chip_capacity(self.desired_replicas)
         for _ in range(self.desired_replicas):
             self._spawn(self._next_id())
         if wait_ready:
@@ -417,6 +469,7 @@ class ReplicaSupervisor:
         with self._lock:
             current = len(self._procs)
         if n > current:
+            self._check_chip_capacity(n)
             self.metrics.count("scale_ups")
             events.emit("scaleout.scale", direction="up",
                         fromReplicas=current, toReplicas=n)

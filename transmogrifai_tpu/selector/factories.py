@@ -6,8 +6,7 @@ BinaryClassificationModelSelector.scala:49-272``,
 and ``selector/DefaultSelectorParams.scala`` — ``.withCrossValidation()`` /
 ``.withTrainValidationSplit()`` assembling default candidates + grids.
 
-Default candidate sets grow with the model zoo (trees land in models/trees);
-grid values mirror DefaultSelectorParams where the family exists.
+Grid values mirror DefaultSelectorParams where the family exists.
 """
 
 from __future__ import annotations
@@ -18,8 +17,13 @@ from transmogrifai_tpu.evaluators import (
     OpBinaryClassificationEvaluator, OpMultiClassificationEvaluator,
     OpRegressionEvaluator,
 )
+from transmogrifai_tpu.models.extras import OpNaiveBayes
 from transmogrifai_tpu.models.linear import (
     OpLinearRegression, OpLinearSVC, OpLogisticRegression,
+)
+from transmogrifai_tpu.models.trees import (
+    OpGBTClassifier, OpGBTRegressor, OpRandomForestClassifier,
+    OpRandomForestRegressor,
 )
 from transmogrifai_tpu.selector.model_selector import ModelSelector
 from transmogrifai_tpu.selector.splitters import (
@@ -47,51 +51,28 @@ def _svc_grid():
 
 
 def _default_binary_candidates():
-    cands = [(OpLogisticRegression(), _lr_grid()),
-             (OpLinearSVC(), _svc_grid())]
-    try:
-        from transmogrifai_tpu.models.trees import (
-            OpGBTClassifier, OpRandomForestClassifier,
-        )
-        cands.append((OpRandomForestClassifier(), [
-            {"num_trees": 50, "max_depth": d} for d in (6, 12)]))
-        cands.append((OpGBTClassifier(), [
-            {"num_rounds": 50, "max_depth": d} for d in (3, 6)]))
-    except ImportError:
-        pass
-    return cands
+    return [(OpLogisticRegression(), _lr_grid()),
+            (OpLinearSVC(), _svc_grid()),
+            (OpRandomForestClassifier(), [
+                {"num_trees": 50, "max_depth": d} for d in (6, 12)]),
+            (OpGBTClassifier(), [
+                {"num_rounds": 50, "max_depth": d} for d in (3, 6)])]
 
 
 def _default_multi_candidates():
     # reference multiclass defaults: LR + RF + DT + NB
-    cands = [(OpLogisticRegression(), _lr_grid())]
-    try:
-        from transmogrifai_tpu.models.trees import OpRandomForestClassifier
-        cands.append((OpRandomForestClassifier(), [
-            {"num_trees": 50, "max_depth": d} for d in (6, 12)]))
-    except ImportError:
-        pass
-    try:
-        from transmogrifai_tpu.models.extras import OpNaiveBayes
-        cands.append((OpNaiveBayes(), [{}]))
-    except ImportError:
-        pass
-    return cands
+    return [(OpLogisticRegression(), _lr_grid()),
+            (OpRandomForestClassifier(), [
+                {"num_trees": 50, "max_depth": d} for d in (6, 12)]),
+            (OpNaiveBayes(), [{}])]
 
 
 def _default_regression_candidates():
-    cands = [(OpLinearRegression(), _lr_grid())]
-    try:
-        from transmogrifai_tpu.models.trees import (
-            OpGBTRegressor, OpRandomForestRegressor,
-        )
-        cands.append((OpRandomForestRegressor(), [
-            {"num_trees": 50, "max_depth": d} for d in (6, 12)]))
-        cands.append((OpGBTRegressor(), [
-            {"num_rounds": 50, "max_depth": d} for d in (3, 6)]))
-    except ImportError:
-        pass
-    return cands
+    return [(OpLinearRegression(), _lr_grid()),
+            (OpRandomForestRegressor(), [
+                {"num_trees": 50, "max_depth": d} for d in (6, 12)]),
+            (OpGBTRegressor(), [
+                {"num_rounds": 50, "max_depth": d} for d in (3, 6)])]
 
 
 class BinaryClassificationModelSelector:

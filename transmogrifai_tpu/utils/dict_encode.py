@@ -60,7 +60,7 @@ def _native():
     if not _native_tried:
         _native_tried = True
         from transmogrifai_tpu.native import build_and_load
-        lib = build_and_load("dict_encode.cpp", "dictenc")
+        lib = build_and_load("dictenc")
         if lib is not None:
             import ctypes
             lib.dict_encode.argtypes = [

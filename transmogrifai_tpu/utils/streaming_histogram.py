@@ -31,7 +31,7 @@ def _lib():
     if not _LIB_TRIED:
         _LIB_TRIED = True
         from transmogrifai_tpu import native
-        lib = native.build_and_load("streaming_histogram.cpp", "shist")
+        lib = native.build_and_load("shist")
         if lib is not None:
             lib.shist_new.restype = ctypes.c_void_p
             lib.shist_new.argtypes = [ctypes.c_int, ctypes.c_int, ctypes.c_int]
