@@ -9,9 +9,23 @@ full scoring pass) three ways:
 - ``spans``  — recorder enabled (the default production state): the full
   hierarchical span tree records through ingest, every DAG stage, the
   sweep, and the fused layer dispatches.
-- ``export`` — spans + a ``jax.profiler`` device trace around the run +
-  the merged chrome-trace JSON export (``AppMetrics.export_chrome_trace``)
-  — the ``--trace-out`` / ``cli profile`` configuration.
+- ``export`` — spans + a ``jax.profiler`` device trace around the run
+  (``profiler.reset(trace_dir=...)``: the ``TRACE_ANCHOR`` annotation, the
+  phase drains) + ``finalize()`` reading it back (``trace_device_events``
+  -> phase ``device_s``, the ``(module, scope)`` table ``device_scopes``,
+  the device-window spans' ``device_s``) + the merged chrome-trace JSON
+  export (``AppMetrics.export_chrome_trace``) — the ``--trace-out`` /
+  ``cli profile`` configuration.
+
+What is on in ``spans`` and ``export`` alike (always-on, host clock): the
+span tree (``stage.fit``, ``fe.fused``, ``selector.sweep`` /
+``sweep.dispatch`` / ``sweep.family`` / ``sweep.tree_group`` /
+``sweep.settle`` / ``selector.refit`` ...), the ``sweep.device`` and
+``refit.device`` stamps (one ``is_ready`` + one clock read a sweep
+program), and the one ``jax.monitoring`` listener
+(``devicewatch.CompileTelemetry``) recording ``compile.program:<site>`` /
+``compile.cache_load:<site>`` spans and the ``SweepCounters`` compile
+counts. docs/OBSERVABILITY.md names each.
 
 The three configurations run INTERLEAVED for ``TRIALS`` rounds after one
 shared warmup (the warmup pays all XLA compiles; fused layer programs
@@ -132,9 +146,8 @@ def main() -> int:
         span_counts.append(len(recorder.spans))
 
     def export_on():
-        # a FRESH xplane dir per trial: finalize() globs the whole
-        # directory, so reusing one would re-parse (and re-attribute)
-        # every earlier trial's protos in later trials
+        # a FRESH trace dir per trial: each trial reads back its own
+        # trace file and nothing of an earlier trial's
         trial_ix["n"] += 1
         recorder.enable(True)
         profiler.reset(app_name="bench_observability",

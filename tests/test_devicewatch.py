@@ -362,7 +362,8 @@ def test_stalled_settle_autopsies_and_keeps_one_sync(dw, tmp_path,
     def stall_settle_once(x):
         import sys as _sys
         if not state["stalled"] \
-                and _sys._getframe(1).f_code.co_name == "_settle":
+                and _sys._getframe(1).f_code.co_name == "_stamp_device":
+            # the settle's one barrier: walked chunk by chunk
             state["stalled"] = True
             time.sleep(0.5)  # past the 0.15s stall deadline
         return real(x)
@@ -400,24 +401,123 @@ def test_compile_telemetry_attribution_and_slow_event(monkeypatch):
     from transmogrifai_tpu.utils.tracing import recorder
     monkeypatch.setenv("TRANSMOGRIFAI_SLOW_COMPILE_S", "0.5")
     tele = CompileTelemetry()
-    with tele.building("sweep.family:OpLR_0"):
+    tele._listening = True   # a throwaway: never hears the real events
+    ev = CompileTelemetry.COMPILE_EVENT
+    with tele.building("sweep.family:OpLR_0", family="OpLR_0"):
         assert tele.in_progress == 1
-        tele._on_event("/jax/core/compile/backend_compile_duration", 0.2)
-        tele._on_event("/jax/core/compile/backend_compile_duration", 0.9)
+        tele._on_event(ev, 0.2)
+        tele._on_event(ev, 0.9, fun_name="train")
         tele._on_event("/jax/other/event", 99.0)  # ignored
-    tele._on_event("/jax/core/compile/backend_compile_duration", 0.1)
+        with tele.building("bin_data"):   # innermost site, family kept
+            tele._on_event(ev, 0.3)
+    tele._on_event(ev, 0.1)
     assert tele.in_progress == 0
     doc = tele.to_json()
-    assert doc["programs"] == 3
+    assert doc["programs"] == 4 and doc["cacheLoads"] == 0
     assert doc["bySite"]["sweep.family:OpLR_0"]["programs"] == 2
+    assert doc["bySite"]["bin_data"]["programs"] == 1
     assert doc["bySite"]["unattributed"]["programs"] == 1
+    assert tele.family_compiles() == {"OpLR_0": 3}
     assert doc["maxWallSeconds"] == pytest.approx(0.9)
     assert doc["slowCompiles"] == 1
     slow = [e for e in events.tail() if e["kind"] == "compile.slow"]
     assert slow and slow[-1]["site"] == "sweep.family:OpLR_0"
-    spans = [s for s in recorder.spans if s.name == "compile.program"]
-    assert len(spans) >= 3
+    # the site rides in the span's NAME (consumers that keep names only)
+    spans = [s for s in recorder.spans
+             if s.name.startswith("compile.program:")]
+    assert len(spans) >= 4
+    assert spans[-1].name == "compile.program:unattributed"
     assert spans[-1].wall_s == pytest.approx(0.1, abs=0.01)
+    assert any(s.name == "compile.program:sweep.family:OpLR_0"
+               and s.attrs.get("program") == "train" for s in spans)
+
+
+def test_compile_telemetry_tells_cache_loads_from_compiles():
+    """On this JAX a persistent-cache hit fires the backend-compile
+    duration event too, preceded on the same thread by
+    ``/jax/compilation_cache/cache_hits``: a duration event that follows
+    a hit is a LOAD, kept apart from compiles in every count and named
+    ``compile.cache_load:<site>``."""
+    from transmogrifai_tpu.utils.devicewatch import CompileTelemetry
+    from transmogrifai_tpu.utils.tracing import recorder
+    tele = CompileTelemetry()
+    tele._listening = True   # a throwaway: never hears the real events
+    ev, hit = CompileTelemetry.COMPILE_EVENT, CompileTelemetry.CACHE_HIT_EVENT
+    n0 = len([s for s in recorder.spans
+              if s.name == "compile.cache_load:predict:M"])
+    with tele.building("predict:M", family="fam"):
+        tele._on_cache_event(hit)
+        tele._on_event(ev, 0.05)      # the load the hit announced
+        tele._on_event(ev, 0.40)      # a real compile
+        tele._on_cache_event("/jax/compilation_cache/cache_misses")
+        tele._on_event(ev, 0.20)      # a miss is a compile
+    doc = tele.to_json()
+    assert doc["programs"] == 2 and doc["cacheLoads"] == 1
+    assert doc["wallSeconds"] == pytest.approx(0.6)
+    site = doc["bySite"]["predict:M"]
+    assert site == {"programs": 2, "wallSeconds": pytest.approx(0.6),
+                    "cacheLoads": 1, "loadSeconds": pytest.approx(0.05)}
+    assert tele.family_compiles() == {"fam": 2}   # loads are no compiles
+    assert [r["cacheLoad"] for r in tele.records] == [True, False, False]
+    loads = [s for s in recorder.spans
+             if s.name == "compile.cache_load:predict:M"]
+    assert len(loads) == n0 + 1
+
+
+def test_real_cache_hit_event_order_and_site(tmp_path):
+    """The order of the two events on the installed JAX, established with
+    a real persistent cache in ``tmp_path``: first run compiles (and
+    writes), second run (in-memory caches cleared) loads — classified
+    apart, each under its ``building`` site."""
+    import time as _time
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+    compile_telemetry.ensure_listener()
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    c = float(_time.time())   # run-unique HLO
+
+    def unique_program(a):
+        return a * c + 1.0
+
+    def site(name):
+        return dict(compile_telemetry.to_json()["bySite"].get(
+            name, {"programs": 0, "cacheLoads": 0}))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        x = jnp.ones(3)   # made outside: its own small programs compile here
+        before_c, before_l = site("test.compile"), site("test.load")
+        with compile_telemetry.building("test.compile"):
+            jax.jit(unique_program)(x).block_until_ready()
+        if site("test.compile")["programs"] == before_c["programs"]:
+            pytest.skip("jax.monitoring backend-compile events unavailable")
+        if not any(tmp_path.iterdir()):
+            pytest.skip("this backend wrote no persistent cache entry")
+        jax.clear_caches()
+        with compile_telemetry.building("test.load"):
+            jax.jit(unique_program)(x).block_until_ready()
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    after_c, after_l = site("test.compile"), site("test.load")
+    assert after_c["programs"] == before_c["programs"] + 1
+    assert after_c["cacheLoads"] == before_c["cacheLoads"]
+    assert after_l["cacheLoads"] == before_l["cacheLoads"] + 1
+    assert after_l["programs"] == before_l["programs"]
+    from transmogrifai_tpu.utils.tracing import recorder
+    names = {s.name for s in recorder.spans}
+    assert "compile.program:test.compile" in names
+    assert "compile.cache_load:test.load" in names
 
 
 def test_compile_telemetry_real_sweep_series(monkeypatch):
@@ -446,6 +546,87 @@ def test_compile_telemetry_real_sweep_series(monkeypatch):
     out = build_registry(include_app=False).render()
     assert "transmogrifai_compile_programs_total{site=" in out
     assert "transmogrifai_compile_wall_seconds_total{site=" in out
+
+
+def test_train_with_tree_winner_compiles_nothing_unattributed(monkeypatch):
+    """Every program a ``Workflow.train()`` builds — feature engineering,
+    SanityChecker, the sweep's operands and families, binning, the winner's
+    refit, its predict program, the evaluators — is built inside a
+    ``building(site)`` block: no compile or cache-load span of the train
+    is named ``...:unattributed``, the winner's predict program is named
+    (``jit(predict_arrays)``, not a lambda) and attributed to its model
+    class, and the per-family compile counts come from the same
+    listener."""
+    import numpy as np
+
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.features.builder import FeatureBuilder
+    from transmogrifai_tpu.models.linear import OpLogisticRegression
+    from transmogrifai_tpu.models.trees import OpGBTClassifier
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.preparators.sanity_checker import SanityChecker
+    from transmogrifai_tpu.selector import (
+        BinaryClassificationModelSelector, DataSplitter,
+    )
+    from transmogrifai_tpu.types import feature_types as ft
+    from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+    from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
+    from transmogrifai_tpu.utils.tracing import recorder
+    from transmogrifai_tpu.workflow import Workflow
+    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
+    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
+    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "1")
+    rng = np.random.default_rng(0)
+    n = 600
+    X = rng.normal(size=(n, 4))
+    # an interaction no linear model finds: the tree family wins
+    y = ((X[:, 0] * X[:, 1] + 0.3 * X[:, 2]) > 0).astype(np.float64)
+    cols = {f"x{i}": (ft.Real, X[:, i]) for i in range(4)}
+    cols["label"] = (ft.RealNN, y)
+    frame = fr.HostFrame.from_dict(cols)
+    feats = FeatureBuilder.from_frame(frame, response="label")
+    label = feats.pop("label")
+    checked = label.transform_with(
+        SanityChecker(), transmogrify(list(feats.values())))
+    # grid values no other test uses: these programs compile here
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        n_folds=2, seed=1, models_and_parameters=[
+            (OpLogisticRegression(max_iter=9), [{"reg_param": 0.0123}]),
+            (OpGBTClassifier(), [{"max_depth": 2, "num_rounds": 3},
+                                 {"max_depth": 3, "num_rounds": 3}]),
+        ], splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
+    pred = label.transform_with(sel, checked)
+    compile_telemetry.ensure_listener()
+    profiler.reset()
+    model = Workflow().set_input_frame(frame).set_result_features(
+        pred).train()
+    assert model.selector_summary().best_model_type == "OpGBTClassifier"
+    built = [s for s in recorder.spans if s.name.startswith("compile.")]
+    if not built:
+        pytest.skip("jax.monitoring backend-compile events unavailable")
+    assert [s.name for s in built if s.name.endswith(":unattributed")] == []
+    assert all(s.name.partition(":")[0] in ("compile.program",
+                                            "compile.cache_load")
+               for s in built)
+    predict = [s for s in built
+               if s.attrs.get("program") == "jit(predict_arrays)"]
+    assert predict and all(
+        s.attrs["site"] == "predict:TreeEnsembleModel" for s in predict)
+    assert not any("lambda" in str(s.attrs.get("program")) for s in built)
+    sites = {s.attrs["site"] for s in built}
+    assert "fe.fused" in sites
+    assert any(site.startswith("sweep.tree:OpGBTClassifier")
+               for site in sites)
+    # SweepCounters reads the same listener: what compiled for the tree
+    # family (sweep + refit) is what its sites' compile spans count
+    fam = "OpGBTClassifier_1"
+    by_site = compile_telemetry.to_json()["bySite"]
+    assert sweep_counters.to_json()[fam]["compiles"] <= sum(
+        v["programs"] for v in by_site.values())
+    n_family_spans = sum(
+        1 for s in built if s.name.startswith("compile.program:")
+        and s.attrs["site"].endswith(fam))
+    assert sweep_counters.to_json()[fam]["compiles"] >= n_family_spans
 
 
 def test_analyze_program_cost_report():

@@ -94,45 +94,150 @@ def test_span_add_retroactive_and_aggregate():
     assert agg["queue_wait"]["maxWallSeconds"] == pytest.approx(0.5)
 
 
-def test_recorder_device_attribution_innermost():
+def _recorded_trace_dir(tmp_path):
+    """A trace directory holding the small trace recorded on one TPU v5e
+    chip (tests/fixtures/README.md): one run of ``jit_prog`` with ops
+    staged under ``tree.L0/hist`` and ``tree.leaf`` and ops with no scope,
+    and the host annotation ``anchor.mark`` 0.3277 ms before its first
+    op."""
+    import shutil
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    shutil.copy(os.path.join(REPO, "tests", "fixtures",
+                             "tpu_v5e_scoped.xplane.pb"), d)
+    return str(tmp_path)
+
+
+def test_trace_reader_names_module_op_and_scope(tmp_path, monkeypatch):
+    """The program's trace reader on a recorded TPU trace: every leaf op
+    comes back with its module, op kind and named-scope path (read from
+    the event METADATA's ``tf_op``, which ``ProfileData`` does not yield),
+    placed on the host's clock by the anchor annotation; the (module,
+    scope) table sums them and span windows take what ran inside."""
+    from transmogrifai_tpu.utils import profiling
+    from transmogrifai_tpu.utils.profiling import (
+        AppMetrics, scope_of, trace_device_events,
+    )
     from transmogrifai_tpu.utils.tracing import SpanRecorder
+    d = _recorded_trace_dir(tmp_path)
+    monkeypatch.setattr(profiling, "TRACE_ANCHOR", "anchor.mark")
+    events = trace_device_events(d, anchor_epoch_s=1000.0)
+    assert len(events) == 10
+    assert {e.module for e in events} == {"jit_prog"}
+    by_op = {e.op: e for e in events}
+    assert by_op["convolution_bitcast_fusion"].scope == \
+        "tree.L0/hist/bcs,bcd->bsd"
+    assert by_op["multiply_reduce_fusion"].scope == "tree.leaf"
+    assert by_op["reduce-window"].scope == ""   # the compiler's own op
+    # on the host's clock: the first op starts 0.3277 ms after the anchor
+    assert min(e.start_s for e in events) == pytest.approx(
+        1000.0 + 0.3277e-3, abs=1e-6)
+    # without an anchor the events stay on the trace's own clock
+    raw = trace_device_events(d)
+    assert min(e.start_s for e in raw) == pytest.approx(0.041372, abs=1e-5)
+    # an anchor that was asked for and is not in the trace is an error
+    monkeypatch.setattr(profiling, "TRACE_ANCHOR", "no.such.mark")
+    with pytest.raises(ValueError, match="no.such.mark"):
+        trace_device_events(d, anchor_epoch_s=1000.0)
+
+    m = AppMetrics()
+    total = m.attribute_device_scopes(events)
+    assert total == pytest.approx(sum(e.duration_s for e in events))
+    assert total == pytest.approx(58.95e-6, rel=1e-3)   # the module's run
+    hist = m.device_scopes[("jit_prog", "tree.L0/hist/bcs,bcd->bsd")]
+    assert hist[0] == pytest.approx(6.790e-6, rel=1e-3) and hist[1] == 1
+    assert m.device_scopes[("jit_prog", "")][1] == 8
+    doc = m.to_json()["deviceScopes"]
+    assert doc[0]["scope"] == "" and doc[0]["module"] == "jit_prog"
+    assert {"module", "scope", "deviceSeconds", "opCount"} == set(doc[0])
+    assert "tree.leaf" in m.pretty() and "(unscoped)" in m.pretty()
+
+    # spans: only device_window spans take device seconds, by containment
     rec = SpanRecorder()
-    rec.add("outer", 0.0, 10.0, stage_uid="o", stage_cls="O")
-    rec.add("inner", 2.0, 4.0, stage_uid="i", stage_cls="I")
-    total = rec.attribute_device_events(
-        [(2.5, 1.0, "op_a"),   # midpoint 3.0 -> inner (innermost)
-         (8.0, 1.0, "op_b"),   # midpoint 8.5 -> outer only
-         (20.0, 1.0, "op_c")])  # outside every span -> unattributed
-    assert total == pytest.approx(2.0)
-    table = rec.stage_table()
-    assert table["I (i)"]["deviceSeconds"] == pytest.approx(1.0)
-    assert table["O (o)"]["deviceSeconds"] == pytest.approx(1.0)
+    t0 = min(e.start_s for e in events)
+    rec.add("sweep.device", t0, t0 + 10e-6, family="rf",
+            device_window=True)                              # hist + part
+    rec.add("sweep.settle", t0 - 1.0, t0 + 1.0, device_window=True)
+    rec.add("sweep.family", t0 - 1.0, t0 + 1.0)             # a dispatch
+    rec.attribute_device_windows(events)
+    spans = {s.name: s for s in rec.spans}
+    assert spans["sweep.device"].device_s == pytest.approx(6.80e-6,
+                                                           rel=1e-2)
+    assert spans["sweep.settle"].device_s == pytest.approx(total)
+    assert spans["sweep.family"].device_s == 0.0
+
+    assert scope_of("jit(f)/vmap(vmap(jit(g)))/while/body/closed_call/"
+                    "vmap(jit(grow_tree))/tree.L3/split/jit(cumsum)/"
+                    "cumsum") == "tree.L3/split"
+    assert scope_of("jit(f)/vmap(vmap(tree.predict))/vmap(jit(clip))/max"
+                    ) == "tree.predict"
+    assert scope_of("") == ""
+
+
+def test_stage_table_takes_device_seconds_from_stage_scopes():
+    """Inside a fused FE program a stage's ops are staged under the scope
+    ``<operation>[<uid>]``: the stage table's device seconds come from the
+    (module, scope) table by that uid, not from any span's window."""
+    from transmogrifai_tpu.utils.profiling import profiler
+    from transmogrifai_tpu.utils.tracing import span
+    profiler.reset()
+    with span("stage.transform", stage_uid="Vec_1", stage_cls="Vec"):
+        pass
+    scopes = profiler.metrics.device_scopes
+    scopes[("jit_fe_fused", "vecReal[Vec_1]")] = [0.25, 3]
+    scopes[("jit_fe_fused", "vecReal[Vec_1]/clip")] = [0.05, 1]
+    scopes[("jit_train_score_stacked", "tree.L0/hist")] = [9.0, 1]
+    m = profiler.finalize()
+    assert m.stages["Vec (Vec_1)"]["deviceSeconds"] == pytest.approx(0.30)
+    assert profiler.finalize().stages["Vec (Vec_1)"]["deviceSeconds"] == \
+        pytest.approx(0.30)     # idempotent
+
+
+def test_trace_reader_raises_on_missing_or_broken_trace(tmp_path):
+    """A trace that should be there and is not, or cannot be decoded, is
+    an error — never an empty timeline read as "the device did nothing"."""
+    from transmogrifai_tpu.utils.profiling import trace_device_events
+    with pytest.raises(FileNotFoundError):
+        trace_device_events(str(tmp_path))
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(b"\x0a\xff\xff\xff\xff\x0fxx")
+    with pytest.raises((ValueError, IndexError)):
+        trace_device_events(str(tmp_path))
 
 
 def test_stage_table_does_not_double_count_nested_same_uid_spans():
     """The selector's sweep/refit spans nest inside its stage.fit span
     with the same stage_uid: the rollup must count the OUTERMOST wall
-    once, while device seconds (attributed to exactly one innermost span
-    each) still sum across all of them."""
+    once, while device seconds (carried by the device-window spans only:
+    ``selector.sweep`` and ``selector.refit``, which are disjoint) sum
+    across all of them."""
     from transmogrifai_tpu.utils.tracing import SpanRecorder
     rec = SpanRecorder()
     with rec.span("stage.fit", stage_uid="sel", stage_cls="ModelSelector",
                   phase="fit"):
         time.sleep(0.02)
         with rec.span("selector.sweep", stage_uid="sel",
-                      stage_cls="ModelSelector", phase="sweep"):
+                      stage_cls="ModelSelector", phase="sweep", device_window=True):
             time.sleep(0.01)
-    # simulate device attribution landing on the inner span
-    inner = [s for s in rec.spans if s.name == "selector.sweep"][0]
-    inner.device_s = 0.5
-    outer = [s for s in rec.spans if s.name == "stage.fit"][0]
-    outer.device_s = 0.1
+        with rec.span("selector.refit", stage_uid="sel",
+                      stage_cls="ModelSelector", phase="refit", device_window=True):
+            time.sleep(0.01)
+    from transmogrifai_tpu.utils.profiling import DeviceEvent
+    by_name = {s.name: s for s in rec.spans}
+    sweep, refit = by_name["selector.sweep"], by_name["selector.refit"]
+    outer = by_name["stage.fit"]
+    rec.attribute_device_windows([
+        DeviceEvent(sweep.t0 + 0.001, 0.004, "jit_a", "fusion", "", 1),
+        DeviceEvent(refit.t0 + 0.001, 0.002, "jit_b", "fusion", "", 2),
+        DeviceEvent(outer.t0 + 0.001, 0.001, "jit_c", "fusion", "", 3)])
+    assert outer.device_s == 0.0     # not a device window: owns nothing
     table = rec.stage_table()
     entry = table["ModelSelector (sel)"]
     assert entry["count"] == 1
     assert entry["wallSeconds"] == pytest.approx(outer.wall_s)
-    assert entry["wallSeconds"] < outer.wall_s + inner.wall_s
-    assert entry["deviceSeconds"] == pytest.approx(0.6)
+    assert entry["wallSeconds"] < outer.wall_s + sweep.wall_s
+    assert entry["deviceSeconds"] == pytest.approx(0.006)
 
 
 # -- device-time attribution units (satellite) --------------------------------

@@ -115,6 +115,62 @@ def test_one_sync_whole_sweep_counters(monkeypatch):
     assert per["OpGBTClassifier_2"]["stackedGroups"] == 1
 
 
+def test_sweep_device_spans_one_per_chunk_in_dispatch_order(monkeypatch):
+    """Every dispatched chunk of the async sweep gets ONE ``sweep.device``
+    span, stamped as the settle walks its one barrier in dispatch order:
+    the spans do not overlap, follow the dispatch order, lie inside the
+    sweep (no program runs before its dispatch), the last ends inside the
+    settle's window, and the walk costs no extra host sync. The winner's
+    refit gets its own ``refit.device`` span."""
+    from transmogrifai_tpu.utils.profiling import profiler
+    from transmogrifai_tpu.utils.tracing import recorder
+    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "1")
+    frame = _frame(seed=5)
+    profiler.reset()
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        n_folds=3, seed=1,
+        models_and_parameters=[
+            (OpLogisticRegression(max_iter=25),
+             [{"reg_param": r} for r in (0.01, 0.1)]),
+            (OpNaiveBayes(), [{"smoothing": s} for s in (0.5, 1.0)]),
+            (OpGBTClassifier(num_rounds=3),
+             [{"max_depth": 2}, {"max_depth": 3, "learning_rate": 0.1},
+              {"max_depth": 3, "learning_rate": 0.3}]),
+        ],
+        splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
+    _train(sel, frame)
+    assert sweep_counters.run_to_json()["sweepHostSyncs"] == 1
+    spans = recorder.spans
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp.name, []).append(sp)
+    dev = by_name["sweep.device"]
+    # one per dispatched chunk: 2 fold-stacked families + 2 tree depth groups
+    assert [(d.attrs["family"], d.attrs["unitKind"], d.attrs.get("depth"),
+             d.attrs["lanes"]) for d in dev] == [
+        ("OpLogisticRegression_0", "stacked", None, 2),
+        ("OpNaiveBayes_1", "stacked", None, 2),
+        ("OpGBTClassifier_2", "tree", 2, 1),
+        ("OpGBTClassifier_2", "tree", 3, 2)]
+    assert all(d.attrs["chunk"] == 0 and "exact" in d.attrs for d in dev)
+    assert [d.attrs.get("group") for d in dev] == [None, None, 0, 1]
+    for a, b in zip(dev, dev[1:]):
+        assert a.t0 <= a.t1 <= b.t0 <= b.t1     # ordered, non-overlapping
+    sweep = by_name["selector.sweep"][0]
+    settle = by_name["sweep.settle"][0]
+    assert sweep.t0 <= dev[0].t0 and dev[-1].t1 <= sweep.t1
+    assert settle.t0 <= dev[-1].t1 <= settle.t1
+    # a program cannot run before the dispatch that launched it ended
+    launches = sorted(by_name["sweep.family"] + by_name["sweep.tree_group"],
+                      key=lambda sp: sp.t0)
+    assert len(launches) == len(dev)
+    for launch, d in zip(launches, dev):
+        assert d.t0 >= launch.t1 - 1e-3
+    refit = by_name["refit.device"]
+    assert len(refit) == 1 and refit[0].attrs["family"].startswith("Op")
+    assert by_name["selector.refit"][0].t0 <= refit[0].t0 <= refit[0].t1
+
+
 def test_async_parity_with_per_family_settle_and_loop(monkeypatch):
     """Async overlap changes WHEN metrics materialize, never their
     values: summaries are identical (exactly) across async, per-family

@@ -547,6 +547,60 @@ def test_stacked_engines_agree(monkeypatch):
     np.testing.assert_array_equal(s_einsum, s_pallas)
 
 
+@pytest.mark.parametrize("hist", ["sorted", "scatter"])
+def test_tree_programs_carry_level_and_phase_scopes(hist, monkeypatch):
+    """The compiled stacked tree program names each level's phases in its
+    ops' ``op_name`` (``tree.L<level>/hist|split|partition``, ``/gather``
+    on the sorted engine, ``tree.leaf``, ``tree.predict``) — what a device
+    trace is split by — and the scopes are metadata only: the program's
+    outputs are bitwise those of the same program traced without them."""
+    import contextlib
+    import re
+
+    from transmogrifai_tpu.models import trees
+    rng = np.random.default_rng(3)
+    k, n, d, L = 2, 256, 4, 2
+    Xb = jnp.asarray(rng.integers(0, 16, (k, n, d)), jnp.int8)
+    y = jnp.asarray(rng.integers(0, 2, (k, n)), jnp.float32)
+    w = jnp.ones((k, n), jnp.float32)
+    Xva = Xb[:, :64]
+    args = (Xb, y, w, Xva, jnp.zeros(k, jnp.float32),
+            jnp.asarray([0.1, 0.3], jnp.float32), jnp.ones(L, jnp.float32),
+            jnp.zeros(L, jnp.float32), jnp.ones(L, jnp.float32))
+    kw = dict(n_rounds=2, max_depth=2, n_bins=16, loss="logistic",
+              subsample=1.0, colsample=1.0, bootstrap=False, seed=0,
+              hist=hist, sorted_engine="einsum", sorted_acc="f32",
+              forest_margin=False)
+    jax.clear_caches()   # the inner jits' traces are cached by shape
+    # the lowered text, not the compiled one: the persistent compile cache
+    # keys on the program without its metadata, so a cached executable
+    # keeps the names it was first built with
+    text = trees.train_score_stacked.lower(*args, **kw).as_text(
+        debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    phases = ["hist", "split", "partition"] + (
+        ["gather"] if hist == "sorted" else [])
+    for level in (0, 1):
+        for phase in phases:
+            assert any(f"tree.L{level}/{phase}" in nm for nm in names), \
+                (level, phase)
+    assert not any("tree.L2/" in nm for nm in names)    # depth 2: two levels
+    assert any("tree.leaf" in nm for nm in names)
+    assert any("tree.predict" in nm for nm in names)
+    scoped = np.asarray(trees.train_score_stacked(*args, **kw))
+
+    monkeypatch.setattr(trees, "device_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    bare_fn = jax.jit(trees.train_score_stacked.__wrapped__,
+                      static_argnames=tuple(kw))
+    assert "tree.L0" not in bare_fn.lower(*args, **kw).as_text(
+        debug_info=True)
+    bare = np.asarray(bare_fn(*args, **kw))
+    jax.clear_caches()   # later tests trace the scoped growers again
+    np.testing.assert_array_equal(scoped, bare)
+
+
 def test_tree_stack_groups_and_bytes():
     est = OpGBTClassifier(num_rounds=4, max_depth=3, max_bins=16)
     groups = est.tree_stack_groups([

@@ -9,9 +9,10 @@ The run opens one ``jax.profiler`` trace (device timeline, when the
 backend supports it), records the hierarchical host span tree
 (``utils/tracing.py``) through ingest, every DAG stage, and the fused
 layer dispatches, then fuses both into ``--trace-out`` — open it at
-chrome://tracing or https://ui.perfetto.dev. The phase/stage tables print
-to stderr; ``--metrics-out`` saves the same ``AppMetrics`` json. See
-docs/OBSERVABILITY.md.
+chrome://tracing or https://ui.perfetto.dev. The phase and stage tables,
+and the device seconds by ``(module, named scope)`` where the backend has a
+device plane, print to stderr; ``--metrics-out`` saves the same
+``AppMetrics`` json. See docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations

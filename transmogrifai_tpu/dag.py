@@ -47,6 +47,7 @@ from transmogrifai_tpu.pipeline_data import PipelineData
 from transmogrifai_tpu.stages.base import (
     Estimator, PipelineStage, Transformer,
 )
+from transmogrifai_tpu.utils.devicewatch import compile_telemetry
 from transmogrifai_tpu.utils.tracing import device_scope, span
 
 __all__ = ["compute_dag", "cut_dag", "CutDag", "DagExecutor", "Dag",
@@ -241,9 +242,11 @@ class DagExecutor:
             for stage in layer:
                 if isinstance(stage, Estimator):
                     t0 = time.time()
-                    with span("stage.fit", hbm=True, stage_uid=stage.uid,
-                              stage_cls=type(stage).__name__,
-                              op=stage.operation_name, phase="fit"):
+                    with compile_telemetry.building(
+                                f"stage.fit:{type(stage).__name__}"), \
+                            span("stage.fit", hbm=True, stage_uid=stage.uid,
+                                 stage_cls=type(stage).__name__,
+                                 op=stage.operation_name, phase="fit"):
                         fitted_layer.append(stage.fit(data))
                     _plog(f"fit {stage.operation_name}", t0)
                 elif isinstance(stage, Transformer):
@@ -276,6 +279,7 @@ class DagExecutor:
                 data = self.apply_layer(data, seg)
         return data
 
+    @compile_telemetry.building("fe.layer")  # host stages open their own
     def apply_layer(self, data: PipelineData,
                     transformers: Sequence[Transformer]) -> PipelineData:
         host_ts = [t for t in transformers if not t.is_device]
@@ -285,9 +289,11 @@ class DagExecutor:
             # own stage span (the "which vectorizer is slow" answer)
             new_host = {}
             for t in host_ts:
-                with span("stage.transform", hbm=True, stage_uid=t.uid,
-                          stage_cls=type(t).__name__,
-                          op=t.operation_name, phase="transform"):
+                with compile_telemetry.building(
+                            f"stage.transform:{type(t).__name__}"), \
+                        span("stage.transform", hbm=True, stage_uid=t.uid,
+                             stage_cls=type(t).__name__,
+                             op=t.operation_name, phase="transform"):
                     new_host[t.get_output().name] = t.output_column(data)
             data = data.with_host_cols(new_host)
         if dev_ts:
@@ -326,6 +332,7 @@ class DagExecutor:
         return compiled
 
     # -- cross-layer fusion (round 14) ---------------------------------------
+    @compile_telemetry.building("fe.fused")  # the stagewise rung: fe.layer
     def apply_fused(self, data: PipelineData,
                     layers: Sequence[Sequence[Transformer]]) -> PipelineData:
         """Apply a run of consecutive all-device layers as ONE jitted
@@ -442,7 +449,7 @@ def fuse_dag_program(layers: Sequence[Sequence[Transformer]],
     layer_list = [list(layer) for layer in layers]
     comp = compute_dtype(precision)
 
-    def fused(params, donate_cols, keep_cols):
+    def fe_fused(params, donate_cols, keep_cols):  # program jit_fe_fused
         env = {**donate_cols, **keep_cols}
         if comp is not None:
             env = cast_float_leaves(env, comp)
@@ -468,4 +475,4 @@ def fuse_dag_program(layers: Sequence[Sequence[Transformer]],
             out = cast_float_leaves(out, jnp.float32)
         return out
 
-    return jax.jit(fused, donate_argnums=(1,) if donate else ())
+    return jax.jit(fe_fused, donate_argnums=(1,) if donate else ())

@@ -287,12 +287,17 @@ class PredictionModel(AllowLabelAsInput, DeviceTransformer):
         Python attributes (probabilistic/family/kind/...) into the trace,
         and those may change via ``set_fitted_state`` after a first
         predict — a stale trace would silently keep the OLD semantics."""
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         cfg = self.config()
         cached = self.__dict__.get("_jit_apply")
         if cached is None or cached[0] != cfg:
-            cached = (cfg, jax.jit(lambda p, c: self.device_apply(p, c)))
+            def predict_arrays(p, c):  # names the program jit_predict_arrays
+                return self.device_apply(p, c)
+
+            cached = (cfg, jax.jit(predict_arrays))
             self.__dict__["_jit_apply"] = cached
-        return cached[1](self.device_params(), fr.VectorColumn(X))
+        with compile_telemetry.building(f"predict:{type(self).__name__}"):
+            return cached[1](self.device_params(), fr.VectorColumn(X))
 
     def transform_row(self, *values):
         """Row path: last value is the feature vector (label may be absent)."""

@@ -47,6 +47,7 @@ import numpy as np
 
 from transmogrifai_tpu import frame as fr
 from transmogrifai_tpu.models.base import PredictionModel, Predictor
+from transmogrifai_tpu.utils.tracing import device_scope
 
 __all__ = [
     "OpGBTClassifier", "OpGBTRegressor",
@@ -439,51 +440,63 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
     feats_out, bins_out = [], []
     feat_gain = jnp.zeros(d, jnp.float32)
     for level in range(max_depth):
+        # the level loop is a Python loop, so every level's phases carry
+        # their own scope in the ops' metadata: a device trace splits the
+        # grower's time by level and phase (``tree.L<level>/<phase>``)
         N = 2 ** level
         C = min(block, _pow2_at_most(max(n // (2 * N), 8)))
-        layout = _sorted_layout(counts, n, C)
-        snode, valid, src_sorted, *_ = layout
-        src_row = order[src_sorted]
-        Xp = Xb_n[src_row]
-        vf = valid.astype(grad.dtype)
-        gp = grad[src_row] * vf
-        hp = hess[src_row] * vf
-        hist_g, hist_h = _sorted_hist(Xp, gp, hp, layout, n_bins=B, C=C,
-                                      acc_dtype=acc_dtype, engine=engine)
+        with device_scope(f"tree.L{level}"):
+            with device_scope("gather"):
+                layout = _sorted_layout(counts, n, C)
+                snode, valid, src_sorted, *_ = layout
+                src_row = order[src_sorted]
+                Xp = Xb_n[src_row]
+                vf = valid.astype(grad.dtype)
+                gp = grad[src_row] * vf
+                hp = hess[src_row] * vf
+            with device_scope("hist"):
+                hist_g, hist_h = _sorted_hist(Xp, gp, hp, layout, n_bins=B,
+                                              C=C, acc_dtype=acc_dtype,
+                                              engine=engine)
+                if data_axis is not None:
+                    # distributed fit (explicit shard_map): per-shard local
+                    # histograms all-reduce once per level — the
+                    # Rabit/MLlib executor-aggregation analog on ICI —
+                    # after which every shard takes identical split
+                    # decisions and routes its own rows (order/counts stay
+                    # shard-local)
+                    hist_g = jax.lax.psum(hist_g, data_axis)
+                    hist_h = jax.lax.psum(hist_h, data_axis)
+            with device_scope("split"):
+                feat, bin_, gain = _best_splits(hist_g, hist_h, feat_mask,
+                                                **split_kw)
+                feats_out.append(feat)
+                bins_out.append(bin_)
+                feat_gain = feat_gain.at[jnp.clip(feat, 0)].add(gain)
+            with device_scope("partition"):
+                fp = feat[snode]
+                bp = bin_[snode]
+                xp = jnp.take_along_axis(
+                    Xp, jnp.clip(fp, 0)[:, None].astype(jnp.int32),
+                    axis=1)[:, 0].astype(jnp.int32)
+                go_left = jnp.where(fp < 0, True, xp <= bp)
+                order, counts = _sorted_partition(counts, layout, go_left,
+                                                  src_row, n)
+    with device_scope("tree.leaf"):
+        leaf_g = _segment_sums(grad[order], counts)
+        leaf_h = _segment_sums(hess[order], counts)
         if data_axis is not None:
-            # distributed fit (explicit shard_map): per-shard local
-            # histograms all-reduce once per level — the Rabit/MLlib
-            # executor-aggregation analog on ICI — after which every
-            # shard takes identical split decisions and routes its own
-            # rows (order/counts stay shard-local)
-            hist_g = jax.lax.psum(hist_g, data_axis)
-            hist_h = jax.lax.psum(hist_h, data_axis)
-        feat, bin_, gain = _best_splits(hist_g, hist_h, feat_mask,
-                                        **split_kw)
-        feats_out.append(feat)
-        bins_out.append(bin_)
-        feat_gain = feat_gain.at[jnp.clip(feat, 0)].add(gain)
-        fp = feat[snode]
-        bp = bin_[snode]
-        xp = jnp.take_along_axis(
-            Xp, jnp.clip(fp, 0)[:, None].astype(jnp.int32),
-            axis=1)[:, 0].astype(jnp.int32)
-        go_left = jnp.where(fp < 0, True, xp <= bp)
-        order, counts = _sorted_partition(counts, layout, go_left,
-                                          src_row, n)
-    leaf_g = _segment_sums(grad[order], counts)
-    leaf_h = _segment_sums(hess[order], counts)
-    if data_axis is not None:
-        leaf_g = jax.lax.psum(leaf_g, data_axis)
-        leaf_h = jax.lax.psum(leaf_h, data_axis)
-    leaf_values = -leaf_g / (leaf_h + reg_lambda)
-    # per-row predictions from the maintained segment order: leaf value of
-    # each sorted row, scattered back to original row ids (unique indices)
-    ends = jnp.cumsum(counts)
-    snode_final = jnp.searchsorted(ends, jnp.arange(n), side="right"
-                                   ).astype(jnp.int32)
-    row_pred = jnp.zeros(n, leaf_values.dtype).at[order].set(
-        leaf_values[snode_final], unique_indices=True)
+            leaf_g = jax.lax.psum(leaf_g, data_axis)
+            leaf_h = jax.lax.psum(leaf_h, data_axis)
+        leaf_values = -leaf_g / (leaf_h + reg_lambda)
+        # per-row predictions from the maintained segment order: leaf value
+        # of each sorted row, scattered back to original row ids (unique
+        # indices)
+        ends = jnp.cumsum(counts)
+        snode_final = jnp.searchsorted(ends, jnp.arange(n), side="right"
+                                       ).astype(jnp.int32)
+        row_pred = jnp.zeros(n, leaf_values.dtype).at[order].set(
+            leaf_values[snode_final], unique_indices=True)
     return tuple(feats_out), tuple(bins_out), leaf_values, feat_gain, \
         row_pred
 
@@ -588,37 +601,44 @@ def grow_tree(Xb, grad, hess, feat_mask, *, max_depth: int, n_bins: int,
     prev_hist = None  # previous level's full (g, h) histograms, if kept
     for level in range(max_depth):
         n_nodes = 2 ** level
+        scope = f"tree.L{level}"  # same scopes as the sorted engine
         if n_nodes <= max_hist_nodes:
-            if prev_hist is None:
-                hist_g, hist_h = hist_of(node, grad, hess, n_nodes)
-            else:
-                # sibling subtraction: scatter left children (even node ids)
-                # under their PARENT index; right = parent - left
-                is_left = (node % 2 == 0).astype(grad.dtype)
-                half = n_nodes // 2
-                lg, lh = hist_of(node // 2, grad * is_left, hess * is_left,
-                                 half)
-                pg, ph = prev_hist
-                hist_g = jnp.stack([lg, pg - lg], axis=1).reshape(
-                    n_nodes, d, B)
-                hist_h = jnp.stack([lh, ph - lh], axis=1).reshape(
-                    n_nodes, d, B)
+            with device_scope(f"{scope}/hist"):
+                if prev_hist is None:
+                    hist_g, hist_h = hist_of(node, grad, hess, n_nodes)
+                else:
+                    # sibling subtraction: scatter left children (even node
+                    # ids) under their PARENT index; right = parent - left
+                    is_left = (node % 2 == 0).astype(grad.dtype)
+                    half = n_nodes // 2
+                    lg, lh = hist_of(node // 2, grad * is_left,
+                                     hess * is_left, half)
+                    pg, ph = prev_hist
+                    hist_g = jnp.stack([lg, pg - lg], axis=1).reshape(
+                        n_nodes, d, B)
+                    hist_h = jnp.stack([lh, ph - lh], axis=1).reshape(
+                        n_nodes, d, B)
             prev_hist = (hist_g, hist_h)
-            feat, bin_, gain = _best_splits(hist_g, hist_h, feat_mask,
-                                            **split_kw)
+            with device_scope(f"{scope}/split"):
+                feat, bin_, gain = _best_splits(hist_g, hist_h, feat_mask,
+                                                **split_kw)
         else:
             # node-chunked: histogram + split per chunk, O(chunk*d*B) memory
             prev_hist = None
             n_chunks = n_nodes // max_hist_nodes
 
             def chunk_splits(c):
-                base = c * max_hist_nodes
-                in_chunk = ((node >= base) & (node < base + max_hist_nodes))
-                mask = in_chunk.astype(grad.dtype)
-                local = jnp.where(in_chunk, node - base, 0).astype(jnp.int32)
-                hg, hh = hist_of(local, grad * mask, hess * mask,
-                                 max_hist_nodes)
-                return _best_splits(hg, hh, feat_mask, **split_kw)
+                with device_scope(f"{scope}/hist"):
+                    base = c * max_hist_nodes
+                    in_chunk = ((node >= base)
+                                & (node < base + max_hist_nodes))
+                    mask = in_chunk.astype(grad.dtype)
+                    local = jnp.where(in_chunk, node - base, 0
+                                      ).astype(jnp.int32)
+                    hg, hh = hist_of(local, grad * mask, hess * mask,
+                                     max_hist_nodes)
+                with device_scope(f"{scope}/split"):
+                    return _best_splits(hg, hh, feat_mask, **split_kw)
 
             feat_c, bin_c, gain_c = jax.lax.map(chunk_splits,
                                                 jnp.arange(n_chunks))
@@ -632,19 +652,22 @@ def grow_tree(Xb, grad, hess, feat_mask, *, max_depth: int, n_bins: int,
         # split's gain under its feature; clip(-1 -> 0) is safe because
         # no-split nodes carry gain 0
         feat_gain = feat_gain.at[jnp.clip(feat, 0)].add(gain)
-        f_row = feat[node]
-        b_row = bin_[node]
-        x_row = Xb[rows, jnp.clip(f_row, 0)]
-        go_left = jnp.where(f_row < 0, True, x_row <= b_row)
-        node = node * 2 + jnp.where(go_left, 0, 1).astype(jnp.int32)
-    # leaf values from accumulated grad/hess at the final nodes
-    n_leaves = 2 ** max_depth
-    leaf_g = jnp.zeros(n_leaves, jnp.float32).at[node].add(grad)
-    leaf_h = jnp.zeros(n_leaves, jnp.float32).at[node].add(hess)
-    leaf_values = -leaf_g / (leaf_h + reg_lambda)
-    # training-row predictions come free from the final node assignment —
-    # the boosting loop must not pay a full re-descent (d more gathers)
-    row_pred = leaf_values[node]
+        with device_scope(f"{scope}/partition"):
+            f_row = feat[node]
+            b_row = bin_[node]
+            x_row = Xb[rows, jnp.clip(f_row, 0)]
+            go_left = jnp.where(f_row < 0, True, x_row <= b_row)
+            node = node * 2 + jnp.where(go_left, 0, 1).astype(jnp.int32)
+    with device_scope("tree.leaf"):
+        # leaf values from accumulated grad/hess at the final nodes
+        n_leaves = 2 ** max_depth
+        leaf_g = jnp.zeros(n_leaves, jnp.float32).at[node].add(grad)
+        leaf_h = jnp.zeros(n_leaves, jnp.float32).at[node].add(hess)
+        leaf_values = -leaf_g / (leaf_h + reg_lambda)
+        # training-row predictions come free from the final node assignment
+        # — the boosting loop must not pay a full re-descent (d more
+        # gathers)
+        row_pred = leaf_values[node]
     return tuple(feats_out), tuple(bins_out), leaf_values, feat_gain, \
         row_pred
 
@@ -828,12 +851,14 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
                 base_score=base_k, bootstrap=bootstrap, seed=seed,
                 hist=hist, sorted_engine=sorted_engine,
                 sorted_acc=sorted_acc)
-            out = predict_ensemble(Xva_k, trees, n_out=1,
-                                   learning_rate=lr_i, base_score=base_k,
-                                   bootstrap=bootstrap)
-            s = out[:, 0]
-            if forest_margin:
-                s = jnp.clip(s, 0.0, 1.0) - 0.5  # margin at 0
+            with device_scope("tree.predict"):
+                out = predict_ensemble(Xva_k, trees, n_out=1,
+                                       learning_rate=lr_i,
+                                       base_score=base_k,
+                                       bootstrap=bootstrap)
+                s = out[:, 0]
+                if forest_margin:
+                    s = jnp.clip(s, 0.0, 1.0) - 0.5  # margin at 0
             return s
 
         return jax.vmap(lane_fn)(lr, lam, gam, mcw)
@@ -1067,6 +1092,14 @@ class _TreePredictor(Predictor):
             return quantile_bin_edges_device(X, max_bins=max_bins)
         return jnp.asarray(quantile_bin_edges(np.asarray(X), max_bins))
 
+    def _binned(self, X, max_bins: int) -> tuple:
+        """``(edges, codes, max_bins)`` of ``X``; the quantile and
+        ``bin_data`` programs build under the compile site ``bin_data``."""
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+        with compile_telemetry.building("bin_data"):
+            edges = self._edges_of(X, max_bins)
+            return edges, bin_data(X, edges), max_bins
+
     def fit_arrays(self, X, y, w, params, _binned=None, _lnb=None):
         params = {self._ALIASES.get(k, k): v for k, v in params.items()}
         p = {**self.default_params, **params}
@@ -1077,8 +1110,7 @@ class _TreePredictor(Predictor):
         if _binned is not None and int(p["max_bins"]) == _binned[2]:
             edges, Xb = _binned[0], _binned[1]
         else:
-            edges = self._edges_of(X, int(p["max_bins"]))
-            Xb = bin_data(X, edges)
+            edges, Xb, _ = self._binned(X, int(p["max_bins"]))
         subsample = p["subsample"] if not self.bootstrap else 1.0
         from transmogrifai_tpu.utils import flops
         n, d = int(Xb.shape[0]), int(Xb.shape[1])
@@ -1152,8 +1184,7 @@ class _TreePredictor(Predictor):
         for g in merged:
             mb = int({**self.default_params, **self.params, **g}["max_bins"])
             if mb not in plan:
-                edges = self._edges_of(X, mb)
-                plan[mb] = (edges, bin_data(X, edges), mb)
+                plan[mb] = self._binned(X, mb)
         return plan
 
     def grid_fit_arrays(self, X, y, w, grid, _fold_plan=None,
@@ -1180,8 +1211,7 @@ class _TreePredictor(Predictor):
                                   jnp.take(codes_full, _fold_rows, axis=0),
                                   mb)
                 else:
-                    edges = self._edges_of(X, mb)
-                    binned[mb] = (edges, bin_data(X, edges), mb)
+                    binned[mb] = self._binned(X, mb)
             models.append(self.fit_arrays(X, y, w, {**self.params, **g},
                                           _binned=binned[mb], _lnb=lnb))
         return models

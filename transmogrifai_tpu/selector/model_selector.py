@@ -53,6 +53,57 @@ __all__ = ["ModelSelector", "SelectedModel", "ModelSelectorSummary",
            "ModelEvaluation"]
 
 
+def _is_ready(a) -> bool:
+    """Whether device future ``a`` has finished; a value that cannot say
+    (no ``is_ready``, or one that raises: a poisoned program) counts as
+    still running, so that the blocking wait surfaces it."""
+    try:
+        return bool(a.is_ready())
+    except Exception:  # noqa: BLE001 failure-ok: block() reports the cause
+        return False
+
+
+def _stamp_device(pending: list, block=None) -> None:
+    """Stamp the device interval of each pending sweep program that has
+    finished, as a retroactive ``sweep.device`` span.
+
+    One chip runs programs in the order they were enqueued, so walking the
+    pending chunks in dispatch order, program *i* ran from
+    ``max(ready[i-1], dispatch_end[i])`` to ``ready[i]``. ``ready`` is the
+    host's clock right after ``block(a)`` returned; a chunk found ready
+    already (``exact=False``) only bounds its end from above, and the
+    programs behind it start no earlier in this account. With ``block=None``
+    the walk is a poll: it stamps what has finished and stops at the first
+    chunk still running (the dispatch phase calls it where it has just
+    waited on the device anyway, which keeps those bounds tight). Cost: one
+    ``is_ready`` and one clock read a chunk. Entries without ``launched``
+    (hand-built in tests) are blocked on and not stamped."""
+    from transmogrifai_tpu.utils.tracing import recorder
+    prev = 0.0
+    for e in pending:
+        ready = e.setdefault("ready", [None] * len(e["chunks"]))
+        launched = e.get("launched")
+        for j, (_c0, _ln, a) in enumerate(e["chunks"]):
+            if ready[j] is not None:
+                if block is not None:
+                    block(a)  # cheap; a poisoned program still raises here
+                prev = ready[j]
+                continue
+            was_ready = _is_ready(a)
+            if block is None and not was_ready:
+                return
+            if block is not None:
+                block(a)
+            ready[j] = time.time()
+            if launched is not None:
+                t_dispatch, attrs = launched[j]
+                recorder.add("sweep.device",
+                             min(max(prev, t_dispatch), ready[j]), ready[j],
+                             exact=not was_ready, device_window=True,
+                             **attrs)
+            prev = ready[j]
+
+
 @dataclass
 class ModelEvaluation:
     model_name: str
@@ -673,15 +724,16 @@ class ModelSelector(Estimator):
                         # folds on "model" when they divide it); validation
                         # folds stay unpadded — metrics must see real rows
                         # only
-                        jtr = jnp.asarray(tr_idx)
-                        jva = jnp.asarray(va_idx)
-                        stacked_data = (
-                            pmesh.shard_stacked_training_rows(
-                                jnp.take(Xt, jtr, axis=0),
-                                jnp.take(yt, jtr, axis=0),
-                                jnp.take(wt, jtr, axis=0))
-                            + (jnp.take(Xt, jva, axis=0),
-                               jnp.take(yt, jva, axis=0)))
+                        with compile_telemetry.building("sweep.operands"):
+                            jtr = jnp.asarray(tr_idx)
+                            jva = jnp.asarray(va_idx)
+                            stacked_data = (
+                                pmesh.shard_stacked_training_rows(
+                                    jnp.take(Xt, jtr, axis=0),
+                                    jnp.take(yt, jtr, axis=0),
+                                    jnp.take(wt, jtr, axis=0))
+                                + (jnp.take(Xt, jva, axis=0),
+                                   jnp.take(yt, jva, axis=0)))
                     Xtr_s, ytr_s, wtr_s, Xva_s, yva_s = stacked_data
                     if n_classes_hint is None:
                         # the ONE class-count pull every softmax/NB/MLP
@@ -691,9 +743,8 @@ class ModelSelector(Estimator):
                         n_classes_hint = max(
                             int(np.asarray(jnp.max(ytr_s))) + 1, 2)
                     try:
-                        with sweep_counters.tracking(fname), \
-                                compile_telemetry.building(
-                                    f"sweep.family:{fname}"), \
+                        with compile_telemetry.building(
+                                    f"sweep.family:{fname}", family=fname), \
                                 span("sweep.family", family=fname,
                                      mode="fold_stacked", folds=k,
                                      grid=len(grid)):
@@ -756,7 +807,10 @@ class ModelSelector(Estimator):
                             pending.append({
                                 "kind": "stacked", "ci": ci, "fname": fname,
                                 "key": skey, "k": k, "grid_len": len(grid),
-                                "chunks": [(0, len(grid), vals_kg)]})
+                                "chunks": [(0, len(grid), vals_kg)],
+                                "launched": [(time.time(), {
+                                    "family": fname, "unitKind": "stacked",
+                                    "lanes": len(grid), "chunk": 0})]})
                             sweep_counters.count_run(async_families=1)
                             continue
                         # per-family settle (TRANSMOGRIFAI_SWEEP_ASYNC=0 or
@@ -781,15 +835,25 @@ class ModelSelector(Estimator):
                         # by the same device expression the per-family
                         # ``_loss_and_nout`` probe runs, so threading it
                         # is bitwise-identical
-                        tree_stats = tuple(np.asarray(jnp.stack(
-                            [jnp.max(yt), jnp.mean(yt),
-                             jnp.clip(jnp.mean(yt), 1e-6, 1 - 1e-6)])))
-                    if self._family_tree_stacked(
+                        with compile_telemetry.building("sweep.operands"):
+                            tree_stats = tuple(np.asarray(jnp.stack(
+                                [jnp.max(yt), jnp.mean(yt),
+                                 jnp.clip(jnp.mean(yt), 1e-6, 1 - 1e-6)])))
+                        # the pull waited for every program enqueued before
+                        # it: stamp those that are done while the bound on
+                        # their end is tight
+                        _stamp_device(pending)
+                    # the family's gathers and label pulls build under
+                    # "sweep.operands"; its programs open their own sites
+                    with compile_telemetry.building("sweep.operands",
+                                                    family=fname):
+                        handled = self._family_tree_stacked(
                             ci, est, grid, tgroups, Xt, yt, wt, tr_idx,
                             va_idx, done, deadline, per_candidate_scores,
                             failures, tree_cache, async_on=async_on,
                             pending=pending, tree_stats=tree_stats,
-                            refit_state=refit_state):
+                            refit_state=refit_state)
+                    if handled:
                         continue
                 # ---- per-fold fallback loop for this family ----------------
                 self._family_fold_loop(
@@ -820,7 +884,7 @@ class ModelSelector(Estimator):
         from transmogrifai_tpu.utils.faults import FaultHarnessError
         from transmogrifai_tpu.utils.profiling import sweep_counters
         from transmogrifai_tpu.utils.tracing import span
-        with span("sweep.settle",
+        with span("sweep.settle", device_window=True,
                   families=len({e["ci"] for e in pending}),
                   units=sum(len(e["chunks"]) for e in pending)), \
                 contextlib.ExitStack() as ledger_stack:
@@ -846,8 +910,10 @@ class ModelSelector(Estimator):
                         "sweep.settle", site="sweep.settle",
                         families=len({e["ci"] for e in pending}),
                         units=sum(len(e["chunks"]) for e in pending)):
-                    jax.block_until_ready(
-                        [a for e in pending for _c0, _ln, a in e["chunks"]])
+                    # still ONE barrier, walked chunk by chunk in dispatch
+                    # order so that each program's device interval is
+                    # stamped as it ends (``sweep.device`` spans)
+                    _stamp_device(pending, block=jax.block_until_ready)
                 sweep_counters.count_run(host_syncs=1)
             except FaultHarnessError:
                 raise  # a preempted process dies; it does not isolate
@@ -1061,6 +1127,8 @@ class ModelSelector(Estimator):
                 # the loop path's per-fold lnb sync
                 cache["fold_means"] = np.asarray(jnp.stack(
                     [jnp.mean(ytr_s[f]) for f in range(k)]))
+                if pending:
+                    _stamp_device(pending)
             cs = chunk_sizes[gi]
             ev0_f = self.evaluators[0]
             fold_metrics_dev = getattr(ev0_f,
@@ -1070,14 +1138,14 @@ class ModelSelector(Estimator):
                          and fold_metrics_dev is not None)
             vals_kl = np.empty((k, L), np.float64)
             chunks: list[tuple[int, int, Any]] = []  # async device futures
+            launched: list[tuple[float, dict]] = []  # their dispatch ends
             cs_cur = cs  # degradation ladder may narrow it mid-group
             from transmogrifai_tpu.utils.devicewatch import (
                 compile_telemetry,
             )
             try:
-                with sweep_counters.tracking(fname), \
-                        compile_telemetry.building(
-                            f"sweep.tree:{fname}"):
+                with compile_telemetry.building(
+                        f"sweep.tree:{fname}", family=fname):
                     c0 = 0
                     while c0 < L:
                         chunk = g["params"][c0:c0 + cs_cur]
@@ -1128,6 +1196,10 @@ class ModelSelector(Estimator):
                             continue
                         if use_async:
                             chunks.append((c0, len(chunk), vals))
+                            launched.append((time.time(), {
+                                "family": fname, "unitKind": "tree",
+                                "depth": int(depth), "lanes": len(chunk),
+                                "chunk": len(launched), "group": gi}))
                         else:
                             vals_kl[:, c0:c0 + len(chunk)] = \
                                 np.asarray(vals)
@@ -1165,7 +1237,7 @@ class ModelSelector(Estimator):
                 first_entry = not any(p["ci"] == ci for p in pending)
                 pending.append({"kind": "tree", "ci": ci, "fname": fname,
                                 "key": tk, "k": k, "lanes": lanes,
-                                "chunks": chunks})
+                                "chunks": chunks, "launched": launched})
                 if first_entry:
                     sweep_counters.count_run(async_families=1)
                 continue
@@ -1206,6 +1278,7 @@ class ModelSelector(Estimator):
         counter bookkeeping. ``Xtr``/``ytr``/``wtr`` arrive mesh-sharded.
         Returns False when the family is dropped (failed or past budget) —
         the caller skips its remaining folds."""
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         from transmogrifai_tpu.utils.profiling import sweep_counters
         from transmogrifai_tpu.utils.retry import with_device_retry
         ev0 = self.evaluators[0]
@@ -1223,7 +1296,8 @@ class ModelSelector(Estimator):
             return False
         from transmogrifai_tpu.utils.tracing import span
         try:
-            with sweep_counters.tracking(fname), \
+            with compile_telemetry.building(
+                        f"sweep.fold_unit:{fname}", family=fname), \
                     span("sweep.fold_unit", family=fname, fold=fold_i,
                          grid=len(grid)):
                 models = with_device_retry(
@@ -1487,9 +1561,8 @@ class ModelSelector(Estimator):
               if stacked_refit else contextlib.nullcontext())
         from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         try:
-            with sweep_counters.tracking(fname), \
-                    compile_telemetry.building(
-                        f"selector.refit:{fname}"), cm:
+            with compile_telemetry.building(
+                    f"selector.refit:{fname}", family=fname), cm:
                 best_model, warm_used = with_device_retry(
                     best_est.refit_winner, Xs, ys, ws, best_params,
                     warm=warm, lane=best_gj, hints=hints or None,
@@ -1508,18 +1581,40 @@ class ModelSelector(Estimator):
                           rows=int(n), cols=int(d))
             warm = None
             refit_state.get("warm", {}).pop(best_ci, None)
-            with sweep_counters.tracking(fname), \
-                    compile_telemetry.building(
-                        f"selector.refit:{fname}"):
+            with compile_telemetry.building(
+                    f"selector.refit:{fname}", family=fname):
                 best_model, warm_used = with_device_retry(
                     best_est.refit_winner, Xs, ys, ws, best_params,
                     warm=None, lane=best_gj, hints=hints or None,
                     site="sweep.fit")
         if warm_used:
             sweep_counters.count_run(refit_warm_starts=1)
+        refit_state["dispatched"] = (time.time(), fname)  # for _stamp_refit
         self._refit_ckpt_save(rkey, best_model)
         fault_point("selector.refit")
         return best_model
+
+    @staticmethod
+    def _stamp_refit(best_model, dispatched) -> None:
+        """Stamp the winner refit's device interval as a ``refit.device``
+        span: from the refit program's dispatch end to its parameters being
+        ready. Called once the train-set predict is enqueued behind it and
+        just before the evaluation pulls (which wait on both anyway), so
+        the wait moves nothing; ``exact=False`` when the refit had finished
+        before the walk got here (the predict program's compile overlaps
+        it). ``dispatched`` is ``_refit``'s ``(dispatch end, family)``,
+        or None when the winner came from the refit checkpoint."""
+        import jax
+        from transmogrifai_tpu.utils.tracing import recorder
+        if dispatched is None:
+            return
+        t_dispatch, fname = dispatched
+        leaves = [a for a in jax.tree_util.tree_leaves(
+            best_model.device_params()) if isinstance(a, jax.Array)]
+        was_ready = all(_is_ready(a) for a in leaves)
+        jax.block_until_ready(leaves)
+        recorder.add("refit.device", t_dispatch, time.time(), family=fname,
+                     exact=not was_ready, device_window=True)
 
     def _finalize(self, results, mean_metrics, Xt, yt, wt, Xh, yh,
                   prep_results: dict, t0: float,
@@ -1540,20 +1635,27 @@ class ModelSelector(Estimator):
             # program peaks HBM
             for ci in [c for c in warm_all if c != best_ci]:
                 del warm_all[ci]
+        refit_state = refit_state or {}
         best_model = self._refit(best_ci, best_gj, best_params, Xt, yt, wt,
-                                 refit_state or {})
+                                 refit_state)
 
         train_eval: dict = {}
         holdout_eval: dict = {}
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         pred_train = best_model.predict_arrays(Xt)
+        self._stamp_refit(best_model, refit_state.get("dispatched"))
         for ev in self.evaluators:
-            train_eval[ev.name] = EvaluatorBase.to_json(
-                ev.evaluate_arrays(yt, pred_train))
+            with compile_telemetry.building(
+                    f"evaluate:{type(ev).__name__}"):
+                train_eval[ev.name] = EvaluatorBase.to_json(
+                    ev.evaluate_arrays(yt, pred_train))
         if Xh is not None and int(Xh.shape[0]):
             pred_h = best_model.predict_arrays(Xh)
             for ev in self.evaluators:
-                holdout_eval[ev.name] = EvaluatorBase.to_json(
-                    ev.evaluate_arrays(yh, pred_h))
+                with compile_telemetry.building(
+                        f"evaluate:{type(ev).__name__}"):
+                    holdout_eval[ev.name] = EvaluatorBase.to_json(
+                        ev.evaluate_arrays(yh, pred_h))
 
         summary = ModelSelectorSummary(
             validation_type=self.validator.name,
@@ -1581,17 +1683,19 @@ class ModelSelector(Estimator):
         # matrix is already an HBM-resident, rows-on-"data"-sharded device
         # column — the sweep consumes it pre-partitioned, no host pull and
         # no resharding device_put. `presharded` makes that assertable.
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         presharded = feat_name in data.device
-        with _span("sweep.operands", presharded=presharded,
-                   feature=feat_name):
-            X = data.device_col(feat_name).values
-            y = data.device_col(label_name).values
-        n = data.n_rows  # logical rows: device arrays may carry mesh padding
+        with compile_telemetry.building("selector.prepare"):
+            with _span("sweep.operands", presharded=presharded,
+                       feature=feat_name):
+                X = data.device_col(feat_name).values
+                y = data.device_col(label_name).values
+            n = data.n_rows  # logical rows: device arrays may carry padding
 
-        train_idx, holdout_idx, w_train, prep_results = \
-            self._split_prepare(n, y[:n])
-        Xt, yt = X[jnp.asarray(train_idx)], y[jnp.asarray(train_idx)]
-        wt = jnp.asarray(w_train)
+            train_idx, holdout_idx, w_train, prep_results = \
+                self._split_prepare(n, y[:n])
+            Xt, yt = X[jnp.asarray(train_idx)], y[jnp.asarray(train_idx)]
+            wt = jnp.asarray(w_train)
         _plog("selector: split+prepare", t0)
 
         yt_np = (np.asarray(yt)
@@ -1602,16 +1706,19 @@ class ModelSelector(Estimator):
         with profiler.phase(OpStep.CROSS_VALIDATION), \
                 span("selector.sweep", hbm=True, stage_uid=self.uid,
                      stage_cls=type(self).__name__, phase="sweep",
-                     n_families=len(self.models_and_grids)):
+                     n_families=len(self.models_and_grids),
+                     device_window=True):
             results, mean_metrics, failures, refit_state = \
                 self._sweep(Xt, yt, wt, yt_np)
         _plog("selector: CV sweep", t1)
         t1 = time.time()
-        Xh = X[jnp.asarray(holdout_idx)] if holdout_idx.size else None
-        yh = y[jnp.asarray(holdout_idx)] if holdout_idx.size else None
+        with compile_telemetry.building("selector.prepare"):
+            Xh = X[jnp.asarray(holdout_idx)] if holdout_idx.size else None
+            yh = y[jnp.asarray(holdout_idx)] if holdout_idx.size else None
         with profiler.phase(OpStep.MODEL_TRAINING), \
                 span("selector.refit", hbm=True, stage_uid=self.uid,
-                     stage_cls=type(self).__name__, phase="refit"):
+                     stage_cls=type(self).__name__, phase="refit",
+                     device_window=True):
             selected = self._finalize(results, mean_metrics, Xt, yt, wt,
                                       Xh, yh, prep_results, t0, failures,
                                       refit_state=refit_state)

@@ -21,11 +21,13 @@ three instruments over one shared device census:
   *deadlines* stay the caller's contract (``run_with_deadline`` still
   raises ``CollectiveTimeoutError`` — now with an autopsy attached).
 - :class:`CompileTelemetry` — every XLA backend compile (observed via
-  the ``jax.monitoring`` duration listener) records wall attributed to
-  the active :meth:`~CompileTelemetry.building` site as a
-  ``compile.program`` span + ``transmogrifai_compile_*`` Prometheus
-  series, with a slow-compile threshold event — a compile storm or a
-  pathological HLO is visible *before* it looks like a hang.
+  the ``jax.monitoring`` duration listener, the program's only one)
+  records wall attributed to the active
+  :meth:`~CompileTelemetry.building` site as a ``compile.program:<site>``
+  span (``compile.cache_load:<site>`` when the persistent cache served
+  it) + ``transmogrifai_compile_*`` Prometheus series, with a
+  slow-compile threshold event — a compile storm or a pathological HLO
+  is visible *before* it looks like a hang.
   :func:`analyze_program` adds HLO size + cost-analysis FLOPs/bytes at
   cold seams (serving warmup) where a program handle exists.
 - an **HBM timeline** — low-rate all-device census samples
@@ -346,41 +348,60 @@ dispatch_ledger = DispatchLedger()
 # -- compile telemetry --------------------------------------------------------
 
 class CompileTelemetry:
-    """XLA compile observability: wall per backend compile (from the
-    ``jax.monitoring`` duration listener, attributed to the active
-    :meth:`building` site), recorded as a retroactive ``compile.program``
-    span and the ``transmogrifai_compile_*`` series; compiles slower
-    than the ``TRANSMOGRIFAI_SLOW_COMPILE_S`` threshold (default 10s)
-    additionally emit a ``compile.slow`` flight-recorder event + warning.
-    Persistent-cache hits don't fire the monitoring event — by design, a
-    warm re-run reports 0 compiles (same contract as ``SweepCounters``).
-    ``record_program_cost`` stores :func:`analyze_program` results
-    (FLOPs, bytes, HLO size) from cold seams that hold a program
-    handle."""
+    """XLA compile observability, the ONE ``jax.monitoring`` listener of
+    the program: wall per backend compile attributed to the active
+    :meth:`building` site, recorded as a retroactive
+    ``compile.program:<site>`` span and the ``transmogrifai_compile_*``
+    series; compiles slower than the ``TRANSMOGRIFAI_SLOW_COMPILE_S``
+    threshold (default 10s) additionally emit a ``compile.slow``
+    flight-recorder event + warning.
+
+    A persistent-cache hit fires the SAME duration event on this JAX
+    (``pxla`` times ``compile_or_get_cached`` as a whole), preceded on
+    the same thread by ``/jax/compilation_cache/cache_hits``. The
+    listener hears both and tells them apart: a duration event that
+    follows a hit is a cache LOAD (``compile.cache_load:<site>`` span,
+    ``cacheLoads``/``loadSeconds`` in ``by_site``), any other a compile
+    — so a warm re-run reports 0 compiles and its loads by site.
+    ``by_family`` counts real compiles per sweep family (``building(...,
+    family=)``; nested sites inherit it) — ``SweepCounters`` reads its
+    per-family ``compiles`` from there. ``record_program_cost`` stores
+    :func:`analyze_program` results (FLOPs, bytes, HLO size) from cold
+    seams that hold a program handle."""
+
+    COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
     def __init__(self, max_records: int = 512):
         self._lock = threading.Lock()
         self._listening = False
-        self._site: contextvars.ContextVar[Optional[str]] = \
+        #: (site, family) of the innermost open ``building`` block
+        self._site: contextvars.ContextVar[tuple] = \
             contextvars.ContextVar("transmogrifai_compile_site",
-                                   default=None)
+                                   default=(None, None))
+        #: cache hits heard on this thread since its last duration event
+        self._hits = threading.local()
         self.records: deque = deque(maxlen=int(max_records))
         self.programs = 0
+        self.cache_loads = 0
         self.wall_s = 0.0
         self.max_wall_s = 0.0
         self.slow = 0
         self.in_progress = 0
         self.by_site: dict[str, dict] = {}
+        self.by_family: dict[str, int] = {}
         self.program_costs: dict[str, dict] = {}
 
     def reset(self) -> None:
         with self._lock:
             self.records.clear()
             self.programs = 0
+            self.cache_loads = 0
             self.wall_s = 0.0
             self.max_wall_s = 0.0
             self.slow = 0
             self.by_site = {}
+            self.by_family = {}
             self.program_costs = {}
 
     @staticmethod
@@ -388,29 +409,34 @@ class CompileTelemetry:
         return _env_float(SLOW_COMPILE_ENV, 10.0)
 
     def ensure_listener(self) -> None:
-        """Register the process-wide monitoring listener once. Compiles
-        stay 0 when the API is absent (never retried — same contract as
-        ``SweepCounters``). The check-and-set runs under the lock:
-        listeners can never unregister, so a double registration would
-        double-count every compile for the process lifetime."""
+        """Register the process-wide monitoring listeners once. Compiles
+        stay 0 when the API is absent (never retried). The check-and-set
+        runs under the lock: listeners can never unregister, so a double
+        registration would double-count every compile for the process
+        lifetime."""
         with self._lock:
             if self._listening:
                 return
             self._listening = True
         try:
             import jax.monitoring as monitoring
+            monitoring.register_event_listener(self._on_cache_event)
             monitoring.register_event_duration_secs_listener(
                 self._on_event)
         except Exception:  # failure-ok: monitoring API absent — compiles stay 0
             pass
 
     @contextlib.contextmanager
-    def building(self, site: str):
-        """Attribute backend compiles to ``site`` while the block runs
-        (thread/task-local), and mark a program build in progress — the
-        autopsy's "what was compiling" answer."""
+    def building(self, site: str, family: Optional[str] = None):
+        """Attribute backend compiles and cache loads to ``site`` while
+        the block runs (thread/task-local; the innermost block wins), and
+        mark a program build in progress — the autopsy's "what was
+        compiling" answer. ``family`` names the sweep family the build
+        belongs to; a nested block without one keeps its parent's."""
         self.ensure_listener()
-        token = self._site.set(site)
+        if family is None:
+            family = self._site.get()[1]
+        token = self._site.set((site, family))
         with self._lock:
             self.in_progress += 1
         try:
@@ -420,28 +446,57 @@ class CompileTelemetry:
                 self.in_progress -= 1
             self._site.reset(token)
 
+    def family_compiles(self) -> dict[str, int]:
+        """Real compiles per sweep family, process lifetime."""
+        with self._lock:
+            return dict(self.by_family)
+
+    def _on_cache_event(self, event: str, **kw) -> None:
+        if event == self.CACHE_HIT_EVENT:
+            self._hits.n = getattr(self._hits, "n", 0) + 1
+
     def _on_event(self, event: str, duration: float, **kw) -> None:
-        if event != "/jax/core/compile/backend_compile_duration":
+        if event != self.COMPILE_EVENT:
             return
-        site = self._site.get() or "unattributed"
+        loaded = getattr(self._hits, "n", 0) > 0
+        if loaded:
+            self._hits.n -= 1
+        site, family = self._site.get()
+        site = site or "unattributed"
+        program = kw.get("fun_name")
         now = time.time()
         wall = float(duration)
+        slow = False
         with self._lock:
-            self.programs += 1
-            self.wall_s += wall
-            self.max_wall_s = max(self.max_wall_s, wall)
             per = self.by_site.setdefault(
-                site, {"programs": 0, "wallSeconds": 0.0})
-            per["programs"] += 1
-            per["wallSeconds"] += wall
+                site, {"programs": 0, "wallSeconds": 0.0,
+                       "cacheLoads": 0, "loadSeconds": 0.0})
+            if loaded:
+                self.cache_loads += 1
+                per["cacheLoads"] += 1
+                per["loadSeconds"] += wall
+            else:
+                self.programs += 1
+                self.wall_s += wall
+                self.max_wall_s = max(self.max_wall_s, wall)
+                per["programs"] += 1
+                per["wallSeconds"] += wall
+                if family is not None:
+                    self.by_family[family] = \
+                        self.by_family.get(family, 0) + 1
+                slow = wall >= self.slow_threshold_s()
+                if slow:
+                    self.slow += 1
             self.records.append({"site": site, "wallSeconds": wall,
-                                 "ts": now})
-            slow = wall >= self.slow_threshold_s()
-            if slow:
-                self.slow += 1
+                                 "ts": now, "cacheLoad": loaded,
+                                 "program": program})
+        kind = "compile.cache_load" if loaded else "compile.program"
         try:
             from transmogrifai_tpu.utils.tracing import recorder
-            recorder.add("compile.program", now - wall, now, site=site)
+            # the site rides in the NAME: consumers that keep span names
+            # only (the benchmark's idle-gap table) still read it
+            recorder.add(f"{kind}:{site}", now - wall, now, site=site,
+                         program=program)
         except Exception:  # failure-ok: span recording is optional telemetry
             pass
         if slow:
@@ -473,6 +528,7 @@ class CompileTelemetry:
     def to_json(self) -> dict:
         with self._lock:
             return {"programs": self.programs,
+                    "cacheLoads": self.cache_loads,
                     "wallSeconds": round(self.wall_s, 4),
                     "maxWallSeconds": round(self.max_wall_s, 4),
                     "slowCompiles": self.slow,

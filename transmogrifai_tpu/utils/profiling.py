@@ -8,12 +8,19 @@ serializable at the end of the run.
 
 TPU-first: where the reference attributes *executor* time to phases via
 Spark job groups, this attributes *device* time via one ``jax.profiler``
-trace spanning the run. Phase enter/exit wall timestamps are recorded; at
-``finalize()`` the trace's XSpace protobuf is parsed directly (the device
-plane's XLA-op timeline) and every device op interval is bucketed into the
-innermost phase whose wall interval contains its midpoint. One trace, no
-nesting restrictions, true device seconds per phase — the drill-down the
-Spark UI gives a job group.
+trace spanning the run. At ``finalize()`` the trace file is read
+(``trace_device_events``: every leaf device op with its module, op kind and
+``jax.named_scope`` path, on the host's clock) and device time is owned two
+ways. PHASES own by time: ``profiler.phase`` drains the device at each
+phase's end while a trace is on, so a phase's wall window does hold its
+ops, and each op goes to the innermost phase containing its midpoint.
+Everything finer owns by what the op IS: the ``(module, scope)`` table
+``AppMetrics.device_scopes`` — host spans are not fenced (the sweep
+dispatches every program asynchronously and blocks once), so a span's
+window says nothing about which ops ran for it. Only spans opened with
+``device_window=True`` get ``Span.device_s``: device intervals by
+construction (``sweep.device``, ``refit.device``) and spans that end in a
+blocking pull (``selector.sweep``, ``sweep.settle``, ``selector.refit``).
 """
 
 from __future__ import annotations
@@ -25,10 +32,10 @@ import os
 import time
 from dataclasses import dataclass, field
 from enum import Enum
-from typing import Optional
+from typing import NamedTuple, Optional
 
 __all__ = ["OpStep", "AppMetrics", "profiler", "phase",
-           "trace_device_intervals", "trace_device_events",
+           "DeviceEvent", "trace_device_events", "scope_of",
            "aggregate_across_hosts", "SweepCounters", "sweep_counters",
            "ServingCounters", "RunCounters", "run_counters",
            "IngestCounters", "ingest_counters"]
@@ -63,58 +70,141 @@ def _device_memory() -> tuple[int, int]:
     return device_memory()
 
 
-def trace_device_events(trace_dir: str) -> list[tuple[float, float, str]]:
-    """Parse a ``jax.profiler`` trace directory into NAMED device-op events
-    ``[(start_epoch_s, duration_s, op_name), ...]``.
+#: name of the host annotation ``profiler.reset(trace_dir=...)`` opens right
+#: after the trace starts: its place on the trace's clock against the host
+#: clock read beside it puts every device event on the host's clock
+TRACE_ANCHOR = "transmogrifai.trace_anchor"
 
-    Reads the XSpace protobuf directly (``tensorflow.tsl`` proto bindings;
-    the tensorboard-plugin converter is not required). Only accelerator
-    planes (``/device:...``) count; per plane the busiest line is used so
-    module- and op-level timelines aren't double-counted. Op names come
-    from the plane's event-metadata table — ``jax.named_scope`` prefixes
-    (the per-stage scopes ``dag.fuse_layer_program`` opens) survive into
-    them, which is what lets the merged chrome trace label device slices
-    with stage names. Returns [] when no trace/proto support is available
-    (e.g. pure-CPU backends expose no device plane).
-    """
-    try:
-        os.environ.setdefault(
-            "PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception:  # failure-ok: proto bindings optional; no trace parsed
-        return []
-    out: list[tuple[float, float, str]] = []
-    for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                          recursive=True):
-        try:
-            xs = xplane_pb2.XSpace()
-            with open(path, "rb") as fh:
-                xs.ParseFromString(fh.read())
-        except Exception:  # failure-ok: unparseable trace file is skipped
+_OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
+#: ops that only enclose other ops of the same line: their time is their
+#: children's
+_CONTAINER_OPS = ("while", "conditional", "call")
+#: ``op_name`` path components that are structure, not a named scope
+_STRUCTURAL = frozenset(("while", "body", "cond", "closed_call",
+                         "checkpoint", "remat"))
+
+
+class DeviceEvent(NamedTuple):
+    """One leaf op of a device's op timeline."""
+    start_s: float      # host clock (epoch seconds)
+    duration_s: float
+    module: str         # the jitted program, e.g. ``jit_train_score_stacked``
+    op: str             # op kind, e.g. ``fusion``, ``reduce-window``
+    scope: str          # ``jax.named_scope`` path, "" where the op has none
+    program_id: int     # tells two programs of one module name apart
+
+
+def scope_of(op_name: str) -> str:
+    """The named-scope path in an op's ``op_name`` metadata:
+    ``jit(f)/vmap(jit(g))/while/body/tree.L0/hist/dot_general:`` ->
+    ``tree.L0/hist``. Transformation wrappers are unwrapped (a scope opened
+    under ``vmap`` reads ``vmap(tree.predict)``), ``jit(...)`` calls,
+    loop structure and the trailing primitive are dropped."""
+    parts, depth, cur = [], 0, ""
+    for ch in op_name.partition(":")[0]:
+        if ch == "/" and depth == 0:
+            parts.append(cur)
+            cur = ""
             continue
-        for plane in xs.planes:
-            if not plane.name.startswith("/device:"):
+        depth += (ch == "(") - (ch == ")")
+        cur += ch
+    out = []
+    for part in parts:              # the last component is the primitive
+        while part.endswith(")") and "(" in part:
+            head, _, inner = part.partition("(")
+            if head in ("jit", "pjit"):
+                part = ""
+                break
+            part = inner[:-1]
+        if part and part not in _STRUCTURAL:
+            out.append(part)
+    return "/".join(out)
+
+
+def _op_kind(display_name: str) -> str:
+    """``fusion.123`` -> ``fusion``."""
+    head, _, tail = display_name.rpartition(".")
+    return head if head and tail.isdigit() else display_name
+
+
+def trace_device_events(trace_dir: str,
+                        anchor_epoch_s: Optional[float] = None
+                        ) -> list[DeviceEvent]:
+    """The leaf device ops of a ``jax.profiler`` trace directory, each with
+    its module, op kind and named-scope path, on the host's clock.
+
+    Reads the newest ``.xplane.pb`` under ``trace_dir`` with
+    ``utils/xplane.py`` (the installed ``jax.profiler.ProfileData`` yields
+    the timeline but not an event's metadata statistics, where the TPU
+    profiler keeps ``tf_op``, the op's ``op_name``, and ``program_id``).
+    Accelerator planes only (``/device:...`` with an ``XLA Ops`` line);
+    container ops (``while``, ``conditional``, ``call``) are left out, so
+    durations sum to device-busy time. ``anchor_epoch_s`` is the host clock
+    read when the ``TRACE_ANCHOR`` annotation was opened
+    (``profiler.reset(trace_dir=...)`` does both): device and host planes
+    share the trace's clock, so the anchor's offset moves every event onto
+    the host's. Without it events stay on the trace's own clock (seconds
+    since the trace began).
+
+    Returns [] only for a trace with no accelerator plane (a CPU backend).
+    Raises when ``trace_dir`` holds no trace file, when the file cannot be
+    decoded, or when an accelerator plane is there and the anchor that was
+    asked for is not."""
+    from transmogrifai_tpu.utils.xplane import read_xspace
+    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir!r}")
+    planes = read_xspace(paths[-1])
+    offset_s = None
+    if anchor_epoch_s is not None:
+        for plane in planes:
+            if plane.name.startswith("/device:"):
                 continue
-            meta = {mid: m.name for mid, m in plane.event_metadata.items()}
-            best: list[tuple[float, float, str]] = []
-            best_busy = 0.0
             for line in plane.lines:
-                ivals = [(line.timestamp_ns / 1e9 + ev.offset_ps / 1e12,
-                          ev.duration_ps / 1e12,
-                          meta.get(ev.metadata_id, ""))
-                         for ev in line.events]
-                busy = sum(d for _, d, _n in ivals)
-                if busy > best_busy:
-                    best, best_busy = ivals, busy
-            out.extend(best)
+                for mid, off_ps, _dur in line.events:
+                    if plane.event_names.get(mid) == TRACE_ANCHOR:
+                        offset_s = anchor_epoch_s - (
+                            line.timestamp_ns * 1e-9 + off_ps * 1e-12)
+                        break
+    out: list[DeviceEvent] = []
+    for plane in planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        lines = {line.name: line for line in plane.lines}
+        if _OPS_LINE not in lines:
+            continue
+        if anchor_epoch_s is not None and offset_s is None:
+            raise ValueError(
+                f"trace {paths[-1]!r} has device plane {plane.name!r} but "
+                f"no {TRACE_ANCHOR!r} annotation to place it on the host "
+                "clock (host_tracer_level must be >= 1)")
+        shift = offset_s or 0.0
+        modules = {}    # program id -> module name
+        mods = lines.get(_MODULES_LINE)
+        for mid, _off, _dur in (mods.events if mods else ()):
+            name, _, pid = plane.event_names.get(mid, "").partition("(")
+            if pid.rstrip(")").isdigit():
+                modules[int(pid.rstrip(")"))] = name
+        ops = lines[_OPS_LINE]
+        base_s = ops.timestamp_ns * 1e-9 + shift
+        described: dict = {}   # metadata id -> (module, kind, scope, pid)
+        for mid, off_ps, dur_ps in ops.events:
+            what = described.get(mid)
+            if what is None:
+                stats = plane.event_stats.get(mid, {})
+                pid = int(stats.get("program_id", 0) or 0)
+                kind = _op_kind(plane.event_display.get(mid)
+                                or plane.event_names.get(mid, "?"))
+                what = described[mid] = (
+                    modules.get(pid, "?"), kind,
+                    scope_of(str(stats.get("tf_op", ""))), pid)
+            if what[1] in _CONTAINER_OPS:
+                continue
+            out.append(DeviceEvent(base_s + off_ps * 1e-12, dur_ps * 1e-12,
+                                   *what))
     return out
-
-
-def trace_device_intervals(trace_dir: str) -> list[tuple[float, float]]:
-    """Unnamed device-op intervals ``[(start_epoch_s, duration_s), ...]``
-    — the pre-existing surface; see :func:`trace_device_events` for the
-    named variant the chrome-trace export fuses with host spans."""
-    return [(s, d) for s, d, _ in trace_device_events(trace_dir)]
 
 
 @dataclass
@@ -131,8 +221,12 @@ class AppMetrics:
     #: per-DAG-stage rollup (tracing span aggregation, finalize()):
     #: label -> {"wallSeconds", "deviceSeconds", "count", "phase"}
     stages: dict = field(default_factory=dict)
-    #: named device-plane events retained at finalize() for trace export
+    #: leaf device ops (``DeviceEvent``) retained at finalize() for trace
+    #: export
     device_events: list = field(default_factory=list)
+    #: device seconds by what the op is: ``(module, scope) -> [seconds,
+    #: op count]`` (finalize(); scope "" = ops staged under no named scope)
+    device_scopes: dict = field(default_factory=dict)
 
     def record(self, step: OpStep, wall_s: float,
                peak_hbm: int = 0) -> None:
@@ -159,6 +253,23 @@ class AppMetrics:
                 total += dur
         return total
 
+    def attribute_device_scopes(self, events: list) -> float:
+        """Roll leaf device ops up by ``(module, scope)``. Returns total
+        device seconds (every op lands in exactly one row)."""
+        total = 0.0
+        for ev in events:
+            row = self.device_scopes.setdefault((ev.module, ev.scope),
+                                                [0.0, 0])
+            row[0] += ev.duration_s
+            row[1] += 1
+            total += ev.duration_s
+        return total
+
+    def top_device_scopes(self, k: int = 10) -> list[tuple[tuple, list]]:
+        """The K ``(module, scope)`` rows with most device seconds."""
+        return sorted(self.device_scopes.items(),
+                      key=lambda kv: -kv[1][0])[:k]
+
     @property
     def total_wall_s(self) -> float:
         return (self.end_time if self.end_time is not None
@@ -179,6 +290,13 @@ class AppMetrics:
                            "deviceSeconds": p.device_s}
                        for k, p in self.phases.items()},
             "stages": {k: dict(v) for k, v in self.stages.items()},
+            # device seconds by (module, named scope), most first: which
+            # program, and which part of it, the device spent its time in
+            "deviceScopes": [
+                {"module": m, "scope": sc, "deviceSeconds": v[0],
+                 "opCount": v[1]}
+                for (m, sc), v in self.top_device_scopes(
+                    len(self.device_scopes))],
             # fault-tolerance counters ride in every run summary — resume
             # and retry behavior is asserted from the same json operators
             # already collect (module global: one run's counters, reset
@@ -220,6 +338,12 @@ class AppMetrics:
                 ["Stage", "Wall (s)", "Device (s)", "Peak HBM (MB)",
                  "Count", "Phase"],
                 srows, title=f"top {len(srows)} slowest stages"))
+        if self.device_scopes:
+            drows = [(m, sc or "(unscoped)", f"{v[0]:.3f}", v[1])
+                     for (m, sc), v in self.top_device_scopes(top_k)]
+            out += "\n" + str(Table(
+                ["Module", "Scope", "Device (s)", "Ops"], drows,
+                title=f"top {len(drows)} device scopes"))
         return out
 
     def export_chrome_trace(self, path: str) -> dict:
@@ -244,10 +368,11 @@ class AppMetrics:
                        "tid": 0, "args": {"name": "phases"}})
         host_events = recorder.chrome_trace_events(pid=1)
         events.extend(host_events)
-        for start, dur, name in self.device_events:
-            events.append({"name": name or "device-op", "ph": "X",
-                           "pid": 2, "tid": 0, "ts": start * 1e6,
-                           "dur": dur * 1e6, "args": {"kind": "device"}})
+        for ev in self.device_events:
+            events.append({"name": f"{ev.module}/{ev.op}", "ph": "X",
+                           "pid": 2, "tid": 0, "ts": ev.start_s * 1e6,
+                           "dur": ev.duration_s * 1e6,
+                           "args": {"kind": "device", "scope": ev.scope}})
         # the HBM timeline (utils/devicewatch.py low-rate census) renders
         # as a chrome-trace counter track on the device process
         from transmogrifai_tpu.utils.devicewatch import hbm_timeline
@@ -275,53 +400,11 @@ def _resource_counters_json() -> dict:
     return resource_counters.to_json()
 
 
-class _CompileAttribution:
-    """Shared ``jax.monitoring`` backend-compile listener: while a
-    ``tracking(key)`` block runs, every XLA backend compile is attributed
-    to ``key`` via the subclass's ``_record_compile``. Counts stay 0 when
-    the monitoring API is unavailable; persistent-cache hits don't fire
-    the event — by design, a warm re-run reports 0 compiles."""
-
-    def __init__(self):
-        self._active = None
-        self._listening = False
-
-    def _record_compile(self, key) -> None:
-        raise NotImplementedError
-
-    def _on_compile(self, event: str, duration: float, **kw) -> None:
-        if (self._active is not None
-                and event == "/jax/core/compile/backend_compile_duration"):
-            self._record_compile(self._active)
-
-    def _ensure_listener(self) -> None:
-        if self._listening:
-            return
-        try:
-            import jax.monitoring as monitoring
-            monitoring.register_event_duration_secs_listener(self._on_compile)
-            self._listening = True
-        except Exception:  # failure-ok: monitoring API absent
-            self._listening = True  # API absent: compiles stay 0, don't retry
-
-    @contextlib.contextmanager
-    def tracking(self, key):
-        """Attribute compile events to ``key`` while the block runs."""
-        self._ensure_listener()
-        prev = self._active
-        self._active = key
-        try:
-            yield
-        finally:
-            self._active = prev
-
-
 @dataclass
 class SweepFamilyCounters:
     """Per-candidate-family sweep observability (see ``SweepCounters``)."""
     #: "fold_stacked" | "tree_stacked" | "fold_loop" | "resumed"
     mode: str = ""
-    compiles: int = 0           # XLA backend compiles while family active
     device_dispatches: int = 0  # train/score/metric program invocations
     host_syncs: int = 0         # device->host materializations (metric pulls)
     #: tree depth-groups dispatched fold x grid-stacked (round 8): on the
@@ -332,7 +415,7 @@ class SweepFamilyCounters:
     lane_chunks: int = 0
 
 
-class SweepCounters(_CompileAttribution):
+class SweepCounters:
     """ModelSelector sweep observability: per family, how many XLA
     compiles, device program dispatches, and host syncs the sweep paid.
 
@@ -342,11 +425,12 @@ class SweepCounters(_CompileAttribution):
     paths optimize: k folds x |grid| points in one dispatch and ONE host
     sync per family (linear fold-stacking), or per depth-group/lane
     chunk (tree fold x grid stacking, ``stacked_groups``/``lane_chunks``),
-    vs k (or k x L) of each on the per-fold loop. Compiles come from
-    a ``jax.monitoring`` backend-compile listener attributed to whichever
-    family is active inside ``tracking()`` (0 when the monitoring API is
-    unavailable; cache hits from the persistent XLA cache don't count —
-    by design, a warm re-run should report 0 compiles).
+    vs k (or k x L) of each on the per-fold loop. Compiles are read from
+    ``devicewatch.compile_telemetry`` (the program's one ``jax.monitoring``
+    listener): real backend compiles inside the family's
+    ``building(site, family=...)`` blocks since this run's ``reset()``
+    (0 when the monitoring API is unavailable; loads from the persistent
+    XLA cache don't count — a warm re-run reports 0 compiles).
 
     Surfaced by ``bench.py`` under ``device_time_breakdown.sweep`` and
     asserted in tests (fast path == 1 sync per family).
@@ -368,18 +452,27 @@ class SweepCounters(_CompileAttribution):
     were."""
 
     def __init__(self):
-        super().__init__()
         self.families: dict = {}  # family name -> SweepFamilyCounters
         self.sweep_host_syncs = 0   # blocking settle barriers, whole sweep
         self.async_families = 0     # families overlapped past dispatch
         self.refit_warm_starts = 0  # winner refits reusing sweep state
+        #: the telemetry's process-lifetime per-family compile counts when
+        #: this run began
+        self._compiles_at_reset: dict = {}
 
     def reset(self) -> None:
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         self.families = {}
         self.sweep_host_syncs = 0
         self.async_families = 0
         self.refit_warm_starts = 0
-        self._active = None
+        self._compiles_at_reset = compile_telemetry.family_compiles()
+
+    def compiles(self, name: str) -> int:
+        """XLA backend compiles attributed to family ``name`` this run."""
+        from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+        return (compile_telemetry.family_compiles().get(name, 0)
+                - self._compiles_at_reset.get(name, 0))
 
     def family(self, name: str) -> SweepFamilyCounters:
         return self.families.setdefault(name, SweepFamilyCounters())
@@ -403,11 +496,8 @@ class SweepCounters(_CompileAttribution):
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
 
-    def _record_compile(self, key) -> None:
-        self.family(key).compiles += 1
-
     def to_json(self) -> dict:
-        return {name: {"mode": fc.mode, "compiles": fc.compiles,
+        return {name: {"mode": fc.mode, "compiles": self.compiles(name),
                        "deviceDispatches": fc.device_dispatches,
                        "hostSyncs": fc.host_syncs,
                        "stackedGroups": fc.stacked_groups,
@@ -562,7 +652,8 @@ class ServingCounters:
 
     One instance per ``CompiledScorer``, fed by the SCORER measuring its
     own fused programs' jit-cache growth per dispatch — NOT the global
-    ``jax.monitoring`` compile listener ``SweepCounters`` uses: monitoring
+    ``jax.monitoring`` compile listener (``devicewatch.CompileTelemetry``,
+    which ``SweepCounters`` reads): monitoring
     events are process-wide, so two servers dispatching concurrently would
     cross-attribute each other's compiles (and per-instance listeners can
     never unregister). Cache-entry deltas are exact, per-program, and
@@ -639,6 +730,7 @@ class _Profiler:
         self.metrics = AppMetrics()
         self.trace_dir: Optional[str] = None
         self._tracing = False
+        self._anchor_epoch_s: Optional[float] = None
         #: per-open-phase accumulated child seconds (exclusive-wall stack)
         self._stack: list[float] = []
 
@@ -669,30 +761,38 @@ class _Profiler:
         if trace_dir is not None:
             try:
                 import jax
-                # lean trace: device timeline only (no host/python events,
-                # no HLO protos) so post-run parsing stays cheap even for
+                # lean trace: the device timeline and the program's own
+                # annotations (host level 1; no python events, no HLO
+                # protos) so post-run parsing stays cheap even for
                 # multi-minute runs
                 opts = None
                 try:
                     opts = jax.profiler.ProfileOptions()
-                    opts.host_tracer_level = 0
+                    opts.host_tracer_level = 1
                     opts.python_tracer_level = 0
                     opts.enable_hlo_proto = False
                 except Exception:  # failure-ok: ProfileOptions API is version-dependent
                     opts = None
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 self._tracing = True
+                # the anchor: one annotation whose place on the trace's
+                # clock, against the host clock read beside it, puts the
+                # device events on the host's clock at finalize()
+                self._anchor_epoch_s = time.time()
+                with jax.profiler.TraceAnnotation(TRACE_ANCHOR):
+                    pass
             except Exception:  # failure-ok: tracing optional; run continues untraced
                 self.trace_dir = None
         return self.metrics
 
     def finalize(self) -> AppMetrics:
-        """Stop the run trace (if any), parse it, and attribute device time
-        — to phases (coarse) AND to the innermost tracing span, so the
-        stage table reports true device seconds per stage. Freezes the
-        run's end timestamp and rolls the span recorder's per-stage
-        aggregation into ``metrics.stages``. Idempotent; safe without a
-        trace (device_s stays 0)."""
+        """Stop the run trace (if any), read it, and attribute device time:
+        to phases by time, to the ``(module, scope)`` table by what each op
+        is, and to the spans that are device windows (module docstring).
+        Freezes the run's end timestamp and rolls the span recorder's
+        per-stage aggregation into ``metrics.stages``. Idempotent; safe
+        without a trace (device_s stays 0). A trace that was written and
+        cannot be read raises."""
         from transmogrifai_tpu.utils.tracing import recorder
         if self._tracing:
             import jax
@@ -700,14 +800,25 @@ class _Profiler:
                 jax.profiler.stop_trace()
             finally:
                 self._tracing = False
-            events = trace_device_events(self.trace_dir)
+            events = trace_device_events(self.trace_dir,
+                                         self._anchor_epoch_s)
             self.metrics.device_events = events
             self.metrics.attribute_device_time(
-                [(s, d) for s, d, _ in events])
-            recorder.attribute_device_events(events)
+                [(ev.start_s, ev.duration_s) for ev in events])
+            self.metrics.attribute_device_scopes(events)
+            recorder.attribute_device_windows(events)
         if self.metrics.end_time is None:
             self.metrics.end_time = time.time()
-        self.metrics.stages = recorder.stage_table()
+        self.metrics.stages = stages = recorder.stage_table()
+        # a stage's ops inside a fused FE program are staged under the
+        # scope "<operation>[<uid>]": what the op is names its stage
+        by_uid = {label.rpartition(" (")[2][:-1]: row
+                  for label, row in stages.items()}
+        for (_module, scope), (seconds, _n) in \
+                self.metrics.device_scopes.items():
+            uid = scope.partition("[")[2].partition("]")[0]
+            if uid in by_uid:
+                by_uid[uid]["deviceSeconds"] += seconds
         return self.metrics
 
     @contextlib.contextmanager
@@ -728,9 +839,13 @@ class _Profiler:
                 # enqueue on every mesh device, not just device 0).
                 try:
                     import jax
-                    jax.block_until_ready(
-                        [jax.device_put(0.0, dev) + 0
-                         for dev in jax.local_devices()])
+                    from transmogrifai_tpu.utils.devicewatch import (
+                        compile_telemetry,
+                    )
+                    with compile_telemetry.building("profiler.fence"):
+                        jax.block_until_ready(
+                            [jax.device_put(0.0, dev) + 0
+                             for dev in jax.local_devices()])
                 except Exception:  # failure-ok: drain fence is best-effort
                     pass
             # record on the error path too — a failed run's post-mortem

@@ -411,7 +411,8 @@ def _devicewatch_collectors(reg: PromRegistry) -> None:
 
     reg.register(
         "transmogrifai_compile_programs_total", "counter",
-        "XLA backend compiles observed, by attributed site",
+        "XLA backend compiles observed (cache loads apart), by attributed "
+        "site",
         lambda: [({"site": s}, v["programs"])
                  for s, v in sorted(_by_site().items())]
                 or [({"site": "none"}, 0)])
@@ -419,6 +420,20 @@ def _devicewatch_collectors(reg: PromRegistry) -> None:
         "transmogrifai_compile_wall_seconds_total", "counter",
         "XLA backend compile wall seconds, by attributed site",
         lambda: [({"site": s}, v["wallSeconds"])
+                 for s, v in sorted(_by_site().items())]
+                or [({"site": "none"}, 0)])
+    reg.register(
+        "transmogrifai_compile_cache_loads_total", "counter",
+        "programs served by the persistent compilation cache, by "
+        "attributed site",
+        lambda: [({"site": s}, v["cacheLoads"])
+                 for s, v in sorted(_by_site().items())]
+                or [({"site": "none"}, 0)])
+    reg.register(
+        "transmogrifai_compile_cache_load_seconds_total", "counter",
+        "wall seconds spent loading programs from the persistent "
+        "compilation cache, by attributed site",
+        lambda: [({"site": s}, v["loadSeconds"])
                  for s, v in sorted(_by_site().items())]
                 or [({"site": "none"}, 0)])
     reg.register(
@@ -516,9 +531,12 @@ def _app_collectors(reg: PromRegistry) -> None:
                      lambda a=attr: [({}, getattr(rc, a))])
 
     sc = profiling.sweep_counters
-    for attr, help_ in (("compiles", "XLA backend compiles during the "
-                                     "family's sweep"),
-                        ("device_dispatches", "sweep device program "
+    reg.register(
+        "transmogrifai_sweep_compiles_total", "counter",
+        "XLA backend compiles during the family's sweep",
+        lambda: [({"family": name}, sc.compiles(name))
+                 for name in sc.families])
+    for attr, help_ in (("device_dispatches", "sweep device program "
                                               "dispatches"),
                         ("host_syncs", "sweep device->host metric pulls"),
                         ("stacked_groups", "tree depth-groups dispatched "

@@ -12,6 +12,13 @@ plane) and device dispatches run under ``jax.named_scope``, a
 ``jax.profiler`` run trace can be fused with this host tree into one
 Perfetto/chrome://tracing JSON (``AppMetrics.export_chrome_trace``).
 
+A span times the HOST. JAX dispatch is asynchronous, so a span around a
+dispatch (``sweep.family``, ``fe.fused``) is milliseconds whatever the
+program costs; device time is owned elsewhere: by the spans the selector
+stamps from the device's own progress (``sweep.device``,
+``refit.device``) and, under a trace, by ``(module, named scope)``
+(``utils/profiling.py``).
+
 Design constraints:
 
 - **cheap when idle**: a disabled recorder costs one attribute check per
@@ -36,6 +43,7 @@ run.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import contextvars
@@ -93,7 +101,7 @@ class Span:
     t1: float
     thread: str
     attrs: dict = field(default_factory=dict)
-    device_s: float = 0.0       # attributed at finalize (device plane)
+    device_s: float = 0.0       # finalize(): device-window spans only
     peak_hbm_bytes: int = 0     # device peak growth while open (hbm=True)
 
     @property
@@ -226,46 +234,30 @@ class SpanRecorder:
             self._spans.append(s)
 
     # -- device attribution ---------------------------------------------------
-    def attribute_device_events(
-            self, events: list[tuple[float, float, str]]) -> float:
-        """Bucket device-op events into the innermost containing span
-        (latest-started span whose wall window contains the op midpoint —
-        the same ownership rule ``AppMetrics.attribute_device_time`` uses
-        for phases). Returns total attributed device seconds.
-
-        Sweep-line, not scan-per-event: a real accelerator trace carries
-        1e5+ device ops against 1e4+ host spans, and the naive
-        O(events x spans) product is minutes of post-run Python for a run
-        that took seconds. Events and spans both sort by time; spans
-        become "active" as the sweep passes their start and are removed
-        for good once their end precedes the current midpoint (a dead
-        span can never own a later event), so the whole attribution is
-        O((E + S) log (E + S)) from the sorts plus an amortized-linear
-        active-list walk."""
-        spans = sorted(self.spans, key=lambda s: s.t0)
-        mids = sorted((start + dur / 2.0, dur, i)
-                      for i, (start, dur, _name) in enumerate(events))
-        total = 0.0
-        active: list[Span] = []   # t0-ascending; innermost = rightmost live
-        si = 0
-        for mid, dur, _i in mids:
-            while si < len(spans) and spans[si].t0 <= mid:
-                active.append(spans[si])
-                si += 1
-            owner = None
-            j = len(active) - 1
-            while j >= 0:
-                s = active[j]
-                if s.t1 < mid:
-                    active.pop(j)   # expired: no future mid is smaller
-                else:
-                    owner = s
-                    break
-                j -= 1
-            if owner is not None:
-                owner.device_s += dur
-                total += dur
-        return total
+    def attribute_device_windows(self, events) -> None:
+        """Fill ``device_s`` of the spans opened with ``device_window=True``:
+        those whose wall window does hold the device ops run for them —
+        a program's device interval stamped from the device's own progress
+        (``sweep.device``, ``refit.device``), or a span that ends in a
+        blocking device->host pull, so that everything enqueued inside has
+        run by its end (``selector.sweep``, ``sweep.settle``,
+        ``selector.refit``). Each gets the device seconds of the leaf ops
+        (``.start_s``, ``.duration_s``) whose midpoint lies in its window;
+        windows may nest, so these are inclusive sums like ``wall_s``.
+        Every other span stays 0: spans are not fenced, and an op's time
+        tells nothing about which open span it ran for — device time
+        below the phases is owned through what the op is
+        (``AppMetrics.device_scopes``)."""
+        mids = sorted((ev.start_s + ev.duration_s / 2.0, ev.duration_s)
+                      for ev in events)
+        times = [m for m, _ in mids]
+        cum = [0.0]
+        for _, dur in mids:
+            cum.append(cum[-1] + dur)
+        for s in self.spans:
+            if s.attrs.get("device_window"):
+                s.device_s = (cum[bisect.bisect_right(times, s.t1)]
+                              - cum[bisect.bisect_left(times, s.t0)])
 
     # -- aggregation ----------------------------------------------------------
     def aggregate(self, key: str = "name") -> dict[str, dict]:
@@ -296,8 +288,9 @@ class SpanRecorder:
         the same uid — the selector's ``selector.sweep``/``selector.refit``
         nest inside its ``stage.fit`` span, and summing parent and children
         would double-count the stage's wall. Device seconds sum over every
-        span of the uid: each device event attributes to exactly one
-        (innermost) span, so nesting cannot double-count them."""
+        span of the uid that carries any: only ``device_window`` spans do
+        (``attribute_device_windows``), and the selector's two
+        (``selector.sweep``, ``selector.refit``) are disjoint."""
         by_id = {s.span_id: s for s in self.spans}
 
         def has_same_uid_ancestor(s: Span, uid) -> bool:
