@@ -274,6 +274,163 @@ def test_grow_tree_sorted_weighted_and_empty_nodes():
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=1e-4)
 
 
+def _exact_sum_rows(n, d, B, seed, zero_weight=0.0):
+    """(Xb, grad, hess) whose sums are exact in float32 in any order
+    (eighths and quarters), so two histogram engines that see the same
+    rows read the same floats and must pick the same splits."""
+    rng = np.random.default_rng(seed)
+    Xb = jnp.asarray(rng.integers(0, B, size=(n, d)), jnp.int32)
+    w = (rng.uniform(size=n) >= zero_weight).astype(np.float32)
+    grad = jnp.asarray(rng.integers(-8, 9, size=n) / 8.0 * w, jnp.float32)
+    hess = jnp.asarray(rng.integers(1, 5, size=n) / 4.0 * w, jnp.float32)
+    return Xb, grad, hess
+
+
+@pytest.mark.parametrize("B", [64, 200])
+@pytest.mark.parametrize("d", [4, 7, 28])
+def test_sorted_packed_rows_round_trip(d, B):
+    """One gather of the packed rows equals the three gathers it replaces,
+    bitwise: int8 words (B 64) and int32 codes (B 200), d a multiple of 4
+    and not."""
+    from transmogrifai_tpu.models.trees import _pack_rows, _unpack_rows
+    n = 257
+    Xb, _, _ = _exact_sum_rows(n, d, B, seed=d + B)
+    rng = np.random.default_rng(d * B)
+    grad = jnp.asarray(rng.normal(size=n), jnp.float32).at[3].set(-0.0)
+    hess = jnp.asarray(rng.uniform(size=n), jnp.float32).at[5].set(jnp.inf)
+    src_row = jnp.asarray(rng.integers(0, n, size=4 * n), jnp.int32)
+    packed = _pack_rows(Xb, grad, hess, B)
+    assert packed.dtype == jnp.int32 and packed.shape[1] % 8 == 0
+    codes, gp, hp = _unpack_rows(packed[src_row], d, B)
+    assert codes.dtype == (jnp.int8 if B <= 127 else jnp.int32)
+    np.testing.assert_array_equal(np.asarray(codes, np.int32),
+                                  np.asarray(Xb[src_row]))
+    for got, want in ((gp, grad[src_row]), (hp, hess[src_row])):
+        np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                      np.asarray(want).view(np.int32))
+
+
+@pytest.mark.parametrize("folds", [1, 2])
+def test_sorted_matches_scatter_blocked_cumsum(folds):
+    """Sorted against scatter, identical feats and bins, at a size whose
+    partition takes ``_long_cumsum``'s blocked branch and whose levels use
+    several block sizes C; zero-weight rows, and nodes that stop
+    splitting (empty right children below them); plain and under a fold
+    ``vmap`` as the stacked sweep runs it."""
+    import jax
+    from transmogrifai_tpu.models.trees import _CUMSUM_BLOCK, grow_tree
+    n, d, B, depth = 12_000, 7, 16, 8
+    assert n > 8 * _CUMSUM_BLOCK
+    cases = [_exact_sum_rows(n, d, B, seed=21 + f, zero_weight=0.4)
+             for f in range(folds)]
+    mask = jnp.ones(d, jnp.float32)
+    kw = dict(max_depth=depth, n_bins=B, reg_lambda=jnp.float32(1.0),
+              gamma=jnp.float32(0.0), min_child_weight=jnp.float32(12.0))
+
+    def grow(hist):
+        def one(Xb, grad, hess):
+            return grow_tree(Xb, grad, hess, mask, hist=hist, **kw)
+        if folds == 1:
+            return [one(*cases[0])]
+        out = jax.vmap(one)(*(jnp.stack(a) for a in zip(*cases)))
+        return [jax.tree_util.tree_map(lambda a: a[f], out)
+                for f in range(folds)]
+
+    for ref, got in zip(grow("scatter"), grow("sorted")):
+        for a, b in zip(ref[0] + ref[1], got[0] + got[1]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        deep = np.asarray(ref[0][-1])
+        assert (deep < 0).any() and (deep >= 0).any()  # stopped + split
+        np.testing.assert_allclose(np.asarray(ref[2]), np.asarray(got[2]),
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(ref[4]), np.asarray(got[4]),
+                                   atol=1e-6)
+
+
+def test_sorted_layout_valid_prefix_cumsum():
+    """What lets the partition do with ONE long cumsum: the valid slots
+    are the first of each node's segment, in node order, so
+    ``cumsum(valid)`` is known in closed form from the per-block layout
+    (rows before the node + slots up to this one, capped at the node's
+    count) — on a layout with empty and one-row nodes. The per-block
+    read of ``order`` puts the sorted rows, in order, on the valid
+    slots."""
+    from transmogrifai_tpu.models.trees import _block_rows, _sorted_layout
+    counts = jnp.asarray([0, 1, 5, 0, 17, 1, 0, 8], jnp.int32)
+    n, C = 32, 4
+    lay = _sorted_layout(counts, n, C)
+    nb = lay.valid.shape[0]
+    assert nb * C == -(-n // C) * C + counts.shape[0] * C
+    starts = jnp.cumsum(counts) - counts
+    closed = starts[lay.bnode][:, None] + jnp.clip(
+        lay.within + 1, 0, counts[lay.bnode][:, None])
+    np.testing.assert_array_equal(
+        np.asarray(closed).reshape(-1),
+        np.cumsum(np.asarray(lay.valid).reshape(-1)))
+    assert int(np.asarray(lay.within).min()) >= 0
+    order = jnp.pad(jnp.arange(100, 100 + n, dtype=jnp.int32), (0, 2 * C))
+    src_row = np.asarray(_block_rows(order, lay.src_start, C))
+    np.testing.assert_array_equal(
+        src_row[np.asarray(lay.valid).reshape(-1)], np.arange(100, 100 + n))
+
+
+@pytest.mark.parametrize("C", [8, 128, 256])
+def test_block_rows_reads_unaligned_runs(C):
+    """``_block_rows`` (two aligned row gathers + a barrel shifter) equals
+    the plain slices ``order[s : s + C]`` at every alignment of ``s``,
+    the last row id included."""
+    from transmogrifai_tpu.models.trees import _block_rows
+    n = 5 * C + 3
+    n_order = (n // C + 2) * C
+    order = jnp.pad(jnp.arange(7, 7 + n, dtype=jnp.int32), (0, n_order - n))
+    starts = np.concatenate([np.arange(0, 2 * C + 1), [n - C, n - 1, n]])
+    got = np.asarray(_block_rows(order, jnp.asarray(starts, jnp.int32), C))
+    want = np.stack([np.asarray(order)[s:s + C] for s in starts])
+    np.testing.assert_array_equal(got.reshape(-1, C), want)
+
+
+def _count_long_moves(jaxpr, n):
+    """(gathers, scatters) among a jaxpr's equations, nested ones
+    included, that move ``n`` slots or more one by one: a gather whose
+    output, or a scatter whose updates, has a leading dimension >= n."""
+    g = s = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" and eqn.outvars[0].aval.shape[:1] >= (n,):
+            g += 1
+        elif name.startswith("scatter") \
+                and eqn.invars[2].aval.shape[:1] >= (n,):
+            s += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    dg, ds = _count_long_moves(sub, n)
+                    g, s = g + dg, s + ds
+    return g, s
+
+
+def test_sorted_level_moves_each_row_once():
+    """The counter of the per-level row movement: one more level adds at
+    most 2 gathers and 1 scatter as long as the rows (the packed row
+    gather, and the partition's scatter), where the table-per-slot form
+    added 14 and 1. Per-block lookups (``nb`` long) do not count."""
+    import jax
+    from transmogrifai_tpu.models.trees import _grow_tree_sorted
+    n, d, B = 20_000, 28, 64
+    Xb, grad, hess = _exact_sum_rows(n, d, B, seed=5)
+
+    def moves(depth):
+        jaxpr = jax.make_jaxpr(lambda X, g, h: _grow_tree_sorted(
+            X, g, h, jnp.ones(d, jnp.float32), max_depth=depth, n_bins=B,
+            reg_lambda=jnp.float32(1.0), gamma=jnp.float32(0.0),
+            min_child_weight=jnp.float32(1.0)))(Xb, grad, hess)
+        return _count_long_moves(jaxpr.jaxpr, n)
+
+    (g3, s3), (g4, s4) = moves(3), moves(4)
+    assert 1 <= g4 - g3 <= 2 and s4 - s3 == 1, ((g3, s3), (g4, s4))
+
+
 def test_train_ensemble_sorted_multiclass_parity():
     """hist='sorted' must thread through the scanned ensemble under the
     multiclass vmap (per-class independent routing) and bootstrap."""

@@ -39,7 +39,7 @@ the class-probability estimate (variance-reduction splits ~ gini for binary).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -200,9 +200,10 @@ def _long_cumsum(x):
     form (within-block cumsum + a cumsum of the block totals). XLA:TPU
     takes 10+ s to COMPILE one flat reduce-window over ~10^5-10^6
     elements (AOT-compiled for v5e: 13 s at 600k rows vs 0.3 s blocked)
-    and the sorted grower emits two per tree level — minutes of compile
-    per depth-group. Exact for the int32 position sums; for f32 the
-    summation order differs from the flat form by rounding only."""
+    and the sorted grower emits one per tree level and two per tree for
+    the leaves — minutes of compile per depth-group. Exact for the int32
+    position sums; for f32 the summation order differs from the flat
+    form by rounding only."""
     n = x.shape[0]
     if n <= 8 * _CUMSUM_BLOCK:
         return jnp.cumsum(x)
@@ -257,15 +258,36 @@ def _sorted_acc_default() -> str:
     return "auto"
 
 
-def _sorted_layout(counts, n: int, C: int):
+class _SortedLayout(NamedTuple):
+    """One level's padded block layout (``_sorted_layout``). ``N`` nodes,
+    ``nb`` blocks of ``C`` slots, ``n_pad = nb * C`` slots."""
+    bnode: jax.Array      # [nb] the node each block belongs to
+    within: jax.Array     # [nb, C] a slot's position in its node's segment
+    valid: jax.Array      # [nb, C] the slot holds a row (False: padding)
+    src_start: jax.Array  # [nb] sorted-row position of a block's first slot
+    pstarts: jax.Array    # [N] first padded slot of each node
+    pends: jax.Array      # [N] one past the last padded slot of each node
+    pcounts: jax.Array    # [N] padded slots per node (a multiple of C)
+
+
+def _sorted_layout(counts, n: int, C: int) -> _SortedLayout:
     """Padded block layout for rows grouped by node.
 
     ``counts``: [N] rows per node (sorted-order segments). Every node's
     segment is padded to a multiple of the block size ``C`` so each
-    C-row block belongs to exactly one node; total padded length is the
-    static ``ceil(n/C)*C + N*C``. Returns (snode, valid, src_sorted,
-    pstarts, pends, pcounts, nb) where ``src_sorted`` maps padded slots
-    to sorted-row positions and ``valid`` masks the real rows.
+    C-slot block belongs to exactly one node; total padded length is the
+    static ``ceil(n/C)*C + N*C``.
+
+    Everything a slot shares with the other slots of its block is looked
+    up PER BLOCK (``nb = n_pad / C`` gathers from the [N] node tables:
+    ``bnode``, and through it the node's start, count and padded start)
+    and reaches the slots as a ``[nb, 1]`` operand of ``[nb, C]``
+    arithmetic. Per slot there is only that arithmetic: ``within`` and
+    ``valid``. A block's slots map to CONSECUTIVE sorted rows, so the
+    layout gives one source position per block (``src_start``), not one
+    per slot. Within a node's segment the valid slots come first, and
+    ``within >= 0`` always (a block starts inside its own node's
+    segment); the trailing blocks past the last node read as invalid.
     """
     N = counts.shape[0]
     ends = jnp.cumsum(counts)
@@ -278,15 +300,69 @@ def _sorted_layout(counts, n: int, C: int):
     block_first = jnp.arange(nb, dtype=jnp.int32) * C
     bnode = jnp.clip(jnp.searchsorted(pends, block_first, side="right"),
                      0, N - 1).astype(jnp.int32)
-    # static scalar repeat = one broadcast+reshape (n_pad == nb * C); a
-    # ``total_repeat_length`` would route jnp.repeat through its general
-    # scatter + n_pad-long cumsum + gather form
-    snode = jnp.repeat(bnode, C)
-    slot = jnp.arange(n_pad, dtype=jnp.int32)
-    within = slot - pstarts[snode]
-    valid = (within >= 0) & (within < counts[snode])
-    src_sorted = jnp.clip(starts[snode] + within, 0, max(n - 1, 0))
-    return snode, valid, src_sorted, pstarts, pends, pcounts, nb
+    boff = block_first - pstarts[bnode]
+    within = boff[:, None] + jnp.arange(C, dtype=jnp.int32)
+    valid = within < counts[bnode][:, None]
+    src_start = jnp.clip(starts[bnode] + boff, 0, n)
+    return _SortedLayout(bnode, within, valid, src_start, pstarts, pends,
+                         pcounts)
+
+
+def _block_rows(order, src_start, C: int):
+    """[nb * C] row ids of the padded slots: per block ONE contiguous
+    C-long run of ``order`` starting at ``src_start``. Read per block, not
+    per slot: the run lies in two consecutive rows of ``order`` viewed as
+    ``[len / C, C]`` (two gathers of ``nb`` rows), and a barrel shifter
+    (``log2 C`` static rolls, each selected per block by one bit of
+    ``src_start % C``) rotates it to the front. XLA:TPU runs a gather of
+    ``nb`` unaligned C-long slices as a loop of ``nb`` dynamic slices
+    (1.25 us a block, and as many trace events). ``len(order)`` is a
+    multiple of ``C`` with at least ``2 * C`` entries past
+    ``src_start``'s largest value."""
+    rows = order.reshape(-1, C)
+    r, off = src_start // C, src_start % C
+    two = jnp.concatenate([rows[r], rows[r + 1]], axis=1)
+    for k in range((C - 1).bit_length()):
+        two = jnp.where((off >> k & 1)[:, None] == 1,
+                        jnp.roll(two, -(1 << k), axis=1), two)
+    return two[:, :C].reshape(-1)
+
+
+def _pack_rows(Xb, grad, hess, n_bins: int):
+    """[n, W] int32: all a row carries through the level loop, so that a
+    level moves a row in ONE gather. Bin codes below 128 go four int8 to
+    a word (``d`` padded to a multiple of 4), wider codes one int32 each;
+    ``grad`` and ``hess`` follow as their float32 BITS (bitcast, nothing
+    rounds); ``W`` is padded to a multiple of 8, which is what the TPU's
+    (8, 128) tiling stores for a narrow second-minor dimension anyway
+    (and a [n, 9] row gather compiled 5-9x slower than [n, 8] or
+    [n, 16], v5e). ``_unpack_rows`` is the exact inverse."""
+    n, d = Xb.shape
+    if n_bins <= 127:
+        codes = jnp.pad(Xb.astype(jnp.int8), ((0, 0), (0, -d % 4)))
+        words = jax.lax.bitcast_convert_type(
+            codes.reshape(n, -1, 4), jnp.int32)
+    else:
+        words = Xb.astype(jnp.int32)
+    gh = jax.lax.bitcast_convert_type(
+        jnp.stack([grad, hess], axis=1).astype(jnp.float32), jnp.int32)
+    packed = jnp.concatenate([words, gh], axis=1)
+    return jnp.pad(packed, ((0, 0), (0, -packed.shape[1] % 8)))
+
+
+def _unpack_rows(rows, d: int, n_bins: int):
+    """(codes [m, d] int8 or int32, grad [m], hess [m]) out of ``m``
+    gathered rows of ``_pack_rows``'s matrix."""
+    if n_bins <= 127:
+        n_words = -(-d // 4)
+        codes = jax.lax.bitcast_convert_type(
+            rows[:, :n_words], jnp.int8).reshape(rows.shape[0], -1)[:, :d]
+    else:
+        n_words = d
+        codes = rows[:, :d]
+    gh = jax.lax.bitcast_convert_type(rows[:, n_words:n_words + 2],
+                                      jnp.float32)
+    return codes, gh[:, 0], gh[:, 1]
 
 
 def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype,
@@ -303,7 +379,8 @@ def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype,
     the block cumsum is accumulated in scratch during the same pass.
     ``"einsum"`` is the pure-XLA oracle (and the off-TPU default).
     """
-    snode, valid, src_sorted, pstarts, pends, pcounts, nb = layout
+    pstarts, pends, pcounts = layout.pstarts, layout.pends, layout.pcounts
+    nb = layout.valid.shape[0]
     counts_pos = pcounts > 0
     n_pad, d = Xp.shape
     B = n_bins
@@ -353,34 +430,45 @@ def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype,
     return hist[:, 0], hist[:, 1]
 
 
-def _sorted_partition(counts, layout, go_left, src_row, n: int):
+def _sorted_partition(counts, layout: _SortedLayout, go_left, src_row,
+                      n_order: int):
     """Stable in-segment partition: the next level's ``order`` groups rows
-    by ``2*node + go_right`` using cumsums and one unique-index scatter —
-    the incremental analog of re-sorting by node each level.
+    by ``2*node + go_right`` using ONE long cumsum and one unique-index
+    scatter — the incremental analog of re-sorting by node each level.
+
+    ``go_left``: [nb, C]; ``src_row``: [nb * C] row ids of the slots;
+    ``n_order``: length of the returned ``order`` (the rows plus the
+    spare entries ``_block_rows`` wants). Per slot: the cumsum of the
+    left-goers, the destination arithmetic and the scatter. Per block
+    (``[nb]`` lookups through ``layout.bnode``): the node's left-goer
+    base and its two children's start positions. Per node: the bases and
+    the new counts. The right-goers need no cumsum of their own: a
+    node's valid slots are the first of its segment, so ``within`` slots
+    precede a valid slot in its node, and those that do not go left go
+    right.
     """
-    snode, valid, _, pstarts, pends, pcounts, _ = layout
-    n_pad = snode.shape[0]
+    bnode, within, valid = layout.bnode, layout.within, layout.valid
+    pstarts, pends, pcounts = layout.pstarts, layout.pends, layout.pcounts
+    nb, C = valid.shape
+    n_pad = nb * C
     N = counts.shape[0]
     glv = (go_left & valid).astype(jnp.int32)
-    grv = ((~go_left) & valid).astype(jnp.int32)
-    cl = _long_cumsum(glv)
-    cr = _long_cumsum(grv)
+    cl = _long_cumsum(glv.reshape(-1))
     pfirst = jnp.clip(pstarts - 1, 0, n_pad - 1)
     plast = jnp.clip(pends - 1, 0, n_pad - 1)
     base_l = jnp.where(pstarts > 0, cl[pfirst], 0)
-    base_r = jnp.where(pstarts > 0, cr[pfirst], 0)
     nl = jnp.where(pcounts > 0, cl[plast] - base_l, 0)
     new_counts = jnp.stack([nl, counts - nl], axis=1).reshape(2 * N)
     new_ends = jnp.cumsum(new_counts)
-    new_starts = new_ends - new_counts
-    pl = cl - glv - base_l[snode]
-    pr = cr - grv - base_r[snode]
-    dest = jnp.where(go_left, new_starts[2 * snode] + pl,
-                     new_starts[2 * snode + 1] + pr)
-    # invalid slots get DISTINCT out-of-range sentinels (n + slot) so the
+    new_starts = (new_ends - new_counts).reshape(N, 2)[bnode]
+    pl = cl.reshape(nb, C) - glv - base_l[bnode][:, None]
+    dest = jnp.where(go_left, new_starts[:, :1] + pl,
+                     new_starts[:, 1:] + (within - pl))
+    # invalid slots get DISTINCT out-of-range sentinels so the
     # unique_indices promise stays true even for dropped updates
-    dest = jnp.where(valid, dest, n + jnp.arange(n_pad, dtype=jnp.int32))
-    new_order = jnp.zeros(n, jnp.int32).at[dest].set(
+    dest = jnp.where(valid, dest, n_order + jnp.arange(
+        n_pad, dtype=jnp.int32).reshape(nb, C))
+    new_order = jnp.zeros(n_order, jnp.int32).at[dest.reshape(-1)].set(
         src_row, mode="drop", unique_indices=True)
     return new_order, new_counts
 
@@ -408,18 +496,19 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
 
     Same contract as the scatter-path ``grow_tree`` body: returns
     (feats, bins, leaf_values, feat_gain, row_pred). Maintains ``order``
-    (row ids
-    grouped by node) and per-node ``counts`` across levels so each level
-    runs: one int8 row gather into the padded block layout, one MXU
-    one-hot contraction for ALL (node, feature, bin) histograms, a
-    cumsum boundary diff, and a cumsum-based stable partition. No
-    scatter-adds and no node-count-dependent chunking.
+    (row ids grouped by node) and per-node ``counts`` across levels. A
+    level does PER SLOT only what differs per slot: one gather of the
+    packed rows (codes, grad and hess in one int32 matrix, built once a
+    tree) into the padded block layout, the MXU one-hot contraction for
+    ALL (node, feature, bin) histograms, one long cumsum and one
+    unique-index scatter for the stable partition. Whatever is constant
+    over a block of ``C`` slots — the node's layout entries, its split
+    feature and bin, its children's positions, the run of ``order`` the
+    block reads — is looked up PER BLOCK (``nb = n_pad / C`` entries)
+    and broadcast. No scatter-adds and no node-count-dependent chunking.
     """
     n, d = Xb.shape
     B = n_bins
-    # bin codes are < B; pack to the narrowest gatherable int so the
-    # per-level row gather moves 4x fewer bytes
-    Xb_n = Xb.astype(jnp.int8) if B <= 127 else Xb.astype(jnp.int32)
     if sorted_acc == "f32":
         acc_dtype = jnp.float32
     elif sorted_acc == "bf16":
@@ -435,8 +524,14 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
         engine = "einsum"
     split_kw = dict(n_bins=B, reg_lambda=reg_lambda, gamma=gamma,
                     min_child_weight=min_child_weight)
-    order = jnp.arange(n, dtype=jnp.int32)
+    packed = _pack_rows(Xb, grad, hess, B)
+    # every level's C is a power of two that divides ``block``; ``order``
+    # is the n row ids and spare entries up to what ``_block_rows`` wants
+    block = _pow2_at_most(block)
+    n_order = (n // block + 2) * block
+    order = jnp.pad(jnp.arange(n, dtype=jnp.int32), (0, n_order - n))
     counts = jnp.full((1,), n, jnp.int32)
+    iota_d = jnp.arange(d, dtype=jnp.int32)
     feats_out, bins_out = [], []
     feat_gain = jnp.zeros(d, jnp.float32)
     for level in range(max_depth):
@@ -448,12 +543,11 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
         with device_scope(f"tree.L{level}"):
             with device_scope("gather"):
                 layout = _sorted_layout(counts, n, C)
-                snode, valid, src_sorted, *_ = layout
-                src_row = order[src_sorted]
-                Xp = Xb_n[src_row]
-                vf = valid.astype(grad.dtype)
-                gp = grad[src_row] * vf
-                hp = hess[src_row] * vf
+                src_row = _block_rows(order, layout.src_start, C)
+                Xp, gp, hp = _unpack_rows(packed[src_row], d, B)
+                vf = layout.valid.reshape(-1).astype(gp.dtype)
+                gp = gp * vf
+                hp = hp * vf
             with device_scope("hist"):
                 hist_g, hist_h = _sorted_hist(Xp, gp, hp, layout, n_bins=B,
                                               C=C, acc_dtype=acc_dtype,
@@ -474,14 +568,17 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
                 bins_out.append(bin_)
                 feat_gain = feat_gain.at[jnp.clip(feat, 0)].add(gain)
             with device_scope("partition"):
-                fp = feat[snode]
-                bp = bin_[snode]
-                xp = jnp.take_along_axis(
-                    Xp, jnp.clip(fp, 0)[:, None].astype(jnp.int32),
-                    axis=1)[:, 0].astype(jnp.int32)
-                go_left = jnp.where(fp < 0, True, xp <= bp)
+                # split feature and bin per block; a slot's code of that
+                # feature is picked out of the d codes it already holds
+                fb = feat[layout.bnode][:, None]
+                bb = bin_[layout.bnode][:, None]
+                xp = jnp.sum(jnp.where(
+                    iota_d == fb[:, :, None],
+                    Xp.reshape(-1, C, d).astype(jnp.int32), 0), axis=2)
+                go_left = (fb < 0) | (xp <= bb)
                 order, counts = _sorted_partition(counts, layout, go_left,
-                                                  src_row, n)
+                                                  src_row, n_order)
+    order = order[:n]
     with device_scope("tree.leaf"):
         leaf_g = _segment_sums(grad[order], counts)
         leaf_h = _segment_sums(hess[order], counts)
