@@ -520,6 +520,69 @@ def test_real_cache_hit_event_order_and_site(tmp_path):
     assert "compile.cache_load:test.load" in names
 
 
+def test_second_workflow_loads_its_fe_programs(tmp_path):
+    """Every ``Workflow`` draws new stage uids. The training executor's
+    fused feature-engineering programs take and return positional leaves,
+    so the uids stay out of the module and of the persistent cache's key:
+    a second train of the same shapes loads the programs the first
+    compiled."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.features.builder import FeatureBuilder
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.preparators.sanity_checker import SanityChecker
+    from transmogrifai_tpu.types import feature_types as ft
+    from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+    from transmogrifai_tpu.workflow import Workflow
+    compile_telemetry.ensure_listener()
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(400, 3))
+    frame = fr.HostFrame.from_dict({
+        "a": (ft.Real, x[:, 0]), "b": (ft.Real, x[:, 1]),
+        "c": (ft.Real, x[:, 2]),
+        "label": (ft.RealNN, (x[:, 0] + x[:, 1] > 0).astype(np.float64))})
+
+    def train():
+        feats = FeatureBuilder.from_frame(frame, response="label")
+        label = feats.pop("label")
+        vec = transmogrify(list(feats.values()))
+        checked = label.transform_with(SanityChecker(), vec)
+        Workflow().set_input_frame(frame).set_result_features(
+            checked).train()
+
+    def fe_sites():
+        by = compile_telemetry.to_json()["bySite"]
+        return tuple(sum(by.get(s, {}).get(k, 0)
+                         for s in ("fe.fused", "fe.layer"))
+                     for k in ("programs", "cacheLoads"))
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        p0, l0 = fe_sites()
+        train()
+        p1, l1 = fe_sites()
+        if p1 == p0:
+            pytest.skip("jax.monitoring backend-compile events unavailable")
+        if not any(tmp_path.iterdir()):
+            pytest.skip("this backend wrote no persistent cache entry")
+        train()
+        p2, l2 = fe_sites()
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    # the second train compiled no FE program: it loaded what it ran
+    assert p2 == p1 and l2 > l1 == l0
+
+
 def test_compile_telemetry_real_sweep_series(monkeypatch):
     """Real-compile integration: backend compiles observed during a
     stacked sweep land in the telemetry, attributed to sweep sites, and

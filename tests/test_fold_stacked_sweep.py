@@ -242,12 +242,12 @@ def test_checkpoint_resume_mid_sweep_per_family_keys(tmp_path, monkeypatch):
     sel = make_sel()
     lr = sel.models_and_grids[0][0]
     calls = {"n": 0}
-    orig = lr.grid_scores_folds
+    orig = lr.sweep_folds
 
     def counting(*a, **k):
         calls["n"] += 1
         return orig(*a, **k)
-    lr.grid_scores_folds = counting
+    lr.sweep_folds = counting
     model = _train(sel, frame)
     assert calls["n"] == 0  # replayed from the per-family checkpoint
     s = model.selector_summary()

@@ -237,13 +237,11 @@ def test_settle_isolates_poisoned_family():
     raises is isolated without touching already-dispatched peers."""
 
     class BoomSVC(OpLinearSVC):
-        def grid_scores_folds(self, X, y, w, grid, Xva, _n_classes=None):
+        def sweep_folds(self, batch, grid, _n_classes=None):
             raise RuntimeError("boom at dispatch")
 
-    # NOTE: BoomSVC overrides below the opt-in, so capability routing
-    # would send it to the loop — force the stacked attempt by keeping
-    # the override AT the opt-in method itself (grid_scores_folds is in
-    # the opt-in set, so BoomSVC still supports fold stacking).
+    # NOTE: the override sits AT the selector's stacked unit itself, so
+    # capability routing still sends BoomSVC down the stacked path.
     frame = _frame(seed=11)
     sel = BinaryClassificationModelSelector.with_cross_validation(
         n_folds=2, seed=1,

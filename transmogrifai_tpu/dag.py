@@ -287,15 +287,21 @@ class DagExecutor:
         if host_ts:
             # host transformers run eagerly one at a time — each gets its
             # own stage span (the "which vectorizer is slow" answer)
-            new_host = {}
+            new_host, new_dev = {}, {}
             for t in host_ts:
                 with compile_telemetry.building(
                             f"stage.transform:{type(t).__name__}"), \
                         span("stage.transform", hbm=True, stage_uid=t.uid,
                              stage_cls=type(t).__name__,
                              op=t.operation_name, phase="transform"):
-                    new_host[t.get_output().name] = t.output_column(data)
+                    out = t.device_output_column(data)
+                    if out is None:
+                        new_host[t.get_output().name] = t.output_column(data)
+                    else:
+                        new_dev[t.get_output().name] = out
             data = data.with_host_cols(new_host)
+            if new_dev:
+                data = data.with_device_cols(new_dev)
         if dev_ts:
             from transmogrifai_tpu.utils.retry import with_device_retry
             fused = self._fused_program(dev_ts)
@@ -326,8 +332,7 @@ class DagExecutor:
         cached = self._fused_cache.get(key)
         if cached is not None:
             return cached
-        base = fuse_layer_program(dev_ts)  # precision-ok: training executor is f32 by contract
-        compiled = lambda params, in_cols: base(params, {}, in_cols)  # noqa: E731
+        compiled = _positional_program([list(dev_ts)])
         self._fused_cache[key] = compiled
         return compiled
 
@@ -353,9 +358,7 @@ class DagExecutor:
         key = tuple(t.uid for t in stages)
         prog = self._fused_dag_cache.get(key)
         if prog is None:
-            base = fuse_dag_program(layers)  # precision-ok: training executor is f32 by contract
-            prog = lambda params, in_cols: base(params, {}, in_cols)  # noqa: E731
-            self._fused_dag_cache[key] = prog
+            prog = self._fused_dag_cache[key] = _positional_program(layers)
         params = {t.uid: t.device_params() for t in stages}
         produced = {t.get_output().name for t in stages}
         in_names = [n for t in stages for n in t.runtime_input_names()
@@ -444,6 +447,12 @@ def fuse_dag_program(layers: Sequence[Sequence[Transformer]],
     (``QuantizedTensor`` weights dequantize, ``ExactTensor`` leaves keep
     their stored dtype) and cast float output leaves back to f32, so
     callers always see f32 results regardless of rung."""
+    return jax.jit(_fe_fused_fn(layers, precision),
+                   donate_argnums=(1,) if donate else ())
+
+
+def _fe_fused_fn(layers: Sequence[Sequence[Transformer]], precision: str):
+    """The traceable body of :func:`fuse_dag_program`."""
     from transmogrifai_tpu.utils.precision import (
         cast_float_leaves, compute_dtype, materialize_tree)
     layer_list = [list(layer) for layer in layers]
@@ -475,4 +484,36 @@ def fuse_dag_program(layers: Sequence[Sequence[Transformer]],
             out = cast_float_leaves(out, jnp.float32)
         return out
 
-    return jax.jit(fe_fused, donate_argnums=(1,) if donate else ())
+    return fe_fused
+
+
+def _positional_program(layers: Sequence[Sequence[Transformer]]):
+    """The training executor's form of :func:`fuse_dag_program`:
+    ``run(params, in_cols) -> {out name: col}`` over ONE jitted program
+    whose arguments and results are positional. Stage uids and the column
+    names built from them are new for every ``Workflow``; as dictionary
+    keys of a jitted function they are written into the module
+    (``jax.arg_info`` / ``jax.result_info``) and so into the persistent
+    cache's key, and every train compiled its feature-engineering programs
+    anew. Here they stay in the tree structure, on the host."""
+    body = _fe_fused_fn(layers, "f32")
+    programs: dict = {}
+
+    def run(params, in_cols):
+        leaves, tree = jax.tree_util.tree_flatten((params, in_cols))
+        entry = programs.get(tree)
+        if entry is None:
+            box: dict = {}
+
+            def fe_fused(*flat):  # program jit_fe_fused
+                p, cols = jax.tree_util.tree_unflatten(tree, flat)
+                flat_out, box["tree"] = jax.tree_util.tree_flatten(
+                    body(p, {}, cols))
+                return flat_out
+
+            entry = programs[tree] = (jax.jit(fe_fused), box)
+        program, box = entry
+        flat_out = program(*leaves)  # the first call traces, and fills box
+        return jax.tree_util.tree_unflatten(box["tree"], flat_out)
+
+    return run

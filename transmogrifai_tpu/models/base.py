@@ -26,8 +26,85 @@ from transmogrifai_tpu.stages.base import (
 )
 from transmogrifai_tpu.types import feature_types as ft
 
-__all__ = ["Predictor", "PredictionModel", "supports_fold_stacking",
-           "supports_tree_stacking", "compile_refit"]
+__all__ = ["Predictor", "PredictionModel", "FoldBatch",
+           "supports_fold_stacking", "supports_tree_stacking",
+           "compile_refit"]
+
+
+class FoldBatch:
+    """The sweep's k-fold plan over ONE resident training matrix.
+
+    ``X [n, d]``, ``y``, ``w [n]`` are the prepared training rows as the
+    selector holds them; ``tr_idx [k, n_tr]`` / ``va_idx [k, n_va]`` are
+    the validator's stacked fold plan over those rows. A family that can
+    train a fold as a row-weight over ``X`` (``fold_weights``) never copies
+    the matrix; one that needs each fold as an array of its own asks for
+    ``training_folds`` / ``validation_folds``, which gather once, are
+    shared by every family that asks, and are counted in
+    ``sweepOperandBytes``."""
+
+    def __init__(self, X, y, w, tr_idx: np.ndarray, va_idx: np.ndarray):
+        from transmogrifai_tpu.parallel import mesh as pmesh
+        self.n = int(X.shape[0])                 # logical training rows
+        self.X, self.y, self.w = pmesh.shard_training_rows(X, y, w)
+        self.tr_idx, self.va_idx = tr_idx, va_idx
+        self.k, self.n_tr = (int(v) for v in tr_idx.shape)
+        self.n_va = int(va_idx.shape[1])
+        self.d = int(X.shape[1])
+        self._cache: dict = {}
+
+    def _once(self, key, build):
+        if key not in self._cache:
+            self._cache[key] = build()
+        return self._cache[key]
+
+    def fold_weights(self):
+        """``[k, rows of X]``: row weight where the row trains fold ``f``,
+        0 where it validates it (and on mesh padding)."""
+        def build():
+            jtr = jnp.asarray(self.tr_idx)
+            wf = jnp.zeros((self.k, int(self.X.shape[0])), jnp.float32)
+            return wf.at[jnp.arange(self.k)[:, None], jtr].set(
+                jnp.take(self.w, jtr).astype(jnp.float32))
+        return self._once("wf", build)
+
+    def n_classes_hint(self) -> int:
+        """Class count of the rows that train some fold: the ONE label
+        pull every softmax family would otherwise block on at dispatch."""
+        def build():
+            member = np.zeros(int(self.X.shape[0]), bool)
+            member[self.tr_idx.ravel()] = True
+            top = jnp.max(jnp.where(jnp.asarray(member), self.y, 0.0))
+            return max(int(np.asarray(top)) + 1, 2)
+        return self._once("n_classes", build)
+
+    def training_folds(self):
+        """``(X [k, n_tr, d], y [k, n_tr], w [k, n_tr])`` gathered copies,
+        rows padded and sharded 2-D under a mesh (rows on "data", folds on
+        "model" when they divide it)."""
+        def build():
+            from transmogrifai_tpu.parallel import mesh as pmesh
+            from transmogrifai_tpu.utils.profiling import sweep_counters
+            jtr = jnp.asarray(self.tr_idx)
+            Xtr = jnp.take(self.X, jtr, axis=0)
+            sweep_counters.count_run(operand_bytes=Xtr.nbytes)
+            return pmesh.shard_stacked_training_rows(
+                Xtr, jnp.take(self.y, jtr, axis=0),
+                jnp.take(self.w, jtr, axis=0))
+        return self._once("train", build)
+
+    def validation_folds(self):
+        """``X [k, n_va, d]`` gathered; unpadded: metrics see real rows."""
+        def build():
+            from transmogrifai_tpu.utils.profiling import sweep_counters
+            Xva = jnp.take(self.X, jnp.asarray(self.va_idx), axis=0)
+            sweep_counters.count_run(operand_bytes=Xva.nbytes)
+            return Xva
+        return self._once("val", build)
+
+    def validation_labels(self):
+        return self._once("yva", lambda: jnp.take(
+            self.y, jnp.asarray(self.va_idx), axis=0))
 
 
 def compile_refit(fn, *, donate_argnums: tuple[int, ...] = (),
@@ -174,6 +251,31 @@ class Predictor(Estimator):
             kw["_n_classes"] = _n_classes
         return self.grid_scores_folds(X, y, w, grid, Xva, **kw), None
 
+    def sweep_folds(self, batch: "FoldBatch", grid: Sequence[dict],
+                    _n_classes: Optional[int] = None):
+        """What the selector's stacked sweep invokes: ``(scores [k, G,
+        n_va], warm handle)`` of every fold x grid point over ``batch``.
+        Default: gather each fold into an array of its own and hand them to
+        ``grid_scores_folds_retained``; a family that trains folds as row
+        weights over the resident matrix overrides."""
+        Xtr, ytr, wtr = batch.training_folds()
+        return self.grid_scores_folds_retained(
+            Xtr, ytr, wtr, grid, batch.validation_folds(),
+            _n_classes=_n_classes)
+
+    def fold_stack_bytes(self, batch: "FoldBatch", grid: Sequence[dict]
+                         ) -> float:
+        """Device bytes ``sweep_folds`` needs beyond the resident matrix,
+        for the selector's HBM guard: the k-fold training gather plus a
+        standardized/derived copy and the gradient residency the trainers
+        materialize, the stacked validation folds, and the per-grid-lane
+        intermediates the vmapped trainer keeps live."""
+        b, G = batch, max(len(grid), 1)
+        return (4.0 * b.k * b.n_tr * max(b.d, 1) * 3.0
+                + 4.0 * b.k * b.n_va * max(b.d, 1)
+                + 4.0 * b.k * (b.n_tr + b.n_va) * G
+                * self.fold_stack_unit_width(grid))
+
     # -- winner refit (round 9) ----------------------------------------------
     def refit_winner(self, X, y, w, params: dict, *, warm=None,
                      lane: Optional[int] = None, hints: Optional[dict] = None
@@ -230,7 +332,7 @@ def supports_fold_stacking(est: Predictor) -> bool:
     return _stacking_safe(
         est,
         ("grid_fit_arrays_folds", "grid_scores_folds",
-         "_fold_stacked_params"),
+         "_fold_stacked_params", "sweep_folds"),
         ("grid_fit_arrays", "fit_arrays", "grid_predict_scores",
          "grid_predict_scores_folds"))
 

@@ -38,134 +38,181 @@ __all__ = [
 # shared trainer
 # ---------------------------------------------------------------------------
 
-def _standardize_stats(X, w):
+def _moments(X, w):
+    """Weighted mean and deviation of every column, and which columns vary
+    at all under the weighting."""
     wsum = jnp.maximum(jnp.sum(w), 1.0)
     mu = jnp.sum(X * w[:, None], axis=0) / wsum
     var = jnp.sum(((X - mu) ** 2) * w[:, None], axis=0) / wsum
     sd = jnp.sqrt(jnp.maximum(var, 1e-12))
-    sd = jnp.where(sd < 1e-6, 1.0, sd)
-    return mu, sd
+    return mu, jnp.where(sd < 1e-6, 1.0, sd), var > 1e-12
 
 
-def _linear_fit_space(X, y, w, *, loss_kind: str, fit_intercept: bool,
-                      standardize: bool):
-    """Shared preamble: standardized features/target and the fold-back
-    statistics. Squared loss trains against the STANDARDIZED target —
-    Adam(0.1) x max_iter steps can only travel ~max_iter/10 from 0, so
-    raw targets with large mean OR large scale (Boston medv ~22, dollar
-    prices ~1e5) silently under-fit; in (y - ym)/ysd space the optimum
-    is O(1) in every direction. Classification is untouched (margins
-    live near 0 already)."""
+def _standardize_stats(X, w):
+    return _moments(X, w)[:2]
+
+
+#: the products with the feature matrix run in three bfloat16 passes: no
+#: standardized copy of the matrix is made (a lane's mean and scale are
+#: folded into its weights), and one pass would round what is left of a
+#: column's offset between the lanes by more than the column's spread
+_X_PRECISION = jax.lax.Precision.HIGH
+
+
+def _lane_stats(X, wf, standardize: bool):
+    """Per weight row of ``wf [F, n]``: the weight sum, the weighted mean
+    and deviation of every column, and which columns vary at all under it;
+    reductions over ``X``, no copy of it."""
+    F, d = wf.shape[0], X.shape[1]
+    wsum = jnp.maximum(jnp.sum(wf, axis=1), 1.0)
+    if not standardize:
+        return wsum, jnp.zeros((F, d)), jnp.ones((F, d)), jnp.ones((F, d))
+    mu, sd, varies = jax.vmap(lambda w: _moments(X, w))(wf)
+    return wsum, mu, sd, varies.astype(X.dtype)
+
+
+def _linear_descent(X, y, wf, reg_param, elastic_net, W_init, b_init, *,
+                    loss_kind: str, n_classes: int, max_iter: int,
+                    fit_intercept: bool, standardize: bool):
+    """THE first-order linear trainer: ``F`` row weightings of one resident
+    matrix (``wf [F, n]``: a fold is a weighting, 0 on the rows it leaves
+    out) x ``G`` grid points (``reg_param``, ``elastic_net`` ``[G]``) as
+    ``F x G`` lanes of one Adam descent. Every lane reads the one ``X [n,
+    d]``: a lane's standardization (its weighting's mean and scale) and, for
+    the squared loss, its target's, are folded into its weights, so one
+    product ``X @ [d, lanes x classes]`` a step serves all lanes and no
+    lane-sized copy of ``X`` exists. Inside that product ``X`` is centred
+    and scaled by the moments of ALL its rows (an elementwise operand the
+    compiler fuses into the product), so a lane folds in only what its own
+    moments differ by: with raw columns of large offset the fold cancels in
+    float32, and the hinge loss turns that rounding into a fold metric 1e-4
+    off at 40,000 rows. A column constant under a lane's weighting (a hash
+    bucket whose rows all validate that fold) gets no gradient there: the
+    centred product leaves rounding where the raw one left exact zeros, and
+    Adam would scale it up to whole steps. Squared loss trains against the
+    STANDARDIZED target: Adam(0.1) x max_iter steps can only travel
+    ~max_iter/10 from 0, so raw targets of large mean or scale would
+    under-fit. ``W_init``/``b_init`` (``[F*G, d, C]``/``[F*G, C]``, in
+    ORIGINAL feature space) warm-start the descent; ``None`` starts from
+    zero. Returns original-space ``(W [F, G, d, C], b [F, G, C], last loss
+    [F, G])``."""
     n, d = X.shape
+    F, G = wf.shape[0], reg_param.shape[0]
+    C = n_classes if loss_kind == "softmax" else 1
+    wsum_f, mu_f, sd_f, live_f = _lane_stats(X, wf, standardize)
     if standardize:
-        mu, sd = _standardize_stats(X, w)
-        Xs = (X - mu) / sd
+        center, scale = _standardize_stats(X, jnp.ones(n, X.dtype))
     else:
-        mu, sd = jnp.zeros(d), jnp.ones(d)
-        Xs = X
-    wsum = jnp.maximum(jnp.sum(w), 1.0)
+        center, scale = jnp.zeros(d), jnp.ones(d)
+    inv_scale = 1.0 / scale
     if loss_kind == "squared" and fit_intercept:
-        ym = jnp.sum(y * w) / wsum
-        ysd = jnp.sqrt(jnp.maximum(
-            jnp.sum(((y - ym) ** 2) * w) / wsum, 1e-12))
-        y_fit = (y - ym) / ysd
+        ym_f = jnp.sum(y * wf, axis=1) / wsum_f
+        ysd_f = jnp.sqrt(jnp.maximum(
+            jnp.sum(((y - ym_f[:, None]) ** 2) * wf, axis=1) / wsum_f,
+            1e-12))
     else:
-        ym, ysd = jnp.float32(0.0), jnp.float32(1.0)
-        y_fit = y
-    return Xs, y_fit, mu, sd, ym, ysd, wsum
-
-
-def _linear_descent(Xs, y, y_fit, w, wsum, reg_param, elastic_net, W0, b0,
-                    *, loss_kind: str, max_iter: int, fit_intercept: bool):
-    """The Adam descent from an explicit fit-space init (shared by the
-    cold ``_train_linear`` and the warm-started refit program)."""
-    n = Xs.shape[0]
+        ym_f, ysd_f = jnp.zeros(F), jnp.ones(F)
+    # lanes are fold-major: lane f * G + g
+    rep = lambda a: jnp.repeat(a, G, axis=0)  # noqa: E731
+    w, wsum, mu, sd = rep(wf), rep(wsum_f), rep(mu_f), rep(sd_f)
+    live = rep(live_f)[:, :, None]
+    ym, ysd = rep(ym_f), rep(ysd_f)
+    reg, en = jnp.tile(reg_param, F), jnp.tile(elastic_net, F)
+    if loss_kind == "softmax":
+        onehot = jax.nn.one_hot(y.astype(jnp.int32), C, axis=0)  # [C, n]
+    else:
+        sign = 2.0 * y - 1.0
 
     def objective(params):
-        W, b = params
-        z = Xs @ W + b
+        W, b = params                              # fit space [L,d,C] [L,C]
+        We = W * (scale / sd)[:, :, None]
+        off = b + jnp.einsum("ld,ldc->lc", (center - mu) / sd, W)
+        z = jnp.einsum("nd,ldc->cln", (X - center) * inv_scale, We,
+                       precision=_X_PRECISION) \
+            + off.T[:, :, None]                    # [C, L, n]: rows minor
         if loss_kind == "softmax":
-            logp = jax.nn.log_softmax(z, axis=-1)
-            nll = -logp[jnp.arange(n), y.astype(jnp.int32)]
-            data_loss = jnp.sum(nll * w) / wsum
+            logp = jax.nn.log_softmax(z, axis=0)
+            per_row = -jnp.sum(logp * onehot[:, None, :], axis=0)
         elif loss_kind == "hinge":
-            s = 2.0 * y_fit - 1.0
-            margin = jnp.maximum(0.0, 1.0 - s * z[:, 0])
-            data_loss = jnp.sum(margin * w) / wsum
-        else:  # squared (y_fit is the standardized target)
-            data_loss = 0.5 * jnp.sum(((z[:, 0] - y_fit) ** 2) * w) / wsum
-        l2 = 0.5 * jnp.sum(W ** 2)
-        l1 = jnp.sum(jnp.abs(W))
-        return data_loss + reg_param * ((1.0 - elastic_net) * l2
-                                        + elastic_net * l1)
+            per_row = jnp.maximum(0.0, 1.0 - sign * z[0])
+        else:  # squared, against the lane's standardized target
+            per_row = 0.5 * (z[0] - (y - ym[:, None]) / ysd[:, None]) ** 2
+        data_loss = jnp.sum(per_row * w, axis=1) / wsum
+        l2 = 0.5 * jnp.sum(W ** 2, axis=(1, 2))
+        l1 = jnp.sum(jnp.abs(W), axis=(1, 2))
+        lane_loss = data_loss + reg * ((1.0 - en) * l2 + en * l1)
+        # lanes share nothing but X: the sum's gradient is each lane's own
+        return jnp.sum(lane_loss), lane_loss
 
+    # columns and target centered by the same weights: the squared loss's
+    # fit-space intercept is 0 whatever W is, and its gradient at 0 is
+    # rounding noise, which Adam would scale up to whole steps
+    train_b = fit_intercept and not (loss_kind == "squared" and standardize)
+    if W_init is None:
+        W0 = jnp.zeros((F * G, d, C), jnp.float32)
+        b0 = jnp.zeros((F * G, C), jnp.float32)
+    else:  # original space -> each lane's fit space
+        W0 = W_init * sd[:, :, None] / ysd[:, None, None]
+        b0 = (b_init + jnp.einsum("ld,ldc->lc", mu, W_init)
+              - ym[:, None]) / ysd[:, None]
+        if fit_intercept and not train_b:
+            b0 = jnp.zeros_like(b0)
     opt = optax.adam(0.1)
-    state0 = opt.init((W0, b0))
 
     def step(carry, _):
         params, opt_state = carry
-        loss, grads = jax.value_and_grad(objective)(params)
-        if not fit_intercept:
-            grads = (grads[0], jnp.zeros_like(grads[1]))
+        (_, lane_loss), grads = jax.value_and_grad(
+            objective, has_aux=True)(params)
+        # a column constant under the lane's weighting has no gradient; what
+        # the centred product leaves there is rounding, which Adam would
+        # scale up to whole steps
+        grads = (grads[0] * live,
+                 grads[1] if train_b else jnp.zeros_like(grads[1]))
         updates, opt_state = opt.update(grads, opt_state)
         params = optax.apply_updates(params, updates)
-        return (params, opt_state), loss
+        return (params, opt_state), lane_loss
 
-    (params, _), losses = jax.lax.scan(step, ((W0, b0), state0), None,
-                                       length=max_iter)
-    return params[0], params[1], losses[-1]
+    ((W, b), _), losses = jax.lax.scan(
+        step, ((W0, b0), opt.init((W0, b0))), None, length=max_iter)
+    # fold target standardization (squared loss) then feature
+    # standardization back into original space
+    W = W * ysd[:, None, None]
+    b = b * ysd[:, None] + ym[:, None]
+    W_orig = W / sd[:, :, None]
+    b_orig = b - jnp.einsum("ld,ldc->lc", mu / sd, W)
+    return (W_orig.reshape(F, G, d, C), b_orig.reshape(F, G, C),
+            losses[-1].reshape(F, G))
 
 
 @functools.partial(jax.jit, static_argnames=("loss_kind", "n_classes",
                                              "max_iter", "fit_intercept",
                                              "standardize"))
-def _train_linear(X, y, w, reg_param, elastic_net, *, loss_kind: str,
+def _train_linear(X, y, wf, reg_param, elastic_net, *, loss_kind: str,
                   n_classes: int, max_iter: int, fit_intercept: bool,
                   standardize: bool):
-    """One linear training run. reg_param/elastic_net are traced scalars so
-    the same compiled program serves every grid point (and vmaps)."""
-    d = X.shape[1]
-    Xs, y_fit, mu, sd, ym, ysd, wsum = _linear_fit_space(
-        X, y, w, loss_kind=loss_kind, fit_intercept=fit_intercept,
+    """``_linear_descent`` from zero: row weightings ``wf [F, n]`` x grid
+    ``[G]``. The regularization scalars are traced, so one compiled program
+    serves every grid of a shape."""
+    return _linear_descent(
+        X, y, wf, reg_param, elastic_net, None, None, loss_kind=loss_kind,
+        n_classes=n_classes, max_iter=max_iter, fit_intercept=fit_intercept,
         standardize=standardize)
-    C = n_classes if loss_kind == "softmax" else 1
-    W0 = jnp.zeros((d, C), dtype=jnp.float32)
-    b0 = jnp.zeros((C,), dtype=jnp.float32)
-    W, b, last_loss = _linear_descent(
-        Xs, y, y_fit, w, wsum, reg_param, elastic_net, W0, b0,
-        loss_kind=loss_kind, max_iter=max_iter, fit_intercept=fit_intercept)
-    # fold target standardization (squared loss) then feature
-    # standardization back into original space
-    W = W * ysd
-    b = b * ysd + ym
-    W_orig = W / sd[:, None]
-    b_orig = b - (mu / sd) @ W
-    return W_orig, b_orig, last_loss
 
 
 def _train_linear_from(X, y, w, reg_param, elastic_net, W_init, b_init, *,
                        loss_kind: str, max_iter: int, fit_intercept: bool,
                        standardize: bool):
-    """Warm-started linear refit (round 9): same descent as
-    ``_train_linear`` but initialized from ``W_init``/``b_init`` given in
-    ORIGINAL feature space (what the stacked fold parameters are in after
-    fold-back) — the init maps into fit space with the refit data's own
-    standardization statistics. Compiled via ``compile_refit`` with the
-    init buffers donated (they are dead once consumed)."""
-    Xs, y_fit, mu, sd, ym, ysd, wsum = _linear_fit_space(
-        X, y, w, loss_kind=loss_kind, fit_intercept=fit_intercept,
+    """Warm-started linear refit (round 9): one lane of ``_linear_descent``
+    initialized from ``W_init``/``b_init`` given in ORIGINAL feature space
+    (what the stacked fold parameters are in after fold-back). Compiled via
+    ``compile_refit`` with the init buffers donated (they are dead once
+    consumed)."""
+    W, b, loss = _linear_descent(
+        X, y, w[None], reg_param[None], elastic_net[None], W_init[None],
+        b_init[None], loss_kind=loss_kind, n_classes=W_init.shape[-1],
+        max_iter=max_iter, fit_intercept=fit_intercept,
         standardize=standardize)
-    # inverse of the fold-back at the bottom of _train_linear
-    W0 = W_init * sd[:, None] / ysd
-    b0 = (b_init + mu @ W_init - ym) / ysd
-    W, b, last_loss = _linear_descent(
-        Xs, y, y_fit, w, wsum, reg_param, elastic_net, W0, b0,
-        loss_kind=loss_kind, max_iter=max_iter, fit_intercept=fit_intercept)
-    W = W * ysd
-    b = b * ysd + ym
-    W_orig = W / sd[:, None]
-    b_orig = b - (mu / sd) @ W
-    return W_orig, b_orig, last_loss
+    return W[0, 0], b[0, 0], loss[0, 0]
 
 
 _WARM_PROGRAM = None  # lazily compiled (backend known only at first use)
@@ -255,46 +302,48 @@ def _shard_candidates(*arrs):
         *([None] * (a.ndim - 1)))) for a in arrs)
 
 
-def _run_grid(X, y, w, grid: Sequence[dict], defaults: dict, kw: dict):
-    """Train the whole grid as one stacked-axis vmapped program. Static
-    config (max_iter etc.) must agree across the grid; the regularization
-    scalars are the batched axes."""
-    from transmogrifai_tpu.utils import flops
-    rp = jnp.asarray([float({**defaults, **g}["reg_param"]) for g in grid],
-                     jnp.float32)
-    en = jnp.asarray([float({**defaults, **g}["elastic_net_param"]) for g in grid],
-                     jnp.float32)
-    rp, en = _shard_candidates(rp, en)
-    f = jax.vmap(lambda r, e: _train_linear(X, y, w, r, e, **kw))
-    n, d = X.shape
-    C = kw["n_classes"] if kw["loss_kind"] == "softmax" else 1
-    # per Adam step: forward z = X@W (2ndC) + backward grads (~4ndC)
-    flops.add("linear", len(grid) * kw["max_iter"] * 6.0 * n * d * C)
-    return f(rp, en)
-
-
-def _run_grid_folds(Xf, yf, wf, grid: Sequence[dict], defaults: dict,
-                    kw: dict):
-    """Fold-stacked grid trainer: ``Xf [k, n, d]`` — all k folds x |grid|
-    Adam descents as ONE vmap-of-vmap program (the CV axis joins the grid
-    axis, so a whole family's sweep is a single dispatch). The grid scalars
-    shard over the mesh "model" axis only when the fold axis doesn't claim
-    it (``shard_stacked_training_rows`` already placed the folds)."""
-    from transmogrifai_tpu.parallel import mesh as pmesh
-    from transmogrifai_tpu.utils import flops
+def _grid_scalars(grid: Sequence[dict], defaults: dict):
     rp = jnp.asarray([float({**defaults, **g}["reg_param"]) for g in grid],
                      jnp.float32)
     en = jnp.asarray([float({**defaults, **g}["elastic_net_param"])
                       for g in grid], jnp.float32)
-    if not pmesh.fold_axis_on_model(int(Xf.shape[0])):
-        rp, en = _shard_candidates(rp, en)
-    inner = lambda Xk, yk, wk: jax.vmap(  # noqa: E731 — vmap composition
-        lambda r, e: _train_linear(Xk, yk, wk, r, e, **kw))(rp, en)
-    k, n, d = Xf.shape
+    return rp, en
+
+
+def _run_grid(X, y, wf, grid: Sequence[dict], defaults: dict, kw: dict):
+    """Train the whole grid under each row weighting of ``wf [F, n]`` as
+    one program over the resident ``X [n, d]`` (``_train_linear``). Static
+    config (max_iter etc.) must agree across the grid; the regularization
+    scalars are the batched axes. Returns ``Ws [F, G, d, C]``,
+    ``bs [F, G, C]``, ``last loss [F, G]``."""
+    from transmogrifai_tpu.utils import flops
+    rp, en = _shard_candidates(*_grid_scalars(grid, defaults))
+    n, d = X.shape
     C = kw["n_classes"] if kw["loss_kind"] == "softmax" else 1
-    flops.add("linear",
-              int(k) * len(grid) * kw["max_iter"] * 6.0 * int(n) * int(d) * C)
-    return jax.vmap(inner)(Xf, yf, wf)  # Ws [k, G, d, C], bs [k, G, C]
+    # per Adam step: forward z = X@W (2ndC) + backward grads (~4ndC)
+    flops.add("linear", int(wf.shape[0]) * len(grid) * kw["max_iter"]
+              * 6.0 * int(n) * int(d) * C)
+    return _train_linear(X, y, wf, rp, en, **kw)
+
+
+def _fold_rows(Xf, yf, wf):
+    """Folds handed over as arrays of their own (``Xf [k, n, d]``) laid
+    end to end as one matrix, each fold a weighting of its own rows: the
+    layout ``_train_linear`` reads."""
+    k, n, d = Xf.shape
+    eye = jnp.eye(k, dtype=wf.dtype)
+    w_rows = (eye[:, :, None] * wf[None, :, :]).reshape(k, k * n)
+    return Xf.reshape(k * n, d), yf.reshape(k * n), w_rows
+
+
+@jax.jit
+def _fold_scores(X, Wm, bm, va_idx):
+    """``[k, G, n_va]``: every lane's score of every row of the resident
+    ``X`` in one product, each fold's lanes read at that fold's validation
+    rows ``va_idx [k, n_va]``."""
+    scores = jnp.einsum("nd,kgd->kgn", X, Wm, precision=_X_PRECISION) \
+        + bm[:, :, None]
+    return jnp.take_along_axis(scores, va_idx[:, None, :], axis=2)
 
 
 def _merge_grid_parts(parts, order):
@@ -453,33 +502,16 @@ class _LinearPredictor(Predictor):
 
     def fit_arrays(self, X, y, w, params):
         kw = self._static_kw(params, self._n_classes(y))
-        W, b, _ = _train_linear(
-            X, y, w, jnp.float32(params["reg_param"]),
-            jnp.float32(params["elastic_net_param"]), **kw)
-        return self._make_model(W, b)
+        Ws, bs, _ = _run_grid(X, y, w[None], [params], self.params, kw)
+        return self._make_model(Ws[0, 0], bs[0, 0])
 
     def grid_fit_arrays(self, X, y, w, grid):
         if not grid:
             return []
-        # group grid points by their static flags (max_iter/intercept/
-        # standardization are compile-time constants): one vmapped program
-        # per distinct combo, so a mixed grid never silently trains with
-        # another point's flags
-        merged = [{**self.params, **g} for g in grid]
-        models: list = [None] * len(grid)
-        by_kw: dict[tuple, list[int]] = {}
-        for i, g in enumerate(merged):
-            key = (int(g["max_iter"]), bool(g["fit_intercept"]),
-                   bool(g["standardization"]))
-            by_kw.setdefault(key, []).append(i)
-        for idxs in by_kw.values():
-            kw = self._static_kw(merged[idxs[0]], self._n_classes(y))
-            Ws, bs, _ = _run_grid(X, y, w, [grid[i] for i in idxs],
-                                  self.params, kw)
-            # keep per-model weights as device views — no host pull in sweep
-            for j, i in enumerate(idxs):
-                models[i] = self._make_model(Ws[j], bs[j])
-        return models
+        # per-model weights stay device views — no host pull in the sweep
+        Ws, bs = self._lane_params(X, y, w[None], grid, self._n_classes(y))
+        return [self._make_model(Ws[0, j], bs[0, j])
+                for j in range(len(grid))]
 
     def grid_predict_scores(self, models, X):
         """All grid candidates score in one einsum: [G, n] margins
@@ -521,10 +553,14 @@ class _LinearPredictor(Predictor):
         return self._fold_stacked_params(X, y, w, grid, **kw)
 
     # -- fold-stacked sweep --------------------------------------------------
-    def _fold_stacked_params(self, X, y, w, grid, _n_classes=None):
-        """All k folds x |grid| points in one vmapped program per distinct
-        static-flag combo; returns the stacked ``(Ws [k, G, d, C],
-        bs [k, G, C])`` in grid order (device-resident)."""
+    def _lane_params(self, X, y, wf, grid, n_classes: int):
+        """Every row weighting of ``wf [k, n]`` x |grid| points over the
+        resident ``X [n, d]``, one program per distinct static-flag combo;
+        returns the stacked ``(Ws [k, G, d, C], bs [k, G, C])`` in grid
+        order (device-resident)."""
+        # group grid points by their static flags (max_iter/intercept/
+        # standardization are compile-time constants), so a mixed grid
+        # never silently trains with another point's flags
         merged = [{**self.params, **g} for g in grid]
         by_kw: dict[tuple, list[int]] = {}
         for i, g in enumerate(merged):
@@ -532,14 +568,56 @@ class _LinearPredictor(Predictor):
                    bool(g["standardization"]))
             by_kw.setdefault(key, []).append(i)
         parts, order = [], []
-        n_classes = self._grid_n_classes(y, _n_classes)
         for idxs in by_kw.values():
             kw = self._static_kw(merged[idxs[0]], n_classes)
-            Ws, bs, _ = _run_grid_folds(X, y, w, [grid[i] for i in idxs],
-                                        self.params, kw)
+            Ws, bs, _ = _run_grid(X, y, wf, [grid[i] for i in idxs],
+                                  self.params, kw)
             parts.append((Ws, bs))
             order.extend(idxs)
         return _merge_grid_parts(parts, order)
+
+    def _fold_stacked_params(self, X, y, w, grid, _n_classes=None):
+        """Folds given as arrays of their own (``X [k, n, d]``): laid end
+        to end, each a weighting of its own rows, for ``_lane_params``."""
+        return self._lane_params(*_fold_rows(X, y, w), grid,
+                                 self._grid_n_classes(y, _n_classes))
+
+    def _batch_params(self, batch, grid, n_classes: int):
+        """Stacked parameters of every fold x grid point of ``batch``,
+        each fold a row weighting of the resident matrix."""
+        return self._lane_params(batch.X, batch.y, batch.fold_weights(),
+                                 grid, n_classes)
+
+    def _margin_params(self, Ws, bs):
+        """``(Wm [k, G, d], bm [k, G])``: the one weight vector a lane's
+        scalar score needs (the prediction, the hinge margin, or the
+        binary margin ``z1 - z0``), or None (multiclass: no scalar score).
+        Scoring with it makes no ``[.., classes, rows]`` logits."""
+        if Ws.shape[-1] == 1:      # squared loss, margin-only (SVC)
+            return Ws[..., 0], bs[..., 0]
+        if Ws.shape[-1] == 2:      # binary margin
+            return Ws[..., 1] - Ws[..., 0], bs[..., 1] - bs[..., 0]
+        return None
+
+    def sweep_folds(self, batch, grid, _n_classes=None):
+        """The selector's stacked unit without a copy of the matrix: every
+        fold trains as a row weighting of ``batch.X``, every lane scores
+        all of its rows in one product, and each fold's lanes are read at
+        that fold's validation rows."""
+        if not grid:
+            return None, None
+        Ws, bs = self._batch_params(
+            batch, grid, self._grid_n_classes(batch.y, _n_classes))
+        margin = self._margin_params(Ws, bs)
+        if margin is None:
+            return None, None
+        return _fold_scores(batch.X, *margin,
+                            jnp.asarray(batch.va_idx)), (Ws, bs)
+
+    def fold_stack_bytes(self, batch, grid) -> float:
+        # no copy of the matrix: the lanes' per-row intermediates only
+        return (4.0 * batch.k * int(batch.X.shape[0]) * max(len(grid), 1)
+                * self.fold_stack_unit_width(grid))
 
     def grid_fit_arrays_folds(self, X, y, w, grid):
         """``[k][G]`` fitted models whose weights stay device views of the
@@ -713,25 +791,15 @@ class OpLogisticRegression(_LinearPredictor):
                 models[i] = rest[j]
         return models
 
-    def _fold_stacked_params(self, X, y, w, grid, _n_classes=None):
-        """Fold-stacked LR sweep: the Newton points vmap over (fold x
-        reg_param) — one second-order program for the whole family's
-        workhorse grid across every fold — and the L1/multiclass rest rides
-        the fold-stacked Adam path. Same point-by-point routing as the
-        per-fold ``grid_fit_arrays``, so both paths pick identical
-        optimizers for every grid point (sweep-parity requirement)."""
+    def _newton_folds(self, X, y, w, merged, newton_idx):
+        """The Newton points of a grid over folds given as arrays of their
+        own (``X [k, n, d]``): vmapped over (fold x reg_param), one
+        second-order program per (fit_intercept, standardization) combo.
+        Returns ``(parts, order)`` for ``_merge_grid_parts``."""
         from transmogrifai_tpu.parallel import mesh as pmesh
-        merged = [{**self.params, **g} for g in grid]
-        # ONE device sync for the family, elided by the selector's hint
-        n_classes = self._grid_n_classes(y, _n_classes)
-        d = int(X.shape[2])
-        k = int(X.shape[0])
-        newton_idx = [i for i, g in enumerate(merged)
-                      if self._newton_ok(g, d, n_classes)]
-        if not newton_idx:
-            return super()._fold_stacked_params(X, y, w, grid,
-                                                _n_classes=n_classes)
-        adam_idx = [i for i in range(len(grid)) if i not in set(newton_idx)]
+        from transmogrifai_tpu.utils import flops
+        from transmogrifai_tpu.utils.profiling import sweep_counters
+        k, n, d = (int(v) for v in X.shape)
         parts, order = [], []
         by_flags: dict[tuple[bool, bool], list[int]] = {}
         for i in newton_idx:
@@ -748,19 +816,67 @@ class OpLogisticRegression(_LinearPredictor):
                     Xk, yk, wk, r, fit_intercept=fit_b,
                     standardize=std_b))(rp)
             Ws, bs, _ = jax.vmap(inner)(X, y, w)  # [k, g, ...]
-            from transmogrifai_tpu.utils import flops
-            n = int(X.shape[1])
             flops.add("linear", k * len(idxs) * 15 * (
                 4.0 * n * (d + 1) + 2.0 * n * (d + 1) ** 2
                 + (2.0 / 3.0) * (d + 1) ** 3))
+            # each fold's standardized matrix with its ones column
+            sweep_counters.count_run(operand_bytes=4 * k * n * (d + 1))
             parts.append((Ws, bs))
             order.extend(idxs)
+        return parts, order
+
+    def _newton_split(self, grid, d: int, n_classes: int):
+        merged = [{**self.params, **g} for g in grid]
+        newton_idx = [i for i, g in enumerate(merged)
+                      if self._newton_ok(g, d, n_classes)]
+        adam_idx = [i for i in range(len(grid)) if i not in set(newton_idx)]
+        return merged, newton_idx, adam_idx
+
+    def _fold_stacked_params(self, X, y, w, grid, _n_classes=None):
+        """Fold-stacked LR sweep: the Newton points vmap over (fold x
+        reg_param) — one second-order program for the whole family's
+        workhorse grid across every fold — and the L1/multiclass rest rides
+        the fold-stacked Adam path. Same point-by-point routing as the
+        per-fold ``grid_fit_arrays``, so both paths pick identical
+        optimizers for every grid point (sweep-parity requirement)."""
+        # ONE device sync for the family, elided by the selector's hint
+        n_classes = self._grid_n_classes(y, _n_classes)
+        merged, newton_idx, adam_idx = self._newton_split(
+            grid, int(X.shape[2]), n_classes)
+        if not newton_idx:
+            return super()._fold_stacked_params(X, y, w, grid,
+                                                _n_classes=n_classes)
+        parts, order = self._newton_folds(X, y, w, merged, newton_idx)
         if adam_idx:
             parts.append(super()._fold_stacked_params(
                 X, y, w, [grid[i] for i in adam_idx],
                 _n_classes=n_classes))
             order.extend(adam_idx)
         return _merge_grid_parts(parts, order)
+
+    def _batch_params(self, batch, grid, n_classes: int):
+        """The Adam points train in place; the Newton points (their
+        Hessian wants each fold's own standardized matrix, and exist only
+        up to ``_NEWTON_MAX_D`` columns) read the gathered folds."""
+        merged, newton_idx, adam_idx = self._newton_split(
+            grid, batch.d, n_classes)
+        if not newton_idx:
+            return super()._batch_params(batch, grid, n_classes)
+        parts, order = self._newton_folds(*batch.training_folds(), merged,
+                                          newton_idx)
+        if adam_idx:
+            parts.append(super()._batch_params(
+                batch, [grid[i] for i in adam_idx], n_classes))
+            order.extend(adam_idx)
+        return _merge_grid_parts(parts, order)
+
+    def fold_stack_bytes(self, batch, grid) -> float:
+        need = super().fold_stack_bytes(batch, grid)
+        if self._newton_split(grid, batch.d, 2)[1]:
+            # the gathered training folds, their standardized copy and the
+            # Hessian build's weighted copy
+            need += 4.0 * batch.k * batch.n_tr * max(batch.d, 1) * 3.0
+        return need
 
     def refit_winner(self, X, y, w, params, *, warm=None, lane=None,
                      hints=None):

@@ -148,9 +148,13 @@ class PipelineData:
             # (wide pre-vectorized matrices are the other >GB upload);
             # the mesh path still places in one transfer — chunked
             # SHARDED placement is future work
+            from transmogrifai_tpu.utils.profiling import sweep_counters
+            from transmogrifai_tpu.utils.tracing import span
             vals = np.asarray(col.values, np.float32)
-            dval = _shard(vals) if pmesh.current_mesh() is not None \
-                else _upload_rows(vals)
+            with span("fe.upload", column=name, bytes=int(vals.nbytes)):
+                dval = _shard(vals) if pmesh.current_mesh() is not None \
+                    else _upload_rows(vals)
+            sweep_counters.count_run(fe_upload_bytes=vals.nbytes)
             dev = fr.VectorColumn(dval, col.meta)
             self.device[name] = dev
             return dev
@@ -229,6 +233,27 @@ class PipelineData:
         dev.update(new)
         out = PipelineData(self.host, dev, n_rows_logical=self._n_logical)
         out._codes_cache = self._codes_cache
+        out._row_mask = self._row_mask
+        return out
+
+    def without(self, names: Iterable[str]) -> "PipelineData":
+        """This data less the columns ``names``, on host and device: the
+        training pass lets go of a derived column once its last consumer has
+        run, so a wide vector and the blocks it was combined from are never
+        resident together for longer than the stage that reads both. Waits
+        first for the programs that still read them: a buffer let go while
+        its consumer runs stays allocated until the consumer ends, and
+        whatever is enqueued meanwhile is allocated beside it."""
+        dead = set(names)
+        jax.block_until_ready([c for n, c in self.device.items()
+                               if n not in dead])
+        host = fr.HostFrame({n: c for n, c in self.host.columns.items()
+                             if n not in dead}, self.host.key)
+        out = PipelineData(host, {n: c for n, c in self.device.items()
+                                  if n not in dead},
+                           n_rows_logical=self.n_rows)
+        out._codes_cache = {n: c for n, c in self._codes_cache.items()
+                            if n not in dead}
         out._row_mask = self._row_mask
         return out
 

@@ -43,7 +43,9 @@ from transmogrifai_tpu.parallel.collectives import (
 from transmogrifai_tpu.stages.base import DeviceTransformer, Estimator
 from transmogrifai_tpu.types import feature_types as ft
 from transmogrifai_tpu.utils.stats import contingency_stats
-from transmogrifai_tpu.vector_metadata import VectorMetadata
+from transmogrifai_tpu.vector_metadata import (
+    VectorColumnMetadata, VectorMetadata,
+)
 
 __all__ = ["SanityChecker", "DropIndicesModel", "SanityCheckerSummary"]
 
@@ -421,10 +423,66 @@ class SanityChecker(Estimator):
             feature_corr=fcorr.tolist() if fcorr is not None else None,
             correlation_type=self.correlation_type,
             sample_fraction=sample_fraction)
-        new_meta = meta.select(keep) if meta is not None and meta.size == d \
-            else None
+        pad = bucketed_width(len(keep)) - len(keep)
+        new_meta = _padded(meta.select(keep), pad) \
+            if meta is not None and meta.size == d else None
         return DropIndicesModel(keep_indices=keep, out_meta=new_meta,
-                                summary=summary)
+                                summary=summary, pad=pad)
+
+
+#: which columns pass is decided by the data (a hash bucket that two rows
+#: fill passes the variance rule, one that a single row fills does not), so
+#: two samples of one table keep widths a few columns apart, and every
+#: program downstream is compiled for its width. From ``_BUCKET_FROM``
+#: kept columns on, the checked vector is therefore filled up with zero
+#: columns to a whole number of ``_WIDTH_BUCKET``-column tiles (at most 1
+#: part in 32 of it): a retrain on a fresh sample finds its programs
+#: compiled. A zero column has no variance, takes no weight and no split.
+_WIDTH_BUCKET = 128
+_BUCKET_FROM = 4096
+_PAD_FEATURE = "sanityCheckerPadding"
+#: a column index no vector has: the gather fills it with zeros
+_NO_COLUMN = np.iinfo(np.int32).max
+_TAKE_BLOCK_ROWS = 4096
+
+
+def bucketed_width(kept: int) -> int:
+    """The width of the checked vector that keeps ``kept`` columns."""
+    if kept < _BUCKET_FROM:
+        return kept
+    return -(-kept // _WIDTH_BUCKET) * _WIDTH_BUCKET
+
+
+def _padded(meta: VectorMetadata, pad: int) -> VectorMetadata:
+    """``meta`` with the provenance of ``pad`` zero columns appended."""
+    if not pad:
+        return meta
+    zero = VectorColumnMetadata(parent_feature=(_PAD_FEATURE,),
+                                parent_feature_type=("Real",),
+                                descriptor_value="zero")
+    return VectorMetadata(meta.name, meta.columns + (zero,) * pad,
+                          meta.history).reindexed(0)
+
+
+def _take_columns(X, keep):
+    """``X[:, keep]``, zeros where ``keep`` is ``_NO_COLUMN``. XLA gathers
+    along the minor axis through transposed copies of the whole operand (1.6
+    times its bytes in temporaries: 7.4 GB for a 4.75 GB matrix), so the
+    matrix is gathered a block of rows at a time into the preallocated
+    result; the last block is moved back to end at the last row, and
+    rewrites rows the block before it wrote."""
+    n, d = X.shape
+    B = min(_TAKE_BLOCK_ROWS, n)
+
+    def body(i, out):
+        start = jnp.minimum(i * B, n - B)
+        rows = jax.lax.dynamic_slice(X, (start, 0), (B, d))
+        return jax.lax.dynamic_update_slice(
+            out, jnp.take(rows, keep, axis=1, mode="fill", fill_value=0),
+            (start, 0))
+
+    return jax.lax.fori_loop(0, -(-n // max(B, 1)), body,
+                             jnp.zeros((n, keep.shape[0]), X.dtype))
 
 
 class DropIndicesModel(DeviceTransformer):
@@ -435,10 +493,11 @@ class DropIndicesModel(DeviceTransformer):
 
     def __init__(self, keep_indices=(), out_meta: Optional[VectorMetadata] = None,
                  summary: Optional[SanityCheckerSummary] = None,
-                 uid: Optional[str] = None):
+                 pad: int = 0, uid: Optional[str] = None):
         self.keep_indices = [int(i) for i in keep_indices]
         self.out_meta = out_meta
         self.summary = summary
+        self.pad = int(pad)     # zero columns after the kept ones
         super().__init__(uid=uid)
 
     def runtime_input_names(self):
@@ -446,22 +505,25 @@ class DropIndicesModel(DeviceTransformer):
             else self.input_names
 
     def device_params(self):
-        return jnp.asarray(self.keep_indices, jnp.int32)
+        return jnp.asarray(self.keep_indices + [_NO_COLUMN] * self.pad,
+                           jnp.int32)
 
     def device_apply(self, params, col: fr.VectorColumn) -> fr.VectorColumn:
         meta = self.out_meta
         if meta is None and col.metadata is not None \
                 and col.metadata.size == int(col.values.shape[1]):
-            meta = col.metadata.select(self.keep_indices)
-        return fr.VectorColumn(jnp.take(col.values, params, axis=1), meta)
+            meta = _padded(col.metadata.select(self.keep_indices), self.pad)
+        return fr.VectorColumn(_take_columns(col.values, params), meta)
 
     def transform_row(self, *values):
         vec = np.asarray(values[-1], dtype=np.float32)
-        return vec[np.asarray(self.keep_indices, dtype=np.int64)]
+        kept = vec[np.asarray(self.keep_indices, dtype=np.int64)]
+        return np.concatenate([kept, np.zeros(self.pad, np.float32)])
 
     def config(self):
         return {
             "keep_indices": self.keep_indices,
+            "pad": self.pad,
             "out_meta": self.out_meta.to_json() if self.out_meta else None,
             "summary": self.summary.to_json() if self.summary else None,
         }
@@ -471,4 +533,4 @@ class DropIndicesModel(DeviceTransformer):
         meta = (VectorMetadata.from_json(config["out_meta"])
                 if config.get("out_meta") else None)
         return cls(keep_indices=config.get("keep_indices", ()),
-                   out_meta=meta, uid=uid)
+                   out_meta=meta, pad=config.get("pad", 0), uid=uid)

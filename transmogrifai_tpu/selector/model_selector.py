@@ -39,6 +39,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,6 +52,22 @@ from transmogrifai_tpu.types import feature_types as ft
 
 __all__ = ["ModelSelector", "SelectedModel", "ModelSelectorSummary",
            "ModelEvaluation"]
+
+
+@jax.jit
+def _gather_rows(X, y, idx):
+    return jnp.take(X, idx, axis=0), jnp.take(y, idx, axis=0)
+
+
+def _take_rows(X, y, idx):
+    """The rows ``idx`` of a matrix and its labels, as ONE gather each:
+    eager ``X[idx]`` runs the gather and then an identity broadcast of its
+    result as a second program, two copies of the split where one is
+    needed. The copy is counted where it is made (``sweepOperandBytes``)."""
+    from transmogrifai_tpu.utils.profiling import sweep_counters
+    Xs, ys = _gather_rows(X, y, idx)
+    sweep_counters.count_run(operand_bytes=Xs.nbytes)
+    return Xs, ys
 
 
 def _is_ready(a) -> bool:
@@ -522,21 +539,16 @@ class ModelSelector(Estimator):
             pass
         return float(4 << 30)
 
-    def _stacked_fits_memory(self, k: int, n_tr: int, n_va: int, d: int,
-                             est, grid) -> bool:
-        """HBM guard for the fold-stacked batch: the k-fold training gather
-        (plus a standardized/derived copy and the gradient residency the
-        trainers materialize), the stacked validation folds, AND the
-        per-grid-lane intermediates the vmapped trainer keeps live (scales
-        with k x G x rows x the family's per-row lane width — scores,
-        logits, activations) must fit the budget, else the sweep falls back
-        to the per-fold loop whose peak is 1/k of this."""
-        G = max(len(grid), 1)
-        width = est.fold_stack_unit_width(grid)
-        need = (4.0 * k * n_tr * max(d, 1) * 3.0
-                + 4.0 * k * n_va * max(d, 1)
-                + 4.0 * k * (n_tr + n_va) * G * width)
-        return need <= self._stacked_hbm_budget()
+    def _stacked_fits_memory(self, batch, est, grid) -> bool:
+        """HBM guard for one family's fold-stacked unit: what the family
+        says it needs beyond the resident training matrix
+        (``Predictor.fold_stack_bytes``: nothing matrix-sized where folds
+        train as row weights; the gathered folds, their derived copies and
+        the validation folds where they are arrays of their own; the
+        per-grid-lane intermediates either way) must fit the budget, else
+        the sweep falls back to the per-fold loop, whose peak is 1/k of a
+        gathered batch."""
+        return est.fold_stack_bytes(batch, grid) <= self._stacked_hbm_budget()
 
     def _sweep(self, Xt, yt, wt, yt_np) -> tuple[list[ModelEvaluation],
                                                  list[tuple[float, int, int]],
@@ -550,7 +562,7 @@ class ModelSelector(Estimator):
         Execution model (PERF.md "Sweep execution", round 9): the sweep
         is TWO phases. The DISPATCH phase walks the families and launches
         every stacked program — linear/NB/GLM/MLP fold-stacks
-        (``grid_scores_folds_retained``) and tree depth-groups
+        (``Predictor.sweep_folds`` over a ``FoldBatch``) and tree depth-groups
         (``_family_tree_stacked``) alike — handing each family's ``[k, G]``
         metric batch back as a DEVICE FUTURE; no family blocks the host,
         so their programs overlap on device. The SETTLE phase
@@ -659,15 +671,13 @@ class ModelSelector(Estimator):
         device metric futures on ``pending``; per-family-settle and loop
         fallbacks record their values inline."""
         from transmogrifai_tpu.models.base import (
-            supports_fold_stacking, supports_tree_stacking,
+            FoldBatch, supports_fold_stacking, supports_tree_stacking,
         )
-        from transmogrifai_tpu.parallel import mesh as pmesh
         from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         from transmogrifai_tpu.utils.profiling import sweep_counters
         from transmogrifai_tpu.utils.retry import with_device_retry
         from transmogrifai_tpu.utils.tracing import span
-        stacked_data = None  # built on the first stacked-capable family
-        n_classes_hint = None  # once-per-sweep label pulls (O(1), uncounted)
+        batch = None  # built on the first stacked-capable family
         tree_stats = None
         with span("sweep.dispatch", families=len(self.models_and_grids),
                   mode="async" if async_on else "per_family"):
@@ -713,35 +723,22 @@ class ModelSelector(Estimator):
                     continue
                 use_stacked = (self._stacked_enabled()
                                and fold_metrics is not None
-                               and supports_fold_stacking(est)
-                               and self._stacked_fits_memory(
-                                   k, n_tr, n_va, d, est, grid))
+                               and supports_fold_stacking(est))
+                if use_stacked and batch is None:
+                    # the fold plan over the ONE resident training matrix:
+                    # a family trains its folds as row weights over it, or
+                    # asks it for gathered folds (made once, shared, counted
+                    # in sweepOperandBytes)
+                    batch = FoldBatch(Xt, yt, wt, tr_idx, va_idx)
+                use_stacked = use_stacked and self._stacked_fits_memory(
+                    batch, est, grid)
                 if use_stacked:
-                    if stacked_data is None:
-                        # one device gather builds the whole fold batch — no
-                        # per-fold Xtr materialization on host; training
-                        # rows pad+shard 2-D over the mesh (rows on "data",
-                        # folds on "model" when they divide it); validation
-                        # folds stay unpadded — metrics must see real rows
-                        # only
-                        with compile_telemetry.building("sweep.operands"):
-                            jtr = jnp.asarray(tr_idx)
-                            jva = jnp.asarray(va_idx)
-                            stacked_data = (
-                                pmesh.shard_stacked_training_rows(
-                                    jnp.take(Xt, jtr, axis=0),
-                                    jnp.take(yt, jtr, axis=0),
-                                    jnp.take(wt, jtr, axis=0))
-                                + (jnp.take(Xt, jva, axis=0),
-                                   jnp.take(yt, jva, axis=0)))
-                    Xtr_s, ytr_s, wtr_s, Xva_s, yva_s = stacked_data
-                    if n_classes_hint is None:
-                        # the ONE class-count pull every softmax/NB/MLP
-                        # family would otherwise block on at dispatch —
-                        # same expression on the same stacked labels, so
-                        # threading it is value-identical
-                        n_classes_hint = max(
-                            int(np.asarray(jnp.max(ytr_s))) + 1, 2)
+                    # the ONE class-count pull every softmax/NB/MLP family
+                    # would otherwise block on at dispatch, and the folds'
+                    # validation labels: the batch makes each once
+                    with compile_telemetry.building("sweep.operands"):
+                        n_classes_hint = batch.n_classes_hint()
+                        yva_s = batch.validation_labels()
                     try:
                         with compile_telemetry.building(
                                     f"sweep.family:{fname}", family=fname), \
@@ -756,8 +753,7 @@ class ModelSelector(Estimator):
                             retain = (self._refit_warm_enabled()
                                       and est.supports_warm_refit())
                             scores, warm = with_device_retry(
-                                est.grid_scores_folds_retained, Xtr_s,
-                                ytr_s, wtr_s, grid, Xva_s,
+                                est.sweep_folds, batch, grid,
                                 _n_classes=n_classes_hint, site="sweep.fit")
                             if scores is None:
                                 raise _FoldStackFallback()
@@ -1694,7 +1690,7 @@ class ModelSelector(Estimator):
 
             train_idx, holdout_idx, w_train, prep_results = \
                 self._split_prepare(n, y[:n])
-            Xt, yt = X[jnp.asarray(train_idx)], y[jnp.asarray(train_idx)]
+            Xt, yt = _take_rows(X, y, jnp.asarray(train_idx))
             wt = jnp.asarray(w_train)
         _plog("selector: split+prepare", t0)
 
@@ -1713,8 +1709,8 @@ class ModelSelector(Estimator):
         _plog("selector: CV sweep", t1)
         t1 = time.time()
         with compile_telemetry.building("selector.prepare"):
-            Xh = X[jnp.asarray(holdout_idx)] if holdout_idx.size else None
-            yh = y[jnp.asarray(holdout_idx)] if holdout_idx.size else None
+            Xh, yh = (_take_rows(X, y, jnp.asarray(holdout_idx))
+                      if holdout_idx.size else (None, None))
         with profiler.phase(OpStep.MODEL_TRAINING), \
                 span("selector.refit", hbm=True, stage_uid=self.uid,
                      stage_cls=type(self).__name__, phase="refit",

@@ -213,6 +213,13 @@ class HostTransformer(Transformer):
         cols = [data.host_col(n) for n in self.runtime_input_names()]
         return self.host_apply(*cols)
 
+    def device_output_column(self, data):
+        """The output as a DEVICE column, for a stage that can fill it
+        there from less than the host column holds (a sparse vector from
+        its entries); None, the default, sends the batch executor to
+        :meth:`output_column`."""
+        return None
+
 
 class DeviceTransformer(Transformer):
     """Jittable columnar transformer, fused per DAG layer by the executor.

@@ -456,6 +456,16 @@ class SweepCounters:
         self.sweep_host_syncs = 0   # blocking settle barriers, whole sweep
         self.async_families = 0     # families overlapped past dispatch
         self.refit_warm_starts = 0  # winner refits reusing sweep state
+        #: bytes of matrix-sized operands the selector materialised beyond
+        #: the resident training matrix (split, fold batch, standardized
+        #: copies)
+        self.operand_bytes = 0
+        #: host feature engineering of string columns: distinct values
+        #: dictionary-encoded, hashed columns that fell to the per-row
+        #: loop, bytes of vector blocks uploaded to the device
+        self.fe_distinct_values = 0
+        self.fe_hash_fallbacks = 0
+        self.fe_upload_bytes = 0
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -466,6 +476,10 @@ class SweepCounters:
         self.sweep_host_syncs = 0
         self.async_families = 0
         self.refit_warm_starts = 0
+        self.operand_bytes = 0
+        self.fe_distinct_values = 0
+        self.fe_hash_fallbacks = 0
+        self.fe_upload_bytes = 0
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -489,12 +503,19 @@ class SweepCounters:
             fc.mode = mode
 
     def count_run(self, *, host_syncs: int = 0, async_families: int = 0,
-                  refit_warm_starts: int = 0) -> None:
+                  refit_warm_starts: int = 0, operand_bytes: int = 0,
+                  fe_distinct_values: int = 0, fe_hash_fallbacks: int = 0,
+                  fe_upload_bytes: int = 0) -> None:
         """Run-level accounting (see class docstring): settle barriers,
-        overlapped families, warm-started refits."""
+        overlapped families, warm-started refits, operand copies, and the
+        host string work that fed the sweep."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
+        self.operand_bytes += int(operand_bytes)
+        self.fe_distinct_values += int(fe_distinct_values)
+        self.fe_hash_fallbacks += int(fe_hash_fallbacks)
+        self.fe_upload_bytes += int(fe_upload_bytes)
 
     def to_json(self) -> dict:
         return {name: {"mode": fc.mode, "compiles": self.compiles(name),
@@ -509,7 +530,11 @@ class SweepCounters:
         ``to_json`` map so existing consumers keep their shape)."""
         return {"sweepHostSyncs": self.sweep_host_syncs,
                 "asyncFamilies": self.async_families,
-                "refitWarmStarts": self.refit_warm_starts}
+                "refitWarmStarts": self.refit_warm_starts,
+                "sweepOperandBytes": self.operand_bytes,
+                "feDistinctValues": self.fe_distinct_values,
+                "feHashPerRowFallbacks": self.fe_hash_fallbacks,
+                "feUploadBytes": self.fe_upload_bytes}
 
 
 sweep_counters = SweepCounters()

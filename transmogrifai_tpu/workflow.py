@@ -309,7 +309,9 @@ class Workflow:
             else:
                 dag = self._substitute_fitted(full_dag, ckpt_overrides)
                 with profiler.phase(OpStep.FEATURE_ENGINEERING):
-                    _, fitted = self._fit_layers(executor, data, dag, ckpt)
+                    _, fitted = self._fit_layers(
+                        executor, data, dag, ckpt,
+                        keep={f.name for f in result})
         finally:
             for s in patched_selectors:
                 s.checkpoint_dir = None
@@ -349,7 +351,8 @@ class Workflow:
 
     @staticmethod
     def _fit_layers(executor: DagExecutor, data: PipelineData, dag: Dag,
-                    ckpt=None, layer_offset: int = 0
+                    ckpt=None, layer_offset: int = 0,
+                    keep: Optional[set] = None
                     ) -> tuple[PipelineData, Dag]:
         """Layer-at-a-time ``fit_transform`` with resume accounting and
         per-layer checkpointing. A layer whose estimators were all replaced
@@ -367,11 +370,25 @@ class Workflow:
         multi-layer fused programs fire where whole fitted DAGs replay:
         ``executor.transform`` (scoring, CV validation transforms) and
         the selector's per-fold during-DAG ``fit_transform`` over the
-        full multi-layer cut (``fit_with_dag``)."""
+        full multi-layer cut (``fit_with_dag``).
+
+        With ``keep`` (the names the caller still reads afterwards) a
+        column that a stage of this DAG produced is let go as soon as no
+        later layer reads it: the blocks a wide vector was combined from,
+        then the un-checked vector once the checked one exists."""
         from transmogrifai_tpu.stages.base import Estimator
         from transmogrifai_tpu.utils.faults import fault_point
         from transmogrifai_tpu.utils.profiling import run_counters
         fitted_dag: Dag = []
+        read_later: list[set] = []   # names layers after li still read
+        if keep is not None:
+            needed = set(keep)
+            for layer in reversed(dag):
+                read_later.append(set(needed))
+                for s in layer:
+                    needed.update(s.input_names)
+            read_later.reverse()
+        produced: set = set()
         for li, layer in enumerate(dag):
             fault_point("train.layer")
             resumed = (not any(isinstance(s, Estimator) for s in layer)
@@ -379,6 +396,12 @@ class Workflow:
                                for s in layer))
             data, fl = executor.fit_transform(data, [layer])
             fitted_dag.extend(fl)
+            if keep is not None:
+                produced.update(s.get_output().name for s in layer)
+                dead = produced - read_later[li]
+                if dead:
+                    data = data.without(dead)
+                    produced -= dead
             if resumed:
                 run_counters.layers_resumed += 1
             else:
