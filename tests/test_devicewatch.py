@@ -4,6 +4,7 @@ no-false-fire), fault-injected hang autopsies end-to-end (a slow
 collective and a stalled one-sync settle), compile telemetry, the
 ``cli autopsy`` reader, and the new artifact schemas."""
 
+import contextlib
 import importlib.util
 import json
 import os
@@ -23,6 +24,28 @@ def _load_script(name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@contextlib.contextmanager
+def _persistent_cache_in(path):
+    """JAX's persistent compilation cache in ``path``, every program
+    cached, for the block; the settings it found come back after it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        yield
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 @pytest.fixture
@@ -473,14 +496,9 @@ def test_real_cache_hit_event_order_and_site(tmp_path):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.compilation_cache import compilation_cache as cc
 
     from transmogrifai_tpu.utils.devicewatch import compile_telemetry
     compile_telemetry.ensure_listener()
-    keep = {k: getattr(jax.config, k) for k in (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")}
     c = float(_time.time())   # run-unique HLO
 
     def unique_program(a):
@@ -489,11 +507,7 @@ def test_real_cache_hit_event_order_and_site(tmp_path):
     def site(name):
         return dict(compile_telemetry.to_json()["bySite"].get(
             name, {"programs": 0, "cacheLoads": 0}))
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        cc.reset_cache()
+    with _persistent_cache_in(tmp_path):
         x = jnp.ones(3)   # made outside: its own small programs compile here
         before_c, before_l = site("test.compile"), site("test.load")
         with compile_telemetry.building("test.compile"):
@@ -505,10 +519,6 @@ def test_real_cache_hit_event_order_and_site(tmp_path):
         jax.clear_caches()
         with compile_telemetry.building("test.load"):
             jax.jit(unique_program)(x).block_until_ready()
-    finally:
-        for k, v in keep.items():
-            jax.config.update(k, v)
-        cc.reset_cache()
     after_c, after_l = site("test.compile"), site("test.load")
     assert after_c["programs"] == before_c["programs"] + 1
     assert after_c["cacheLoads"] == before_c["cacheLoads"]
@@ -526,9 +536,6 @@ def test_second_workflow_loads_its_fe_programs(tmp_path):
     so the uids stay out of the module and of the persistent cache's key:
     a second train of the same shapes loads the programs the first
     compiled."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
     from transmogrifai_tpu import frame as fr
     from transmogrifai_tpu.features.builder import FeatureBuilder
     from transmogrifai_tpu.ops.transmogrifier import transmogrify
@@ -557,15 +564,7 @@ def test_second_workflow_loads_its_fe_programs(tmp_path):
         return tuple(sum(by.get(s, {}).get(k, 0)
                          for s in ("fe.fused", "fe.layer"))
                      for k in ("programs", "cacheLoads"))
-    keep = {k: getattr(jax.config, k) for k in (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")}
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        cc.reset_cache()
+    with _persistent_cache_in(tmp_path):
         p0, l0 = fe_sites()
         train()
         p1, l1 = fe_sites()
@@ -575,12 +574,84 @@ def test_second_workflow_loads_its_fe_programs(tmp_path):
             pytest.skip("this backend wrote no persistent cache entry")
         train()
         p2, l2 = fe_sites()
-    finally:
-        for k, v in keep.items():
-            jax.config.update(k, v)
-        cc.reset_cache()
     # the second train compiled no FE program: it loaded what it ran
     assert p2 == p1 and l2 > l1 == l0
+
+
+@pytest.mark.parametrize("winner", ["gbt_classifier", "rf_classifier",
+                                    "gbt_regressor"])
+def test_train_on_fresh_table_loads_tree_winner_programs(tmp_path, winner):
+    """A tree winner's base score (a GBT classifier's is the log-odds of
+    its training split's label mean, a regressor's the mean) is a value of
+    the table. It reaches the winner's programs as an argument, so a train
+    on ANOTHER table of the same shapes compiles none of them anew: the
+    fused feature-engineering programs, the one in which the winner scores
+    the frame among them, and the selector's two ``predict_arrays``
+    programs all load from the persistent cache."""
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.features.builder import FeatureBuilder
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.preparators.sanity_checker import SanityChecker
+    from transmogrifai_tpu.selector import (
+        BinaryClassificationModelSelector, DataSplitter,
+        RegressionModelSelector,
+    )
+    from transmogrifai_tpu.types import feature_types as ft
+    from transmogrifai_tpu.utils.devicewatch import compile_telemetry
+    from transmogrifai_tpu.workflow import Workflow
+    compile_telemetry.ensure_listener()
+    sites = ("fe.fused", "fe.layer", "predict:TreeEnsembleModel")
+    # grid values no other test uses: the first train compiles its programs
+    grid = [{"max_depth": 2, "num_rounds": 4}]
+    selector, estimator = {
+        "gbt_classifier": (BinaryClassificationModelSelector,
+                           trees.OpGBTClassifier),
+        "rf_classifier": (BinaryClassificationModelSelector,
+                          trees.OpRandomForestClassifier),
+        "gbt_regressor": (RegressionModelSelector, trees.OpGBTRegressor),
+    }[winner]
+
+    def train(seed):
+        rng = np.random.default_rng(seed)
+        X = rng.normal(size=(500, 4))
+        y = X[:, 0] * X[:, 1] + 0.3 * X[:, 2] + 0.2 * seed
+        if winner.endswith("classifier"):
+            y = (y > 0).astype(np.float64)
+        cols = {f"x{i}": (ft.Real, X[:, i]) for i in range(4)}
+        cols["label"] = (ft.RealNN, y)
+        frame = fr.HostFrame.from_dict(cols)
+        feats = FeatureBuilder.from_frame(frame, response="label")
+        label = feats.pop("label")
+        checked = label.transform_with(
+            SanityChecker(), transmogrify(list(feats.values())))
+        sel = selector.with_cross_validation(
+            n_folds=2, seed=1, models_and_parameters=[(estimator(), grid)],
+            splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
+        model = Workflow().set_input_frame(frame).set_result_features(
+            label.transform_with(sel, checked)).train()
+        return model.selector_summary()
+
+    def counts():
+        by = compile_telemetry.to_json()["bySite"]
+        return {s: (by.get(s, {}).get("programs", 0),
+                    by.get(s, {}).get("cacheLoads", 0)) for s in sites}
+    with _persistent_cache_in(tmp_path):
+        c0 = counts()
+        first = train(1)
+        c1 = counts()
+        if c1["fe.fused"][0] == c0["fe.fused"][0]:
+            pytest.skip("jax.monitoring backend-compile events unavailable")
+        if not any(tmp_path.iterdir()):
+            pytest.skip("this backend wrote no persistent cache entry")
+        second = train(2)
+        c2 = counts()
+    # another table: another split, another label mean, another model
+    assert first.train_evaluation != second.train_evaluation
+    # ... and the same programs: none compiled, the winner's were loaded
+    assert {s: c2[s][0] - c1[s][0] for s in sites} == dict.fromkeys(sites, 0)
+    for s in ("fe.fused", "predict:TreeEnsembleModel"):
+        assert c2[s][1] > c1[s][1], s
 
 
 def test_compile_telemetry_real_sweep_series(monkeypatch):

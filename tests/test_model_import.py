@@ -294,6 +294,38 @@ def test_xgboost_multiclass_softprob_parity():
     np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("path", ["predict_arrays", "fused", "bf16", "int8"])
+@pytest.mark.parametrize("source", ["sklearn_per_class", "xgboost_scalar"])
+def test_imported_base_score_is_an_argument(source, path):
+    """An imported booster's base score (a per-class float32 vector from
+    scikit-learn's multiclass prior, a scalar log-odds from XGBoost) rides
+    in ``device_params()``: the score is ``base + learning_rate * sum`` in
+    float32 to the bit, and on the rungs below f32 the base is added
+    unrounded to the rung's own sum (what a zero base gives)."""
+    from tree_reference import (
+        assert_is_margin, assert_rung_adds_base_unrounded, fused_predict,
+        margin_of,
+    )
+    if source == "sklearn_per_class":
+        from sklearn.ensemble import GradientBoostingClassifier
+        model = import_sklearn(GradientBoostingClassifier(
+            n_estimators=6, max_depth=3, learning_rate=0.25, random_state=0
+        ).fit(X, y_mc))
+        assert np.shape(model.base_score) == (3,)
+    else:
+        model = import_xgboost_json(FIXTURE)
+        assert np.ndim(model.base_score) == 0 and model.base_score != 0.0
+    base = model.device_params()[2]
+    assert base.dtype == np.float32 and base.shape == np.shape(
+        model.base_score)
+    if path in ("predict_arrays", "fused"):
+        pred = (model.predict_arrays(jnp.asarray(X))
+                if path == "predict_arrays" else fused_predict(model, X))
+        assert_is_margin(margin_of(pred, model), model, X)
+    else:
+        assert_rung_adds_base_unrounded(model, X, path)
+
+
 def test_imported_model_serves_inside_workflow():
     """The MLeap-analog end game: an imported foreign model wired as the
     prediction stage of a normal workflow — vectorization from raw

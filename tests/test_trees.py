@@ -584,3 +584,110 @@ def test_tree_bin_once_fold_plan(monkeypatch):
                                np.asarray(m_ref.trees[2]), atol=1e-6)
     monkeypatch.setenv("TRANSMOGRIFAI_TREE_BIN_ONCE", "0")
     assert est.fold_sweep_plan(X, grid) is None
+
+
+# -- the base score is an argument of the model's programs --------------------
+
+def _fit(kind, seed):
+    """A small fitted boosted model on a table drawn from ``seed``."""
+    from transmogrifai_tpu.models.trees import OpGBTClassifier, OpGBTRegressor
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(300, 5)).astype(np.float32)
+    y = np.sin(2 * X[:, 0]) + X[:, 1] * X[:, 2] + 0.4 + 0.3 * seed
+    if kind == "gbt_classifier":
+        y = (y > 0.9).astype(np.float64)
+    est = {"gbt_classifier": OpGBTClassifier,
+           "gbt_regressor": OpGBTRegressor}[kind](
+        num_rounds=5, max_depth=3, learning_rate=0.3)
+    model = est.fit_arrays(jnp.asarray(X), jnp.asarray(y),
+                           jnp.ones(len(X)), est.params)
+    return model, X
+
+
+@pytest.mark.parametrize("path", ["predict_arrays", "fused"])
+@pytest.mark.parametrize("kind", ["gbt_regressor", "gbt_classifier"])
+def test_score_is_base_plus_rate_times_sum(kind, path):
+    """With the base score an argument, the score is still
+    ``base_score + learning_rate * sum(tree outputs)`` in float32, to the
+    bit, through ``predict_arrays`` and through the fused program."""
+    from tree_reference import assert_is_margin, fused_predict, margin_of
+    model, X = _fit(kind, seed=1)
+    assert abs(model.base_score) > 0.05       # a value of the table
+    pred = (model.predict_arrays(jnp.asarray(X)) if path == "predict_arrays"
+            else fused_predict(model, X))
+    assert_is_margin(margin_of(pred, model), model, X)
+
+
+@pytest.mark.parametrize("rung", ["bf16", "int8"])
+def test_quantized_rungs_add_the_base_score_unrounded(rung):
+    """On the rungs below f32 the leaves take the rung's dtype and the base
+    score stays the float32 it is (an offset on the margin, pinned like the
+    bin edges): the score is float32(base) + the rung's own
+    ``learning_rate * sum``, which the same trees under a zero base give."""
+    from tree_reference import assert_rung_adds_base_unrounded
+    from transmogrifai_tpu.utils.precision import ExactTensor
+    model, X = _fit("gbt_regressor", seed=2)
+    base = np.float32(model.base_score)
+    assert base != np.float32(jnp.asarray(base, jnp.bfloat16))
+    edges, (feats, bins, leaves), qbase = model.quantize_device_params(rung)
+    assert isinstance(edges, ExactTensor) and isinstance(qbase, ExactTensor)
+    assert np.asarray(qbase.value).dtype == np.float32
+    assert np.asarray(qbase.value) == base
+    assert all(a.dtype == (jnp.int16 if rung == "int8" else jnp.int32)
+               for a in (*feats, *bins))
+    assert_rung_adds_base_unrounded(model, X, rung)
+
+
+def test_models_of_two_tables_lower_to_one_module():
+    """Nothing of the training data is a constant of the model's program:
+    two models fitted on two tables of the same shapes lower
+    ``device_apply`` to the same module text, so the second one's programs
+    are found in the persistent compile cache."""
+    import jax
+    from transmogrifai_tpu import frame as fr
+    texts, bases = [], []
+    for seed in (1, 2):
+        model, X = _fit("gbt_classifier", seed)
+        bases.append(model.base_score)
+        texts.append(jax.jit(model.device_apply).lower(
+            model.device_params(),
+            fr.VectorColumn(jnp.asarray(X))).as_text())
+    assert bases[0] != bases[1]
+    assert texts[0] == texts[1]
+
+
+def test_manifest_keeps_base_score_and_old_manifests_load():
+    """The base score is saved where it always was, in the stage's
+    ``config``: the record a fitted model writes is the one the parent of
+    this change wrote, and such a record loads and scores the same."""
+    from transmogrifai_tpu.serialization import (
+        fitted_stage_record, restore_fitted_stage,
+    )
+    model, X = _fit("gbt_classifier", seed=3)
+    rec, arrays = fitted_stage_record(model)
+    assert rec["config"] == {
+        "kind": "gbt_classifier", "n_out": 1, "learning_rate": 0.3,
+        "base_score": model.base_score, "max_depth": 3}
+    assert isinstance(rec["config"]["base_score"], float)
+    assert rec["stateJson"] == {}
+    assert sorted(k.partition("||")[2] for k in arrays) == sorted(
+        ["bin_edges", "leaves", "feature_gains"]
+        + [f"{a}_l{l}" for a in ("feat", "bin") for l in range(3)])
+    # a record as it was written before the base score became an argument
+    old = {"class": "TreeEnsembleModel",
+           "module": "transmogrifai_tpu.models.trees",
+           "uid": "TreeEnsembleModel_00000000004d",
+           "operationName": rec["operationName"],
+           "config": {"kind": "gbt_classifier", "n_out": 1,
+                      "learning_rate": 0.3,
+                      "base_score": float(model.base_score), "max_depth": 3},
+           "stateJson": {}}
+    old_arrays = {f"{old['uid']}||{k.partition('||')[2]}": v
+                  for k, v in arrays.items()}
+    loaded = restore_fitted_stage(old, old_arrays)
+    a = model.predict_arrays(jnp.asarray(X))
+    b = loaded.predict_arrays(jnp.asarray(X))
+    np.testing.assert_array_equal(np.asarray(a.raw_prediction),
+                                  np.asarray(b.raw_prediction))
+    np.testing.assert_array_equal(np.asarray(a.probability),
+                                  np.asarray(b.probability))

@@ -388,7 +388,18 @@ class PredictionModel(AllowLabelAsInput, DeviceTransformer):
         The cache keys on ``config()``: device_apply bakes structural
         Python attributes (probabilistic/family/kind/...) into the trace,
         and those may change via ``set_fitted_state`` after a first
-        predict — a stale trace would silently keep the OLD semantics."""
+        predict — a stale trace would silently keep the OLD semantics.
+
+        What a trace may hold, and what it must not: attributes that
+        choose the program's STRUCTURE (a kind, a class count, a depth, a
+        link) and values the user stated (a grid's learning rate) are
+        trace constants. A value FITTED FROM THE DATA (weights, trees,
+        bin edges, a base score, a prior) is never one: it goes through
+        ``device_params()`` as an array. A data value in the trace makes
+        the lowered module differ from table to table, so that every
+        retrain on fresh data misses the persistent compile cache and
+        compiles this program, and the fused program that scores with it,
+        anew (``tests/test_devicewatch.py -k fresh_table``)."""
         from transmogrifai_tpu.utils.devicewatch import compile_telemetry
         cfg = self.config()
         cached = self.__dict__.get("_jit_apply")

@@ -1011,11 +1011,17 @@ class TreeEnsembleModel(PredictionModel):
         return self.kind.endswith("classifier")
 
     def device_params(self):
-        return (jnp.asarray(self.bin_edges), self.trees)
+        # the base score is a value of the training data (a GBT
+        # classifier's is the log-odds of its split's label mean): it
+        # enters every program as an argument, so that a model fitted on
+        # another table runs the same program (a host array: nothing is
+        # dispatched to hand it over)
+        return (jnp.asarray(self.bin_edges), self.trees,
+                np.asarray(self.base_score, np.float32))
 
     def quantize_device_params(self, precision):
         from transmogrifai_tpu.utils.precision import ExactTensor, fits_int16
-        edges, (feats, bins, leaves) = self.device_params()
+        edges, (feats, bins, leaves), base = self.device_params()
         if precision == "int8" and all(fits_int16(a)
                                        for a in (*feats, *bins)):
             # node traversal compares binned int data: int16 vs int32
@@ -1023,16 +1029,17 @@ class TreeEnsembleModel(PredictionModel):
             feats = tuple(jnp.asarray(a, jnp.int16) for a in feats)
             bins = tuple(jnp.asarray(a, jnp.int16) for a in bins)
         # bin edges stay f32 master values at every rung (ExactTensor
-        # pins them through the builder's generic float cast); leaf
-        # values take the rung's activation dtype like any float param
-        return (ExactTensor(edges), (feats, bins, leaves))
+        # pins them through the builder's generic float cast), and so
+        # does the base score, an offset on the margin; leaf values take
+        # the rung's activation dtype like any float param
+        return (ExactTensor(edges), (feats, bins, leaves), ExactTensor(base))
 
     def device_apply(self, params, col: fr.VectorColumn) -> fr.PredictionColumn:
-        edges, trees = params
+        edges, trees, base = params
         Xb = bin_data(col.values, edges)
         out = predict_ensemble(
             Xb, trees, n_out=self.n_out,
-            learning_rate=self.learning_rate, base_score=self.base_score,
+            learning_rate=self.learning_rate, base_score=base,
             bootstrap=self.is_forest)  # [n, n_out]
         n = out.shape[0]
         if not self.is_classifier:
