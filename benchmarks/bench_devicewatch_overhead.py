@@ -150,8 +150,6 @@ def _sweep_one_sync_leg() -> dict:
     from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
     from transmogrifai_tpu.workflow import Workflow
 
-    os.environ["TRANSMOGRIFAI_SWEEP_STACKED"] = "1"
-    os.environ["TRANSMOGRIFAI_SWEEP_ASYNC"] = "1"
     profiler.reset(app_name="devicewatch_sweep")
     stalls_before = devicewatch.watchdog.stalls
     guards_before = devicewatch.watchdog.guards

@@ -35,9 +35,6 @@ import warnings
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-os.environ.setdefault("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-os.environ.setdefault("TRANSMOGRIFAI_TREE_STACKED", "1")
-
 import numpy as np
 
 ROWS = int(os.environ.get("RESILIENCE_ROWS", 4_000))

@@ -66,15 +66,6 @@ FRAME_SIZES = (1, 7, 64, 256)
 #: that this tolerance REJECTS a reply paired with another row's oracle.
 SERVE_TOL = 5e-2
 
-#: sorted-histogram Pallas kernel vs the einsum engine, per-feature split
-#: gains: identical bf16 one-hot operands and f32 accumulation on the TPU, so
-#: split structure must match exactly and the histogram sums differ by f32
-#: accumulation order only (the kernel sums a block's 256 rows inside one
-#: MXU dot, the einsum inside XLA's tiling; both then cumsum ~4k blocks).
-#: Gains are differences of squared sums, so allow 1e-3: four orders over
-#: f32 epsilon, two under bf16's.
-HIST_RTOL = 1e-3
-
 
 class Leg:
     """One leg's record: what ran, what was asserted, its smoke wall."""
@@ -182,10 +173,7 @@ def _run_higgs(leg: Leg, rows: int, ckpt_dir: str, on_tpu: bool) -> dict:
     """One run of bench.run_pipeline with every sweep assertion of the
     higgs leg (shared with the mesh leg)."""
     import bench
-    from transmogrifai_tpu.models.trees import (
-        _SORT_MIN_ROWS, _sorted_acc_default, _sorted_engine_default,
-        _TreePredictor,
-    )
+    from transmogrifai_tpu.models.trees import _SORT_MIN_ROWS, _hist_engine
     from transmogrifai_tpu.parallel.mesh import current_mesh
     from transmogrifai_tpu.selector import factories
     from transmogrifai_tpu.utils.resources import resource_counters
@@ -200,17 +188,13 @@ def _run_higgs(leg: Leg, rows: int, ckpt_dir: str, on_tpu: bool) -> dict:
     k = 3
     n_tr = int(rows * 0.9) * (k - 1) // k
     modes = {f: c["mode"] for f, c in res["sweep_counters"].items()}
-    acc = _sorted_acc_default()
-    if acc == "auto":
-        acc = "bf16" if on_tpu else "f32"
-    hist = _TreePredictor._tree_stack_hist_mode(n_tr)
+    hist = _hist_engine(n_tr, stacked=True)
     leg.info.update(
         rows=rows, smoke_wall_s=round(res["wall"], 1), best=res["best"],
         holdout_auroc=round(res["auroc"], 4), grid_points=n_points,
         sweep_modes=modes, sweep_run_counters=res["sweep_run_counters"],
         sweep_counters=res["sweep_counters"],
-        tree_hist_engine=hist, tree_sorted_engine=_sorted_engine_default(),
-        tree_accumulate_dtype=acc if hist == "sorted" else "f32",
+        tree_hist_engine=hist,
         fold_train_rows=n_tr,
         phases_wall_s={p: v["wall_s"] for p, v in res["phases"].items()},
         cv_metrics={r.model_name: list(r.metric_values.values())[0]
@@ -230,17 +214,16 @@ def _run_higgs(leg: Leg, rows: int, ckpt_dir: str, on_tpu: bool) -> dict:
               "every grid point has a finite metric per fold "
               "(sweep checkpoint: k x grid values per family)")
     leg.check(degradations == [], "sweep checkpoint records no degradation")
-    if on_tpu:
-        leg.check(all(m in ("fold_stacked", "tree_stacked")
-                      for m in modes.values()) and len(modes) == len(zoo),
-                  "every family took its stacked sweep mode (none fell "
-                  "back to the per-fold loop)")
-        leg.check(res["sweep_run_counters"]["sweepHostSyncs"] == 1,
-                  "sweepHostSyncs == 1 (one-sync async sweep)")
-        if current_mesh() is None and n_tr >= _SORT_MIN_ROWS:
-            leg.check(hist == "sorted" and acc == "bf16",
-                      "trees took the sorted engine with bf16 accumulate "
-                      f"(fold rows {n_tr} >= {_SORT_MIN_ROWS})")
+    leg.check(all(m in ("fold_stacked", "tree_stacked")
+                  for m in modes.values()) and len(modes) == len(zoo),
+              "every family took its stacked sweep mode (none fell "
+              "back to the per-fold loop)")
+    leg.check(res["sweep_run_counters"]["sweepHostSyncs"] == 1,
+              "sweepHostSyncs == 1 (one settle for the whole sweep)")
+    if on_tpu and current_mesh() is None and n_tr >= _SORT_MIN_ROWS:
+        leg.check(hist == "sorted",
+                  "trees took the sorted engine with bf16 operands "
+                  f"(fold rows {n_tr} >= {_SORT_MIN_ROWS})")
     floor = 0.80 if rows >= 400_000 else 0.60
     leg.check(res["auroc"] >= floor, f"holdout AuROC >= {floor}")
     _counters_clean(leg)
@@ -384,16 +367,13 @@ def _lowers_to_custom_call(jitted, *args, **kw) -> bool:
 def leg_kernels(leg: Leg, out: str, ctx: dict) -> None:
     """Each Pallas kernel through its public stage at > 1 block with a
     ragged tail, against its XLA twin."""
-    import bench
     import jax.numpy as jnp
     import numpy as np
     from transmogrifai_tpu import dsl  # noqa: F401 — installs feature DSL
     from transmogrifai_tpu import frame as fr
     from transmogrifai_tpu.features.builder import FeatureBuilder
-    from transmogrifai_tpu.models import trees
     from transmogrifai_tpu.ops import hashing_pallas as hp
     from transmogrifai_tpu.ops import quantile_bin_pallas as qb
-    from transmogrifai_tpu.ops import sorted_hist_pallas as sh
     from transmogrifai_tpu.ops.transmogrifier import transmogrify
     from transmogrifai_tpu.types import feature_types as ft
     from transmogrifai_tpu.workflow import Workflow
@@ -467,45 +447,6 @@ def leg_kernels(leg: Leg, out: str, ctx: dict) -> None:
     leg.info["hashing_width"] = int(got.shape[1])
     del got, ref
 
-    # 3. train_ensemble(hist="sorted", sorted_engine="pallas")
-    #    -> ops/sorted_hist_pallas.py
-    X, y = bench.make_data(n, seed=5)
-    edges = trees.quantile_bin_edges(X, 64)
-    Xb = trees.bin_data(jnp.asarray(X), jnp.asarray(edges))
-    kw = dict(n_rounds=2, max_depth=3, n_bins=64, n_out=1, loss="logistic",
-              learning_rate=jnp.float32(0.3), reg_lambda=jnp.float32(1.0),
-              gamma=jnp.float32(0.0), min_child_weight=jnp.float32(1.0),
-              subsample=1.0, colsample=1.0, base_score=jnp.float32(0.0),
-              bootstrap=False, seed=5, hist="sorted",
-              sorted_acc="bf16" if on_tpu else "f32")
-    yj, wj = jnp.asarray(y, jnp.float32), jnp.ones(n, jnp.float32)
-    (f_k, b_k, _), g_k = trees.train_ensemble(Xb, yj, wj,
-                                              sorted_engine="pallas", **kw)
-    (f_e, b_e, _), g_e = trees.train_ensemble(Xb, yj, wj,
-                                              sorted_engine="einsum", **kw)
-    same = all(bool(jnp.array_equal(a, b))
-               for a, b in zip((*f_k, *b_k), (*f_e, *b_e)))
-    leg.check(same, "histogram kernel: split structure (feature, bin per "
-                    "node) identical to the einsum engine")
-    # split gains are computed from the histograms (leaf values are not:
-    # they come from segment sums of the row partition). Off the TPU the
-    # einsum reference runs f32 operands against the kernel's bf16 ones
-    # (XLA:CPU has no bf16 dot), so the rehearsal bound is bf16's
-    rtol = HIST_RTOL if on_tpu else 5e-2
-    gain_err = float(jnp.max(jnp.abs(g_k - g_e) / (1e-6 + jnp.abs(g_e))))
-    leg.info["hist_gain_max_rel_err"] = gain_err
-    leg.check(gain_err <= rtol,
-              f"histogram kernel: per-feature split gains within rtol "
-              f"{rtol} of the einsum engine")
-    leg.check(_lowers_to_custom_call(trees.train_ensemble, Xb, yj, wj,
-                                     sorted_engine="pallas", **kw)
-              if on_tpu else _lowers_to_custom_call(
-                  sh.sorted_block_hist, jnp.zeros((5, 256, 28), jnp.int8),
-                  jnp.zeros((5, 2, 256), jnp.float32), n_bins=64,
-                  interpret=False),
-              "histogram kernel lowers to a tpu_custom_call (on the TPU: "
-              "inside train_ensemble's own program)")
-    leg.info["hist_shape"] = [n, 28, 64]
     leg.info["interpret"] = not on_tpu
     leg.info["peak_bytes_in_use"] = _peaks()
 

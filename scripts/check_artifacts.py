@@ -78,14 +78,10 @@ def validate_artifact(doc: object) -> list[str]:
         errors.extend(_validate_observability(doc))
     if doc.get("metric") == "tracing_overhead":
         errors.extend(_validate_tracing_overhead(doc))
-    if doc.get("metric") == "tree_stacked_sweep":
-        errors.extend(_validate_tree_stacked(doc))
     if doc.get("metric") == "serving_fleet":
         errors.extend(_validate_serving_fleet(doc))
     if doc.get("metric") == "serving_scaleout":
         errors.extend(_validate_serving_scaleout(doc))
-    if doc.get("metric") == "one_sync_sweep":
-        errors.extend(_validate_one_sync(doc))
     if doc.get("metric") == "continuous_loop":
         errors.extend(_validate_continuous_loop(doc))
     if doc.get("metric") == "resource_resilience":
@@ -973,72 +969,6 @@ def _validate_continuous_loop(doc: dict) -> list[str]:
     return errors
 
 
-#: warm-vs-cold winner-refit metric tolerance for the one-sync sweep
-#: artifact: a converged convex refit must land on the cold optimum
-MAX_REFIT_PARITY = 1e-5
-
-
-def _validate_one_sync(doc: dict) -> list[str]:
-    """The ``benchmarks/ONE_SYNC_SWEEP.json`` contract (round 9): three
-    measured whole-train walls (per-family settle / one-sync / one-sync +
-    warm refit), counter-backed sync structure — the async stacked path
-    must record exactly ONE blocking host sync for the entire sweep while
-    the per-family path records one per family — at least one warm-
-    started refit, and metric parity: the sweep's validation metrics
-    identical across modes, the warm refit's train/holdout metrics within
-    ``MAX_REFIT_PARITY`` of the cold serial refit."""
-    errors = []
-
-    def num(v) -> bool:
-        return isinstance(v, (int, float)) and not isinstance(v, bool)
-
-    for k in ("per_family_settle_s", "one_sync_s", "one_sync_warm_refit_s"):
-        if not (num(doc.get(k)) and doc[k] > 0):
-            errors.append(f"one-sync artifact: missing positive {k!r}")
-    if not num(doc.get("speedup_vs_per_family")):
-        errors.append("one-sync artifact: missing numeric "
-                      "'speedup_vs_per_family'")
-    syncs = doc.get("total_host_syncs")
-    if not (isinstance(syncs, dict) and all(
-            isinstance(syncs.get(k), int) and not isinstance(
-                syncs.get(k), bool)
-            for k in ("per_family_settle", "one_sync", "one_sync_warm"))):
-        errors.append("one-sync artifact: 'total_host_syncs' must map "
-                      "per_family_settle/one_sync/one_sync_warm to ints")
-    else:
-        if syncs["one_sync"] != 1 or syncs["one_sync_warm"] != 1:
-            errors.append(
-                f"one-sync contract violated: the async stacked sweep "
-                f"recorded {syncs['one_sync']}/{syncs['one_sync_warm']} "
-                "blocking host syncs (must be exactly 1)")
-        fams = doc.get("families")
-        if isinstance(fams, int) and syncs["per_family_settle"] < fams:
-            errors.append(
-                "one-sync artifact: the per-family-settle leg must record "
-                "at least one sync per family (the baseline being beaten)")
-    if not (isinstance(doc.get("refit_warm_starts"), int)
-            and doc.get("refit_warm_starts", 0) >= 1):
-        errors.append("one-sync artifact: 'refit_warm_starts' must be "
-                      ">= 1 — the warm leg must actually warm-start")
-    vp = doc.get("validation_parity")
-    if not num(vp):
-        errors.append("one-sync artifact: missing numeric "
-                      "'validation_parity'")
-    elif vp != 0.0:
-        errors.append(
-            f"one-sync artifact: validation metrics drifted ({vp}) across "
-            "settle modes — async settling must not change values")
-    rp = doc.get("refit_parity")
-    if not num(rp):
-        errors.append("one-sync artifact: missing numeric 'refit_parity'")
-    elif rp > MAX_REFIT_PARITY:
-        errors.append(
-            f"warm-refit metric parity {rp} exceeds {MAX_REFIT_PARITY} — "
-            "the warm-started winner landed on a different model, not the "
-            "same refit faster")
-    return errors
-
-
 #: p99 while a hot-swap is in flight may cost at most this factor over
 #: steady state — the zero-downtime acceptance bound the committed
 #: benchmarks/SERVING_FLEET.json is held to
@@ -1251,50 +1181,6 @@ def _validate_serving_scaleout(doc: dict) -> list[str]:
                 f"scaleout artifact: only {mr}/{reps} replicas mapped "
                 "the shared program artifacts — compile-once-map-"
                 "everywhere did not hold")
-    return errors
-
-
-#: stacked-vs-loop metric parity bound for the tree-stacked sweep
-#: artifact: both paths bin once and draw the same PRNG streams, so any
-#: difference is pure fp accumulation noise
-MAX_TREE_STACK_PARITY = 1e-5
-
-
-def _validate_tree_stacked(doc: dict) -> list[str]:
-    """The ``benchmarks/TREE_STACKED_SWEEP.json`` contract: the three
-    measured walls (per-point loop / per-fold batched / fold x grid
-    stacked), the derived speedups, exact-parity metric deltas within fp
-    tolerance, and the structural dispatch/host-sync count blocks that
-    back the gating default."""
-    errors = []
-
-    def num(v) -> bool:
-        return isinstance(v, (int, float)) and not isinstance(v, bool)
-
-    for k in ("tree_stacked_s", "per_fold_s", "per_point_s"):
-        if not (num(doc.get(k)) and doc[k] > 0):
-            errors.append(f"tree-stacked artifact: missing positive {k!r}")
-    for k in ("speedup_vs_per_fold", "speedup_vs_per_point"):
-        if not num(doc.get(k)):
-            errors.append(f"tree-stacked artifact: missing numeric {k!r}")
-    par = doc.get("metric_parity_stacked_vs_per_fold")
-    if not num(par):
-        errors.append("tree-stacked artifact: missing numeric "
-                      "'metric_parity_stacked_vs_per_fold'")
-    elif par > MAX_TREE_STACK_PARITY:
-        errors.append(
-            f"stacked-vs-loop metric parity {par} exceeds the fp "
-            f"tolerance {MAX_TREE_STACK_PARITY} — the stacked program "
-            "computed something different, not the same sweep faster")
-    for block in ("dispatches", "host_syncs"):
-        b = doc.get(block)
-        if not (isinstance(b, dict) and all(
-                k in b and isinstance(b[k], int) and not isinstance(
-                    b[k], bool) and b[k] > 0
-                for k in ("tree_stacked", "per_fold", "per_point"))):
-            errors.append(
-                f"tree-stacked artifact: {block!r} must map each of "
-                "tree_stacked/per_fold/per_point to a positive int")
     return errors
 
 

@@ -24,6 +24,8 @@ from transmogrifai_tpu.utils.compile_cache import (  # noqa: E402
 
 enable_compile_cache()
 
+import contextlib  # noqa: E402
+
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
@@ -74,6 +76,25 @@ def mesh4x2():
     ctx = make_mesh(n_data=4, n_model=2)
     with use_mesh(ctx):
         yield ctx
+
+
+@contextlib.contextmanager
+def _no_stacked_form():
+    from transmogrifai_tpu.models import base
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(base, "supports_fold_stacking", lambda est: False)
+        mp.setattr(base, "supports_tree_stacking", lambda est: False)
+        yield
+
+
+@pytest.fixture(scope="session")
+def fold_loop():
+    """``with fold_loop():`` — every family reports no stacked form, so a
+    sweep trained inside takes the per-fold loop for the reason the
+    selector observes (``models.base.supports_fold_stacking`` /
+    ``supports_tree_stacking``): the reference leg of the stacked-vs-loop
+    parity tests and the other layout of the checkpoint-resume tests."""
+    return _no_stacked_form
 
 
 @pytest.fixture

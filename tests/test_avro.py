@@ -1,5 +1,10 @@
-"""Avro container IO + AvroReader tests, validated against real Java-written
-(snappy) files in the reference test-data plus full round-trips."""
+"""Avro container IO + AvroReader tests: full round-trips over a
+Passenger-shaped file written here (the shape of the reference test-data's
+Java-written ``PassengerData.avro``: nullable unions, string / boolean /
+numeric maps), plus the Java-written snappy file itself where the reference
+checkout is on the machine."""
+
+import os
 
 import numpy as np
 import pytest
@@ -12,9 +17,70 @@ from transmogrifai_tpu.utils.avro_io import (
 )
 
 PASSENGER_AVRO = "/root/reference/test-data/PassengerData.avro"
-PASSENGER_ALL_AVRO = "/root/reference/test-data/PassengerDataAll.avro"
 
 
+def _nullable(t):
+    return ["null", t]
+
+
+PASSENGER_SCHEMA = {
+    "type": "record", "name": "Passenger",
+    "namespace": "com.salesforce.op.test",
+    "fields": [
+        {"name": "passengerId", "type": "int"},
+        {"name": "age", "type": _nullable("int")},
+        {"name": "gender", "type": _nullable("string")},
+        {"name": "height", "type": _nullable("int")},
+        {"name": "weight", "type": _nullable("int")},
+        {"name": "description", "type": _nullable("string")},
+        {"name": "boarded", "type": _nullable("long")},
+        {"name": "recordDate", "type": _nullable("long")},
+        {"name": "survived", "type": _nullable("boolean")},
+        {"name": "numericMap",
+         "type": _nullable({"type": "map", "values": "double"})},
+        {"name": "booleanMap",
+         "type": _nullable({"type": "map", "values": "boolean"})},
+        {"name": "stringMap",
+         "type": _nullable({"type": "map", "values": "string"})},
+    ],
+}
+
+
+def _passenger_records():
+    """Eight records over six passengers (ids 1 and 4 appear twice, at
+    different ``recordDate``), with missing ages, descriptions and maps."""
+    def rec(pid, age, gender, height, weight, desc, date, survived):
+        return {
+            "passengerId": pid, "age": age, "gender": gender,
+            "height": height, "weight": weight, "description": desc,
+            "boarded": 1471046200 + pid, "recordDate": date,
+            "survived": survived,
+            "numericMap": {gender: float(pid)} if age is not None else None,
+            "booleanMap": {gender: bool(survived)} if pid != 3 else {},
+            "stringMap": {gender: "string"},
+        }
+    return [
+        rec(1, 32, "Female", 168, 67, None, 1471046100, False),
+        rec(1, 33, "Female", 168, 68, "a year on", 1502582100, False),
+        rec(2, None, "Male", 180, 78, "", 1471046400, True),
+        rec(3, 23, "Female", 172, 85, "this is a description", None, True),
+        rec(4, 45, "Male", 175, 0, "stuff", 1471046600, False),
+        rec(4, None, "Male", 175, 92, None, 1471046700, True),
+        rec(5, 50, "Male", 186, 96, "text text", 1471046800, None),
+        rec(6, 19, "Female", 160, 54, None, 1471046900, True),
+    ]
+
+
+@pytest.fixture
+def passenger_avro(tmp_path):
+    p = str(tmp_path / "PassengerData.avro")
+    write_avro(p, PASSENGER_SCHEMA, _passenger_records(), codec="snappy")
+    return p
+
+
+@pytest.mark.skipif(not os.path.exists(PASSENGER_AVRO),
+                    reason="the reference checkout's test-data is not on "
+                           "this machine")
 def test_read_java_written_snappy_file():
     schema, recs = read_avro(PASSENGER_AVRO)
     assert schema["name"] == "Passenger"
@@ -28,7 +94,7 @@ def test_read_java_written_snappy_file():
 
 @pytest.mark.parametrize("codec", ["null", "deflate", "snappy"])
 def test_round_trip_all_codecs(tmp_path, codec):
-    schema, recs = read_avro(PASSENGER_ALL_AVRO)
+    schema, recs = PASSENGER_SCHEMA, _passenger_records()
     p = str(tmp_path / f"rt_{codec}.avro")
     write_avro(p, schema, recs, codec=codec)
     s2, r2 = read_avro(p)
@@ -37,8 +103,9 @@ def test_round_trip_all_codecs(tmp_path, codec):
     assert read_avro_schema(p) == schema
 
 
-def test_avro_reader_infers_feature_schema_and_generates_frame():
-    reader = AvroReader(PASSENGER_AVRO, key_col="passengerId")
+def test_avro_reader_infers_feature_schema_and_generates_frame(
+        passenger_avro):
+    reader = AvroReader(passenger_avro, key_col="passengerId")
     sch = reader.schema()
     assert sch["age"] is ft.Integral
     assert sch["gender"] is ft.Text
@@ -54,14 +121,14 @@ def test_avro_reader_infers_feature_schema_and_generates_frame():
     assert frame["age"].mask.sum() < 8
 
 
-def test_aggregate_avro_reader():
+def test_aggregate_avro_reader(passenger_avro):
     reader = DataReaders.Aggregate.avro(
-        PASSENGER_AVRO, key_fn=lambda r: str(r["passengerId"]),
+        passenger_avro, key_fn=lambda r: str(r["passengerId"]),
         time_fn=lambda r: int(r["recordDate"] or 0))
     weight = FeatureBuilder.Integral("weight").as_predictor()
     frame = reader.generate_frame([weight])
     # one row per distinct passengerId
-    assert frame.n_rows == len(set(frame.key))
+    assert frame.n_rows == len(set(frame.key)) == 6
 
 
 def test_save_avro_round_trips_frame(tmp_path):

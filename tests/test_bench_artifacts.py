@@ -101,95 +101,6 @@ def test_serving_artifact_committed_and_healthy(checker):
     assert art["parity_max_abs_diff"] < 1e-4
 
 
-def test_tree_stacked_artifact_committed_and_healthy(checker):
-    """The fold x grid-stacked tree sweep's acceptance contract, pinned
-    on the COMMITTED artifact: the three-way comparison exists, the
-    stacked path's metric parity vs the loop is within fp tolerance, and
-    the structural dispatch/host-sync counts back the k x L-fewer-round-
-    trips argument (stacked = 1 per group vs folds x grid_points)."""
-    path = os.path.join(REPO, "benchmarks", "TREE_STACKED_SWEEP.json")
-    assert os.path.exists(path), \
-        "benchmarks/TREE_STACKED_SWEEP.json not committed"
-    art = json.load(open(path))
-    assert checker.validate_artifact(art) == []
-    assert art["metric"] == "tree_stacked_sweep"
-    assert art["rows"] >= 100_000 and art["cols"] >= 28 \
-        and art["bins"] >= 64
-    assert art["metric_parity_stacked_vs_per_fold"] <= 1e-5
-    hs = art["host_syncs"]
-    assert hs["tree_stacked"] == art["groups"]
-    assert hs["per_fold"] == art["folds"]
-    assert hs["per_point"] == art["folds"] * art["grid_points"]
-
-
-def test_tree_stacked_artifact_schema_rejections(checker):
-    v = checker.validate_artifact
-    good = {"metric": "tree_stacked_sweep", "platform": "cpu",
-            "rows": 100000, "tree_stacked_s": 1.0, "per_fold_s": 2.0,
-            "per_point_s": 3.0, "speedup_vs_per_fold": 2.0,
-            "speedup_vs_per_point": 3.0,
-            "metric_parity_stacked_vs_per_fold": 0.0,
-            "dispatches": {"tree_stacked": 1, "per_fold": 3,
-                           "per_point": 12},
-            "host_syncs": {"tree_stacked": 1, "per_fold": 3,
-                           "per_point": 12}}
-    assert v(good) == []
-    assert any("parity" in e for e in v(
-        {**good, "metric_parity_stacked_vs_per_fold": 0.5}))
-    bad = dict(good)
-    del bad["per_point_s"]
-    assert any("per_point_s" in e for e in v(bad))
-    assert any("host_syncs" in e for e in v(
-        {**good, "host_syncs": {"tree_stacked": 1}}))
-
-
-def test_one_sync_artifact_committed_and_healthy(checker):
-    """Round 9's acceptance contract, pinned on the COMMITTED artifact:
-    the async stacked sweep records exactly ONE blocking host sync for
-    the whole train() (vs >= one per family on the per-family-settle
-    leg), at least one refit actually warm-started, validation metrics
-    are bit-equal across settle modes, and the warm refit's metrics are
-    within 1e-5 of the cold serial refit."""
-    path = os.path.join(REPO, "benchmarks", "ONE_SYNC_SWEEP.json")
-    assert os.path.exists(path), \
-        "benchmarks/ONE_SYNC_SWEEP.json not committed"
-    art = json.load(open(path))
-    assert checker.validate_artifact(art) == []
-    assert art["metric"] == "one_sync_sweep"
-    syncs = art["total_host_syncs"]
-    assert syncs["one_sync"] == 1 and syncs["one_sync_warm"] == 1
-    assert syncs["per_family_settle"] >= art["families"] >= 2
-    assert art["async_families"] == art["families"]
-    assert art["refit_warm_starts"] >= 1
-    assert art["validation_parity"] == 0.0
-    assert art["refit_parity"] <= checker.MAX_REFIT_PARITY
-
-
-def test_one_sync_artifact_schema_rejections(checker):
-    v = checker.validate_artifact
-    good = {"metric": "one_sync_sweep", "platform": "cpu", "rows": 60000,
-            "families": 2, "per_family_settle_s": 2.0, "one_sync_s": 1.0,
-            "one_sync_warm_refit_s": 1.0, "speedup_vs_per_family": 2.0,
-            "total_host_syncs": {"per_family_settle": 2, "one_sync": 1,
-                                 "one_sync_warm": 1},
-            "refit_warm_starts": 1, "validation_parity": 0.0,
-            "refit_parity": 0.0}
-    assert v(good) == []
-    assert any("exactly 1" in e for e in v(
-        {**good, "total_host_syncs": {"per_family_settle": 2,
-                                      "one_sync": 2, "one_sync_warm": 1}}))
-    assert any("per family" in e for e in v(
-        {**good, "total_host_syncs": {"per_family_settle": 1,
-                                      "one_sync": 1, "one_sync_warm": 1}}))
-    assert any("warm" in e for e in v({**good, "refit_warm_starts": 0}))
-    assert any("drifted" in e for e in v(
-        {**good, "validation_parity": 1e-6}))
-    assert any("parity" in e for e in v({**good, "refit_parity": 1e-3}))
-    bad = dict(good)
-    del bad["one_sync_warm_refit_s"]
-    assert any("one_sync_warm_refit_s" in e for e in v(bad))
-
-
 def test_serving_fleet_artifact_schema_rejections(checker):
     v = checker.validate_artifact
     good = {"metric": "serving_fleet", "platform": "cpu",

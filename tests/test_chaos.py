@@ -333,13 +333,12 @@ def _build_tree_workflow(n=200, seed=4):
     return wf, host, pred
 
 
-def test_transient_fault_inside_stacked_tree_group(monkeypatch):
+def test_transient_fault_inside_stacked_tree_group():
     """A transient device error during a fold x grid-stacked tree group's
     dispatch retries the WHOLE group (all k folds x L lanes — no fold is
     lost, no candidate fails), the retry counters record it, and the
     result matches the fault-free stacked run exactly."""
     from transmogrifai_tpu.utils.profiling import sweep_counters
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     UID.reset()
     wf, host, pred = _build_tree_workflow()
     ref = _probs(wf.train(), host, pred)
@@ -365,14 +364,13 @@ def test_transient_fault_inside_stacked_tree_group(monkeypatch):
     del ref_summary
 
 
-def test_async_dispatch_transient_retries_only_affected_family(monkeypatch):
+def test_async_dispatch_transient_retries_only_affected_family():
     """Round 9: a transient fault injected mid-async-dispatch (the 2nd
     family's ``sweep.fit`` site) retries ONLY that family's program —
     every family still dispatches exactly once (zero duplicate work), the
     whole sweep settles behind its single barrier, and metrics match the
     fault-free async run bitwise."""
     from transmogrifai_tpu.utils.profiling import sweep_counters
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     ref = _reference_scores(families=2)
 
     UID.reset()
@@ -399,7 +397,6 @@ def test_refit_preemption_resumes_from_refit_checkpoint(tmp_path,
     replays the sweep from ``sweep.json`` AND restores the winner from
     its shape-keyed refit entry — the winner is never retrained, and
     scores match the uninterrupted run bitwise."""
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     ckpt = str(tmp_path / "ck")
     ref = _reference_scores()
 
@@ -426,12 +423,11 @@ def test_refit_preemption_resumes_from_refit_checkpoint(tmp_path,
     np.testing.assert_array_equal(_probs(model, host, pred), ref)
 
 
-def test_stacked_tree_group_span_nests_under_sweep(monkeypatch):
+def test_stacked_tree_group_span_nests_under_sweep():
     """The per-group span replaces the per-(family, fold) spans on the
     tree fast path: it carries k/lanes/depth attrs and nests under
     selector.sweep."""
     from transmogrifai_tpu.utils.tracing import recorder
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     UID.reset()
     wf, host, pred = _build_tree_workflow(seed=6)
     wf.train()
@@ -975,13 +971,12 @@ def _continuous_loop(wf, stream, state, **kw):
 
 
 def test_continuous_retrain_preemption_resumes_zero_duplicate_fits(
-        tmp_path, monkeypatch):
+        tmp_path):
     """A preemption mid-retrain (inside the retrain's ``train.layer``)
     kills the loop with the pendingRetrain manifest durable; the
     restarted loop re-runs the SAME retrain resuming from the per-window
     fitted-DAG checkpoints — completed layers are restored, not refit —
     and promotes. Serving state machinery is untouched throughout."""
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     stream = tmp_path / "stream"
     state = tmp_path / "state"
     stream.mkdir()
@@ -1024,7 +1019,6 @@ def test_continuous_promote_preemption_resumes_with_zero_fits(tmp_path,
     checkpoints written) but the swap never started. The restarted loop
     re-runs the pending retrain fully from checkpoints — counter-asserted
     ZERO model fits — and promotes the identical model."""
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     stream = tmp_path / "stream"
     state = tmp_path / "state"
     stream.mkdir()

@@ -57,7 +57,9 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
                                                 monkeypatch):
     """The same legs the chip runs, at a few thousand rows, with the tree
     families cut to a few shallow trees (the LR/SVC grids stay whole) so
-    the module stays lean; on the chip the zoo is un-cut and asserted so."""
+    the module stays lean; on the chip the zoo is un-cut and asserted so.
+    The titanic leg (891 rows) keeps the un-cut zoo here too: its 0.88 floor
+    is set by that zoo's winner (RF, 0.8902)."""
     from transmogrifai_tpu.models.linear import (
         OpLinearSVC, OpLogisticRegression,
     )
@@ -74,7 +76,16 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
                  [{"num_trees": 4, "max_depth": 3}]),
                 (OpGBTClassifier(), [{"num_rounds": 4, "max_depth": 2}])]
 
+    full_zoo = factories._default_binary_candidates
+    leg_titanic = smoke.LEG_FNS["titanic"]
+
+    def titanic_uncut(leg, out, ctx):
+        with monkeypatch.context() as m:
+            m.setattr(factories, "_default_binary_candidates", full_zoo)
+            leg_titanic(leg, out, ctx)
+
     monkeypatch.setattr(factories, "_default_binary_candidates", light_zoo)
+    monkeypatch.setitem(smoke.LEG_FNS, "titanic", titanic_uncut)
     out = str(tmp_path / "smoke")
     try:
         rc = smoke.main(["--rehearsal", "--legs",
@@ -90,6 +101,8 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     assert list(summary["legs"]) == ["titanic", "higgs", "serve", "kernels"]
     for leg in summary["legs"].values():
         assert leg["ok"] and leg["asserted"]
+    assert summary["legs"]["titanic"]["best"].startswith(
+        "OpRandomForestClassifier")
     assert summary["legs"]["higgs"]["grid_points"] == 14
     assert summary["legs"]["serve"]["frame_sizes"] == [1, 7, 64, 256]
     assert set(summary["native_libraries"]) == {"texthash", "shist",
@@ -122,7 +135,6 @@ def test_pallas_kernels_cross_lower_for_tpu(smoke):
     import jax.numpy as jnp
     from transmogrifai_tpu.ops import hashing_pallas as hp
     from transmogrifai_tpu.ops import quantile_bin_pallas as qb
-    from transmogrifai_tpu.ops import sorted_hist_pallas as sh
 
     n = 3 * qb._BLOCK_ROWS + 77
     splits = jnp.asarray([-jnp.inf, -1.0, 0.0, 1.0, jnp.inf], jnp.float32)
@@ -133,9 +145,6 @@ def test_pallas_kernels_cross_lower_for_tpu(smoke):
         hp._segment_onehot_pallas,
         jnp.zeros((3 * hp._BLOCK_ROWS + 77, 3), jnp.int32), n_bins=64,
         interpret=False)
-    assert smoke._lowers_to_custom_call(
-        sh.sorted_block_hist, jnp.zeros((5, 256, 28), jnp.int8),
-        jnp.zeros((5, 2, 256), jnp.float32), n_bins=64, interpret=False)
 
 
 def test_supervisor_keeps_one_process_per_chip(monkeypatch, tmp_path):

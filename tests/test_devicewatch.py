@@ -371,8 +371,6 @@ def test_stalled_settle_autopsies_and_keeps_one_sync(dw, tmp_path,
     import jax
 
     from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "1")
     dw.configure(incident_dir=str(tmp_path), stall_timeout_s=0.15,
                  poll_interval_s=0.03)
     stalls0 = dw.watchdog.stalls
@@ -654,7 +652,7 @@ def test_train_on_fresh_table_loads_tree_winner_programs(tmp_path, winner):
         assert c2[s][1] > c1[s][1], s
 
 
-def test_compile_telemetry_real_sweep_series(monkeypatch):
+def test_compile_telemetry_real_sweep_series():
     """Real-compile integration: backend compiles observed during a
     stacked sweep land in the telemetry, attributed to sweep sites, and
     render as transmogrifai_compile_* series."""
@@ -671,7 +669,6 @@ def test_compile_telemetry_real_sweep_series(monkeypatch):
     jax.jit(lambda a: a * c)(jnp.ones(3)).block_until_ready()
     if compile_telemetry.programs == before:
         pytest.skip("jax.monitoring backend-compile events unavailable")
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     before = compile_telemetry.programs
     _tiny_stacked_workflow(seed=11, families=1).train()
     assert compile_telemetry.programs > before
@@ -682,7 +679,7 @@ def test_compile_telemetry_real_sweep_series(monkeypatch):
     assert "transmogrifai_compile_wall_seconds_total{site=" in out
 
 
-def test_train_with_tree_winner_compiles_nothing_unattributed(monkeypatch):
+def test_train_with_tree_winner_compiles_nothing_unattributed():
     """Every program a ``Workflow.train()`` builds — feature engineering,
     SanityChecker, the sweep's operands and families, binning, the winner's
     refit, its predict program, the evaluators — is built inside a
@@ -707,9 +704,6 @@ def test_train_with_tree_winner_compiles_nothing_unattributed(monkeypatch):
     from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
     from transmogrifai_tpu.utils.tracing import recorder
     from transmogrifai_tpu.workflow import Workflow
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "1")
     rng = np.random.default_rng(0)
     n = 600
     X = rng.normal(size=(n, 4))

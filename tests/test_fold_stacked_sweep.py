@@ -73,17 +73,16 @@ def _summaries_equal(s1, s2, tol=1e-6):
             assert abs(v1[k][m] - v2[k][m]) <= tol, (k, m)
 
 
-def test_stacked_parity_binary(monkeypatch):
+def test_stacked_parity_binary(fold_loop):
     """The fold-stacked sweep selects the identical winner with identical
     per-candidate mean metrics and summary JSON as the per-fold loop."""
     frame = _frame()
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     sweep_counters.reset()
     s1 = _train(_binary_selector(), frame).selector_summary()
     c1 = sweep_counters.to_json()
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "0")
     sweep_counters.reset()
-    s2 = _train(_binary_selector(), frame).selector_summary()
+    with fold_loop():
+        s2 = _train(_binary_selector(), frame).selector_summary()
     c2 = sweep_counters.to_json()
     _summaries_equal(s1, s2)
     # identical validationResults in the summary JSON too
@@ -96,7 +95,7 @@ def test_stacked_parity_binary(monkeypatch):
     assert all(v["mode"] == "fold_loop" for v in c2.values()), c2
 
 
-def test_stacked_parity_regression(monkeypatch):
+def test_stacked_parity_regression(fold_loop):
     frame = _frame(seed=3)
     models = lambda: [  # noqa: E731
         (OpLinearRegression(max_iter=25),
@@ -104,33 +103,31 @@ def test_stacked_parity_regression(monkeypatch):
         (OpGeneralizedLinearRegression(max_iter=25),
          [{"reg_param": r} for r in (0.0, 0.1)]),
     ]
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     s1 = _train(RegressionModelSelector.with_cross_validation(
         n_folds=2, seed=1, models_and_parameters=models(),
         splitter=DataSplitter(reserve_test_fraction=0.2, seed=1)),
         frame).selector_summary()
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "0")
-    s2 = _train(RegressionModelSelector.with_cross_validation(
-        n_folds=2, seed=1, models_and_parameters=models(),
-        splitter=DataSplitter(reserve_test_fraction=0.2, seed=1)),
-        frame).selector_summary()
+    with fold_loop():
+        s2 = _train(RegressionModelSelector.with_cross_validation(
+            n_folds=2, seed=1, models_and_parameters=models(),
+            splitter=DataSplitter(reserve_test_fraction=0.2, seed=1)),
+            frame).selector_summary()
     _summaries_equal(s1, s2)
 
 
-def test_stacked_one_host_sync_per_family(monkeypatch):
+def test_stacked_one_host_sync_per_family(fold_loop):
     """The acceptance counter: vmappable families cost exactly ONE host
     sync (and one dispatch) on the fast path, k of each on the loop."""
     frame = _frame(seed=5)
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     sweep_counters.reset()
     _train(_binary_selector(), frame)
     for name, c in sweep_counters.to_json().items():
         assert c["mode"] == "fold_stacked", (name, c)
         assert c["hostSyncs"] == 1, (name, c)
         assert c["deviceDispatches"] == 1, (name, c)
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "0")
     sweep_counters.reset()
-    _train(_binary_selector(), frame)
+    with fold_loop():
+        _train(_binary_selector(), frame)
     for name, c in sweep_counters.to_json().items():
         assert c["mode"] == "fold_loop", (name, c)
         assert c["hostSyncs"] == 3, (name, c)   # one per fold
@@ -158,12 +155,11 @@ def test_fold_stacking_capability_rules():
     assert not supports_fold_stacking(OpGBTClassifier())  # never opted in
 
 
-def test_fallback_family_without_fold_axis(monkeypatch):
+def test_fallback_family_without_fold_axis():
     """A family whose subclass overrides grid_fit_arrays routes through
     the per-fold loop (override honored), while vmappable co-candidates
     still take the stacked path."""
     frame = _frame(seed=6)
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     CountingLR.counts["n"] = 0
     sweep_counters.reset()
     sel = BinaryClassificationModelSelector.with_cross_validation(
@@ -185,7 +181,6 @@ def test_memory_guard_falls_back(monkeypatch):
     fall back to the per-fold loop and the sweep still completes with
     identical results."""
     frame = _frame(seed=7)
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_HBM_BUDGET", "1")
     sweep_counters.reset()
     s1 = _train(_binary_selector(), frame).selector_summary()
@@ -207,11 +202,10 @@ class CrashOnce(OpLinearSVC):
         return super().grid_fit_arrays(X, y, w, grid)
 
 
-def test_checkpoint_resume_mid_sweep_per_family_keys(tmp_path, monkeypatch):
+def test_checkpoint_resume_mid_sweep_per_family_keys(tmp_path):
     """A crash after the first (stacked) family completes leaves its
     per-family checkpoint key; the re-run replays it without refitting
     and sweeps only the remainder."""
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     frame = _frame(seed=9)
     ckpt = str(tmp_path / "sweep")
 
@@ -302,16 +296,13 @@ def test_fold_metric_batches_match_per_fold():
             np.testing.assert_allclose(got[f], want, atol=1e-5)
 
 
-def test_stacked_sweep_under_mesh(monkeypatch):
+def test_stacked_sweep_under_mesh():
     """The stacked (fold x grid) batch shards 2-D over an active mesh
     (rows on "data"; the fold axis takes "model" when it divides it) and
-    reproduces the unsharded metrics. An active mesh also turns the
-    stacked path on by default (no env var here for the mesh leg)."""
+    reproduces the unsharded metrics."""
     from transmogrifai_tpu.parallel.mesh import make_mesh, use_mesh
     frame = _frame(seed=11)
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     s1 = _train(_binary_selector(), frame).selector_summary()
-    monkeypatch.delenv("TRANSMOGRIFAI_SWEEP_STACKED")
     ctx = make_mesh(n_data=4, n_model=2)
     with use_mesh(ctx):
         sweep_counters.reset()

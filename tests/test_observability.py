@@ -386,10 +386,11 @@ def test_sweep_and_ingest_spans_recorded():
     runner.run(RunTypes.TRAIN, OpParams())
     names = {s.name for s in recorder.spans}
     assert {"workflow.ingest", "reader.generate_frame", "stage.fit",
-            "selector.sweep", "sweep.dispatch", "sweep.fold_unit"} <= names
+            "selector.sweep", "sweep.dispatch", "sweep.family",
+            "sweep.settle"} <= names
 
 
-def test_one_sync_sweep_span_nesting(monkeypatch):
+def test_one_sync_sweep_span_nesting():
     """Round 9 span topology: the dispatch/settle phases nest under
     ``selector.sweep`` with every ``sweep.family`` a child of
     ``sweep.dispatch`` (families overlap; the chrome trace shows one
@@ -408,7 +409,6 @@ def test_one_sync_sweep_span_nesting(monkeypatch):
     from transmogrifai_tpu.utils.tracing import recorder
     from transmogrifai_tpu.workflow import Workflow
 
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
     profiler.reset()
     rng = np.random.default_rng(3)
     x = rng.normal(size=N)

@@ -6,7 +6,6 @@ import os
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from transmogrifai_tpu import frame as fr
 from transmogrifai_tpu.features.builder import FeatureBuilder
@@ -84,20 +83,14 @@ def _summaries_equal(s1, s2, tol=0.0):
             assert abs(v1[k][m] - v2[k][m]) <= tol, (k, m)
 
 
-@pytest.fixture(autouse=True)
-def _stacked_on(monkeypatch):
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-    yield
-
-
 # ---------------------------------------------------------------------------
 # one-sync dispatch/settle
 # ---------------------------------------------------------------------------
 
-def test_one_sync_whole_sweep_counters(monkeypatch):
-    """The tentpole assertion: an entire stacked train() — linear, NB and
-    tree families together — settles behind ONE blocking host sync, every
+def test_one_sync_whole_sweep_counters():
+    """The tentpole assertion: an entire train() with NOTHING set, on
+    whatever backend the suite runs — linear, NB and tree families
+    together — settles behind ONE blocking host sync, every
     family dispatched asynchronously; per-family counters keep their
     metric-pull meaning (one per family / per tree group)."""
     frame = _frame(seed=5)
@@ -115,7 +108,7 @@ def test_one_sync_whole_sweep_counters(monkeypatch):
     assert per["OpGBTClassifier_2"]["stackedGroups"] == 1
 
 
-def test_sweep_device_spans_one_per_chunk_in_dispatch_order(monkeypatch):
+def test_sweep_device_spans_one_per_chunk_in_dispatch_order():
     """Every dispatched chunk of the async sweep gets ONE ``sweep.device``
     span, stamped as the settle walks its one barrier in dispatch order:
     the spans do not overlap, follow the dispatch order, lie inside the
@@ -124,7 +117,6 @@ def test_sweep_device_spans_one_per_chunk_in_dispatch_order(monkeypatch):
     refit gets its own ``refit.device`` span."""
     from transmogrifai_tpu.utils.profiling import profiler
     from transmogrifai_tpu.utils.tracing import recorder
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "1")
     frame = _frame(seed=5)
     profiler.reset()
     sel = BinaryClassificationModelSelector.with_cross_validation(
@@ -171,33 +163,20 @@ def test_sweep_device_spans_one_per_chunk_in_dispatch_order(monkeypatch):
     assert by_name["selector.refit"][0].t0 <= refit[0].t0 <= refit[0].t1
 
 
-def test_async_parity_with_per_family_settle_and_loop(monkeypatch):
+def test_async_parity_with_per_family_settle_and_loop(fold_loop):
     """Async overlap changes WHEN metrics materialize, never their
-    values: summaries are identical (exactly) across async, per-family
-    settle (TRANSMOGRIFAI_SWEEP_ASYNC=0), and the per-fold loop."""
+    values: summaries are identical (exactly) across the one-settle
+    stacked sweep and the per-fold loop."""
     frame = _frame(seed=7)
     s_async = _train(_mixed_selector(), frame).selector_summary()
-
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_ASYNC", "0")
-    sweep_counters.reset()
-    s_sync = _train(_mixed_selector(), frame).selector_summary()
-    run = sweep_counters.run_to_json()
-    assert run["asyncFamilies"] == 0
-    # per-family settle: one barrier per family (3 families, 1 group each)
-    assert run["sweepHostSyncs"] == 3, run
-    monkeypatch.delenv("TRANSMOGRIFAI_SWEEP_ASYNC")
-
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "0")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    s_loop = _train(_mixed_selector(), frame).selector_summary()
-
-    _summaries_equal(s_async, s_sync, tol=0.0)
+    with fold_loop():
+        s_loop = _train(_mixed_selector(), frame).selector_summary()
     _summaries_equal(s_async, s_loop, tol=0.0)
 
 
 def test_custom_evaluator_without_device_metric_settles_per_family():
-    """An evaluator exposing only the host fold-metric keeps the
-    pre-round-9 per-family settle (no futures to defer)."""
+    """An evaluator exposing only the host fold-metric has no futures to
+    defer: every family takes the per-fold loop."""
     from transmogrifai_tpu.evaluators.binary import (
         OpBinaryClassificationEvaluator,
     )
@@ -226,9 +205,9 @@ def test_custom_evaluator_without_device_metric_settles_per_family():
     _train(sel, frame)
     run = sweep_counters.run_to_json()
     assert run["asyncFamilies"] == 0
-    assert run["sweepHostSyncs"] == 2  # one per family
+    assert run["sweepHostSyncs"] == 4  # one per (fold, family)
     per = sweep_counters.to_json()
-    assert all(v["mode"] == "fold_stacked" for v in per.values())
+    assert all(v["mode"] == "fold_loop" for v in per.values())
 
 
 def test_settle_isolates_poisoned_family():
@@ -280,7 +259,10 @@ def test_warm_refit_regression_metric_parity(monkeypatch):
     s_warm = _train(make_sel(), frame).selector_summary()
     assert sweep_counters.run_to_json()["refitWarmStarts"] == 1
 
-    monkeypatch.setenv("TRANSMOGRIFAI_REFIT_WARM", "0")
+    # the cold refit, for the reason the selector observes: the family
+    # reports it cannot use a warm handle
+    monkeypatch.setattr(OpLinearRegression, "supports_warm_refit",
+                        lambda self: False)
     sweep_counters.reset()
     s_cold = _train(make_sel(), frame).selector_summary()
     assert sweep_counters.run_to_json()["refitWarmStarts"] == 0

@@ -92,12 +92,6 @@ def _assert_summaries_equal(s1, s2):
             assert v1[k][m] == v2[k][m], (k, m)
 
 
-@pytest.fixture(autouse=True)
-def _stacked_on(monkeypatch):
-    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-
-
 # ---------------------------------------------------------------------------
 # classifiers (the shared cause-chain walk)
 # ---------------------------------------------------------------------------
@@ -189,21 +183,11 @@ def sweep_frame():
 
 
 @pytest.fixture(scope="module")
-def loop_summary(sweep_frame):
+def loop_summary(sweep_frame, fold_loop):
     """The per-fold-loop reference run every rung's result must match
     bitwise."""
-    saved = {k: os.environ.get(k) for k in ("TRANSMOGRIFAI_SWEEP_STACKED",
-                                            "TRANSMOGRIFAI_TREE_STACKED")}
-    os.environ["TRANSMOGRIFAI_SWEEP_STACKED"] = "0"
-    os.environ["TRANSMOGRIFAI_TREE_STACKED"] = "0"
-    try:
+    with fold_loop():
         return _train(_selector(), sweep_frame).selector_summary()
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
 
 
 def test_stacked_family_oom_degrades_to_fold_loop(sweep_frame,
@@ -304,14 +288,13 @@ def test_ladder_disabled_sweep_fault_fails_fast(sweep_frame,
 
 def test_refit_warm_oom_falls_back_cold(sweep_frame):
     """An OOM inside the warm-started winner refit releases the retained
-    fold parameters and refits cold (bitwise the TRANSMOGRIFAI_REFIT_WARM=0
-    refit) instead of dying after a completed sweep."""
-    os.environ["TRANSMOGRIFAI_REFIT_WARM"] = "0"
-    try:
+    fold parameters and refits cold (bitwise the refit of a family that
+    reports no warm refit) instead of dying after a completed sweep."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(OpLogisticRegression, "supports_warm_refit",
+                   lambda self: False)
         s_cold = _train(_selector(single=True),
                         sweep_frame).selector_summary()
-    finally:
-        del os.environ["TRANSMOGRIFAI_REFIT_WARM"]
     resource_counters.reset()
     # single LR family: sweep.fit#0 is the stacked sweep dispatch,
     # #1 is the refit unit

@@ -130,6 +130,33 @@ def test_max_wait_skips_later_families():
     assert any("max_wait" in f["reason"] for f in s.failures)
 
 
+def test_max_wait_rescues_a_skipped_family_when_the_settle_fails():
+    """``max_wait_s`` skipped the second family because the first had its
+    metric future pending; that future fails at the settle (not an OOM), so
+    nothing scored: the skipped family runs after all and wins, and the
+    poisoned one is the only failure left on record."""
+
+    class _Poisoned:
+        def __array__(self, dtype=None):
+            raise RuntimeError("poisoned program")
+
+    frame = _frame(seed=5)
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        n_folds=2, seed=1,
+        models_and_parameters=[
+            (OpLogisticRegression(max_iter=30), [{"reg_param": 0.01}]),
+            (OpLogisticRegression(max_iter=30), [{"reg_param": 0.1}]),
+        ],
+        splitter=DataSplitter(reserve_test_fraction=0.2, seed=1),
+        max_wait_s=0.0)
+    sel.evaluators[0].metric_batch_scores_folds_device = (
+        lambda y, scores, metric: _Poisoned())
+    s = _train(sel, frame).selector_summary()
+    assert s.best_model_name.endswith("_1_0")
+    assert [f["modelName"] for f in s.failures] == ["OpLogisticRegression_0"]
+    assert "poisoned program" in s.failures[0]["reason"]
+
+
 def test_with_device_retry_transient_then_success():
     calls = {"n": 0}
 

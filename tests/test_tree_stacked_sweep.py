@@ -1,7 +1,7 @@
 """Fold x grid-stacked TREE sweep (round 8): exact stacked-vs-loop metric
 parity for RF/GBT on binary and regression suites, the one-sync-per-
 depth-group counter contract, HBM-guard lane chunking, checkpoint resume
-across layouts (stacked <-> loop), gating overrides, the batched
+across layouts (stacked <-> loop), the selector's route table, the batched
 histogram engines, and the capability rules."""
 
 import json
@@ -17,7 +17,7 @@ from transmogrifai_tpu.features.builder import FeatureBuilder
 from transmogrifai_tpu.models.base import (
     supports_fold_stacking, supports_tree_stacking,
 )
-from transmogrifai_tpu.models.linear import OpLinearSVC
+from transmogrifai_tpu.models.linear import OpLinearSVC, OpLogisticRegression
 from transmogrifai_tpu.models.trees import (
     OpDecisionTreeClassifier, OpGBTClassifier, OpGBTRegressor,
     OpRandomForestClassifier, OpRandomForestRegressor, OpXGBoostClassifier,
@@ -96,19 +96,16 @@ def shared_frame():
 @pytest.fixture(scope="module")
 def stacked_run(shared_frame):
     """Module-scoped canonical STACKED sweep: (summary, counters) for
-    ``_tree_binary_selector`` trained once with stacking forced on."""
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-        sweep_counters.reset()
-        s = _train(_tree_binary_selector(), shared_frame).selector_summary()
-        return s, sweep_counters.to_json()
+    ``_tree_binary_selector`` trained once with nothing set."""
+    sweep_counters.reset()
+    s = _train(_tree_binary_selector(), shared_frame).selector_summary()
+    return s, sweep_counters.to_json()
 
 
 @pytest.fixture(scope="module")
-def loop_run(shared_frame):
+def loop_run(shared_frame, fold_loop):
     """Module-scoped canonical per-fold LOOP sweep on the same frame."""
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
+    with fold_loop():
         sweep_counters.reset()
         s = _train(_tree_binary_selector(), shared_frame).selector_summary()
         return s, sweep_counters.to_json()
@@ -135,16 +132,15 @@ def test_tree_stacked_parity_binary(stacked_run, loop_run):
     assert all(v["mode"] == "fold_loop" for v in c2.values()), c2
 
 
-def test_tree_stacked_parity_regression(monkeypatch):
+def test_tree_stacked_parity_regression(fold_loop):
     frame = _frame(seed=3, regression=True)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     s1 = _train(_tree_regression_selector(), frame).selector_summary()
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    s2 = _train(_tree_regression_selector(), frame).selector_summary()
+    with fold_loop():
+        s2 = _train(_tree_regression_selector(), frame).selector_summary()
     _summaries_equal(s1, s2, tol=0.0)
 
 
-def test_tree_stacked_one_sync_per_depth_group(monkeypatch):
+def test_tree_stacked_one_sync_per_depth_group(fold_loop):
     """The acceptance counter: a tree depth-group costs <= 1 blocking
     host sync and 1 fused dispatch for all k folds x L lanes. A
     mixed-depth grid forms one group per depth; each costs one
@@ -161,7 +157,6 @@ def test_tree_stacked_one_sync_per_depth_group(monkeypatch):
              [{"max_depth": 2}, {"max_depth": 3}]),           # 2 groups
         ],
         splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     sweep_counters.reset()
     _train(sel(), frame)
     c = sweep_counters.to_json()
@@ -172,15 +167,15 @@ def test_tree_stacked_one_sync_per_depth_group(monkeypatch):
     assert gbt["hostSyncs"] == gbt["deviceDispatches"] == 1, gbt
     assert rf["hostSyncs"] == rf["deviceDispatches"] == 2, rf
     assert gbt["laneChunks"] == 1 and rf["laneChunks"] == 2
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    sweep_counters.reset()
-    _train(sel(), frame)
+    with fold_loop():
+        sweep_counters.reset()
+        _train(sel(), frame)
     c = sweep_counters.to_json()
     assert c["OpGBTClassifier_0"]["hostSyncs"] == 3       # one per fold
     assert c["OpRandomForestClassifier_1"]["hostSyncs"] == 6  # k x L
 
 
-def test_tree_stacked_mixed_depth_close_to_loop(monkeypatch):
+def test_tree_stacked_mixed_depth_close_to_loop(fold_loop):
     """Mixed-depth grids: the loop path has no batched scorer (mixed
     shapes) and falls to the EXACT per-model metric, while the stacked
     path scores through the binned batch metric — the same binned-vs-
@@ -195,10 +190,9 @@ def test_tree_stacked_mixed_depth_close_to_loop(monkeypatch):
              [{"max_depth": 2}, {"max_depth": 3}]),
         ],
         splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     s1 = _train(sel(), frame).selector_summary()
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    s2 = _train(sel(), frame).selector_summary()
+    with fold_loop():
+        s2 = _train(sel(), frame).selector_summary()
     v1 = {r.model_name: r.metric_values for r in s1.validation_results}
     v2 = {r.model_name: r.metric_values for r in s2.validation_results}
     assert set(v1) == set(v2)
@@ -228,28 +222,55 @@ def test_tree_stacking_capability_rules():
     assert not supports_tree_stacking(CountingGBT())
 
 
-def test_tree_stacked_default_gating(monkeypatch):
-    """Plain CPU defaults to the loop (the microbench artifact gates the
-    flip); TRANSMOGRIFAI_TREE_STACKED forces either way."""
-    from transmogrifai_tpu.selector.model_selector import ModelSelector
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_STACKED", raising=False)
-    expected_default = jax.default_backend() != "cpu"
-    assert ModelSelector._tree_stacked_enabled() == expected_default
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-    assert ModelSelector._tree_stacked_enabled()
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    assert not ModelSelector._tree_stacked_enabled()
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_STACKED")
-    from transmogrifai_tpu.parallel.mesh import make_mesh, use_mesh
-    with use_mesh(make_mesh()):
-        assert ModelSelector._tree_stacked_enabled()  # meshes default ON
+@pytest.mark.parametrize("stackable", [True, False])
+@pytest.mark.parametrize("device_metric", [True, False])
+@pytest.mark.parametrize("fits", [True, False])
+def test_selector_route_table(stackable, device_metric, fits, monkeypatch,
+                              fold_loop, shared_frame):
+    """The selector's whole routing decision, read from ``sweep_counters``'
+    ``mode``: a family's unit runs stacked exactly when the family has a
+    stacked form AND the evaluator has the device fold metric AND the unit
+    fits the budget; any one missing sends it to the per-fold loop. No
+    environment variable takes part (the budget variable only sizes the
+    "does not fit" case)."""
+    import contextlib
+
+    from transmogrifai_tpu.evaluators.binary import (
+        OpBinaryClassificationEvaluator,
+    )
+
+    class HostMetricEvaluator(OpBinaryClassificationEvaluator):
+        metric_batch_scores_folds_device = None  # no device fold metric
+
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        n_folds=2, seed=1,
+        models_and_parameters=[
+            (OpLogisticRegression(max_iter=10), [{"reg_param": 0.01}]),
+            (OpRandomForestClassifier(num_rounds=2, max_depth=2,
+                                      max_bins=8), [{}]),
+        ],
+        splitter=DataSplitter(reserve_test_fraction=0.2, seed=1))
+    if not device_metric:
+        sel.evaluators = [HostMetricEvaluator()]
+        sel.validation_metric = "auPR"
+    if not fits:
+        monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_HBM_BUDGET", "1")
+    sweep_counters.reset()
+    with (contextlib.nullcontext() if stackable else fold_loop()):
+        _train(sel, shared_frame)
+    modes = {f: c["mode"] for f, c in sweep_counters.to_json().items()}
+    if stackable and device_metric and fits:
+        assert modes == {"OpLogisticRegression_0": "fold_stacked",
+                         "OpRandomForestClassifier_1": "tree_stacked"}
+        assert sweep_counters.run_to_json()["sweepHostSyncs"] == 1
+    else:
+        assert set(modes.values()) == {"fold_loop"}, modes
 
 
-def test_tree_stacked_multiclass_falls_back(monkeypatch):
+def test_tree_stacked_multiclass_falls_back():
     """Multiclass has no scalar stacked score: the family keeps the
-    per-fold loop even with stacking forced on."""
+    per-fold loop."""
     frame = _frame(seed=7, classes=3)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     sweep_counters.reset()
     sel = BinaryClassificationModelSelector.with_cross_validation(
         n_folds=2, seed=1,
@@ -261,22 +282,6 @@ def test_tree_stacked_multiclass_falls_back(monkeypatch):
     _train(sel, frame)
     c = sweep_counters.to_json()
     assert c["OpRandomForestClassifier_0"]["mode"] == "fold_loop", c
-
-
-def test_tree_stacked_bin_once_disabled_falls_back(monkeypatch):
-    """TRANSMOGRIFAI_TREE_BIN_ONCE=0 requests exact per-fold quantile
-    edges — nothing stacks, the loop keeps the family, results match the
-    loop run bit for bit."""
-    frame = _frame(seed=8)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_BIN_ONCE", "0")
-    sweep_counters.reset()
-    s1 = _train(_tree_binary_selector(), frame).selector_summary()
-    assert all(v["mode"] == "fold_loop"
-               for v in sweep_counters.to_json().values())
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
-    s2 = _train(_tree_binary_selector(), frame).selector_summary()
-    _summaries_equal(s1, s2, tol=0.0)
 
 
 def test_hbm_guard_lane_chunking(monkeypatch, shared_frame, stacked_run):
@@ -292,7 +297,6 @@ def test_hbm_guard_lane_chunking(monkeypatch, shared_frame, stacked_run):
     # the training frame: 240 rows, 0.2 holdout -> 192; 3 folds -> 128
     # training rows / 64 validation rows; 2 transmogrified features
     shared, per_lane = est.tree_stack_bytes(3, 128, 64, 2, group)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_HBM_BUDGET",
                        str(shared + 1.5 * per_lane))
     sweep_counters.reset()
@@ -339,11 +343,11 @@ def _crash_selector(ckpt, stacked_tree_first=True):
 
 def test_checkpoint_stacked_written_loop_resumed(tmp_path, monkeypatch):
     """A crash after the tree family completes on the STACKED path leaves
-    per-group treestack keys; a re-run under the LOOP layout replays them
-    without refitting (and vice versa below)."""
+    per-group treestack keys; a re-run that would take the LOOP layout
+    (here: a budget no stacked unit fits) replays them without refitting
+    (and vice versa below)."""
     frame = _frame(seed=10)
     ckpt = str(tmp_path / "sweep")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     CrashOnce.crash["on"] = True
     with pytest.raises(KeyboardInterrupt):
         _train(_crash_selector(ckpt), frame)
@@ -355,7 +359,6 @@ def test_checkpoint_stacked_written_loop_resumed(tmp_path, monkeypatch):
         and keys[0].endswith(":2x2"), keys
     assert len(saved["entries"][keys[0]]) == 3 * 2  # fold-major k x L
 
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
     CrashOnce.crash["on"] = False
     sel = _crash_selector(ckpt)
     gbt = sel.models_and_grids[0][0]
@@ -366,6 +369,7 @@ def test_checkpoint_stacked_written_loop_resumed(tmp_path, monkeypatch):
         calls["n"] += 1
         return orig(*a, **k)
     gbt.grid_fit_arrays = counting
+    monkeypatch.setenv("TRANSMOGRIFAI_SWEEP_HBM_BUDGET", "1")
     model = _train(sel, frame)
     assert calls["n"] == 0  # replayed from the treestack checkpoint
     names = {r.model_name
@@ -374,20 +378,18 @@ def test_checkpoint_stacked_written_loop_resumed(tmp_path, monkeypatch):
     assert any(n.startswith("CrashOnce_1") for n in names)
 
 
-def test_checkpoint_loop_written_stacked_resumed(tmp_path, monkeypatch):
+def test_checkpoint_loop_written_stacked_resumed(tmp_path, fold_loop):
     """The reverse layout hop: per-fold keys written by the loop path
     replay under the stacked path without retraining."""
     frame = _frame(seed=11)
     ckpt = str(tmp_path / "sweep")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "0")
     CrashOnce.crash["on"] = True
-    with pytest.raises(KeyboardInterrupt):
+    with fold_loop(), pytest.raises(KeyboardInterrupt):
         _train(_crash_selector(ckpt), frame)
     saved = json.load(open(os.path.join(ckpt, "sweep.json")))
     assert all(":treestack:" not in k for k in saved["entries"])
     assert len(saved["entries"]) == 3  # one per (fold, tree family)
 
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
     CrashOnce.crash["on"] = False
     sel = _crash_selector(ckpt)
     gbt = sel.models_and_grids[0][0]
@@ -403,12 +405,11 @@ def test_checkpoint_loop_written_stacked_resumed(tmp_path, monkeypatch):
     sweep_counters.reset()
 
 
-def test_checkpoint_mid_family_group_resume(tmp_path, monkeypatch):
+def test_checkpoint_mid_family_group_resume(tmp_path):
     """A crash BETWEEN depth-groups of one family: the completed group's
     treestack key replays, only the remaining group dispatches."""
     frame = _frame(seed=12)
     ckpt = str(tmp_path / "sweep")
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_STACKED", "1")
 
     def make_sel():
         return BinaryClassificationModelSelector.with_cross_validation(
@@ -456,7 +457,7 @@ def test_checkpoint_mid_family_group_resume(tmp_path, monkeypatch):
     assert len(names) == 2
 
 
-def test_tree_stacked_under_mesh(monkeypatch, shared_frame, stacked_run):
+def test_tree_stacked_under_mesh(shared_frame, stacked_run):
     """The stacked (fold x lane) tree batch shards 2-D over an active
     mesh (rows on "data", folds on "model" when they divide it) and
     completes on the GSPMD scatter engine. Trees are discrete: sharded
@@ -466,7 +467,6 @@ def test_tree_stacked_under_mesh(monkeypatch, shared_frame, stacked_run):
     from transmogrifai_tpu.parallel.mesh import make_mesh, use_mesh
     frame = shared_frame
     s1 = stacked_run[0]
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_STACKED", raising=False)
     ctx = make_mesh(n_data=4, n_model=2)
     with use_mesh(ctx):
         sweep_counters.reset()
@@ -516,8 +516,10 @@ def test_batched_scatter_histogram_folds_exactly():
 
 
 def test_stacked_engines_agree(monkeypatch):
-    """Forced sorted engine (einsum and the interpret-mode Pallas kernel)
-    under the stacked fold x lane vmaps agrees with the scatter engine."""
+    """The sorted engine (routed to by patching the ONE routing function:
+    off a TPU it never is) under the stacked fold x lane vmaps agrees with
+    the scatter engine."""
+    from transmogrifai_tpu.models import trees
     from transmogrifai_tpu.selector.validator import OpCrossValidation
     rng = np.random.default_rng(1)
     n, d = 160, 3
@@ -537,14 +539,11 @@ def test_stacked_engines_agree(monkeypatch):
     group = est.tree_stack_groups(grid)[0]
     s_scatter = np.asarray(
         est.tree_stack_scores(*args, group["params"], lnb))
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "sorted")
-    s_einsum = np.asarray(
+    monkeypatch.setattr(trees, "_hist_engine",
+                        lambda n_rows, n_devices=1, stacked=False: "sorted")
+    s_sorted = np.asarray(
         est.tree_stack_scores(*args, group["params"], lnb))
-    monkeypatch.setenv("TRANSMOGRIFAI_SORTED_HIST", "pallas")
-    s_pallas = np.asarray(
-        est.tree_stack_scores(*args, group["params"], lnb))
-    assert np.abs(s_scatter - s_einsum).max() <= 1e-5
-    np.testing.assert_array_equal(s_einsum, s_pallas)
+    assert np.abs(s_scatter - s_sorted).max() <= 1e-5
 
 
 @pytest.mark.parametrize("hist", ["sorted", "scatter"])
@@ -569,8 +568,7 @@ def test_tree_programs_carry_level_and_phase_scopes(hist, monkeypatch):
             jnp.zeros(L, jnp.float32), jnp.ones(L, jnp.float32))
     kw = dict(n_rounds=2, max_depth=2, n_bins=16, loss="logistic",
               subsample=1.0, colsample=1.0, bootstrap=False, seed=0,
-              hist=hist, sorted_engine="einsum", sorted_acc="f32",
-              forest_margin=False)
+              hist=hist, forest_margin=False)
     jax.clear_caches()   # the inner jits' traces are cached by shape
     # the lowered text, not the compiled one: the persistent compile cache
     # keys on the program without its metadata, so a cached executable

@@ -460,88 +460,83 @@ def test_train_ensemble_sorted_multiclass_parity():
     np.testing.assert_allclose(np.asarray(p1), np.asarray(p2), atol=1e-4)
 
 
-def test_hist_mode_routing(monkeypatch):
-    """_hist_mode_for is the single source of truth for the engine route:
-    forced env values win (invalid raise), sharded inputs only go
-    sorted_sharded under an active mesh with a divisible row count."""
-    from transmogrifai_tpu.models.trees import _hist_mode_for
-    from transmogrifai_tpu.parallel.mesh import (
-        make_mesh, shard_training_rows, use_mesh,
-    )
-
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_HIST", raising=False)
-    small = jnp.zeros((64, 3), jnp.int32)
-    assert _hist_mode_for(small) == "scatter"  # tiny, cpu backend
-
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "sorted")
-    assert _hist_mode_for(small) == "sorted"
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "scatter")
-    assert _hist_mode_for(small) == "scatter"
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "sort")
-    with pytest.raises(ValueError):
-        _hist_mode_for(small)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "sorted")
-
-    ctx = make_mesh(n_data=4, n_model=2)
-    with use_mesh(ctx):
-        Xs, ys, ws = shard_training_rows(
-            jnp.zeros((128, 3), jnp.int32), jnp.zeros(128), jnp.ones(128))
-        assert _hist_mode_for(Xs) == "sorted_sharded"
-    # sharded input but NO active mesh context -> GSPMD scatter fallback
-    assert _hist_mode_for(Xs) == "scatter"
+#: what ``_hist_engine`` answers on a TPU for a fit above ``_SORT_MIN_ROWS``,
+#: by (input layout, stacked unit); every other (backend, rows) answers
+#: "scatter" whatever the layout
+_TPU_BIG_ROUTES = {
+    ("one_device", False): "sorted",
+    ("one_device", True): "sorted",
+    # row-sharded over an active mesh whose data axis divides the rows: the
+    # explicit shard_map wrapper; the stacked batch cannot ride it (B1)
+    ("sharded_mesh", False): "sorted_sharded",
+    ("sharded_mesh", True): "scatter",
+    # sharded input but NO active mesh context -> GSPMD scatter; a stacked
+    # unit never looks at the input's devices
+    ("sharded_no_mesh", False): "scatter",
+    ("sharded_no_mesh", True): "sorted",
+    # rows the mesh's data axis does not divide
+    ("indivisible_rows", False): "scatter",
+    ("indivisible_rows", True): "scatter",
+}
 
 
-def test_forced_sorted_downgrade_warns_and_strict_raises(monkeypatch):
-    """A forced TRANSMOGRIFAI_TREE_HIST=sorted that the router downgrades
-    to scatter (multi-device input, no mesh) must be LOUD: silent
-    downgrades make A/B reruns time the wrong engine (ADVICE r5)."""
-    from transmogrifai_tpu.models.trees import _hist_mode_for
-    from transmogrifai_tpu.parallel.mesh import (
-        make_mesh, shard_training_rows, use_mesh,
-    )
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("layout", ["one_device", "sharded_mesh",
+                                    "sharded_no_mesh", "indivisible_rows"])
+@pytest.mark.parametrize("big", [False, True])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_hist_engine_routing(backend, big, layout, stacked, monkeypatch):
+    """``_hist_engine`` is the single source of truth for the engine route,
+    decided from the backend, the rows, the input's devices, the active
+    mesh and whether the unit is the stacked batch — nothing else."""
+    import contextlib
 
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST", "sorted")
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_HIST_STRICT", raising=False)
-    ctx = make_mesh(n_data=4, n_model=2)
-    with use_mesh(ctx):
-        Xs, _, _ = shard_training_rows(
-            jnp.zeros((128, 3), jnp.int32), jnp.zeros(128), jnp.ones(128))
-    # sharded input, mesh context GONE -> downgrade, warned
-    with pytest.warns(RuntimeWarning, match="downgraded to 'scatter'"):
-        assert _hist_mode_for(Xs) == "scatter"
-    # indivisible rows under an active mesh -> downgrade, warned
-    with use_mesh(make_mesh(n_data=8, n_model=1)):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        odd = jax.device_put(
-            jnp.zeros((126, 3), jnp.int32),
-            NamedSharding(make_mesh(n_data=2, n_model=4).mesh, P("data")))
-        with pytest.warns(RuntimeWarning, match="not divisible"):
-            assert _hist_mode_for(odd) == "scatter"
-    # strict mode: the downgrade is fatal
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_HIST_STRICT", "1")
-    with pytest.raises(RuntimeError, match="downgraded to 'scatter'"):
-        _hist_mode_for(Xs)
-    # single-device / successfully sharded routes never trip it
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_HIST_STRICT")
-    assert _hist_mode_for(jnp.zeros((64, 3), jnp.int32)) == "sorted"
+    import jax
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.parallel.mesh import make_mesh, use_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n_data = 8
+    rows = trees._SORT_MIN_ROWS if big else trees._SORT_MIN_ROWS - n_data
+    assert rows % n_data == 0
+    if layout == "indivisible_rows":
+        rows += 1
+    n_devices = 1 if layout == "one_device" else n_data
+    mesh = (use_mesh(make_mesh(n_data=n_data))
+            if layout in ("sharded_mesh", "indivisible_rows")
+            else contextlib.nullcontext())
+    with mesh:
+        got = trees._hist_engine(rows, n_devices, stacked=stacked)
+    want = (_TPU_BIG_ROUTES[layout, stacked]
+            if backend == "tpu" and big else "scatter")
+    assert got == want
 
 
-def test_sorted_acc_escape_hatch_cpu(monkeypatch):
-    """The f32-accumulation escape hatch for the sorted path's histogram
-    contraction: forced f32 matches the scatter engine; forced bf16 runs
-    the TPU numerics on CPU and stays finite."""
-    from transmogrifai_tpu.models.trees import (
-        _sorted_acc_default, grow_tree,
-    )
-    monkeypatch.delenv("TRANSMOGRIFAI_SORTED_ACC", raising=False)
-    assert _sorted_acc_default() == "auto"
-    monkeypatch.setenv("TRANSMOGRIFAI_SORTED_ACC", "f32")
-    assert _sorted_acc_default() == "f32"
-    monkeypatch.setenv("TRANSMOGRIFAI_SORTED_ACC", "nope")
-    with pytest.raises(ValueError, match="TRANSMOGRIFAI_SORTED_ACC"):
-        _sorted_acc_default()
+def test_fit_arrays_routes_by_input_devices(monkeypatch, mesh8):
+    """``fit_arrays`` hands the router the fit's rows and the number of
+    devices its binned matrix lives on."""
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.parallel.mesh import shard_training_rows
+    seen = []
 
+    def spy(n_rows, n_devices=1, *, stacked=False):
+        seen.append((n_rows, n_devices, stacked))
+        return "scatter"
+
+    monkeypatch.setattr(trees, "_hist_engine", spy)
+    X, y = _xor_data(256)
+    w = jnp.ones(X.shape[0], jnp.float32)
+    est = OpGBTClassifier(num_rounds=1, max_depth=2)
+    est.fit_arrays(X, y, w, {})
+    est.fit_arrays(*shard_training_rows(X, y, w), {})
+    assert seen == [(256, 1, False), (256, 8, False)]
+
+
+def test_sorted_engine_f32_grows_the_scatter_tree():
+    """Off a TPU the sorted engine's contraction runs float32 operands
+    (decided in ``_grow_tree_sorted``, nowhere else) and grows the scatter
+    engine's tree."""
+    from transmogrifai_tpu.models.trees import grow_tree
     X, y = _xor_data(512)
     edges = quantile_bin_edges(np.asarray(X), 32)
     Xb = bin_data(X, jnp.asarray(edges))
@@ -551,22 +546,17 @@ def test_sorted_acc_escape_hatch_cpu(monkeypatch):
     kw = dict(max_depth=4, n_bins=32, reg_lambda=jnp.float32(1.0),
               gamma=jnp.float32(0.0), min_child_weight=jnp.float32(1.0))
     ref = grow_tree(Xb, g, h, mask, hist="scatter", **kw)
-    f32 = grow_tree(Xb, g, h, mask, hist="sorted", sorted_acc="f32", **kw)
+    f32 = grow_tree(Xb, g, h, mask, hist="sorted", **kw)
+    for a, b in zip((*ref[0], *ref[1]), (*f32[0], *f32[1])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_allclose(np.asarray(ref[2]), np.asarray(f32[2]),
                                atol=1e-5)  # identical leaf values
-    bf16 = grow_tree(Xb, g, h, mask, hist="sorted", sorted_acc="bf16", **kw)
-    assert np.all(np.isfinite(np.asarray(bf16[2])))
-    # bf16 stats accumulate at reduced precision but the trees still agree
-    # on this well-separated data's split structure
-    np.testing.assert_allclose(np.asarray(bf16[2]), np.asarray(ref[2]),
-                               atol=0.05)
 
 
-def test_tree_bin_once_fold_plan(monkeypatch):
+def test_tree_bin_once_fold_plan():
     """fold_sweep_plan computes dataset-level codes once; per-fold
     grid_fit_arrays gathers rows from them (same edges, same models as a
-    manual gather), and the env kill-switch disables the plan."""
-    monkeypatch.delenv("TRANSMOGRIFAI_TREE_BIN_ONCE", raising=False)
+    manual gather)."""
     X, y = _xor_data(400)
     w = jnp.ones(X.shape[0], jnp.float32)
     est = OpGBTClassifier(num_rounds=3, max_depth=3)
@@ -582,8 +572,6 @@ def test_tree_bin_once_fold_plan(monkeypatch):
                                     jnp.take(plan[64][1], rows, axis=0), 64))
     np.testing.assert_allclose(np.asarray(m_plan.trees[2]),
                                np.asarray(m_ref.trees[2]), atol=1e-6)
-    monkeypatch.setenv("TRANSMOGRIFAI_TREE_BIN_ONCE", "0")
-    assert est.fold_sweep_plan(X, grid) is None
 
 
 # -- the base score is an argument of the model's programs --------------------

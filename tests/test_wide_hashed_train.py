@@ -105,12 +105,10 @@ def small_train():
     config = _config(224)
     table = data.make_table(config["dataset"], 4000, SEED)
     frame = pipeline.to_frame(table)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TRANSMOGRIFAI_SWEEP_STACKED", "1")
-        profiler.reset(app_name="test")
-        model, handles, summary = kind.train_unit(frame, config["pipeline"])
-        counters = dict(sweep_counters.run_to_json())
-        families = sweep_counters.to_json()
+    profiler.reset(app_name="test")
+    model, handles, summary = kind.train_unit(frame, config["pipeline"])
+    counters = dict(sweep_counters.run_to_json())
+    families = sweep_counters.to_json()
     produced = compare_criteo.collect(
         model, handles, summary, frame, config["pipeline"],
         np.random.default_rng(0))

@@ -103,69 +103,35 @@ def bin_data(X, edges):
 # single-tree growth (one jitted program per (n, d, depth, B) shape)
 # ---------------------------------------------------------------------------
 
-def _hist_mode_for(Xb) -> str:
-    """Static histogram-engine choice for a fit: the sorted MXU path for
-    large TPU fits (round-5 on-chip shootout: ~7x/level at 1M rows) —
-    single-shard directly, mesh-sharded via
-    the explicit shard_map wrapper (``train_ensemble_sharded``) — and
-    the scatter path for small fits and for sharded inputs without a
-    mesh context (whose per-shard scatters GSPMD all-reduces; the sorted
-    path's global-index bookkeeping would generate heavy cross-shard
-    collectives under plain GSPMD). Overridable via
-    TRANSMOGRIFAI_TREE_HIST."""
-    import os
-    forced = os.environ.get("TRANSMOGRIFAI_TREE_HIST")
-    if forced and forced not in ("scatter", "sorted"):
-        raise ValueError(
-            f"TRANSMOGRIFAI_TREE_HIST={forced!r}: expected 'scatter' "
-            "or 'sorted'")
-    if forced == "scatter":
+def _hist_engine(n_rows: int, n_devices: int = 1, *,
+                 stacked: bool = False) -> str:
+    """THE histogram-engine choice, for a fit of ``n_rows`` rows held on
+    ``n_devices`` devices (``stacked``: the unit is the selector's vmapped
+    fold x lane batch): ``"scatter"``, ``"sorted"`` or ``"sorted_sharded"``.
+
+    The sorted MXU engine is for large fits on a TPU (round-5 on-chip
+    shootout: ~7x/level at 1M rows; it trades ~B-times more, MXU-friendly,
+    FLOPs for the serialized scatter, a trade only measured there) — a
+    single-device input directly, a row-sharded one through the explicit
+    shard_map wrapper (``train_ensemble_sharded``), which needs an active
+    mesh and a row count its data axis divides (what
+    ``shard_training_rows`` produces). Everything else keeps the scatter
+    engine: small fits, CPU/GPU, sharded inputs the wrapper cannot take
+    (GSPMD all-reduces the per-shard scatters; the sorted engine's
+    global-index bookkeeping would generate heavy cross-shard collectives
+    under plain GSPMD), and the stacked batch under a mesh, which cannot
+    ride the per-family ``shard_map`` at all."""
+    if n_rows < _SORT_MIN_ROWS or jax.default_backend() != "tpu":
         return "scatter"
-    try:
-        single = len(Xb.devices()) == 1
-    except Exception:  # failure-ok: device probe; default to single-device route
-        single = True
-
-    def sharded_route() -> tuple[str, str]:
-        # multi-device input: the sorted engine needs the explicit
-        # shard_map wrapper, which requires an active mesh and a row
-        # count divisible by the data axis (what shard_training_rows
-        # produces); anything else keeps the GSPMD scatter path, which
-        # accepts replicated/unevenly-sharded inputs. Returns
-        # (route, downgrade reason or "").
-        from transmogrifai_tpu.parallel.mesh import current_mesh
-        ctx = current_mesh()
-        if ctx is None:
-            return "scatter", "multi-device input but no active mesh context"
-        if Xb.shape[0] % ctx.n_data:
-            return "scatter", (
-                f"row count {int(Xb.shape[0])} not divisible by the mesh "
-                f"data axis ({ctx.n_data})")
-        return "sorted_sharded", ""
-
-    if forced == "sorted":
-        if single:
-            return "sorted"
-        route, why = sharded_route()
-        if route == "scatter":
-            # a forced engine that silently downgrades poisons A/B reruns —
-            # the measurement would time the WRONG engine (ADVICE r5). Loud
-            # by default; TRANSMOGRIFAI_TREE_HIST_STRICT=1 makes it fatal.
-            import warnings
-            msg = (f"TRANSMOGRIFAI_TREE_HIST=sorted downgraded to "
-                   f"'scatter': {why}. Shard the rows via "
-                   "shard_training_rows under an active mesh to keep the "
-                   "sorted engine.")
-            if os.environ.get("TRANSMOGRIFAI_TREE_HIST_STRICT") == "1":
-                raise RuntimeError(msg)
-            warnings.warn(msg, RuntimeWarning)
-        return route
-    # auto-select only on TPU: the einsum path trades ~B-times more
-    # (MXU-friendly) FLOPs for the serialized scatter, a trade validated
-    # on-chip; CPU/GPU keep the scatter path unless forced
-    if Xb.shape[0] >= _SORT_MIN_ROWS and jax.default_backend() == "tpu":
-        return "sorted" if single else sharded_route()[0]
-    return "scatter"
+    from transmogrifai_tpu.parallel.mesh import current_mesh
+    ctx = current_mesh()
+    if stacked:
+        return "scatter" if ctx is not None else "sorted"
+    if n_devices == 1:
+        return "sorted"
+    if ctx is None or n_rows % ctx.n_data:
+        return "scatter"
+    return "sorted_sharded"
 
 
 #: histogram node budget per materialized array: [nodes, d, B] f32 x2 (g, h).
@@ -213,49 +179,6 @@ def _long_cumsum(x):
     totals = inner[:, -1]
     offsets = jnp.cumsum(totals) - totals
     return (inner + offsets[:, None]).reshape(-1)[:n]
-
-
-def _sorted_engine_default() -> str:
-    """Histogram contraction engine for the sorted path. The XLA einsum
-    is the measured winner ON CHIP (1M x 28 x 64, host-fenced: einsum
-    440/1300 ms for d6/d12 trees vs 521/1502 ms for the fused Pallas
-    kernel — per-grid-step overhead of 28 small dots x ~4k blocks beats
-    the one-hot HBM traffic it saves), so it is the default everywhere;
-    TRANSMOGRIFAI_SORTED_HIST=pallas opts into the kernel (A/B reruns).
-
-    Consulted ONCE per fit at Python level (fit_arrays) and threaded as
-    a STATIC argument — never read inside a traced function, where the
-    jit cache would silently pin the first value seen."""
-    import os
-    forced = os.environ.get("TRANSMOGRIFAI_SORTED_HIST")
-    if forced:
-        if forced not in ("einsum", "pallas"):
-            raise ValueError(
-                f"TRANSMOGRIFAI_SORTED_HIST={forced!r}: expected 'einsum' "
-                "or 'pallas'")
-        return forced
-    return "einsum"
-
-
-def _sorted_acc_default() -> str:
-    """Accumulation dtype policy for the sorted path's one-hot histogram
-    contraction. ``"auto"`` (default) keeps the measured TPU choice — bf16
-    one-hot with f32 ``preferred_element_type`` accumulation on chip, f32
-    everywhere else; ``TRANSMOGRIFAI_SORTED_ACC=f32`` forces full-f32
-    operands (the escape hatch when bf16 bin-code/stat rounding is
-    suspected in split decisions — A/B rerun knob, ADVICE r5), and
-    ``=bf16`` forces bf16 operands on any backend (lets a CPU test
-    exercise the TPU numerics). Same static-threading discipline as
-    ``_sorted_engine_default``: consulted once per fit at Python level."""
-    import os
-    forced = os.environ.get("TRANSMOGRIFAI_SORTED_ACC")
-    if forced:
-        if forced not in ("auto", "f32", "bf16"):
-            raise ValueError(
-                f"TRANSMOGRIFAI_SORTED_ACC={forced!r}: expected 'auto', "
-                "'f32' or 'bf16'")
-        return forced
-    return "auto"
 
 
 class _SortedLayout(NamedTuple):
@@ -365,19 +288,14 @@ def _unpack_rows(rows, d: int, n_bins: int):
     return codes, gh[:, 0], gh[:, 1]
 
 
-def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype,
-                 engine: str = "einsum"):
+def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype):
     """[N, d, B] grad/hess histograms from the padded block layout.
 
     Per block: a [C, d*B] bin one-hot contracted with the [C, 2] (g, h)
     rows on the MXU; per-node totals come from a block-axis cumsum and
     one boundary diff per node — no scatter anywhere, and the work is
-    proportional to padded rows, not nodes.
-
-    ``engine="pallas"`` runs the fused VMEM kernel
-    (``ops/sorted_hist_pallas.py``): the one-hot never reaches HBM and
-    the block cumsum is accumulated in scratch during the same pass.
-    ``"einsum"`` is the pure-XLA oracle (and the off-TPU default).
+    proportional to padded rows, not nodes. The contraction is XLA's
+    einsum over ``acc_dtype`` operands with float32 accumulation.
     """
     pstarts, pends, pcounts = layout.pstarts, layout.pends, layout.pcounts
     nb = layout.valid.shape[0]
@@ -385,42 +303,28 @@ def _sorted_hist(Xp, gp, hp, layout, *, n_bins: int, C: int, acc_dtype,
     n_pad, d = Xp.shape
     B = n_bins
     Xpb = Xp.reshape(nb, C, d)
-    if engine == "pallas" and B > 256:
-        engine = "einsum"  # kernel's bf16 code broadcast is exact to 256
-    if engine == "pallas":
-        from transmogrifai_tpu.ops.sorted_hist_pallas import (
-            sorted_block_hist,
-        )
-        ghb_k = jnp.stack([gp, hp]).reshape(2, nb, C).transpose(1, 0, 2)
-        part_k = sorted_block_hist(Xpb, ghb_k, n_bins=B
-                                   ).reshape(nb, 2, d, B)
-        bc = jnp.cumsum(part_k, axis=0)
-    else:
-        ghb = jnp.stack([gp, hp], axis=-1).reshape(nb, C, 2).astype(
-            acc_dtype)
-        esize = jnp.dtype(acc_dtype).itemsize  # bf16 on TPU, f32 off it
-        rows_per_chunk = max(C, _SORT_OH_BUDGET // (esize * d * B))
-        cb = max(1, rows_per_chunk // C)
-        n_chunks = -(-nb // cb)
-        if n_chunks * cb != nb:
-            pad = n_chunks * cb - nb
-            Xpb = jnp.concatenate(
-                [Xpb, jnp.zeros((pad, C, d), Xpb.dtype)])
-            ghb = jnp.concatenate(
-                [ghb, jnp.zeros((pad, C, 2), ghb.dtype)])
-        iota_b = jnp.arange(B, dtype=jnp.int32).astype(Xpb.dtype)
+    ghb = jnp.stack([gp, hp], axis=-1).reshape(nb, C, 2).astype(acc_dtype)
+    esize = jnp.dtype(acc_dtype).itemsize  # bf16 on TPU, f32 off it
+    rows_per_chunk = max(C, _SORT_OH_BUDGET // (esize * d * B))
+    cb = max(1, rows_per_chunk // C)
+    n_chunks = -(-nb // cb)
+    if n_chunks * cb != nb:
+        pad = n_chunks * cb - nb
+        Xpb = jnp.concatenate([Xpb, jnp.zeros((pad, C, d), Xpb.dtype)])
+        ghb = jnp.concatenate([ghb, jnp.zeros((pad, C, 2), ghb.dtype)])
+    iota_b = jnp.arange(B, dtype=jnp.int32).astype(Xpb.dtype)
 
-        def chunk_part(args):
-            xc, gc = args
-            oh = (xc[..., None] == iota_b).astype(acc_dtype)
-            return jnp.einsum("bcs,bcdk->bsdk", gc, oh,
-                              preferred_element_type=jnp.float32)
+    def chunk_part(args):
+        xc, gc = args
+        oh = (xc[..., None] == iota_b).astype(acc_dtype)
+        return jnp.einsum("bcs,bcdk->bsdk", gc, oh,
+                          preferred_element_type=jnp.float32)
 
-        part = jax.lax.map(chunk_part,
-                           (Xpb.reshape(n_chunks, cb, C, d),
-                            ghb.reshape(n_chunks, cb, C, 2)))
-        part = part.reshape(n_chunks * cb, 2, d, B)[:nb]
-        bc = jnp.cumsum(part, axis=0)
+    part = jax.lax.map(chunk_part,
+                       (Xpb.reshape(n_chunks, cb, C, d),
+                        ghb.reshape(n_chunks, cb, C, 2)))
+    part = part.reshape(n_chunks * cb, 2, d, B)[:nb]
+    bc = jnp.cumsum(part, axis=0)
     firstb = (pstarts // C).astype(jnp.int32)
     lastb = jnp.clip(pends // C - 1, 0, nb - 1)
     upper = bc[lastb]
@@ -488,10 +392,7 @@ def _segment_sums(vals_sorted, counts):
 
 def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
                       n_bins: int, reg_lambda, gamma, min_child_weight,
-                      block: int = _SORT_BLOCK,
-                      sorted_engine: str = "einsum",
-                      sorted_acc: str = "auto",
-                      data_axis=None):
+                      block: int = _SORT_BLOCK, data_axis=None):
     """Sort-based level-wise histogram tree (single-shard hot path).
 
     Same contract as the scatter-path ``grow_tree`` body: returns
@@ -509,19 +410,12 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
     """
     n, d = Xb.shape
     B = n_bins
-    if sorted_acc == "f32":
-        acc_dtype = jnp.float32
-    elif sorted_acc == "bf16":
-        acc_dtype = jnp.bfloat16
-    else:  # auto: the measured on-chip default
-        acc_dtype = jnp.bfloat16 if jax.default_backend() == "tpu" \
-            else jnp.float32
-    engine = sorted_engine
-    if engine == "pallas" and acc_dtype == jnp.float32 \
-            and jax.default_backend() == "tpu":
-        # the fused kernel's one-hot broadcast is bf16-only; a forced-f32
-        # accumulation must really accumulate in f32, so take the XLA path
-        engine = "einsum"
+    # the contraction's operand dtype, decided here and nowhere else:
+    # bfloat16 one-hot and stats on a TPU (the measured choice; the
+    # accumulation is float32 either way), float32 elsewhere (XLA:CPU has
+    # no bf16 x bf16 -> f32 dot)
+    acc_dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
+                 else jnp.float32)
     split_kw = dict(n_bins=B, reg_lambda=reg_lambda, gamma=gamma,
                     min_child_weight=min_child_weight)
     packed = _pack_rows(Xb, grad, hess, B)
@@ -550,8 +444,7 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
                 hp = hp * vf
             with device_scope("hist"):
                 hist_g, hist_h = _sorted_hist(Xp, gp, hp, layout, n_bins=B,
-                                              C=C, acc_dtype=acc_dtype,
-                                              engine=engine)
+                                              C=C, acc_dtype=acc_dtype)
                 if data_axis is not None:
                     # distributed fit (explicit shard_map): per-shard local
                     # histograms all-reduce once per level — the
@@ -631,13 +524,11 @@ def _best_splits(hist_g, hist_h, feat_mask, *, n_bins, reg_lambda, gamma,
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth", "n_bins",
-                                             "max_hist_nodes",
-                                             "hist", "sorted_engine",
-                                             "sorted_acc", "data_axis"))
+                                             "max_hist_nodes", "hist",
+                                             "data_axis"))
 def grow_tree(Xb, grad, hess, feat_mask, *, max_depth: int, n_bins: int,
               reg_lambda, gamma, min_child_weight,
               max_hist_nodes: int = _MAX_HIST_NODES, hist: str = "scatter",
-              sorted_engine: str = "einsum", sorted_acc: str = "auto",
               data_axis=None):
     """Level-wise histogram tree. Returns (feats, bins, leaf_values,
     feat_gain, row_pred): feats/bins are tuples of per-level [2^level]
@@ -668,8 +559,7 @@ def grow_tree(Xb, grad, hess, feat_mask, *, max_depth: int, n_bins: int,
         return _grow_tree_sorted(
             Xb, grad, hess, feat_mask, max_depth=max_depth, n_bins=n_bins,
             reg_lambda=reg_lambda, gamma=gamma,
-            min_child_weight=min_child_weight, sorted_engine=sorted_engine,
-            sorted_acc=sorted_acc, data_axis=data_axis)
+            min_child_weight=min_child_weight, data_axis=data_axis)
     if hist != "scatter":
         raise ValueError(f"hist={hist!r}: expected 'scatter' or 'sorted'")
     if data_axis is not None:
@@ -789,14 +679,13 @@ def predict_tree(Xb, feats, bins, leaf_values):
 @functools.partial(jax.jit, static_argnames=(
     "n_rounds", "max_depth", "n_bins", "n_out", "loss", "seed",
     "bootstrap", "subsample", "colsample", "max_hist_nodes",
-    "hist", "sorted_engine", "sorted_acc", "data_axis"))
+    "hist", "data_axis"))
 def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
                    n_out: int, loss: str, learning_rate, reg_lambda, gamma,
                    min_child_weight, subsample, colsample, base_score,
                    bootstrap: bool, seed: int,
                    max_hist_nodes: int = _MAX_HIST_NODES,
-                   hist: str = "scatter", sorted_engine: str = "einsum",
-                   sorted_acc: str = "auto", data_axis=None):
+                   hist: str = "scatter", data_axis=None):
     """Train a whole ensemble in one scanned program.
 
     loss: 'logistic' (n_out=1), 'softmax' (n_out=K one-vs-all), 'squared'.
@@ -854,8 +743,6 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
                              reg_lambda=reg_lambda, gamma=gamma,
                              min_child_weight=min_child_weight,
                              max_hist_nodes=max_hist_nodes, hist=hist,
-                             sorted_engine=sorted_engine,
-                             sorted_acc=sorted_acc,
                              data_axis=data_axis)
 
         feats, bins, leaves, gains, preds = jax.vmap(
@@ -909,13 +796,11 @@ def train_ensemble_sharded(ctx, Xb, y, w, **kw):
 
 @functools.partial(jax.jit, static_argnames=(
     "n_rounds", "max_depth", "n_bins", "loss", "subsample",
-    "colsample", "bootstrap", "seed", "hist", "sorted_engine", "sorted_acc",
-    "forest_margin"))
+    "colsample", "bootstrap", "seed", "hist", "forest_margin"))
 def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
                         n_rounds: int, max_depth: int, n_bins: int,
                         loss: str, subsample, colsample,
                         bootstrap: bool, seed: int, hist: str,
-                        sorted_engine: str, sorted_acc: str,
                         forest_margin: bool):
     """ONE compiled program for a whole (family, depth-group) of the CV
     sweep: train all ``k`` folds x ``L`` same-shape grid lanes and score
@@ -946,8 +831,7 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
                 reg_lambda=lam_i, gamma=gam_i, min_child_weight=mcw_i,
                 subsample=subsample, colsample=colsample,
                 base_score=base_k, bootstrap=bootstrap, seed=seed,
-                hist=hist, sorted_engine=sorted_engine,
-                sorted_acc=sorted_acc)
+                hist=hist)
             with device_scope("tree.predict"):
                 out = predict_ensemble(Xva_k, trees, n_out=1,
                                        learning_rate=lr_i,
@@ -1220,7 +1104,11 @@ class _TreePredictor(Predictor):
         n, d = int(Xb.shape[0]), int(Xb.shape[1])
         depth, rounds, B = int(p["max_depth"]), int(p["num_rounds"]), \
             int(p["max_bins"])
-        hist_mode = _hist_mode_for(Xb)
+        try:
+            n_devices = len(Xb.devices())
+        except Exception:  # failure-ok: device probe; default to single-device route
+            n_devices = 1
+        hist_mode = _hist_engine(n, n_devices)
         if hist_mode.startswith("sorted"):
             # per level: padded-row one-hot contraction 4*n*d*B MXU MACs
             # (g+h stats) + layout/partition cumsums ~10n + split eval
@@ -1245,9 +1133,7 @@ class _TreePredictor(Predictor):
             subsample=float(subsample),
             colsample=float(p["colsample"]),
             base_score=jnp.float32(base),
-            bootstrap=self.bootstrap, seed=int(p["seed"]),
-            sorted_engine=_sorted_engine_default(),
-            sorted_acc=_sorted_acc_default())
+            bootstrap=self.bootstrap, seed=int(p["seed"]))
         if hist_mode == "sorted_sharded":
             from transmogrifai_tpu.parallel.mesh import current_mesh
             trees, gains = train_ensemble_sharded(current_mesh(), Xb, y, w,
@@ -1276,12 +1162,7 @@ class _TreePredictor(Predictor):
 
         Documented ``bin_once`` approximation: fold edges come from the
         whole training matrix, the XGBoost global-sketch analog; metrics
-        shift by sub-bin-width amounts. ``TRANSMOGRIFAI_TREE_BIN_ONCE=0``
-        disables the plan and restores exact per-fold quantile edges.
-        Returns None when disabled."""
-        import os
-        if os.environ.get("TRANSMOGRIFAI_TREE_BIN_ONCE", "1") == "0":
-            return None
+        shift by sub-bin-width amounts."""
         merged = [{self._ALIASES.get(k, k): v for k, v in g.items()}
                   for g in grid]
         plan: dict[int, tuple] = {}
@@ -1397,43 +1278,6 @@ class _TreePredictor(Predictor):
             lnb = self._loss_and_nout(y)
         return lnb if lnb[1] == 1 else None
 
-    @staticmethod
-    def _tree_stack_hist_mode(n_rows: int) -> str:
-        """Histogram engine for the stacked program — ``scatter`` or
-        ``sorted``, never ``sorted_sharded``: the vmapped (fold x lane)
-        batch cannot ride the explicit per-family ``shard_map`` wrapper,
-        so under an active mesh the GSPMD scatter path (per-shard
-        scatters + XLA-inserted psum) is the safe engine. Same
-        TRANSMOGRIFAI_TREE_HIST override and loud-downgrade discipline as
-        ``_hist_mode_for``; ``n_rows`` is one fold's training rows."""
-        import os
-        import warnings
-        forced = os.environ.get("TRANSMOGRIFAI_TREE_HIST")
-        if forced and forced not in ("scatter", "sorted"):
-            raise ValueError(
-                f"TRANSMOGRIFAI_TREE_HIST={forced!r}: expected 'scatter' "
-                "or 'sorted'")
-        from transmogrifai_tpu.parallel.mesh import current_mesh
-        meshed = current_mesh() is not None
-        if forced == "scatter":
-            return "scatter"
-        if forced == "sorted":
-            if not meshed:
-                return "sorted"
-            msg = ("TRANSMOGRIFAI_TREE_HIST=sorted downgraded to 'scatter' "
-                   "for the fold x grid-stacked tree sweep: the stacked "
-                   "batch runs under GSPMD, where the sorted engine's "
-                   "global-index bookkeeping would generate heavy "
-                   "cross-shard collectives")
-            if os.environ.get("TRANSMOGRIFAI_TREE_HIST_STRICT") == "1":
-                raise RuntimeError(msg)
-            warnings.warn(msg, RuntimeWarning)
-            return "scatter"
-        if (not meshed and n_rows >= _SORT_MIN_ROWS
-                and jax.default_backend() == "tpu"):
-            return "sorted"
-        return "scatter"
-
     def tree_stack_bytes(self, k: int, n_tr: int, n_va: int, d: int,
                          group: dict) -> tuple[float, float]:
         """``(shared_bytes, per_lane_bytes)`` HBM estimate for one stacked
@@ -1453,7 +1297,7 @@ class _TreePredictor(Predictor):
                              + 8.0 * n_tr + 4.0 * n_va)
         nodes = min(2 ** max(depth - 1, 0), _MAX_HIST_NODES)
         hist_bytes = 16.0 * nodes * d * B  # (g, h) x (level, prev) f32
-        if self._tree_stack_hist_mode(n_tr) == "sorted":
+        if _hist_engine(n_tr, stacked=True) == "sorted":
             hist_bytes += min(float(_SORT_OH_BUDGET), 4.0 * n_tr * d * B)
         per_lane = float(k) * (28.0 * n_tr + hist_bytes + 8.0 * n_va)
         return shared, per_lane
@@ -1509,7 +1353,7 @@ class _TreePredictor(Predictor):
                            jnp.float32)
         depth, rounds, B = (int(p0["max_depth"]), int(p0["num_rounds"]),
                             int(p0["max_bins"]))
-        hist_mode = self._tree_stack_hist_mode(n_tr)
+        hist_mode = _hist_engine(n_tr, stacked=True)
         from transmogrifai_tpu.utils import flops
         if hist_mode == "sorted":
             per_tree = sum(4.0 * n_tr * d * B + 10.0 * n_tr
@@ -1526,8 +1370,6 @@ class _TreePredictor(Predictor):
             subsample=1.0 if self.bootstrap else float(p0["subsample"]),
             colsample=float(p0["colsample"]), bootstrap=self.bootstrap,
             seed=int(p0["seed"]), hist=hist_mode,
-            sorted_engine=_sorted_engine_default(),
-            sorted_acc=_sorted_acc_default(),
             forest_margin=self.bootstrap and self.kind.endswith("classifier"))
 
     # -- winner refit (round 9) ----------------------------------------------

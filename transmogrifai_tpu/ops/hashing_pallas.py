@@ -21,8 +21,7 @@ The hashing-trick vectorizers split into two halves:
 
 Engine selection: ``TRANSMOGRIFAI_HASH_ENGINE`` = ``auto`` (pallas on TPU
 backends) | ``pallas`` | ``xla``. The kernel is stateless per grid step —
-``vmap`` batching stays legal (same discipline as
-``ops/sorted_hist_pallas.py``).
+``vmap`` batching stays legal.
 """
 
 from __future__ import annotations

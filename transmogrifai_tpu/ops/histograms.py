@@ -27,10 +27,8 @@ History: an earlier Pallas compare+matmul kernel lived beside this
 (``ops/histogram_pallas.py``, rounds 1-4) for levels with <= 8 nodes.
 Its justifying on-chip numbers turned out to be enqueue-time artifacts
 (unfenced walls); re-measured with fences its niche
-(sub-ms shallow levels of the small-fit path) was irrelevant, and the
-sorted-path kernel (``ops/sorted_hist_pallas.py``) supersedes it as the
-measured Pallas variant. Deleted in round 5: benchmark-or-delete,
-resolved by deletion with data.
+(sub-ms shallow levels of the small-fit path) was irrelevant. Deleted in
+round 5: benchmark-or-delete, resolved by deletion with data.
 """
 
 from __future__ import annotations

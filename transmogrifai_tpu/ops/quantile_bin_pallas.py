@@ -15,8 +15,7 @@ the lane axis, the split vector (tiny, <= a few hundred f32) in SMEM, bin
 index by comparison count and the one-hot written as a single iota-compare
 — no intermediate index array ever reaches HBM.
 
-Engine selection mirrors the sorted-histogram kernel
-(``ops/sorted_hist_pallas.py``): ``TRANSMOGRIFAI_BUCKET_ENGINE`` picks
+Engine selection: ``TRANSMOGRIFAI_BUCKET_ENGINE`` picks
 ``pallas`` / ``xla`` / ``auto`` (auto = pallas on TPU backends, xla
 elsewhere); CPU CI runs the kernel in interpret mode and asserts BITWISE
 parity with the XLA path (`tests/test_ingest_fusion.py`). The kernel is

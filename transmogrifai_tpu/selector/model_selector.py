@@ -70,6 +70,10 @@ def _take_rows(X, y, idx):
     return Xs, ys
 
 
+#: reason prefix of a family skipped for ``max_wait_s``
+_MAX_WAIT_SKIP = "skipped: sweep exceeded max_wait_s="
+
+
 def _is_ready(a) -> bool:
     """Whether device future ``a`` has finished; a value that cannot say
     (no ``is_ready``, or one that raises: a poisoned program) counts as
@@ -448,67 +452,6 @@ class ModelSelector(Estimator):
         return f"{type(self.models_and_grids[ci][0]).__name__}_{ci}"
 
     @staticmethod
-    def _stacking_default(env_var: str) -> bool:
-        """Shared gating policy for both stacked fast paths: the env var
-        forces either way (A/B reruns, parity checks); otherwise ON where
-        the win lives — accelerator backends and active meshes (the
-        saving is k-or-k x L fewer dispatches + host syncs; their cost
-        is not measured on the attached chip) — and OFF on plain
-        single-device CPU, where the microbenches measure the batched
-        programs at ~0.9x the per-fold loop (the CPU default only flips
-        if an artifact measures >= 1.0x)."""
-        import os
-        env = os.environ.get(env_var)
-        if env is not None:
-            return env != "0"
-        from transmogrifai_tpu.parallel import mesh as pmesh
-        if pmesh.current_mesh() is not None:
-            return True
-        import jax
-        return jax.default_backend() != "cpu"
-
-    @classmethod
-    def _stacked_enabled(cls) -> bool:
-        """Linear fold-stacked gating (benchmarks/FOLD_STACKED_SWEEP.json
-        measures CPU at ~0.9x -> default OFF there)."""
-        return cls._stacking_default("TRANSMOGRIFAI_SWEEP_STACKED")
-
-    @classmethod
-    def _tree_stacked_enabled(cls) -> bool:
-        """Tree fold x grid-stacked gating
-        (benchmarks/TREE_STACKED_SWEEP.json measures CPU at 0.93x ->
-        default OFF there; a tree depth-group on the fast path costs one
-        dispatch + ONE host sync instead of k x L of each)."""
-        return cls._stacking_default("TRANSMOGRIFAI_TREE_STACKED")
-
-    @staticmethod
-    def _async_enabled() -> bool:
-        """One-sync overlapped dispatch gating (round 9): default ON.
-        With it, every stacked family's/depth-group's metric batch is
-        held as a DEVICE FUTURE at dispatch and the whole sweep settles
-        behind a single ``jax.block_until_ready`` — families overlap on
-        device instead of serializing on per-family metric pulls, and
-        the entire sweep costs ONE blocking host sync.
-        ``TRANSMOGRIFAI_SWEEP_ASYNC=0`` restores the per-family settle
-        (A/B reruns, and the behavior every fallback path keeps). Only
-        meaningful where a stacked path runs at all (the per-fold loop
-        is inherently synchronous)."""
-        import os
-        return os.environ.get("TRANSMOGRIFAI_SWEEP_ASYNC", "1") != "0"
-
-    @staticmethod
-    def _refit_warm_enabled() -> bool:
-        """Warm winner-refit gating (round 9): default ON. The selector
-        then retains warm-capable families' stacked fold parameters past
-        the sweep and the winner refit initializes from them (metrics
-        within the artifact-gated 1e-5 of the cold refit; trees reuse
-        bin codes bitwise regardless of this knob).
-        ``TRANSMOGRIFAI_REFIT_WARM=0`` forces every refit cold —
-        bitwise-identical to the pre-round-9 serial refit."""
-        import os
-        return os.environ.get("TRANSMOGRIFAI_REFIT_WARM", "1") != "0"
-
-    @staticmethod
     def _stacked_hbm_budget() -> float:
         """Byte budget for one family's stacked fold batch.
         ``TRANSMOGRIFAI_SWEEP_HBM_BUDGET`` overrides; otherwise half the
@@ -559,9 +502,9 @@ class ModelSelector(Estimator):
         (retained warm-start parameters + tree bin plans) for
         ``_finalize``.
 
-        Execution model (PERF.md "Sweep execution", round 9): the sweep
-        is TWO phases. The DISPATCH phase walks the families and launches
-        every stacked program — linear/NB/GLM/MLP fold-stacks
+        Execution model (docs/SWEEP.md), the same on every backend: the
+        sweep is TWO phases. The DISPATCH phase walks the families and
+        launches every stacked program — linear/NB/GLM/MLP fold-stacks
         (``Predictor.sweep_folds`` over a ``FoldBatch``) and tree depth-groups
         (``_family_tree_stacked``) alike — handing each family's ``[k, G]``
         metric batch back as a DEVICE FUTURE; no family blocks the host,
@@ -573,11 +516,16 @@ class ModelSelector(Estimator):
         count, tree base-score stats) are pulled up front so no family
         pays a blocking scalar sync at dispatch.
 
-        ``TRANSMOGRIFAI_SWEEP_ASYNC=0``, a custom evaluator without the
-        device metric variant, and every fallback route (per-fold loop,
-        HBM-guard refusal under ``TRANSMOGRIFAI_SWEEP_STACKED`` gating)
-        keep the pre-round-9 per-family settle. Work units shard 2-D
-        over the mesh (rows on "data", fold/grid candidates on "model").
+        A family leaves that path for the per-fold loop
+        (``_family_fold_loop``) only for a reason the program observes: it
+        has no stacked form (``supports_fold_stacking`` /
+        ``supports_tree_stacking``, ``_FoldStackFallback``, multiclass
+        trees), the evaluator has no device fold metric
+        (``metric_batch_scores_folds_device``), its unit does not fit the
+        budget (``fold_stack_bytes`` / ``tree_stack_bytes`` against
+        ``_stacked_hbm_budget``), or the OOM ladder sent it there. Work
+        units shard 2-D over the mesh (rows on "data", fold/grid
+        candidates on "model").
 
         Semantics preserved exactly from the per-fold loop: failure
         isolation per family (dispatch-time errors isolate immediately;
@@ -601,11 +549,10 @@ class ModelSelector(Estimator):
             return results, mean_metrics, failures, refit_state
         k, n_tr = tr_idx.shape
         n_va = int(va_idx.shape[1])
-        ev0 = self.evaluators[0]
-        fold_metrics = getattr(ev0, "metric_batch_scores_folds", None)
-        fold_metrics_dev = getattr(ev0, "metric_batch_scores_folds_device",
-                                   None)
-        async_on = self._async_enabled() and fold_metrics_dev is not None
+        # the [k, G] fold metric as a device program: without it nothing
+        # can be held as a future, and every family takes the loop
+        fold_metrics_dev = getattr(self.evaluators[0],
+                                   "metric_batch_scores_folds_device", None)
         per_candidate_scores: dict[tuple[int, int], list[float]] = {}
         failures: list[dict] = []
         pending: list[dict] = []  # device futures awaiting the one settle
@@ -619,14 +566,12 @@ class ModelSelector(Estimator):
             self._dispatch(
                 Xt, yt, wt, tr_idx, va_idx, k, n_tr, n_va, d, n_tr_pad,
                 done, deadline, per_candidate_scores, failures, pending,
-                refit_state, async_on, fold_metrics, fold_metrics_dev,
-                tree_cache)
+                refit_state, fold_metrics_dev, tree_cache)
         except BaseException:
             # mid-sweep crash (KeyboardInterrupt, preemption, ...): settle
             # whatever was already dispatched so completed families reach
-            # the checkpoint before the crash propagates — the same
-            # crash granularity the per-family settle always had (a real
-            # SIGKILL can't salvage; it just re-runs those families)
+            # the checkpoint before the crash propagates (a real SIGKILL
+            # can't salvage; it just re-runs those families)
             if pending:
                 try:
                     self._settle(pending, done, per_candidate_scores,
@@ -658,18 +603,33 @@ class ModelSelector(Estimator):
                         ci, est, grid, Xt, yt, wt, tr_idx, va_idx, done,
                         deadline, per_candidate_scores, failures,
                         refit_state=refit_state)
+            # a winner must survive: ``_deadline_skip`` let the later
+            # families go because an earlier one had futures pending. If
+            # every one of those failed at the settle, the skipped
+            # families run after all, in order, until one scores (on the
+            # loop a failed family leaves no scores and the next one runs)
+            for ci, (est, grid) in enumerate(self.models_and_grids):
+                skips = [f for f in failures
+                         if f["modelName"] == self._family_name(ci)
+                         and f["reason"].startswith(_MAX_WAIT_SKIP)]
+                if per_candidate_scores or not skips:
+                    continue
+                failures.remove(skips[0])
+                self._family_fold_loop(
+                    ci, est, grid, Xt, yt, wt, tr_idx, va_idx, done, None,
+                    per_candidate_scores, failures, refit_state=refit_state)
         results, mean_metrics, failures = self._collect_results(
             per_candidate_scores, failures)
         return results, mean_metrics, failures, refit_state
 
     def _dispatch(self, Xt, yt, wt, tr_idx, va_idx, k, n_tr, n_va, d,
                   n_tr_pad, done, deadline, per_candidate_scores, failures,
-                  pending, refit_state, async_on, fold_metrics,
-                  fold_metrics_dev, tree_cache) -> None:
+                  pending, refit_state, fold_metrics_dev,
+                  tree_cache) -> None:
         """The sweep's dispatch phase (see ``_sweep``): walk the families,
         replay checkpointed ones, launch every stacked program, and queue
-        device metric futures on ``pending``; per-family-settle and loop
-        fallbacks record their values inline."""
+        device metric futures on ``pending``; a family that falls to the
+        loop records its values inline."""
         from transmogrifai_tpu.models.base import (
             FoldBatch, supports_fold_stacking, supports_tree_stacking,
         )
@@ -679,8 +639,7 @@ class ModelSelector(Estimator):
         from transmogrifai_tpu.utils.tracing import span
         batch = None  # built on the first stacked-capable family
         tree_stats = None
-        with span("sweep.dispatch", families=len(self.models_and_grids),
-                  mode="async" if async_on else "per_family"):
+        with span("sweep.dispatch", families=len(self.models_and_grids)):
             for ci, (est, grid) in enumerate(self.models_and_grids):
                 fname = self._family_name(ci)
                 skey = f"{ci}:stacked:{k}x{n_tr}x{d}"
@@ -701,8 +660,8 @@ class ModelSelector(Estimator):
                                                       per_candidate_scores):
                     # restart path: every depth-group of this tree family
                     # already scored under per-group treestack keys —
-                    # replays regardless of the current gating, so a
-                    # stacked-written checkpoint resumes under the loop
+                    # replays whatever route the family would take now, so
+                    # a stacked-written checkpoint resumes under the loop
                     # layout too
                     sweep_counters.count(fname, mode="resumed")
                     continue
@@ -719,10 +678,9 @@ class ModelSelector(Estimator):
                     continue
                 if self._deadline_skip(ci, grid, deadline,
                                        per_candidate_scores, failures,
-                                       pop=False):
+                                       pending, pop=False):
                     continue
-                use_stacked = (self._stacked_enabled()
-                               and fold_metrics is not None
+                use_stacked = (fold_metrics_dev is not None
                                and supports_fold_stacking(est))
                 if use_stacked and batch is None:
                     # the fold plan over the ONE resident training matrix:
@@ -750,20 +708,16 @@ class ModelSelector(Estimator):
                             # materialization — the sweep discards models;
                             # the winner refits), retaining the stacked
                             # parameters as the refit's warm-start handle
-                            retain = (self._refit_warm_enabled()
-                                      and est.supports_warm_refit())
                             scores, warm = with_device_retry(
                                 est.sweep_folds, batch, grid,
                                 _n_classes=n_classes_hint, site="sweep.fit")
                             if scores is None:
                                 raise _FoldStackFallback()
-                            if retain and warm is not None:
+                            if warm is not None and est.supports_warm_refit():
                                 refit_state["warm"][ci] = warm
                             # the family's [k, G] metric batch: a device
-                            # FUTURE on the async path (settled once for
-                            # the whole sweep), a host pull otherwise
-                            vals_kg = (fold_metrics_dev if async_on
-                                       else fold_metrics)(
+                            # FUTURE, settled once for the whole sweep
+                            vals_kg = fold_metrics_dev(
                                 yva_s, scores, self.validation_metric)
                     except _FoldStackFallback:
                         use_stacked = False  # no stacked axis: fold loop
@@ -799,32 +753,16 @@ class ModelSelector(Estimator):
                     else:
                         sweep_counters.count(fname, dispatches=1,
                                              mode="fold_stacked")
-                        if async_on:
-                            pending.append({
-                                "kind": "stacked", "ci": ci, "fname": fname,
-                                "key": skey, "k": k, "grid_len": len(grid),
-                                "chunks": [(0, len(grid), vals_kg)],
-                                "launched": [(time.time(), {
-                                    "family": fname, "unitKind": "stacked",
-                                    "lanes": len(grid), "chunk": 0})]})
-                            sweep_counters.count_run(async_families=1)
-                            continue
-                        # per-family settle (TRANSMOGRIFAI_SWEEP_ASYNC=0 or
-                        # a host-only evaluator): the pre-round-9 behavior
-                        flat = [float(v)
-                                for v in np.asarray(vals_kg).reshape(-1)]
-                        for f in range(k):
-                            for gj in range(len(grid)):
-                                per_candidate_scores.setdefault(
-                                    (ci, gj), []).append(
-                                    flat[f * len(grid) + gj])
-                        sweep_counters.count(fname, host_syncs=1)
-                        sweep_counters.count_run(host_syncs=1)
-                        done[skey] = flat
-                        self._ckpt_save(done)
+                        pending.append({
+                            "kind": "stacked", "ci": ci, "fname": fname,
+                            "key": skey, "k": k, "grid_len": len(grid),
+                            "chunks": [(0, len(grid), vals_kg)],
+                            "launched": [(time.time(), {
+                                "family": fname, "unitKind": "stacked",
+                                "lanes": len(grid), "chunk": 0})]})
+                        sweep_counters.count_run(async_families=1)
                         continue
-                if (tgroups and self._tree_stacked_enabled()
-                        and fold_metrics is not None):
+                if tgroups and fold_metrics_dev is not None:
                     if tree_stats is None:
                         # the tree families' (max, mean, clipped-mean)
                         # label pull, once per sweep — each value produced
@@ -846,20 +784,19 @@ class ModelSelector(Estimator):
                         handled = self._family_tree_stacked(
                             ci, est, grid, tgroups, Xt, yt, wt, tr_idx,
                             va_idx, done, deadline, per_candidate_scores,
-                            failures, tree_cache, async_on=async_on,
-                            pending=pending, tree_stats=tree_stats,
-                            refit_state=refit_state)
+                            failures, tree_cache, pending, fold_metrics_dev,
+                            tree_stats=tree_stats, refit_state=refit_state)
                     if handled:
                         continue
                 # ---- per-fold fallback loop for this family ----------------
                 self._family_fold_loop(
                     ci, est, grid, Xt, yt, wt, tr_idx, va_idx, done,
                     deadline, per_candidate_scores, failures,
-                    refit_state=refit_state)
+                    refit_state=refit_state, pending=pending)
 
     def _settle(self, pending, done, per_candidate_scores,
                 failures, oom_retry: Optional[list] = None) -> None:
-        """The ONE settle of the async sweep: block until every dispatched
+        """The ONE settle of the sweep: block until every dispatched
         family's metric futures are ready — a single
         ``jax.block_until_ready`` over the whole sweep, counted as ONE
         run-level host sync — then materialize, record, and checkpoint
@@ -1015,24 +952,23 @@ class ModelSelector(Estimator):
     def _family_tree_stacked(self, ci, est, grid, tgroups, Xt, yt, wt,
                              tr_idx, va_idx, done, deadline,
                              per_candidate_scores, failures,
-                             cache: dict, *, async_on: bool = False,
-                             pending: Optional[list] = None,
-                             tree_stats=None,
+                             cache: dict, pending: list, fold_metrics_dev,
+                             *, tree_stats=None,
                              refit_state: Optional[dict] = None) -> bool:
         """One tree family's fold x grid-stacked sweep: every depth-group
         (grid lanes sharing one compiled-program shape) trains all
         k folds x L lanes as ONE compiled program over the stacked gather
         of the dataset-level bin codes (``fold_sweep_plan`` — no
-        re-binning), scores its validation folds batched, and pulls the
-        whole group's ``[k, L]`` metric block with ONE host sync. The HBM
-        guard (``tree_stack_bytes``) splits a too-wide group into lane
-        chunks (one dispatch + one sync each) instead of falling all the
-        way back. Returns True when the family was fully handled (scored,
-        group-resumed, failed-and-isolated, or deadline-skipped); False
-        routes it to the per-fold loop untouched (multiclass, bin-once
-        disabled, or a group where not even one lane fits the budget —
-        sub-grid loop units can't be expressed, so the loop keeps the
-        whole family)."""
+        re-binning), scores its validation folds batched, and queues the
+        group's ``[k, L]`` metric block on ``pending`` as device futures
+        for the sweep's one settle. The HBM guard (``tree_stack_bytes``)
+        splits a too-wide group into lane chunks (one dispatch each)
+        instead of falling all the way back. Returns True when the family
+        was fully handled (dispatched, group-resumed, failed-and-isolated,
+        or deadline-skipped); False routes it to the per-fold loop
+        untouched (multiclass, or a group where not even one lane fits
+        the budget — sub-grid loop units can't be expressed, so the loop
+        keeps the whole family)."""
         import inspect
         from transmogrifai_tpu.parallel import mesh as pmesh
         from transmogrifai_tpu.utils.profiling import sweep_counters
@@ -1061,9 +997,6 @@ class ModelSelector(Estimator):
             if max_lanes < 1:
                 return False  # not even one lane fits: loop (peak 1/k)
             chunk_sizes.append(max_lanes)
-        import os
-        if os.environ.get("TRANSMOGRIFAI_TREE_BIN_ONCE", "1") == "0":
-            return False  # exact per-fold edges requested: nothing stacks
         jtr = jnp.asarray(tr_idx)
         jva = jnp.asarray(va_idx)
         if "yva" not in cache:
@@ -1076,8 +1009,6 @@ class ModelSelector(Estimator):
             # plan and its stacked gathers are shared across tree families
             # — only missing max_bins pay the quantile sort + searchsorted
             plan = est.fold_sweep_plan(Xt, grid)
-            if plan is None:
-                return False
             if refit_state is not None:
                 # retained for the winner refit: the SAME codes fit_arrays
                 # would recompute from the identical full matrix, so the
@@ -1098,8 +1029,6 @@ class ModelSelector(Estimator):
                 jnp.take(yt, jtr, axis=0),
                 jnp.take(wt, jtr, axis=0))
                 + (jnp.take(codes, jva, axis=0),))
-        ev0 = self.evaluators[0]
-        fold_metrics = ev0.metric_batch_scores_folds
         for gi, g in enumerate(tgroups):
             lanes = g["lanes"]
             L = len(lanes)
@@ -1112,7 +1041,7 @@ class ModelSelector(Estimator):
                 continue
             if self._deadline_skip(ci, grid, deadline,
                                    per_candidate_scores, failures,
-                                   pop=True):
+                                   pending, pop=True):
                 return True
             Xb_tr, ytr_s, wtr_s, Xb_va = cache[g["max_bins"]]
             if "fold_means" not in cache:
@@ -1123,19 +1052,10 @@ class ModelSelector(Estimator):
                 # the loop path's per-fold lnb sync
                 cache["fold_means"] = np.asarray(jnp.stack(
                     [jnp.mean(ytr_s[f]) for f in range(k)]))
-                if pending:
-                    _stamp_device(pending)
-            cs = chunk_sizes[gi]
-            ev0_f = self.evaluators[0]
-            fold_metrics_dev = getattr(ev0_f,
-                                       "metric_batch_scores_folds_device",
-                                       None)
-            use_async = (async_on and pending is not None
-                         and fold_metrics_dev is not None)
-            vals_kl = np.empty((k, L), np.float64)
-            chunks: list[tuple[int, int, Any]] = []  # async device futures
+                _stamp_device(pending)
+            chunks: list[tuple[int, int, Any]] = []  # device futures
             launched: list[tuple[float, dict]] = []  # their dispatch ends
-            cs_cur = cs  # degradation ladder may narrow it mid-group
+            cs_cur = chunk_sizes[gi]  # the OOM ladder may narrow it
             from transmogrifai_tpu.utils.devicewatch import (
                 compile_telemetry,
             )
@@ -1161,11 +1081,9 @@ class ModelSelector(Estimator):
                                     fold_means=cache["fold_means"],
                                     site="sweep.fit")
                                 # the chunk's [k, Lc] metric batch: a
-                                # device FUTURE on the async path
-                                # (settled once for the whole sweep),
-                                # one host pull otherwise
-                                vals = (fold_metrics_dev if use_async
-                                        else fold_metrics)(
+                                # device FUTURE, settled once for the
+                                # whole sweep
+                                vals = fold_metrics_dev(
                                     yva_s, scores, self.validation_metric)
                         except Exception as oom_e:  # noqa: BLE001 — re-raised unless an OOM rung applies
                             from transmogrifai_tpu.utils.faults import (
@@ -1190,17 +1108,11 @@ class ModelSelector(Estimator):
                                 depth=int(depth), folds=int(k),
                                 lanes=len(chunk))
                             continue
-                        if use_async:
-                            chunks.append((c0, len(chunk), vals))
-                            launched.append((time.time(), {
-                                "family": fname, "unitKind": "tree",
-                                "depth": int(depth), "lanes": len(chunk),
-                                "chunk": len(launched), "group": gi}))
-                        else:
-                            vals_kl[:, c0:c0 + len(chunk)] = \
-                                np.asarray(vals)
-                            sweep_counters.count(fname, host_syncs=1)
-                            sweep_counters.count_run(host_syncs=1)
+                        chunks.append((c0, len(chunk), vals))
+                        launched.append((time.time(), {
+                            "family": fname, "unitKind": "tree",
+                            "depth": int(depth), "lanes": len(chunk),
+                            "chunk": len(launched), "group": gi}))
                         sweep_counters.count(
                             fname, dispatches=1, lane_chunks=1,
                             mode="tree_stacked")
@@ -1215,58 +1127,54 @@ class ModelSelector(Estimator):
                 if self._oom_ladder(e):
                     # bottom of the stacked rungs: even one lane at a
                     # time OOMs — the whole family falls to the per-fold
-                    # loop (peak 1/k). Drop any pending async futures of
-                    # this family so the settle can't double-record it.
+                    # loop (peak 1/k). Drop the family's pending futures
+                    # so the settle can't double-record it.
                     self._degrade("sweep.tree_group", "fold_loop",
                                   error=e, family=fname, group=gi,
                                   depth=int(depth))
-                    if pending is not None:
-                        pending[:] = [p for p in pending
-                                      if p["ci"] != ci]
+                    pending[:] = [p for p in pending if p["ci"] != ci]
                     return False
                 failures.append({
                     "modelName": fname,
                     "reason": f"tree stacked sweep (group {gi}): "
                               f"{type(e).__name__}: {str(e)[:300]}"})
                 return True
-            if use_async:
-                first_entry = not any(p["ci"] == ci for p in pending)
-                pending.append({"kind": "tree", "ci": ci, "fname": fname,
-                                "key": tk, "k": k, "lanes": lanes,
-                                "chunks": chunks, "launched": launched})
-                if first_entry:
-                    sweep_counters.count_run(async_families=1)
-                continue
-            flat = [float(v) for v in vals_kl.reshape(-1)]
-            self._record_treestack(per_candidate_scores, ci, lanes, k,
-                                   flat)
-            done[tk] = flat
-            self._ckpt_save(done)
+            if not any(p["ci"] == ci for p in pending):
+                sweep_counters.count_run(async_families=1)
+            pending.append({"kind": "tree", "ci": ci, "fname": fname,
+                            "key": tk, "k": k, "lanes": lanes,
+                            "chunks": chunks, "launched": launched})
         return True
 
     def _deadline_skip(self, ci, grid, deadline, per_candidate_scores,
-                       failures, pop: bool) -> bool:
+                       failures, pending=(), *, pop: bool) -> bool:
         """True when the family must be skipped for exceeding the
         ``max_wait_s`` budget (reference maxWait) — never when it is the
-        only family with any chance of scoring (a winner must survive).
-        ``pop`` drops partial fold scores (a partial-fold mean must not
-        compete against full-fold means)."""
+        only family with any chance of scoring (a winner must survive):
+        another family has a chance when it has recorded scores or
+        dispatched programs whose metric futures wait on ``pending`` (if
+        those all fail at the settle, ``_sweep`` runs the skipped families
+        after all). ``pop`` drops the family's partial scores and its own pending
+        futures (a partial-fold mean must not compete against full-fold
+        means)."""
         if deadline is None or time.time() <= deadline:
             return False
-        if not any(kk[0] != ci for kk in per_candidate_scores):
+        if not (any(kk[0] != ci for kk in per_candidate_scores)
+                or any(p["ci"] != ci for p in pending)):
             return False
         if pop:
             for gj in range(len(grid)):
                 per_candidate_scores.pop((ci, gj), None)
+            if pending:
+                pending[:] = [p for p in pending if p["ci"] != ci]
         failures.append({
             "modelName": self._family_name(ci),
-            "reason": f"skipped: sweep exceeded max_wait_s="
-                      f"{self.max_wait_s}"})
+            "reason": f"{_MAX_WAIT_SKIP}{self.max_wait_s}"})
         return True
 
     def _run_fold_unit(self, ci, est, grid, fold_i, Xtr, ytr, wtr, Xva, yva,
                        done, deadline, per_candidate_scores, failures,
-                       fit_kwargs=None) -> bool:
+                       fit_kwargs=None, pending=()) -> bool:
         """One (fold, family) train+score+record unit — the shared body of
         the stacked sweep's fallback loop and the legacy fold-major loop:
         checkpoint replay, the mid-family ``max_wait_s`` check (after
@@ -1288,7 +1196,7 @@ class ModelSelector(Estimator):
                     float(val))
             return True
         if self._deadline_skip(ci, grid, deadline, per_candidate_scores,
-                               failures, pop=True):
+                               failures, pending, pop=True):
             return False
         from transmogrifai_tpu.utils.tracing import span
         try:
@@ -1345,7 +1253,7 @@ class ModelSelector(Estimator):
 
     def _family_fold_loop(self, ci, est, grid, Xt, yt, wt, tr_idx, va_idx,
                           done, deadline, per_candidate_scores,
-                          failures, refit_state=None) -> None:
+                          failures, refit_state=None, pending=()) -> None:
         """One family's sequential per-fold sweep (the fallback path and
         the home of families without a fold axis — tree ensembles, custom
         subclasses). Tree families still avoid re-binning every fold: a
@@ -1360,7 +1268,7 @@ class ModelSelector(Estimator):
                 and "_fold_plan" in inspect.signature(
                     est.grid_fit_arrays).parameters):
             plan = plan_fn(Xt, grid)
-            if plan is not None and refit_state is not None:
+            if refit_state is not None:
                 refit_state["bin_plans"].update(plan)
         for fold_i in range(tr_idx.shape[0]):
             jtr = jnp.asarray(tr_idx[fold_i])
@@ -1375,7 +1283,7 @@ class ModelSelector(Estimator):
             if not self._run_fold_unit(
                     ci, est, grid, fold_i, Xtr, ytr, wtr, Xt[jva], yt[jva],
                     done, deadline, per_candidate_scores, failures,
-                    fit_kwargs=fit_kwargs):
+                    fit_kwargs=fit_kwargs, pending=pending):
                 return
 
     def _fold_arrays_iter(self, Xt, yt, wt, yt_np):
@@ -1543,8 +1451,7 @@ class ModelSelector(Estimator):
             fault_point("selector.refit")
             return restored
         Xs, ys, ws = pmesh.shard_training_rows(Xt, yt, wt)
-        warm = (refit_state.get("warm", {}).get(best_ci)
-                if self._refit_warm_enabled() else None)
+        warm = refit_state.get("warm", {}).get(best_ci)
         hints = {}
         bin_plans = refit_state.get("bin_plans")
         if bin_plans and int(Xs.shape[0]) == n:
