@@ -220,6 +220,10 @@ def _run_higgs(leg: Leg, rows: int, ckpt_dir: str, on_tpu: bool) -> dict:
               "back to the per-fold loop)")
     leg.check(res["sweep_run_counters"]["sweepHostSyncs"] == 1,
               "sweepHostSyncs == 1 (one settle for the whole sweep)")
+    if on_tpu:
+        leg.check(res["sweep_run_counters"]["treeGatherWalks"] == 0,
+                  "treeGatherWalks == 0 (28 columns, depth 12: every tree "
+                  "walk compares against whole tables)")
     if on_tpu and current_mesh() is None and n_tr >= _SORT_MIN_ROWS:
         leg.check(hist == "sorted",
                   "trees took the sorted engine with bf16 operands "

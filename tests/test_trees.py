@@ -389,24 +389,29 @@ def test_block_rows_reads_unaligned_runs(C):
     np.testing.assert_array_equal(got.reshape(-1, C), want)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
 def _count_long_moves(jaxpr, n):
     """(gathers, scatters) among a jaxpr's equations, nested ones
     included, that move ``n`` slots or more one by one: a gather whose
     output, or a scatter whose updates, has a leading dimension >= n."""
     g = s = 0
-    for eqn in jaxpr.eqns:
+    for eqn in _eqns(jaxpr):
         name = eqn.primitive.name
         if name == "gather" and eqn.outvars[0].aval.shape[:1] >= (n,):
             g += 1
         elif name.startswith("scatter") \
                 and eqn.invars[2].aval.shape[:1] >= (n,):
             s += 1
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
-                if hasattr(sub, "eqns"):
-                    dg, ds = _count_long_moves(sub, n)
-                    g, s = g + dg, s + ds
     return g, s
 
 
@@ -679,3 +684,217 @@ def test_manifest_keeps_base_score_and_old_manifests_load():
                                   np.asarray(b.raw_prediction))
     np.testing.assert_array_equal(np.asarray(a.probability),
                                   np.asarray(b.probability))
+
+
+# -- the scoring path's lookups compare against whole tables (on a TPU) -------
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Trace as on a TPU: ``models/trees.py`` asks ``jax.default_backend()``
+    which form a lookup takes, and XLA:CPU runs either."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _fresh(fn):
+    """``fn`` under a new identity: jax keeps traces by function and
+    shapes, and one kept under the other backend's name is the other
+    form."""
+    return lambda *args: fn(*args)
+
+
+def _row_long_gathers(jaxpr, n, scope=""):
+    """Gathers that look up ``n`` or more indices one by one, among the
+    equations traced under ``scope``."""
+    return [e for e in _eqns(jaxpr)
+            if e.primitive.name == "gather"
+            and np.prod(e.invars[1].aval.shape[:-1]) >= n
+            and scope in str(e.source_info.name_stack)]
+
+
+_BIN_CASES = {
+    # name -> (X [n, d], edges [d, E]) with the values that test a rule
+    "tied_edges": lambda rng: (
+        rng.normal(size=(501, 3)),
+        np.sort(np.round(rng.normal(size=(3, 31)), 1), axis=1)),
+    "equal_to_an_edge": lambda rng: (
+        np.round(rng.normal(size=(501, 3)), 1),
+        np.sort(np.round(rng.normal(size=(3, 31)), 1), axis=1)),
+    "negative_zero": lambda rng: (
+        np.array([[-0.0], [0.0], [-1e-30], [1e-30]]),
+        np.array([[-1.0, -0.0, 0.0, 1.0]])),
+    "infinities": lambda rng: (
+        np.array([[-np.inf], [np.inf], [3e38], [-3e38]]),
+        np.array([[-np.inf, -1.0, 1.0, np.inf]])),
+    "nan": lambda rng: (
+        np.array([[np.nan], [0.5], [-np.nan]]),
+        np.array([[-1.0, 0.0, 1.0]])),
+    "one_column": lambda rng: (
+        rng.normal(size=(257, 1)), np.sort(rng.normal(size=(1, 63)), axis=1)),
+    "wider_than_the_walk_selects": lambda rng: (
+        rng.normal(size=(65, 300)),
+        np.sort(rng.normal(size=(300, 15)), axis=1)),
+}
+
+
+@pytest.mark.parametrize("form", ["count", "search"])
+@pytest.mark.parametrize("case", sorted(_BIN_CASES))
+def test_bin_data_is_searchsorted_left(case, form, request):
+    """``bin_data`` gives ``np.searchsorted(edges[f], x, side="left")`` to
+    the last code in both its forms: the count of edges below the value
+    (a TPU's) and the binary search (everything else's)."""
+    import jax
+    from tree_reference import bin_codes
+    if form == "count":
+        request.getfixturevalue("as_tpu")
+    X, edges = (np.asarray(a, np.float32)
+                for a in _BIN_CASES[case](np.random.default_rng(7)))
+    text = str(jax.make_jaxpr(_fresh(bin_data.__wrapped__))(X, edges))
+    assert ("gather" in text) == (form == "search")
+    got = np.asarray(jax.jit(_fresh(bin_data.__wrapped__))(
+        jnp.asarray(X), jnp.asarray(edges)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, bin_codes(X, edges))
+
+
+def test_bin_data_searches_past_the_edge_cap(as_tpu, monkeypatch):
+    """Counting is ``O(edges)``: past ``_COUNT_MAX_EDGES`` (an imported
+    booster's thousands of thresholds a feature) the search stays."""
+    import jax
+    from transmogrifai_tpu.models import trees
+    monkeypatch.setattr(trees, "_COUNT_MAX_EDGES", 8)
+    X, edges = np.zeros((5, 2), np.float32), np.zeros((2, 9), np.float32)
+    assert "gather" in str(jax.make_jaxpr(_fresh(bin_data.__wrapped__))(
+        X, edges))
+    assert "gather" not in str(jax.make_jaxpr(_fresh(bin_data.__wrapped__))(
+        X, edges[:, :8]))
+
+
+def _random_trees(rng, lead, depth, d, n_bins, dtype=np.int32):
+    """Stacked tables of random complete trees: a third of the nodes do not
+    split (feature -1, bin ``n_bins``), every third leaf is ``-0.0``."""
+    feats, bins = [], []
+    for level in range(depth):
+        f = rng.integers(0, d, size=lead + (2 ** level,))
+        b = rng.integers(0, n_bins, size=f.shape)
+        dead = rng.random(f.shape) < 1 / 3
+        feats.append(jnp.asarray(np.where(dead, -1, f).astype(dtype)))
+        bins.append(jnp.asarray(np.where(dead, n_bins, b).astype(dtype)))
+    leaves = rng.normal(size=lead + (2 ** depth,)).astype(np.float32)
+    leaves[..., ::3] = -0.0
+    return tuple(feats), tuple(bins), jnp.asarray(leaves)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.int16, 4: np.int32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("d", [28, 300], ids=["narrow", "wide"])
+@pytest.mark.parametrize("depth", [1, 3, 6, 12, 13])
+def test_select_walk_is_the_gather_walk(depth, d, as_tpu):
+    """The walk whose lookups compare against whole tables reaches the
+    gather walk's leaf for every row and returns that leaf's bits (a
+    ``-0.0`` leaf included): nodes that do not split, a row count that is
+    a multiple of nothing, both sides of the width threshold
+    (``_SELECT_MAX_WIDTH``: wider frames gather the code) and of the table
+    threshold (``_SELECT_MAX_NODES``: depth 13's leaves are gathered)."""
+    import jax
+    from tree_reference import gather_walk
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.utils.profiling import sweep_counters
+    rng = np.random.default_rng(100 * depth + d)
+    n, B = 1237, 64
+    Xb = jnp.asarray(rng.integers(0, B, size=(n, d)).astype(np.int32))
+    tree = _random_trees(rng, (), depth, d, B)
+    sweep_counters.reset()
+    jaxpr = jax.make_jaxpr(_fresh(trees.predict_tree))(Xb, *tree)
+    all_selected = d <= trees._SELECT_MAX_WIDTH \
+        and 2 ** depth <= trees._SELECT_MAX_NODES
+    assert sweep_counters.run_to_json()["treeGatherWalks"] == \
+        (0 if all_selected else 1)
+    assert (not _row_long_gathers(jaxpr.jaxpr, n)) == all_selected
+    got = jax.jit(_fresh(trees.predict_tree))(Xb, *tree)
+    want = jax.jit(gather_walk)(Xb, *tree)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert (_bits(want) == _bits(np.float32(-0.0))).any()
+
+
+@pytest.mark.parametrize("case", ["int16_tables", "int8_codes",
+                                  "bf16_leaves", "three_classes"])
+def test_select_walk_of_other_operands(case, as_tpu, monkeypatch):
+    """What the serving rungs and the sweep hand the walk: int16 tables
+    (compared after promotion), int8 codes, bfloat16 leaves (returned as
+    they are), and a ``[rounds, classes]`` stack through
+    ``predict_ensemble`` (against the same ensemble over the gather
+    walk)."""
+    import jax
+    from tree_reference import gather_walk
+    from transmogrifai_tpu.models import trees
+    rng = np.random.default_rng(3)
+    n, d, B, depth = 515, 28, 64, 5
+    Xb = rng.integers(0, B, size=(n, d)).astype(
+        np.int8 if case == "int8_codes" else np.int32)
+    lead = (4, 3) if case == "three_classes" else ()
+    feats, bins, leaves = _random_trees(
+        rng, lead, depth, d, B,
+        np.int16 if case == "int16_tables" else np.int32)
+    if case == "bf16_leaves":
+        leaves = leaves.astype(jnp.bfloat16)
+    if case == "three_classes":
+        kw = dict(n_out=3, learning_rate=jnp.float32(0.3),
+                  base_score=jnp.float32(0.1), bootstrap=False)
+        got = jax.jit(lambda X, t: trees.predict_ensemble(X, t, **kw))(
+            Xb, (feats, bins, leaves))
+        monkeypatch.setattr(trees, "predict_tree", gather_walk)
+        want = jax.jit(lambda X, t: trees.predict_ensemble(X, t, **kw))(
+            Xb, (feats, bins, leaves))
+        assert got.shape == (n, 3)
+    else:
+        got = jax.jit(_fresh(trees.predict_tree))(Xb, feats, bins, leaves)
+        want = jax.jit(gather_walk)(Xb, feats, bins, leaves)
+        assert got.dtype == leaves.dtype
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("d", [28, 300], ids=["higgs_zoo", "wide"])
+@pytest.mark.parametrize("program", ["device_apply", "train_score_stacked"])
+def test_scoring_programs_hold_no_row_long_gather(program, d, as_tpu):
+    """At ``higgs_zoo``'s shapes (28 columns, 64 bins, depth 12) neither
+    the winner's ``device_apply`` nor the sweep's scoring looks anything up
+    row by row, and ``treeGatherWalks`` stays 0; on a frame wider than
+    ``_SELECT_MAX_WIDTH`` the walk gathers its codes and the counter says
+    so (``bin_data`` still counts)."""
+    import jax
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.utils.profiling import sweep_counters
+    rng = np.random.default_rng(11)
+    B, depth, rounds = 64, 12, 2
+    narrow = d <= trees._SELECT_MAX_WIDTH
+    sweep_counters.reset()
+    if program == "device_apply":
+        n = 1031
+        model = trees.TreeEnsembleModel(kind="rf_classifier", n_out=1,
+                                        max_depth=depth)
+        model.bin_edges = np.sort(rng.normal(size=(d, B - 1)), axis=1
+                                  ).astype(np.float32)
+        model.trees = _random_trees(rng, (rounds, 1), depth, d, B)
+        X = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+        jaxpr = jax.make_jaxpr(model.device_apply)(
+            model.device_params(), fr.VectorColumn(X))
+        scope = ""
+    else:
+        k, n_tr, n = 2, 257, 1031
+        args = (jnp.zeros((k, n_tr, d), jnp.int8), jnp.zeros((k, n_tr)),
+                jnp.ones((k, n_tr)), jnp.zeros((k, n, d), jnp.int8),
+                jnp.zeros(k), *(jnp.ones(1),) * 4)
+        jaxpr = jax.make_jaxpr(lambda *a: trees.train_score_stacked(
+            *a, n_rounds=rounds, max_depth=depth, n_bins=B, loss="squared",
+            subsample=1.0, colsample=0.7, bootstrap=True, seed=0,
+            hist="scatter", forest_margin=True))(*args)
+        scope = "tree.predict"   # the grower's own gathers are not scoring
+        assert _row_long_gathers(jaxpr.jaxpr, n_tr)   # the scope is read
+    assert (not _row_long_gathers(jaxpr.jaxpr, n, scope)) == narrow
+    assert sweep_counters.run_to_json()["treeGatherWalks"] == \
+        (0 if narrow else 1)

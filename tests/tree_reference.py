@@ -1,7 +1,10 @@
 """A fitted ``TreeEnsembleModel``'s score re-done in NumPy float32, for the
 tests that hold the program to ``base_score + learning_rate * sum(tree
 outputs)``: binning by ``searchsorted``, the level-wise walk of every tree,
-the rounds summed one after another."""
+the rounds summed one after another. And the plain forms of the two
+lookups the scoring path makes (``bin_codes``, ``gather_walk``), which the
+program's compare-and-select forms are held to, code for code and bit for
+bit."""
 
 import numpy as np
 
@@ -13,11 +16,28 @@ from transmogrifai_tpu.features.builder import FeatureBuilder
 from transmogrifai_tpu.types import feature_types as ft
 
 
+def bin_codes(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
+    """``[n, d]`` codes: ``np.searchsorted(edges[j], X[:, j], "left")``."""
+    return np.stack([np.searchsorted(edges[j], X[:, j], side="left")
+                     for j in range(X.shape[1])], axis=1)
+
+
+def gather_walk(Xb, feats, bins, leaf_values):
+    """``predict_tree`` as it was before its lookups compared against whole
+    tables: three row-long gathers a level and one for the leaf."""
+    rows = jnp.arange(Xb.shape[0])
+    node = jnp.zeros(Xb.shape[0], dtype=jnp.int32)
+    for f_tab, b_tab in zip(feats, bins):
+        f, b = f_tab[node], b_tab[node]
+        left = jnp.where(f < 0, True, Xb[rows, jnp.clip(f, 0)] <= b)
+        node = node * 2 + jnp.where(left, 0, 1).astype(jnp.int32)
+    return leaf_values[node]
+
+
 def tree_outputs(model, X: np.ndarray) -> np.ndarray:
     """``[rounds, n_out, n]`` float32: the leaf each tree gives each row."""
     edges = np.asarray(model.bin_edges, np.float32)
-    Xb = np.stack([np.searchsorted(edges[j], X[:, j], side="left")
-                   for j in range(X.shape[1])], axis=1)
+    Xb = bin_codes(X, edges)
     feats, bins, leaves = model.trees
     feats = [np.asarray(f) for f in feats]
     bins = [np.asarray(b) for b in bins]

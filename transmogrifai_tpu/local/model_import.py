@@ -23,7 +23,8 @@ Conversion notes (how foreign trees map onto the binned representation):
   float thresholds converts by collecting every threshold used per feature
   into that feature's bin-edge list, then rewriting each split's threshold
   as its edge INDEX. ``bin_data`` assigns ``x_bin = searchsorted(edges, x,
-  'left')``, so ``x_bin <= b  <=>  x <= edges[b]``:
+  'left')`` (on a TPU as the count of edges below ``x``, which is the same
+  number), so ``x_bin <= b  <=>  x <= edges[b]``:
   sklearn routes left on ``x <= t`` (edge = t exactly) while XGBoost routes
   left on ``x < t`` (edge = nextafter(t, -inf), the largest float32 below
   t — exact float semantics, not an epsilon).

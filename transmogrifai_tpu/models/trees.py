@@ -29,7 +29,10 @@ native engines with one device-resident histogram learner (SURVEY §2.7 P5):
   operands and the scatter histograms folding every batch axis into the
   node axis (``ops/histograms.py``'s custom_vmap rule)
 - trees are fixed-shape: a non-splitting node stores feature -1 and routes
-  rows left, so depth-d trees are dense arrays and prediction is d gathers.
+  rows left, so depth-d trees are dense arrays and prediction is d table
+  lookups a row; on a TPU the scoring path (``bin_data``, ``predict_tree``)
+  makes a lookup in a small table by comparing against the whole table,
+  never by a per-element gather (``_compares_all``).
 
 Random forests grow CART-style regression trees on bootstrap (Poisson)
 weights with per-tree feature subsampling; for classification the leaf holds
@@ -90,13 +93,50 @@ def quantile_bin_edges_device(X, *, max_bins: int):
     return jnp.quantile(X, qs, axis=0).T.astype(jnp.float32)
 
 
+#: the shapes up to which a lookup in a small table is made by comparing
+#: against the WHOLE table (``_compares_all``): edges a feature (past it,
+#: imported boosters with thousands of thresholds a feature, the binary
+#: search's ``log2`` serial lookups are cheaper), the width of the frame
+#: whose rows pick their split feature's code out of the ``d`` codes they
+#: hold (``O(d)`` a row and level), and the length of a per-level table of
+#: split features, bins or leaves (``O(N)`` a row: depth 12)
+_COUNT_MAX_EDGES = 4096
+_SELECT_MAX_WIDTH = 256
+_SELECT_MAX_NODES = 4096
+
+
+def _compares_all(size: int, cap: int) -> bool:
+    """THE choice of how the scoring path looks a value up in a table of
+    ``size`` entries: True = by comparison against all of them (dense VPU
+    code that fuses into its reduction), False = by a per-element gather.
+    XLA:TPU runs such a gather nearly serially, about 10 ns an element
+    whatever the table holds, so on a TPU everything up to ``cap`` is
+    compared; XLA:CPU gathers fast and MATERIALISES the ``[size, rows]``
+    comparison (cpu, PR 30: 304 MB and 215 ms against 3 ms for 18 depth-12
+    trees over 1,000 rows), so off the TPU nothing is."""
+    return size <= cap and jax.default_backend() == "tpu"
+
+
 @jax.jit
 def bin_data(X, edges):
-    """Bin values: [n, d] int32 in [0, B-1] via vectorized searchsorted."""
+    """Bin values: [n, d] int32 in [0, B-1], ``searchsorted(edges[f], x,
+    side="left")`` for every (row, feature).
+
+    On a TPU a row's code is the COUNT of its feature's edges below its
+    value (``method="compare_all"``: jax's total-order comparison, so ties,
+    values equal to an edge, ``-0.0``, infinities and NaN get the binary
+    search's answers): ``B - 1`` compares fused into their sum, where the
+    binary search is ``log2 B`` serial per-element gathers from the edge
+    table (58 ns an element at 63 edges; PERF.md section 6, PR 30)."""
+    method = ("compare_all" if _compares_all(edges.shape[1], _COUNT_MAX_EDGES)
+              else "scan")
+
     def per_feature(x_col, e_col):
-        return jnp.searchsorted(e_col, x_col, side="left")
-    return jax.vmap(per_feature, in_axes=(1, 1), out_axes=1)(
-        X, edges.T.astype(X.dtype)).astype(jnp.int32)
+        return jnp.searchsorted(e_col, x_col, side="left", method=method)
+
+    with device_scope("tree.bin"):
+        return jax.vmap(per_feature, in_axes=(1, 1), out_axes=1)(
+            X, edges.T.astype(X.dtype)).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -659,17 +699,65 @@ def grow_tree(Xb, grad, hess, feat_mask, *, max_depth: int, n_bins: int,
         row_pred
 
 
+def _select(table, key):
+    """``table[key[r]]`` (an ``[N]`` integer table) or ``table[key[r], r]``
+    (``[N, n]``: a table a row) for ``[n]`` keys, with no gather: every
+    key is compared with all ``N`` indices and the one entry it selects is
+    summed out of zeros (exact; a key outside ``[0, N)`` gives 0). The
+    compared axis is the MAJOR one, so the rows stay on the TPU's lanes
+    and the ``[N, n]`` comparison fuses into its reduction."""
+    iota = jnp.arange(table.shape[0], dtype=jnp.int32)
+    entries = table if table.ndim == 2 else table[:, None]
+    return jnp.sum(jnp.where(iota[:, None] == key[None, :], entries, 0),
+                   axis=0)
+
+
+def _select_float(table, key):
+    """``_select`` for a table of float32 or narrower floats, through the
+    values' BITS: what comes back is the entry itself (``-0.0`` and NaN
+    payloads included), never a rounded sum."""
+    bits = jax.lax.bitcast_convert_type(table.astype(jnp.float32), jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        _select(bits, key), jnp.float32).astype(table.dtype)
+
+
 def predict_tree(Xb, feats, bins, leaf_values):
-    n = Xb.shape[0]
-    rows = jnp.arange(n)
+    """[n] leaf values of one tree for the binned rows ``Xb`` [n, d].
+
+    Level by level a row looks up its node's split feature and bin in the
+    level's ``[2**level]`` tables, then its own code of that feature among
+    the ``d`` it holds, and at the end its leaf. Each lookup takes the
+    form ``_compares_all`` gives for its table's size: ``_select`` (the
+    leaf through its bits), or the per-row gather. The node a row reaches
+    is decided by integer comparisons in either, so the forms agree to the
+    bit. ``treeGatherWalks`` counts the traces that kept a gather."""
+    n, d = Xb.shape
+    select_code = _compares_all(d, _SELECT_MAX_WIDTH)
+    select_leaf = _compares_all(leaf_values.shape[0], _SELECT_MAX_NODES)
+    if not (select_code and select_leaf):  # the level tables are shorter
+        from transmogrifai_tpu.utils.profiling import sweep_counters
+        sweep_counters.count_run(tree_gather_walks=1)
+    if select_code:
+        codes = Xb.T.astype(jnp.int32)  # [d, n]: rows on the lanes
+    else:
+        rows = jnp.arange(n)
     node = jnp.zeros(n, dtype=jnp.int32)
     for level in range(len(feats)):
-        f = feats[level][node]
-        b = bins[level][node]
-        x = Xb[rows, jnp.clip(f, 0)]
-        go_left = jnp.where(f < 0, True, x <= b)
-        node = node * 2 + jnp.where(go_left, 0, 1).astype(jnp.int32)
-    return leaf_values[node]
+        with device_scope(f"L{level}"):
+            f_tab = feats[level].astype(jnp.int32)
+            b_tab = bins[level].astype(jnp.int32)
+            if _compares_all(f_tab.shape[0], _SELECT_MAX_NODES):
+                f, b = _select(f_tab, node), _select(b_tab, node)
+            else:
+                f, b = f_tab[node], b_tab[node]
+            # a node that does not split (feature -1) sends its rows left
+            x = _select(codes, f) if select_code else Xb[rows, jnp.clip(f, 0)]
+            go_left = (f < 0) | (x <= b)
+            node = node * 2 + jnp.where(go_left, 0, 1).astype(jnp.int32)
+    with device_scope("leaf"):
+        if select_leaf:
+            return _select_float(leaf_values, node)
+        return leaf_values[node]
 
 
 # ---------------------------------------------------------------------------
@@ -807,7 +895,9 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
     their validation folds, returning ``[k, L, n_va]`` scores.
 
     ``Xb/Xva``: ``[k, n, d]`` stacked int bin codes (one fold gather of
-    the dataset-level ``fold_sweep_plan`` codes — no re-binning);
+    the dataset-level ``fold_sweep_plan`` codes — no re-binning; the
+    validation rows are scored by ``predict_ensemble`` under the scope
+    ``tree.predict``, on a TPU with no row-long gather);
     ``y/w``: ``[k, n]``; ``base``: ``[k]`` per-fold base scores
     (host-computed with the loop path's exact f32/f64 arithmetic —
     ``tree_stack_fold_bases`` — so stacked-vs-loop parity stays bitwise);
@@ -832,14 +922,12 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
                 subsample=subsample, colsample=colsample,
                 base_score=base_k, bootstrap=bootstrap, seed=seed,
                 hist=hist)
-            with device_scope("tree.predict"):
-                out = predict_ensemble(Xva_k, trees, n_out=1,
-                                       learning_rate=lr_i,
-                                       base_score=base_k,
-                                       bootstrap=bootstrap)
-                s = out[:, 0]
-                if forest_margin:
-                    s = jnp.clip(s, 0.0, 1.0) - 0.5  # margin at 0
+            out = predict_ensemble(Xva_k, trees, n_out=1,
+                                   learning_rate=lr_i, base_score=base_k,
+                                   bootstrap=bootstrap)
+            s = out[:, 0]
+            if forest_margin:
+                s = jnp.clip(s, 0.0, 1.0) - 0.5  # margin at 0
             return s
 
         return jax.vmap(lane_fn)(lr, lam, gam, mcw)
@@ -849,17 +937,13 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
 
 def predict_ensemble(Xb, trees, *, n_out: int, learning_rate, base_score,
                      bootstrap: bool):
-    feats, bins, leaves = trees
-    n_rounds = leaves.shape[0]
-
-    def one_round(r):
-        f = tuple(x[r] for x in feats)
-        b = tuple(x[r] for x in bins)
-        l = leaves[r]
-        return jax.vmap(lambda ff, bb, ll: predict_tree(Xb, ff, bb, ll))(
-            f, b, l)  # [n_out, n]
-
-    preds = jax.vmap(one_round)(jnp.arange(n_rounds))  # [R, n_out, n]
+    """[n, n_out] margins of a stacked ensemble (``trees``: per-level
+    ``[R, n_out, 2**level]`` tables and ``[R, n_out, 2**depth]`` leaves):
+    ``predict_tree`` over rounds and classes, under the device scope
+    ``tree.predict`` (its levels read ``tree.predict/L<level>``)."""
+    walk = functools.partial(predict_tree, Xb)
+    with device_scope("tree.predict"):
+        preds = jax.vmap(jax.vmap(walk))(*trees)  # [R, n_out, n]
     if bootstrap:
         return jnp.mean(preds, axis=0).T  # [n, n_out]
     return base_score + learning_rate * jnp.sum(preds, axis=0).T

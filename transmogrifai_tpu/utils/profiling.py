@@ -466,6 +466,10 @@ class SweepCounters:
         self.fe_distinct_values = 0
         self.fe_hash_fallbacks = 0
         self.fe_upload_bytes = 0
+        #: ``models/trees.py::predict_tree`` traces that kept a per-row
+        #: gather (a frame wider, or a tree deeper, than its
+        #: compare-and-select lookups take)
+        self.tree_gather_walks = 0
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -480,6 +484,7 @@ class SweepCounters:
         self.fe_distinct_values = 0
         self.fe_hash_fallbacks = 0
         self.fe_upload_bytes = 0
+        self.tree_gather_walks = 0
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -505,10 +510,12 @@ class SweepCounters:
     def count_run(self, *, host_syncs: int = 0, async_families: int = 0,
                   refit_warm_starts: int = 0, operand_bytes: int = 0,
                   fe_distinct_values: int = 0, fe_hash_fallbacks: int = 0,
-                  fe_upload_bytes: int = 0) -> None:
+                  fe_upload_bytes: int = 0,
+                  tree_gather_walks: int = 0) -> None:
         """Run-level accounting (see class docstring): settle barriers,
-        overlapped families, warm-started refits, operand copies, and the
-        host string work that fed the sweep."""
+        overlapped families, warm-started refits, operand copies, the
+        host string work that fed the sweep, and tree walks traced with a
+        per-row gather."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
@@ -516,6 +523,7 @@ class SweepCounters:
         self.fe_distinct_values += int(fe_distinct_values)
         self.fe_hash_fallbacks += int(fe_hash_fallbacks)
         self.fe_upload_bytes += int(fe_upload_bytes)
+        self.tree_gather_walks += int(tree_gather_walks)
 
     def to_json(self) -> dict:
         return {name: {"mode": fc.mode, "compiles": self.compiles(name),
@@ -534,7 +542,8 @@ class SweepCounters:
                 "sweepOperandBytes": self.operand_bytes,
                 "feDistinctValues": self.fe_distinct_values,
                 "feHashPerRowFallbacks": self.fe_hash_fallbacks,
-                "feUploadBytes": self.fe_upload_bytes}
+                "feUploadBytes": self.fe_upload_bytes,
+                "treeGatherWalks": self.tree_gather_walks}
 
 
 sweep_counters = SweepCounters()
