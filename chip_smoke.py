@@ -13,6 +13,8 @@ retry, a lazy serving compile, a degraded serving entry).
 
 Legs: ``titanic`` (mixed schema, committed fixture), ``higgs`` (bench.py's own
 ``run_pipeline``: 28 numeric columns, the un-cut default binary zoo, 3 folds),
+``multiclass`` (a seven-class label over the same 28 columns through the
+multiclass selector: no unit leaves the stacked sweep, no tree walk gathers),
 ``serve`` (the higgs winner behind a real localhost endpoint, JSON + binary
 frames against the row-path oracle), ``kernels`` (each Pallas kernel through
 its public stage, compiled, against its XLA twin), ``mesh`` (the higgs leg
@@ -44,7 +46,7 @@ import warnings
 from unittest import mock
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-LEGS = ("titanic", "higgs", "serve", "kernels", "mesh")
+LEGS = ("titanic", "higgs", "multiclass", "serve", "kernels", "mesh")
 TITANIC_CSV = os.path.join(HERE, "tests", "fixtures",
                            "TitanicPassengersTrainData.csv")
 
@@ -239,6 +241,92 @@ def leg_higgs(leg: Leg, out: str, ctx: dict) -> None:
                               os.path.join(out, "ckpt", "higgs"),
                               ctx["on_tpu"])
     ctx["single_cv_metrics"] = leg.info["cv_metrics"]
+
+
+# -- multiclass ---------------------------------------------------------------
+
+def _seven_class_frame(rows: int):
+    """``bench.make_data``'s 28 columns under a label of seven classes at
+    skewed shares (the rarest about one row in twenty): the largest of seven
+    noisy scores, each linear in six columns and curved in one, which a
+    softmax fits in part and a forest in part."""
+    import numpy as np
+    import bench
+    from transmogrifai_tpu import frame as fr
+    from transmogrifai_tpu.types import feature_types as ft
+    X, _ = bench.make_data(rows, seed=21)
+    rng = np.random.default_rng(22)
+    A = rng.normal(size=(6, 7))
+    scores = (X[:, :6] @ A + 0.8 * X[:, 6:13] ** 2
+              + np.array([1.6, 1.4, 0.8, 0.5, 0.2, -0.4, -1.6])
+              + 0.5 * rng.gumbel(size=(rows, 7)))
+    y = np.argmax(scores, axis=1)
+    cols = {f"f{i}": fr.HostColumn(ft.Real, X[:, i].astype(np.float64),
+                                   np.ones(rows, bool))
+            for i in range(X.shape[1])}
+    cols["label"] = fr.HostColumn(ft.RealNN, y.astype(np.float64),
+                                  np.ones(rows, bool))
+    return fr.HostFrame(cols)
+
+
+def leg_multiclass(leg: Leg, out: str, ctx: dict) -> None:
+    """A seven-class table through the multiclass selector (softmax LR at
+    its default grid of 8, random forest at depth 6 and 12 with 3 trees)
+    on a FIRST train: every family on its stacked mode with a class axis,
+    one settle, no unit sent to the per-fold loop, and on the chip no tree
+    walk traced with a gather."""
+    from transmogrifai_tpu.features.builder import FeatureBuilder
+    from transmogrifai_tpu.models.linear import OpLogisticRegression
+    from transmogrifai_tpu.models.trees import OpRandomForestClassifier
+    from transmogrifai_tpu.ops.transmogrifier import transmogrify
+    from transmogrifai_tpu.preparators.sanity_checker import SanityChecker
+    from transmogrifai_tpu.selector import (
+        DataSplitter, MultiClassificationModelSelector,
+    )
+    from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
+    from transmogrifai_tpu.workflow import Workflow
+    frame = _seven_class_frame(ctx["rows"])
+    zoo = [(OpLogisticRegression(),
+            [{"reg_param": r, "elastic_net_param": e}
+             for r in (0.001, 0.01, 0.1, 0.2) for e in (0.0, 0.5)]),
+           (OpRandomForestClassifier(),
+            [{"num_trees": 3, "max_depth": d} for d in (6, 12)])]
+    profiler.reset(app_name="chip_smoke")
+    t0 = time.time()
+    feats = FeatureBuilder.from_frame(frame, response="label")
+    label = feats.pop("label")
+    checked = label.transform_with(SanityChecker(),
+                                   transmogrify(list(feats.values())))
+    selector = MultiClassificationModelSelector.with_cross_validation(
+        n_folds=3, seed=42, models_and_parameters=zoo,
+        splitter=DataSplitter(reserve_test_fraction=0.1, seed=42))
+    pred = label.transform_with(selector, checked)
+    s = (Workflow().set_input_frame(frame).set_result_features(pred)
+         .train().selector_summary())
+    hold = s.holdout_evaluation["multiclass classification"]
+    run = sweep_counters.run_to_json()
+    modes = {f: c["mode"] for f, c in sweep_counters.to_json().items()}
+    leg.info.update(
+        rows=ctx["rows"], smoke_wall_s=round(time.time() - t0, 1),
+        best=s.best_model_name, holdout_f1=round(float(hold["f1"]), 4),
+        sweep_modes=modes, sweep_run_counters=run,
+        cv_metrics={r.model_name: list(r.metric_values.values())[0]
+                    for r in s.validation_results},
+        peak_bytes_in_use=_peaks())
+    leg.check(s.failures == [], "summary.failures == []")
+    leg.check(len(s.validation_results) == 10,
+              "all 10 grid points have a validation result")
+    leg.check(sorted(modes.values()) == ["fold_stacked", "tree_stacked"],
+              "both families took their stacked sweep mode")
+    leg.check(run["sweepLoopFallbacks"] == 0, "sweepLoopFallbacks == 0")
+    leg.check(run["sweepHostSyncs"] == 1,
+              "sweepHostSyncs == 1 (one settle for the whole sweep)")
+    if ctx["on_tpu"]:
+        leg.check(run["treeGatherWalks"] == 0,
+                  "treeGatherWalks == 0 (28 columns, depth 12: every tree "
+                  "walk compares against whole tables)")
+    leg.check(hold["f1"] >= 0.55, "holdout F1 >= 0.55")
+    _counters_clean(leg)
 
 
 # -- serve --------------------------------------------------------------------
@@ -549,7 +637,8 @@ def leg_mesh(leg: Leg, out: str, ctx: dict) -> None:
               "__graft_entry__.dryrun_multichip(4) on the attached devices")
 
 
-LEG_FNS = {"titanic": leg_titanic, "higgs": leg_higgs, "serve": leg_serve,
+LEG_FNS = {"titanic": leg_titanic, "higgs": leg_higgs,
+           "multiclass": leg_multiclass, "serve": leg_serve,
            "kernels": leg_kernels, "mesh": leg_mesh}
 
 

@@ -89,7 +89,8 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     out = str(tmp_path / "smoke")
     try:
         rc = smoke.main(["--rehearsal", "--legs",
-                         "titanic,higgs,serve,kernels", "--rows", "4000",
+                         "titanic,higgs,multiclass,serve,kernels",
+                         "--rows", "4000",
                          "--big-rows", "3000", "--out", out])
     finally:
         devicewatch.configure(incident_dir="")
@@ -98,12 +99,15 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     assert rc == 0, {k: v.get("error") for k, v in summary["legs"].items()}
     assert summary["ok"] is True and summary["rehearsal"] is True
     assert summary["claim"] is None
-    assert list(summary["legs"]) == ["titanic", "higgs", "serve", "kernels"]
+    assert list(summary["legs"]) == ["titanic", "higgs", "multiclass",
+                                     "serve", "kernels"]
     for leg in summary["legs"].values():
         assert leg["ok"] and leg["asserted"]
     assert summary["legs"]["titanic"]["best"].startswith(
         "OpRandomForestClassifier")
     assert summary["legs"]["higgs"]["grid_points"] == 14
+    multi = summary["legs"]["multiclass"]["sweep_run_counters"]
+    assert multi["sweepLoopFallbacks"] == 0 and multi["sweepHostSyncs"] == 1
     assert summary["legs"]["serve"]["frame_sizes"] == [1, 7, 64, 256]
     assert set(summary["native_libraries"]) == {"texthash", "shist",
                                                 "dictenc"}

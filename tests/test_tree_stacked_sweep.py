@@ -268,8 +268,10 @@ def test_selector_route_table(stackable, device_metric, fits, monkeypatch,
 
 
 def test_tree_stacked_multiclass_falls_back():
-    """Multiclass has no scalar stacked score: the family keeps the
-    per-fold loop."""
+    """A binary evaluator's fold metric takes one scalar score a row: under
+    it a family of three outputs keeps the per-fold loop, and says why
+    (under the multiclass evaluator it rides the stacked path:
+    ``tests/test_multiclass_sweep.py``)."""
     frame = _frame(seed=7, classes=3)
     sweep_counters.reset()
     sel = BinaryClassificationModelSelector.with_cross_validation(
@@ -282,6 +284,8 @@ def test_tree_stacked_multiclass_falls_back():
     _train(sel, frame)
     c = sweep_counters.to_json()
     assert c["OpRandomForestClassifier_0"]["mode"] == "fold_loop", c
+    assert sweep_counters.run_to_json()["sweepLoopFallbackReasons"] == {
+        "no_device_metric": 1}
 
 
 def test_hbm_guard_lane_chunking(monkeypatch, shared_frame, stacked_run):
@@ -535,7 +539,7 @@ def test_stacked_engines_agree(monkeypatch):
     codes = codes.astype(jnp.int8)
     args = (jnp.take(codes, jtr, axis=0), jnp.take(y, jtr, axis=0),
             jnp.take(w, jtr, axis=0), jnp.take(codes, jva, axis=0))
-    lnb = est.tree_stack_scalar_lnb(y)
+    lnb = est.tree_stack_lnb(y)
     group = est.tree_stack_groups(grid)[0]
     s_scatter = np.asarray(
         est.tree_stack_scores(*args, group["params"], lnb))

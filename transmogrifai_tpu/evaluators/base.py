@@ -22,6 +22,11 @@ class EvaluatorBase:
     default_metric: str = ""
     #: metric name -> larger_is_better
     metric_directions: dict[str, bool] = {}
+    #: whether ``metric_batch_scores_folds_device`` (where the evaluator
+    #: has one) reduces ``[k, G, K, n]`` class scores; False: one scalar
+    #: score a row (``[k, G, n]``) only, and the selector keeps a family of
+    #: several outputs on the per-fold loop
+    scores_class_axis: bool = False
 
     def evaluate_arrays(self, y, pred_col, w=None) -> Any:
         """Compute metrics from a label array + PredictionColumn."""

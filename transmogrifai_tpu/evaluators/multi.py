@@ -26,8 +26,11 @@ Python in the hot path (the RDD treeAggregate analog is a bincount).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+import functools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from transmogrifai_tpu.evaluators.base import EvaluatorBase
@@ -112,11 +115,53 @@ def _weighted_prf(conf: np.ndarray) -> tuple[float, float, float, float]:
     return precision, recall, f1, error
 
 
+@functools.partial(jax.jit, static_argnames=("metric",))
+def _metric_batch_folds(y, scores, w, metric: str):
+    """The fold-stacked multiclass metric batch: ``y [k, n]`` class labels,
+    ``scores [k, G, K, n]`` class scores (the class axis BEFORE the rows, so
+    the rows stay on the TPU's lanes: a trailing axis of 7 pads to 128),
+    ``w [k, n]`` -> ``[k, G]``. A lane's prediction is the argmax over the
+    class axis (the first of equal scores, as ``jnp.argmax`` in
+    ``device_apply``); its ``[K, K]`` confusion matrix is a product of the
+    two one-hot encodings over the rows (0/1 operands, so exact at any
+    matmul precision up to 2**24 rows); precision, recall, F1 and error
+    follow from it as ``_weighted_prf`` computes them on the host."""
+    K = scores.shape[2]
+    with jax.named_scope("metric.confusion"):
+        yhat = jnp.argmax(scores, axis=2)                        # [k, G, n]
+        classes = jnp.arange(K, dtype=jnp.int32)
+        oh_y = (y.astype(jnp.int32)[:, None, :] == classes[None, :, None]
+                ).astype(jnp.float32) * w[:, None, :]            # [k, K, n]
+        oh_hat = (yhat[:, :, None, :] == classes[None, None, :, None]
+                  ).astype(jnp.float32)                          # [k,G,K,n]
+        conf = jnp.einsum("kan,kgbn->kgab", oh_y, oh_hat,
+                          precision=jax.lax.Precision.HIGHEST)
+    support = jnp.sum(conf, axis=3)                              # [k, G, K]
+    pred_count = jnp.sum(conf, axis=2)
+    diag = jnp.diagonal(conf, axis1=2, axis2=3)
+    prec_c = jnp.where(pred_count > 0,
+                       diag / jnp.maximum(pred_count, 1e-30), 0.0)
+    rec_c = jnp.where(support > 0, diag / jnp.maximum(support, 1e-30), 0.0)
+    wsum = jnp.maximum(jnp.sum(support, axis=2), 1e-12)
+    precision = jnp.sum(prec_c * support, axis=2) / wsum
+    recall = jnp.sum(rec_c * support, axis=2) / wsum
+    if metric == "Precision":
+        return precision
+    if metric == "Recall":
+        return recall
+    if metric == "F1":
+        both = precision + recall
+        return jnp.where(both > 0, 2 * precision * recall
+                         / jnp.maximum(both, 1e-30), 0.0)
+    return 1.0 - jnp.sum(diag, axis=2) / wsum                    # Error
+
+
 class OpMultiClassificationEvaluator(EvaluatorBase):
     name = "multiclass classification"
     default_metric = "F1"
     metric_directions = {"Precision": True, "Recall": True, "F1": True,
                          "Error": False}
+    scores_class_axis = True
 
     def __init__(self, top_ns: tuple = (1, 3),
                  top_ks: tuple = (5, 10, 20, 50, 100),
@@ -260,11 +305,43 @@ class OpMultiClassificationEvaluator(EvaluatorBase):
             m) if m in ("Precision", "Recall", "F1", "Error") else \
             self.metric_value(self.evaluate_arrays(y, pred_col, w), m)
 
+    def metric_batch_scores_folds_device(self, y, scores, metric=None,
+                                         w=None):
+        """Fold-stacked metric batch WITHOUT the host pull: ``y [k, n]``,
+        class scores ``[k, G, K, n]`` (or the ``[k, G, n]`` margins the
+        families hand on for a two-class label) -> the ``[k, G]`` values of
+        one of the four summary metrics as a device array future, which
+        the one-sync sweep settles behind its single barrier."""
+        metric = metric or self.default_metric
+        if metric not in self.metric_directions:
+            raise ValueError(f"no fold-batched form of metric {metric!r}")
+        y = jnp.asarray(y, jnp.float32)
+        w = jnp.ones_like(y) if w is None else jnp.asarray(w, jnp.float32)
+        scores = jnp.asarray(scores, jnp.float32)
+        if scores.ndim == 3:    # a two-class label: margins, decided at 0
+            scores = jnp.stack([-scores, scores], axis=2)
+        return _metric_batch_folds(y, scores, w, metric)
+
+    def metric_batch_scores_folds(self, y, scores, metric=None,
+                                  w=None) -> np.ndarray:
+        """``metric_batch_scores_folds_device`` pulled to the host."""
+        return np.asarray(self.metric_batch_scores_folds_device(
+            y, scores, metric, w))
+
     def evaluate_arrays(self, y, pred_col, w=None) -> MultiClassificationMetrics:
+        from transmogrifai_tpu.utils.tracing import span
+        # the pulls wait for the device; the span holds the host's own work
         y = np.asarray(y).astype(np.int64)
         yhat = np.asarray(pred_col.prediction).astype(np.int64)
         w = np.ones_like(y, dtype=np.float64) if w is None else np.asarray(w)
         prob = np.asarray(pred_col.probability)
+        with span("evaluate.multiclass", rows=int(y.shape[0]),
+                  classes=int(prob.shape[1]) if prob.ndim == 2 else 0):
+            return self._evaluate_host(y, yhat, w, prob)
+
+    def _evaluate_host(self, y, yhat, w, prob) -> MultiClassificationMetrics:
+        """The report's host part: confusion counts, top-K, threshold and
+        misclassification families over the pulled scores."""
         n_cls = max(int(y.max()), int(yhat.max())) + 1 if y.size else 1
         conf = np.zeros((n_cls, n_cls))
         np.add.at(conf, (y, yhat), w)

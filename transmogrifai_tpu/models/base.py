@@ -195,8 +195,10 @@ class Predictor(Estimator):
         """Fold-stacked scoring: ``models`` is the ``[k][G]`` nest from
         ``grid_fit_arrays_folds``, ``X: [k, n_va, d]`` the stacked
         validation folds; returns one ``[k, G, n_va]`` device score array
-        (margins for binary, predictions for regression) or None when no
-        batched scalar score exists (e.g. multiclass)."""
+        (margins for binary, predictions for regression), past two classes
+        ``[k, G, C, n_va]`` class scores (the class axis before the rows;
+        an evaluator with ``scores_class_axis`` reduces them), or None
+        when the family has no batched score."""
         return None
 
     def fold_stack_unit_width(self, grid: Sequence[dict]) -> int:
@@ -254,7 +256,8 @@ class Predictor(Estimator):
     def sweep_folds(self, batch: "FoldBatch", grid: Sequence[dict],
                     _n_classes: Optional[int] = None):
         """What the selector's stacked sweep invokes: ``(scores [k, G,
-        n_va], warm handle)`` of every fold x grid point over ``batch``.
+        n_va], warm handle)`` of every fold x grid point over ``batch``
+        (``[k, G, C, n_va]`` class scores past two classes).
         Default: gather each fold into an array of its own and hand them to
         ``grid_scores_folds_retained``; a family that trains folds as row
         weights over the resident matrix overrides."""

@@ -119,9 +119,10 @@ class OpNaiveBayes(Predictor):
                                log_theta=np.asarray(log_theta))
 
     def grid_predict_scores(self, models, X):
-        """[G, n] binary log-odds margins (None for multiclass) — the same
-        batched metric program the fold-stacked path uses, so both sweep
-        paths score identically."""
+        """[G, n] binary log-odds margins for the per-fold loop's batched
+        metric (None past two classes: the loop then scores model by
+        model; the fold-stacked path scores every class,
+        ``grid_predict_scores_folds``)."""
         if not models:
             return None
         lt = jnp.stack([jnp.asarray(m.log_theta, jnp.float32)
@@ -155,7 +156,9 @@ class OpNaiveBayes(Predictor):
                  for j in range(len(grid))] for f in range(int(X.shape[0]))]
 
     def grid_predict_scores_folds(self, models, X):
-        """[k, G, n_va] binary log-odds margins (None for multiclass)."""
+        """``[k, G, n_va]`` binary log-odds margins, or ``[k, G, C, n_va]``
+        class log-posteriors past two classes (the class axis before the
+        rows, as ``models/linear.py::_fold_class_scores`` lays them)."""
         if not models or not models[0]:
             return None
         lt = jnp.stack([jnp.stack([jnp.asarray(m.log_theta, jnp.float32)
@@ -163,7 +166,8 @@ class OpNaiveBayes(Predictor):
         lp = jnp.stack([jnp.stack([jnp.asarray(m.log_prior, jnp.float32)
                                    for m in row]) for row in models])
         if lt.shape[-1] != 2:
-            return None
+            return jnp.einsum("knd,kgdc->kgcn", jnp.maximum(X, 0.0), lt) \
+                + lp[:, :, :, None]
         logits = jnp.einsum("knd,kgdc->kgnc", jnp.maximum(X, 0.0), lt) \
             + lp[:, :, None, :]
         return logits[..., 1] - logits[..., 0]
@@ -349,8 +353,9 @@ class OpMultilayerPerceptronClassifier(Predictor):
         return models
 
     def grid_predict_scores_folds(self, models, X):
-        """[k, G, n_va] binary margins via one stacked forward pass; None
-        when grid models have heterogeneous layer shapes or >2 classes."""
+        """``[k, G, n_va]`` binary margins via one stacked forward pass
+        (``[k, G, C, n_va]`` class logits past two classes); None when
+        grid models have heterogeneous layer shapes."""
         if not models or not models[0]:
             return None
         shapes = {tuple((tuple(W.shape), tuple(b.shape)) for W, b in m.params)
@@ -372,7 +377,7 @@ class OpMultilayerPerceptronClassifier(Predictor):
         z = jax.vmap(lambda p_row, Xk: jax.vmap(
             lambda p: fwd(p, Xk))(p_row))(stacked, X)  # [k, G, n, C]
         if z.shape[-1] != 2:
-            return None
+            return jnp.moveaxis(z, -1, 2)
         return z[..., 1] - z[..., 0]
 
     def grid_scores_folds_retained(self, X, y, w, grid, Xva,

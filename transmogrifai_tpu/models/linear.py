@@ -346,6 +346,16 @@ def _fold_scores(X, Wm, bm, va_idx):
     return jnp.take_along_axis(scores, va_idx[:, None, :], axis=2)
 
 
+@jax.jit
+def _fold_class_scores(X, Ws, bs, va_idx):
+    """``[k, G, C, n_va]``: ``_fold_scores`` past two classes, every
+    class's score of a lane. The class axis comes before the rows, which
+    stay on the TPU's lanes (a trailing axis of 7 classes pads to 128)."""
+    z = jnp.einsum("nd,kgdc->kgcn", X, Ws, precision=_X_PRECISION) \
+        + bs[:, :, :, None]
+    return jnp.take_along_axis(z, va_idx[:, None, None, :], axis=3)
+
+
 def _merge_grid_parts(parts, order):
     """Reassemble per-static-group stacked params ``[(Ws [k, g_i, d, C],
     bs [k, g_i, C]), ...]`` into grid order along the grid axis."""
@@ -591,8 +601,9 @@ class _LinearPredictor(Predictor):
     def _margin_params(self, Ws, bs):
         """``(Wm [k, G, d], bm [k, G])``: the one weight vector a lane's
         scalar score needs (the prediction, the hinge margin, or the
-        binary margin ``z1 - z0``), or None (multiclass: no scalar score).
-        Scoring with it makes no ``[.., classes, rows]`` logits."""
+        binary margin ``z1 - z0``), or None past two classes, where a lane
+        is scored on every class (``_fold_class_scores``). Scoring with it
+        makes no ``[.., classes, rows]`` logits."""
         if Ws.shape[-1] == 1:      # squared loss, margin-only (SVC)
             return Ws[..., 0], bs[..., 0]
         if Ws.shape[-1] == 2:      # binary margin
@@ -603,21 +614,26 @@ class _LinearPredictor(Predictor):
         """The selector's stacked unit without a copy of the matrix: every
         fold trains as a row weighting of ``batch.X``, every lane scores
         all of its rows in one product, and each fold's lanes are read at
-        that fold's validation rows."""
+        that fold's validation rows: ``[k, G, n_va]`` scalar scores, or
+        ``[k, G, C, n_va]`` class scores past two classes."""
         if not grid:
             return None, None
         Ws, bs = self._batch_params(
             batch, grid, self._grid_n_classes(batch.y, _n_classes))
         margin = self._margin_params(Ws, bs)
+        va_idx = jnp.asarray(batch.va_idx)
         if margin is None:
-            return None, None
-        return _fold_scores(batch.X, *margin,
-                            jnp.asarray(batch.va_idx)), (Ws, bs)
+            return _fold_class_scores(batch.X, Ws, bs, va_idx), (Ws, bs)
+        return _fold_scores(batch.X, *margin, va_idx), (Ws, bs)
 
     def fold_stack_bytes(self, batch, grid) -> float:
-        # no copy of the matrix: the lanes' per-row intermediates only
+        # no copy of the matrix: the lanes' per-row intermediates only,
+        # which past two classes are as wide as the classes (the logits,
+        # their log-softmax and gradient, the class scores)
+        classes = (batch.n_classes_hint() if self.loss_kind == "softmax"
+                   else 2)
         return (4.0 * batch.k * int(batch.X.shape[0]) * max(len(grid), 1)
-                * self.fold_stack_unit_width(grid))
+                * self.fold_stack_unit_width(grid) * max(classes, 2) / 2.0)
 
     def grid_fit_arrays_folds(self, X, y, w, grid):
         """``[k][G]`` fitted models whose weights stay device views of the
@@ -629,16 +645,18 @@ class _LinearPredictor(Predictor):
                  for j in range(len(grid))] for f in range(int(X.shape[0]))]
 
     def _scores_from_stacked(self, Ws, bs, Xva):
-        """[k, G, n_va] scores straight from stacked parameters."""
+        """``[k, G, n_va]`` scores straight from stacked parameters
+        (``[k, G, C, n_va]`` class scores past two classes)."""
         if self.loss_kind == "squared":
             return jnp.einsum("knd,kgd->kgn", Xva, Ws[..., 0]) \
                 + bs[..., 0][:, :, None]
+        if Ws.shape[-1] > 2:
+            return jnp.einsum("knd,kgdc->kgcn", Xva, Ws) \
+                + bs[:, :, :, None]
         z = jnp.einsum("knd,kgdc->kgnc", Xva, Ws) + bs[:, :, None, :]
         if z.shape[-1] == 1:       # margin-only (SVC)
             return z[..., 0]
-        if z.shape[-1] == 2:       # binary margin
-            return z[..., 1] - z[..., 0]
-        return None                # multiclass: no scalar score
+        return z[..., 1] - z[..., 0]   # binary margin
 
     def grid_scores_folds(self, X, y, w, grid, Xva, _n_classes=None):
         """Fused sweep unit: stacked parameters -> stacked scores with no
@@ -666,10 +684,7 @@ class _LinearPredictor(Predictor):
             return None, None
         Ws, bs = self._fold_stacked_params_gated(X, y, w, grid,
                                                  _n_classes=_n_classes)
-        scores = self._scores_from_stacked(Ws, bs, Xva)
-        if scores is None:
-            return None, None
-        return scores, (Ws, bs)
+        return self._scores_from_stacked(Ws, bs, Xva), (Ws, bs)
 
     # -- warm winner refit (round 9) -----------------------------------------
     def supports_warm_refit(self) -> bool:
@@ -710,14 +725,7 @@ class _LinearPredictor(Predictor):
                                   for m in row]) for row in models])
         b = jnp.stack([jnp.stack([jnp.asarray(m.intercept, jnp.float32)
                                   for m in row]) for row in models])
-        if self.loss_kind == "squared":
-            return jnp.einsum("knd,kgd->kgn", X, W) + b[:, :, None]
-        z = jnp.einsum("knd,kgdc->kgnc", X, W) + b[:, :, None, :]
-        if z.shape[-1] == 1:       # margin-only (SVC)
-            return z[..., 0]
-        if z.shape[-1] == 2:       # binary margin
-            return z[..., 1] - z[..., 0]
-        return None                # multiclass: no scalar score
+        return self._scores_from_stacked(W, b, X)
 
 
 class OpLogisticRegression(_LinearPredictor):

@@ -833,8 +833,16 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
                              max_hist_nodes=max_hist_nodes, hist=hist,
                              data_axis=data_axis)
 
-        feats, bins, leaves, gains, preds = jax.vmap(
-            grow_one, in_axes=(1, 1))(g, h)
+        if n_out == 1:  # the one-output programs as they always were
+            feats, bins, leaves, gains, preds = jax.vmap(
+                grow_one, in_axes=(1, 1))(g, h)
+        else:
+            # the K one-vs-all trees of a round share nothing but the rows:
+            # grown one after another inside the program (a ``vmap`` over
+            # them multiplies the grower's temporaries, and on the TPU its
+            # compile time, by K, and one chip runs them in turn anyway)
+            feats, bins, leaves, gains, preds = jax.lax.map(
+                lambda gh: grow_one(*gh), (g.T, h.T))
         # feats/bins: tuples of [n_out, 2^level]; leaves [n_out, 2^depth];
         # preds [n_out, n] come from the grower's final node assignment
         # (no re-descent)
@@ -884,15 +892,17 @@ def train_ensemble_sharded(ctx, Xb, y, w, **kw):
 
 @functools.partial(jax.jit, static_argnames=(
     "n_rounds", "max_depth", "n_bins", "loss", "subsample",
-    "colsample", "bootstrap", "seed", "hist", "forest_margin"))
+    "colsample", "bootstrap", "seed", "hist", "forest_margin", "n_out"))
 def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
                         n_rounds: int, max_depth: int, n_bins: int,
                         loss: str, subsample, colsample,
                         bootstrap: bool, seed: int, hist: str,
-                        forest_margin: bool):
+                        forest_margin: bool, n_out: int = 1):
     """ONE compiled program for a whole (family, depth-group) of the CV
     sweep: train all ``k`` folds x ``L`` same-shape grid lanes and score
-    their validation folds, returning ``[k, L, n_va]`` scores.
+    their validation folds, returning ``[k, L, n_va]`` scores, or with
+    ``n_out`` one-vs-all outputs a lane ``[k, L, n_out, n_va]`` class
+    scores (the class axis before the rows, which stay on the TPU's lanes).
 
     ``Xb/Xva``: ``[k, n, d]`` stacked int bin codes (one fold gather of
     the dataset-level ``fold_sweep_plan`` codes — no re-binning; the
@@ -910,21 +920,25 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
     node axis via the ``custom_vmap`` rule in ``ops/histograms.py`` —
     one flat scatter per level for the whole (fold x lane x class)
     batch. ``forest_margin`` re-centers forest-classifier probabilities
-    at 0, matching ``grid_predict_scores``.
+    at 0, matching ``grid_predict_scores`` (past one output the class
+    votes stay in ``[0, 1]``: their argmax is the prediction).
     """
 
     def fold_fn(Xb_k, y_k, w_k, Xva_k, base_k):
         def lane_fn(lr_i, lam_i, gam_i, mcw_i):
             trees, _gains = train_ensemble(
                 Xb_k, y_k, w_k, n_rounds=n_rounds, max_depth=max_depth,
-                n_bins=n_bins, n_out=1, loss=loss, learning_rate=lr_i,
+                n_bins=n_bins, n_out=n_out, loss=loss, learning_rate=lr_i,
                 reg_lambda=lam_i, gamma=gam_i, min_child_weight=mcw_i,
                 subsample=subsample, colsample=colsample,
                 base_score=base_k, bootstrap=bootstrap, seed=seed,
                 hist=hist)
-            out = predict_ensemble(Xva_k, trees, n_out=1,
+            out = predict_ensemble(Xva_k, trees, n_out=n_out,
                                    learning_rate=lr_i, base_score=base_k,
                                    bootstrap=bootstrap)
+            if n_out > 1:  # class scores, rows minor
+                s = out.T  # [n_out, n_va]
+                return jnp.clip(s, 0.0, 1.0) if forest_margin else s
             s = out[:, 0]
             if forest_margin:
                 s = jnp.clip(s, 0.0, 1.0) - 0.5  # margin at 0
@@ -1152,10 +1166,11 @@ class _TreePredictor(Predictor):
         ``"mean"`` = fold label mean (squared losses, forests included —
         forests trained on a mean base fit residuals whose base is never
         re-added at predict, the established semantics), ``"logodds"`` =
-        log-odds of the fold's positive rate, ``"zero"`` = 0."""
+        log-odds of the fold's positive rate, ``"zero"`` = 0 (forest
+        classifiers, and one-vs-all boosting past two classes)."""
         if loss == "squared":
             return "mean"
-        return "zero" if self.bootstrap else "logodds"
+        return "zero" if self.bootstrap or loss == "softmax" else "logodds"
 
     def _edges_of(self, X, max_bins: int):
         """Quantile edges; device path for device-resident X (no host pull),
@@ -1296,6 +1311,8 @@ class _TreePredictor(Predictor):
                or m.trees[2].shape != m0.trees[2].shape for m in models):
             return None
         if m0.n_out != 1:
+            # the per-fold loop's batched metric takes a scalar score; the
+            # fold-stacked path scores every class (``tree_stack_scores``)
             return None
         edges0 = m0.bin_edges
         same_edges = all(np.array_equal(m.bin_edges, edges0) for m in models)
@@ -1346,34 +1363,32 @@ class _TreePredictor(Predictor):
             g["params"].append(p)
         return list(groups.values())
 
-    def tree_stack_scalar_lnb(self, y, _stats=None):
-        """``(loss, n_out, base)`` when the family has a scalar stacked
-        score (binary margin / regression prediction), else None —
-        multiclass has no batched scalar and keeps the per-fold loop.
-        One blocking device sync (max of y) per FAMILY, elided by the
-        selector's once-per-sweep ``_stats`` hint on the one-sync
-        dispatch path (signature-gated: a subclass overriding
+    def tree_stack_lnb(self, y, _stats=None):
+        """``(loss, n_out, base)`` of the family's stacked unit:
+        ``_loss_and_nout`` with the selector's once-per-sweep ``_stats``
+        hint, which elides the ONE blocking device sync (max of y) a
+        family would pay (signature-gated: a subclass overriding
         ``_loss_and_nout`` with the old arity keeps its own probe)."""
         import inspect
         if _stats is not None and "_stats" in \
                 inspect.signature(self._loss_and_nout).parameters:
-            lnb = self._loss_and_nout(y, _stats=_stats)
-        else:
-            lnb = self._loss_and_nout(y)
-        return lnb if lnb[1] == 1 else None
+            return self._loss_and_nout(y, _stats=_stats)
+        return self._loss_and_nout(y)
 
     def tree_stack_bytes(self, k: int, n_tr: int, n_va: int, d: int,
-                         group: dict) -> tuple[float, float]:
+                         group: dict, n_out: int = 1
+                         ) -> tuple[float, float]:
         """``(shared_bytes, per_lane_bytes)`` HBM estimate for one stacked
         depth-group — the tree-specific extension of the selector's
         ``fold_stack_unit_width`` guard. Shared: the stacked int8/int32
         code gathers plus labels/weights. Per lane (times k folds): the
-        boosting margins/grad/hess/row-weight residency, both levels'
-        (g, h) node-stat histograms, the sorted engine's materialized
-        one-hot chunk when that engine is selected, and the ``[k, L,
-        n_va]`` score slab. The selector divides the budget by this to
-        split a group into lane chunks instead of falling all the way
-        back to the per-fold loop."""
+        boosting margins/grad/hess of each of the ``n_out`` outputs and the
+        row-weight residency, both levels' (g, h) node-stat histograms and
+        the sorted engine's materialized one-hot chunk when that engine is
+        selected (once: the one-vs-all trees of a round grow one after
+        another), and the ``[k, L, n_out, n_va]`` score slab. The selector
+        divides the budget by this to split a group into lane chunks
+        instead of falling all the way back to the per-fold loop."""
         B = int(group["max_bins"])
         depth = int(group["max_depth"])
         csize = 1 if B <= 127 else 4
@@ -1383,7 +1398,9 @@ class _TreePredictor(Predictor):
         hist_bytes = 16.0 * nodes * d * B  # (g, h) x (level, prev) f32
         if _hist_engine(n_tr, stacked=True) == "sorted":
             hist_bytes += min(float(_SORT_OH_BUDGET), 4.0 * n_tr * d * B)
-        per_lane = float(k) * (28.0 * n_tr + hist_bytes + 8.0 * n_va)
+        outs = max(int(n_out), 1)
+        per_lane = float(k) * ((16.0 + 12.0 * outs) * n_tr + hist_bytes
+                               + 8.0 * n_va * outs)
         return shared, per_lane
 
     def tree_stack_fold_bases(self, fold_means, loss: str) -> np.ndarray:
@@ -1407,17 +1424,16 @@ class _TreePredictor(Predictor):
     def tree_stack_scores(self, Xb, y, w, Xva, lane_params, lnb,
                           fold_means=None):
         """``[k, L, n_va]`` validation scores for one (family,
-        depth-group): the selector fast path's fused train+score unit.
-        ``Xb/Xva`` are the stacked fold gathers of the dataset-level bin
-        codes, ``lane_params`` the merged param dicts of this chunk's
-        lanes (same static shape — ``tree_stack_groups`` guarantees it),
-        ``lnb`` the family-level ``tree_stack_scalar_lnb``, and
-        ``fold_means`` the folds' label means (the selector pulls them
-        once per sweep; computed here — one sync — when absent). Returns
-        None when no scalar stacked score exists (multiclass)."""
+        depth-group), or ``[k, L, n_out, n_va]`` class scores where the
+        family grows ``n_out`` one-vs-all trees a member: the selector
+        fast path's fused train+score unit. ``Xb/Xva`` are the stacked
+        fold gathers of the dataset-level bin codes, ``lane_params`` the
+        merged param dicts of this chunk's lanes (same static shape —
+        ``tree_stack_groups`` guarantees it), ``lnb`` the family-level
+        ``tree_stack_lnb``, and ``fold_means`` the folds' label means (the
+        selector pulls them once per sweep; computed here — one sync —
+        when absent)."""
         loss, n_out, _base = lnb
-        if n_out != 1 or not lane_params:
-            return None
         p0 = lane_params[0]
         k, n_tr, d = (int(Xb.shape[0]), int(Xb.shape[1]), int(Xb.shape[2]))
         L = len(lane_params)
@@ -1447,14 +1463,15 @@ class _TreePredictor(Predictor):
             per_tree = sum(5.0 * n_tr * d + 4.0 * n_tr
                            + 12.0 * (2 ** lv) * d * B
                            for lv in range(depth))
-        flops.add("tree", k * L * rounds * per_tree)
+        flops.add("tree", k * L * rounds * n_out * per_tree)
         return train_score_stacked(
             Xb, y, w, Xva, bases, lrs, lams, gams, mcws,
             n_rounds=rounds, max_depth=depth, n_bins=B, loss=loss,
             subsample=1.0 if self.bootstrap else float(p0["subsample"]),
             colsample=float(p0["colsample"]), bootstrap=self.bootstrap,
             seed=int(p0["seed"]), hist=hist_mode,
-            forest_margin=self.bootstrap and self.kind.endswith("classifier"))
+            forest_margin=self.bootstrap and self.kind.endswith("classifier"),
+            n_out=n_out)
 
     # -- winner refit (round 9) ----------------------------------------------
     def refit_winner(self, X, y, w, params, *, warm=None, lane=None,

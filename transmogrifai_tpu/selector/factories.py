@@ -60,7 +60,9 @@ def _default_binary_candidates():
 
 
 def _default_multi_candidates():
-    # reference multiclass defaults: LR + RF + DT + NB
+    # the reference's multiclass defaults (modelTypesToUse) are LR + RF;
+    # naive Bayes rides along here as a closed-form third family. All
+    # three take the fold-stacked sweep with a class axis in their scores
     return [(OpLogisticRegression(), _lr_grid()),
             (OpRandomForestClassifier(), [
                 {"num_trees": 50, "max_depth": d} for d in (6, 12)]),

@@ -25,9 +25,11 @@ entire sweep costs ONE blocking host sync (asserted end-to-end via
 machinery: a G=1 full-data program warm-started from the retained stacked
 fold parameters (linear/GLM/MLP; trees reuse the dataset-level bin codes
 bitwise) with donated init buffers, checkpointed under a shape-keyed
-refit entry. Custom subclasses that override the per-fold trainers,
-multiclass scoring, and batches that would not fit HBM at even one lane
-fall back to a sequential per-fold loop (compile once, run k times). No
+refit entry. A family of several outputs (a softmax, K one-vs-all trees a
+member) rides the same path with a class axis in its scores. Custom
+subclasses that override the per-fold trainers, and batches that would not
+fit HBM at even one lane, fall back to a sequential per-fold loop (compile
+once, run k times). No
 thread pool, no executor dispatch. See PERF.md "Sweep execution model"
 and docs/SWEEP.md.
 """
@@ -198,8 +200,9 @@ class ModelSelectorSummary:
 
 class _FoldStackFallback(Exception):
     """Internal: a family opted into the stacked path but produced no
-    batched fold scores (e.g. multiclass margins) — reroute it through the
-    per-fold loop instead of recording a failure."""
+    batched fold scores (``sweep_folds`` returned None: grid models of
+    unlike shapes, an override without a stacked form) — reroute it
+    through the per-fold loop instead of recording a failure."""
 
 
 def _jsonable(x: Any) -> Any:
@@ -519,13 +522,17 @@ class ModelSelector(Estimator):
         A family leaves that path for the per-fold loop
         (``_family_fold_loop``) only for a reason the program observes: it
         has no stacked form (``supports_fold_stacking`` /
-        ``supports_tree_stacking``, ``_FoldStackFallback``, multiclass
-        trees), the evaluator has no device fold metric
-        (``metric_batch_scores_folds_device``), its unit does not fit the
-        budget (``fold_stack_bytes`` / ``tree_stack_bytes`` against
-        ``_stacked_hbm_budget``), or the OOM ladder sent it there. Work
-        units shard 2-D over the mesh (rows on "data", fold/grid
-        candidates on "model").
+        ``supports_tree_stacking``, ``_FoldStackFallback``), the evaluator
+        has no device fold metric (``metric_batch_scores_folds_device``),
+        its unit does not fit the budget (``fold_stack_bytes`` /
+        ``tree_stack_bytes`` against ``_stacked_hbm_budget``), or the OOM
+        ladder sent it there; each such unit counts once in
+        ``sweepLoopFallbacks``, under its reason. The number of outputs is
+        no such reason: a softmax family hands on ``[k, G, K, n_va]`` class
+        scores and a tree family of K one-vs-all trees a member
+        ``[k, L, K, n_va]``, which the multiclass evaluator's device metric
+        reduces to the same ``[k, G]`` futures. Work units shard 2-D over
+        the mesh (rows on "data", fold/grid candidates on "model").
 
         Semantics preserved exactly from the per-fold loop: failure
         isolation per family (dispatch-time errors isolate immediately;
@@ -596,7 +603,9 @@ class ModelSelector(Estimator):
                 # through the retry, and a winner refit warm-started
                 # from them could materialize a poisoned buffer
                 refit_state.get("warm", {}).pop(ci, None)
+                from transmogrifai_tpu.utils.profiling import sweep_counters
                 from transmogrifai_tpu.utils.tracing import span
+                sweep_counters.count_run(loop_fallback="oom")
                 with span("resource.degrade", site="sweep.settle",
                           family=self._family_name(ci), rung="fold_loop"):
                     self._family_fold_loop(
@@ -639,6 +648,10 @@ class ModelSelector(Estimator):
         from transmogrifai_tpu.utils.tracing import span
         batch = None  # built on the first stacked-capable family
         tree_stats = None
+        # whether the evaluator's fold metric reduces class scores
+        # ([k, G, K, n_va]) or a scalar score a row only
+        class_axis = bool(getattr(self.evaluators[0], "scores_class_axis",
+                                  False))
         with span("sweep.dispatch", families=len(self.models_and_grids)):
             for ci, (est, grid) in enumerate(self.models_and_grids):
                 fname = self._family_name(ci)
@@ -682,27 +695,37 @@ class ModelSelector(Estimator):
                     continue
                 use_stacked = (fold_metrics_dev is not None
                                and supports_fold_stacking(est))
+                # why the family leaves the stacked path, if it does
+                # (``sweepLoopFallbacks``)
+                reason = ("no_device_metric" if fold_metrics_dev is None
+                          else "no_stacked_form")
                 if use_stacked and batch is None:
                     # the fold plan over the ONE resident training matrix:
                     # a family trains its folds as row weights over it, or
                     # asks it for gathered folds (made once, shared, counted
                     # in sweepOperandBytes)
                     batch = FoldBatch(Xt, yt, wt, tr_idx, va_idx)
-                use_stacked = use_stacked and self._stacked_fits_memory(
-                    batch, est, grid)
                 if use_stacked:
                     # the ONE class-count pull every softmax/NB/MLP family
-                    # would otherwise block on at dispatch, and the folds'
-                    # validation labels: the batch makes each once
+                    # would otherwise block on at dispatch (their guard
+                    # reads it too), and the folds' validation labels: the
+                    # batch makes each once
                     with compile_telemetry.building("sweep.operands"):
                         n_classes_hint = batch.n_classes_hint()
                         yva_s = batch.validation_labels()
+                    use_stacked = self._stacked_fits_memory(batch, est, grid)
+                    reason = "budget"
+                    # outputs a lane: the label's classes where the fold
+                    # metric reduces a class axis, else one scalar score
+                    n_out = (n_classes_hint
+                             if class_axis and n_classes_hint > 2 else 1)
+                if use_stacked:
                     try:
                         with compile_telemetry.building(
                                     f"sweep.family:{fname}", family=fname), \
                                 span("sweep.family", family=fname,
                                      mode="fold_stacked", folds=k,
-                                     grid=len(grid)):
+                                     grid=len(grid), nOut=n_out):
                             # fused unit: stacked train + stacked scores in
                             # one call (no per-(fold, grid) model
                             # materialization — the sweep discards models;
@@ -713,6 +736,10 @@ class ModelSelector(Estimator):
                                 _n_classes=n_classes_hint, site="sweep.fit")
                             if scores is None:
                                 raise _FoldStackFallback()
+                            # a class axis before the rows where the
+                            # family scores several outputs
+                            if scores.ndim == 4 and not class_axis:
+                                raise _FoldStackFallback()
                             if warm is not None and est.supports_warm_refit():
                                 refit_state["warm"][ci] = warm
                             # the family's [k, G] metric batch: a device
@@ -721,6 +748,8 @@ class ModelSelector(Estimator):
                                 yva_s, scores, self.validation_metric)
                     except _FoldStackFallback:
                         use_stacked = False  # no stacked axis: fold loop
+                        reason = ("no_scores" if scores is None
+                                  else "no_device_metric")
                     except Exception as e:  # noqa: BLE001 — isolation by design
                         from transmogrifai_tpu.utils.faults import (
                             FaultHarnessError,
@@ -743,6 +772,7 @@ class ModelSelector(Estimator):
                                 grid=len(grid), rows=int(n_tr),
                                 cols=int(d))
                             use_stacked = False
+                            reason = "oom"
                         else:
                             failures.append({
                                 "modelName": fname,
@@ -759,7 +789,8 @@ class ModelSelector(Estimator):
                             "chunks": [(0, len(grid), vals_kg)],
                             "launched": [(time.time(), {
                                 "family": fname, "unitKind": "stacked",
-                                "lanes": len(grid), "chunk": 0})]})
+                                "lanes": len(grid), "chunk": 0,
+                                "nOut": n_out})]})
                         sweep_counters.count_run(async_families=1)
                         continue
                 if tgroups and fold_metrics_dev is not None:
@@ -781,18 +812,23 @@ class ModelSelector(Estimator):
                     # "sweep.operands"; its programs open their own sites
                     with compile_telemetry.building("sweep.operands",
                                                     family=fname):
-                        handled = self._family_tree_stacked(
+                        # None: handled; else why the family takes the loop
+                        reason = self._family_tree_stacked(
                             ci, est, grid, tgroups, Xt, yt, wt, tr_idx,
                             va_idx, done, deadline, per_candidate_scores,
                             failures, tree_cache, pending, fold_metrics_dev,
-                            tree_stats=tree_stats, refit_state=refit_state)
-                    if handled:
+                            tree_stats=tree_stats, refit_state=refit_state,
+                            class_axis=class_axis)
+                    if reason is None:
                         continue
                 # ---- per-fold fallback loop for this family ----------------
-                self._family_fold_loop(
-                    ci, est, grid, Xt, yt, wt, tr_idx, va_idx, done,
-                    deadline, per_candidate_scores, failures,
-                    refit_state=refit_state, pending=pending)
+                sweep_counters.count_run(loop_fallback=reason)
+                with span("sweep.family", family=fname, mode="fold_loop",
+                          folds=k, grid=len(grid), reason=reason):
+                    self._family_fold_loop(
+                        ci, est, grid, Xt, yt, wt, tr_idx, va_idx, done,
+                        deadline, per_candidate_scores, failures,
+                        refit_state=refit_state, pending=pending)
 
     def _settle(self, pending, done, per_candidate_scores,
                 failures, oom_retry: Optional[list] = None) -> None:
@@ -954,48 +990,48 @@ class ModelSelector(Estimator):
                              per_candidate_scores, failures,
                              cache: dict, pending: list, fold_metrics_dev,
                              *, tree_stats=None,
-                             refit_state: Optional[dict] = None) -> bool:
+                             refit_state: Optional[dict] = None,
+                             class_axis: bool = False) -> Optional[str]:
         """One tree family's fold x grid-stacked sweep: every depth-group
         (grid lanes sharing one compiled-program shape) trains all
         k folds x L lanes as ONE compiled program over the stacked gather
         of the dataset-level bin codes (``fold_sweep_plan`` — no
         re-binning), scores its validation folds batched, and queues the
         group's ``[k, L]`` metric block on ``pending`` as device futures
-        for the sweep's one settle. The HBM guard (``tree_stack_bytes``)
+        for the sweep's one settle. A family of ``n_out`` one-vs-all trees
+        a member trains and scores them in the same program (class scores
+        ``[k, L, n_out, n_va]``). The HBM guard (``tree_stack_bytes``)
         splits a too-wide group into lane chunks (one dispatch each)
-        instead of falling all the way back. Returns True when the family
+        instead of falling all the way back. Returns None when the family
         was fully handled (dispatched, group-resumed, failed-and-isolated,
-        or deadline-skipped); False routes it to the per-fold loop
-        untouched (multiclass, or a group where not even one lane fits
-        the budget — sub-grid loop units can't be expressed, so the loop
-        keeps the whole family)."""
-        import inspect
+        or deadline-skipped); else the reason that routes it to the
+        per-fold loop untouched (``budget``: a group where not even one
+        lane fits — sub-grid loop units can't be expressed, so the loop
+        keeps the whole family; ``no_device_metric``: several outputs and
+        an evaluator whose fold metric takes no class axis; ``oom``)."""
         from transmogrifai_tpu.parallel import mesh as pmesh
         from transmogrifai_tpu.utils.profiling import sweep_counters
         from transmogrifai_tpu.utils.retry import with_device_retry
         from transmogrifai_tpu.utils.tracing import span
         fname = self._family_name(ci)
         # the selector's once-per-sweep label stats elide what was ONE
-        # blocking family-level sync here (signature-gated: a subclass
-        # overriding the lnb probe with the old arity keeps its own pull)
-        if tree_stats is not None and "_stats" in inspect.signature(
-                est.tree_stack_scalar_lnb).parameters:
-            lnb = est.tree_stack_scalar_lnb(yt, _stats=tree_stats)
-        else:
-            lnb = est.tree_stack_scalar_lnb(yt)
-        if lnb is None:
-            return False  # multiclass: no batched scalar score
+        # blocking family-level sync here
+        lnb = est.tree_stack_lnb(yt, _stats=tree_stats)
+        n_out = int(lnb[1])
+        if n_out > 1 and not class_axis:
+            return "no_device_metric"
         k, n_tr = tr_idx.shape
         n_va = int(va_idx.shape[1])
         d = int(Xt.shape[1])
         budget = self._stacked_hbm_budget()
         chunk_sizes = []
         for g in tgroups:
-            shared, per_lane = est.tree_stack_bytes(k, n_tr, n_va, d, g)
+            shared, per_lane = est.tree_stack_bytes(k, n_tr, n_va, d, g,
+                                                    n_out)
             max_lanes = (int((budget - shared) // per_lane)
                          if budget > shared and per_lane > 0 else 0)
             if max_lanes < 1:
-                return False  # not even one lane fits: loop (peak 1/k)
+                return "budget"  # not even one lane fits: loop (peak 1/k)
             chunk_sizes.append(max_lanes)
         jtr = jnp.asarray(tr_idx)
         jva = jnp.asarray(va_idx)
@@ -1042,7 +1078,7 @@ class ModelSelector(Estimator):
             if self._deadline_skip(ci, grid, deadline,
                                    per_candidate_scores, failures,
                                    pending, pop=True):
-                return True
+                return None
             Xb_tr, ytr_s, wtr_s, Xb_va = cache[g["max_bins"]]
             if "fold_means" not in cache:
                 # the folds' label means feed the host-computed per-fold
@@ -1069,7 +1105,7 @@ class ModelSelector(Estimator):
                             with span("sweep.tree_group", family=fname,
                                       mode="tree_stacked", k=int(k),
                                       lanes=len(chunk), depth=int(depth),
-                                      group=gi):
+                                      group=gi, nOut=n_out):
                                 # fused unit: stacked train + stacked
                                 # scores in one compiled program (no
                                 # per-(fold, lane) model materialization
@@ -1112,7 +1148,8 @@ class ModelSelector(Estimator):
                         launched.append((time.time(), {
                             "family": fname, "unitKind": "tree",
                             "depth": int(depth), "lanes": len(chunk),
-                            "chunk": len(launched), "group": gi}))
+                            "chunk": len(launched), "group": gi,
+                            "nOut": n_out}))
                         sweep_counters.count(
                             fname, dispatches=1, lane_chunks=1,
                             mode="tree_stacked")
@@ -1133,18 +1170,18 @@ class ModelSelector(Estimator):
                                   error=e, family=fname, group=gi,
                                   depth=int(depth))
                     pending[:] = [p for p in pending if p["ci"] != ci]
-                    return False
+                    return "oom"
                 failures.append({
                     "modelName": fname,
                     "reason": f"tree stacked sweep (group {gi}): "
                               f"{type(e).__name__}: {str(e)[:300]}"})
-                return True
+                return None
             if not any(p["ci"] == ci for p in pending):
                 sweep_counters.count_run(async_families=1)
             pending.append({"kind": "tree", "ci": ci, "fname": fname,
                             "key": tk, "k": k, "lanes": lanes,
                             "chunks": chunks, "launched": launched})
-        return True
+        return None
 
     def _deadline_skip(self, ci, grid, deadline, per_candidate_scores,
                        failures, pending=(), *, pop: bool) -> bool:

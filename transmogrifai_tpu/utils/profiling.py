@@ -470,6 +470,9 @@ class SweepCounters:
         #: gather (a frame wider, or a tree deeper, than its
         #: compare-and-select lookups take)
         self.tree_gather_walks = 0
+        #: families (or tree lane groups) that left the fold-stacked path
+        #: for the per-fold loop, by the reason the selector observed
+        self.loop_fallbacks: dict = {}
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -485,6 +488,7 @@ class SweepCounters:
         self.fe_hash_fallbacks = 0
         self.fe_upload_bytes = 0
         self.tree_gather_walks = 0
+        self.loop_fallbacks = {}
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -511,11 +515,13 @@ class SweepCounters:
                   refit_warm_starts: int = 0, operand_bytes: int = 0,
                   fe_distinct_values: int = 0, fe_hash_fallbacks: int = 0,
                   fe_upload_bytes: int = 0,
-                  tree_gather_walks: int = 0) -> None:
+                  tree_gather_walks: int = 0,
+                  loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
         overlapped families, warm-started refits, operand copies, the
-        host string work that fed the sweep, and tree walks traced with a
-        per-row gather."""
+        host string work that fed the sweep, tree walks traced with a
+        per-row gather, and (``loop_fallback``: the reason) one unit that
+        left the stacked path for the per-fold loop."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
@@ -524,6 +530,9 @@ class SweepCounters:
         self.fe_hash_fallbacks += int(fe_hash_fallbacks)
         self.fe_upload_bytes += int(fe_upload_bytes)
         self.tree_gather_walks += int(tree_gather_walks)
+        if loop_fallback is not None:
+            self.loop_fallbacks[loop_fallback] = \
+                self.loop_fallbacks.get(loop_fallback, 0) + 1
 
     def to_json(self) -> dict:
         return {name: {"mode": fc.mode, "compiles": self.compiles(name),
@@ -543,7 +552,9 @@ class SweepCounters:
                 "feDistinctValues": self.fe_distinct_values,
                 "feHashPerRowFallbacks": self.fe_hash_fallbacks,
                 "feUploadBytes": self.fe_upload_bytes,
-                "treeGatherWalks": self.tree_gather_walks}
+                "treeGatherWalks": self.tree_gather_walks,
+                "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
+                "sweepLoopFallbackReasons": dict(self.loop_fallbacks)}
 
 
 sweep_counters = SweepCounters()
