@@ -436,6 +436,149 @@ def test_sorted_level_moves_each_row_once():
     assert 1 <= g4 - g3 <= 2 and s4 - s3 == 1, ((g3, s3), (g4, s4))
 
 
+def _long_sums(jaxpr, at_least):
+    """The prefix-sum equations (``cumsum``, ``reduce_window*``) of a
+    jaxpr, nested ones included, whose operand holds ``at_least``
+    elements or more, as (dtype, ndim, size)."""
+    out = []
+    for eqn in _eqns(jaxpr):
+        name = eqn.primitive.name
+        if name.startswith(("cumsum", "reduce_window")):
+            aval = eqn.invars[0].aval
+            if aval.size >= at_least:
+                out.append((str(aval.dtype), aval.ndim, aval.size))
+    return out
+
+
+def test_sorted_level_has_no_prefix_sum_over_the_blocks():
+    """Beside the row-movement counter: no level of the sorted grower
+    holds a prefix sum over the per-block partial histograms (on a TPU a
+    ``reduce-window`` over the whole block axis, a third of a tree
+    sweep's device time). Every ``cumsum`` / ``reduce_window`` equation
+    over ``n`` elements or more is shorter than the smallest level's
+    ``blocks x 2 x d x B`` partials: the partition's sum of ``int32``
+    positions in ``_long_cumsum``'s blocked form, ONE a level (the
+    layout's sums are ``[N]`` long), and the leaves' two sums of per-row
+    floats, whatever the depth."""
+    import jax
+    from transmogrifai_tpu.models.trees import _SORT_BLOCK, _grow_tree_sorted
+    n, d, B = 20_000, 28, 64
+    Xb, grad, hess = _exact_sum_rows(n, d, B, seed=5)
+
+    def sums(depth):
+        jaxpr = jax.make_jaxpr(lambda X, g, h: _grow_tree_sorted(
+            X, g, h, jnp.ones(d, jnp.float32), max_depth=depth, n_bins=B,
+            reg_lambda=jnp.float32(1.0), gamma=jnp.float32(0.0),
+            min_child_weight=jnp.float32(1.0)))(Xb, grad, hess)
+        return _long_sums(jaxpr.jaxpr, n)
+
+    s3, s4 = sums(3), sums(4)
+    partials = (n // _SORT_BLOCK) * 2 * d * B   # the shortest block axis
+    assert all(size < partials for _, _, size in s4), s4
+    ints = [s for s in s4 if s[0] == "int32"]
+    assert len(ints) == 4, s4                  # one a level
+    # the floats are the leaves' two, whatever the depth
+    assert len(s4) - len(ints) == len(s3) - 3 == 2, (s3, s4)
+
+
+def _node_sums64(Xp, gp, hp, counts, layout, B):
+    """[N, d, B] float64 histograms of the padded slots, node by node: the
+    plain sums ``_sorted_hist`` has to give."""
+    N, d = len(counts), Xp.shape[1]
+    pstarts, pends = np.asarray(layout.pstarts), np.asarray(layout.pends)
+    want = np.zeros((2, N, d, B))
+    for node in range(N):
+        rows = slice(pstarts[node], pends[node])
+        for s, v in enumerate((gp, hp)):
+            for f in range(d):
+                want[s, node, f] = np.bincount(
+                    Xp[rows, f], weights=v[rows].astype(np.float64),
+                    minlength=B)
+    return want
+
+
+#: (counts in blocks a node, what the case is for); C is 8 and the group
+#: width the module's own, so ``nb = n / 8 + N`` blocks
+_HIST_LAYOUTS = {
+    # 5 nodes in under one group of blocks
+    "one_group": [3, 40, 1, 17, 20],
+    # a block axis that is no multiple of the group width
+    "ragged_groups": [130, 7, 150, 1, 61],
+    # a node over three and more groups, and one that starts at block 0
+    "spans_groups": [420, 3, 300, 2],
+    # empty nodes first, last and in a run; a node that ends a group
+    "empty_nodes": [0, 0, 128, 0, 0, 0, 90, 140, 0],
+}
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("d,exact", [(7, True), (28, False), (54, True)])
+@pytest.mark.parametrize("case", list(_HIST_LAYOUTS))
+def test_sorted_hist_node_totals(case, d, exact, stacked):
+    """``_sorted_hist``'s per-node totals against float64 segment sums of
+    the same padded slots: every layout of ``_HIST_LAYOUTS``, narrow and
+    wide rows, plain and under the two-axis ``vmap`` of the stacked sweep
+    (folds whose rows differ, lanes whose (g, h) differ). With operands
+    whose sums are exact in float32 (``_exact_sum_rows``) the result is
+    the plain sum to the bit, in whatever order the blocks were added."""
+    import jax
+    from transmogrifai_tpu.models.trees import (
+        _HIST_GROUP, _sorted_hist, _sorted_layout)
+    C, B = 8, 16
+    blocks = np.asarray(_HIST_LAYOUTS[case])
+    rng = np.random.default_rng(len(case) + d)
+    # rows a node: its blocks' slots, the last block part filled
+    counts = np.where(blocks > 0, blocks * C - rng.integers(0, C, blocks.size),
+                      0).astype(np.int32)
+    n = int(counts.sum())
+    layout = _sorted_layout(jnp.asarray(counts), n, C)
+    nb = layout.valid.shape[0]
+    assert (nb <= _HIST_GROUP) == (case == "one_group")
+    assert nb % _HIST_GROUP != 0
+    if case == "spans_groups":
+        assert blocks.max() > 3 * _HIST_GROUP
+
+    def operands(seed):
+        Xb, g, h = _exact_sum_rows(nb * C, d, B, seed=seed)
+        if not exact:
+            r = np.random.default_rng(seed)
+            g = jnp.asarray(r.normal(size=nb * C), jnp.float32)
+            h = jnp.asarray(r.uniform(size=nb * C), jnp.float32)
+        vf = layout.valid.reshape(-1).astype(jnp.float32)
+        return Xb.astype(jnp.int8), g * vf, h * vf
+
+    def hist(Xp, gp, hp):
+        return _sorted_hist(Xp, gp, hp, layout, n_bins=B, C=C,
+                            acc_dtype=jnp.float32)
+
+    if stacked:
+        folds = [operands(31), operands(32)]
+        lanes = [1.0, -0.5]       # exact scalings: a lane's own (g, h)
+        Xp = jnp.stack([f[0] for f in folds])
+        gp = jnp.stack([jnp.stack([f[1] * s for s in lanes]) for f in folds])
+        hp = jnp.stack([jnp.stack([f[2] * s for s in lanes]) for f in folds])
+        got = jax.jit(jax.vmap(jax.vmap(hist, in_axes=(None, 0, 0))))(
+            Xp, gp, hp)
+        cells = [(np.asarray(got[0][i, j]), np.asarray(got[1][i, j]),
+                  np.asarray(Xp[i]), np.asarray(gp[i, j]),
+                  np.asarray(hp[i, j]))
+                 for i in range(2) for j in range(2)]
+    else:
+        Xp, gp, hp = operands(33)
+        got = hist(Xp, gp, hp)
+        cells = [(np.asarray(got[0]), np.asarray(got[1]), np.asarray(Xp),
+                  np.asarray(gp), np.asarray(hp))]
+    for hg, hh, Xp_, gp_, hp_ in cells:
+        assert hg.shape == hh.shape == (len(counts), d, B)
+        want = _node_sums64(Xp_, gp_, hp_, counts, layout, B)
+        for have, w64 in ((hg, want[0]), (hh, want[1])):
+            if exact:
+                np.testing.assert_array_equal(have, w64.astype(np.float32))
+            else:
+                np.testing.assert_allclose(have, w64, rtol=0, atol=2e-4)
+        assert not hg[counts == 0].any() and not hh[counts == 0].any()
+
+
 def test_train_ensemble_sorted_multiclass_parity():
     """hist='sorted' must thread through the scanned ensemble under the
     multiclass vmap (per-class independent routing) and bootstrap."""
