@@ -15,6 +15,9 @@ Legs: ``titanic`` (mixed schema, committed fixture), ``higgs`` (bench.py's own
 ``run_pipeline``: 28 numeric columns, the un-cut default binary zoo, 3 folds),
 ``multiclass`` (a seven-class label over the same 28 columns through the
 multiclass selector: no unit leaves the stacked sweep, no tree walk gathers),
+``free_text`` (100,000 generated reviews, title + text, through
+``transmogrify`` at its defaults and the linear zoo: one native pass a column,
+the vector filled on the device and equal to the plain reference's to the bit),
 ``serve`` (the higgs winner behind a real localhost endpoint, JSON + binary
 frames against the row-path oracle), ``kernels`` (each Pallas kernel through
 its public stage, compiled, against its XLA twin), ``mesh`` (the higgs leg
@@ -46,7 +49,8 @@ import warnings
 from unittest import mock
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-LEGS = ("titanic", "higgs", "multiclass", "serve", "kernels", "mesh")
+LEGS = ("titanic", "higgs", "multiclass", "free_text", "serve", "kernels",
+        "mesh")
 TITANIC_CSV = os.path.join(HERE, "tests", "fixtures",
                            "TitanicPassengersTrainData.csv")
 
@@ -56,6 +60,8 @@ TITANIC_CSV = os.path.join(HERE, "tests", "fixtures",
 #: time, is what the 1200 s window of this script pays for.
 HIGGS_ROWS = 400_000
 BIG_ROWS = 1_000_000
+#: rows of the free_text leg (fewer where ``--rows`` asks for fewer)
+FREE_TEXT_ROWS = 100_000
 FRAME_SIZES = (1, 7, 64, 256)
 
 #: serving replies vs the ``score_function`` row oracle, max abs difference
@@ -326,6 +332,67 @@ def leg_multiclass(leg: Leg, out: str, ctx: dict) -> None:
                   "treeGatherWalks == 0 (28 columns, depth 12: every tree "
                   "walk compares against whole tables)")
     leg.check(hold["f1"] >= 0.55, "holdout F1 >= 0.55")
+    _counters_clean(leg)
+
+
+# -- free text ----------------------------------------------------------------
+
+def leg_free_text(leg: Leg, out: str, ctx: dict) -> None:
+    """Two columns of free text (the benchmark's review-shaped generator)
+    through ``transmogrify`` at its defaults and the linear zoo on a FIRST
+    train: each column one native tokenize-and-hash pass, only the odd rows
+    through the Python tokenizer, the vector filled on the device and equal
+    to the plain reference's on 20,000 rows, both families stacked."""
+    import numpy as np
+    from chipbench import data, pipeline
+    from chipbench import reference_amazon as reference
+    from transmogrifai_tpu.utils.profiling import profiler, sweep_counters
+    with open(os.path.join(HERE, "chipbench", "configs",
+                           "amazon_polarity_text.json")) as fh:
+        config = json.load(fh)
+    rows = min(FREE_TEXT_ROWS, ctx["rows"])
+    table = data.make_table(config["dataset"], rows, 23)
+    frame = pipeline.to_frame(table)
+    profiler.reset(app_name="chip_smoke")
+    t0 = time.time()
+    wf, handles = pipeline.build_workflow(frame, config["pipeline"])
+    model = wf.train()
+    s = model.selector_summary()
+    hold = s.holdout_evaluation["binary classification"]
+    run = sweep_counters.run_to_json()
+    modes = {f: c["mode"] for f, c in sweep_counters.to_json().items()}
+    wall = round(time.time() - t0, 1)
+    idx = np.sort(np.random.default_rng(24).choice(
+        rows, size=min(rows, 20_000), replace=False))
+    got = np.asarray(model.compute_data_up_to(
+        handles["vector"], frame.take(idx))[handles["vector"].name].values)
+    want = reference.apply_fe(
+        table, reference.fit_fe(table, reference.fe_settings(config)),
+        rows=idx)
+    spec = config["dataset"]
+    odd = round(spec["non_ascii_share"] * rows) \
+        + round(spec["long_share"] * rows)
+    leg.info.update(
+        rows=rows, smoke_wall_s=wall, best=s.best_model_name,
+        holdout_aupr=round(float(hold["au_pr"]), 4), sweep_modes=modes,
+        sweep_run_counters=run, vector_width=int(got.shape[1]),
+        peak_bytes_in_use=_peaks())
+    leg.check(s.failures == [], "summary.failures == []")
+    leg.check(len(s.validation_results) == 12,
+              "all 12 grid points have a validation result")
+    leg.check(set(modes.values()) == {"fold_stacked"},
+              "both families took the fold-stacked sweep")
+    leg.check(run["sweepLoopFallbacks"] == 0, "sweepLoopFallbacks == 0")
+    leg.check(run["sweepHostSyncs"] == 1,
+              "sweepHostSyncs == 1 (one settle for the whole sweep)")
+    leg.check(run["feHashPerRowFallbacks"] == 0,
+              "feHashPerRowFallbacks == 0 (no column in the per-row loop)")
+    leg.check(run["feTextPythonRows"] == odd,
+              "feTextPythonRows == the table's quota of odd rows")
+    leg.check(got.shape == want.shape == (idx.size, 1028)
+              and float(np.max(np.abs(got - want))) == 0.0,
+              "fe_max_abs == 0 against chipbench.reference_amazon")
+    leg.check(hold["au_pr"] >= 0.6, "holdout auPR >= 0.6")
     _counters_clean(leg)
 
 
@@ -638,7 +705,8 @@ def leg_mesh(leg: Leg, out: str, ctx: dict) -> None:
 
 
 LEG_FNS = {"titanic": leg_titanic, "higgs": leg_higgs,
-           "multiclass": leg_multiclass, "serve": leg_serve,
+           "multiclass": leg_multiclass, "free_text": leg_free_text,
+           "serve": leg_serve,
            "kernels": leg_kernels, "mesh": leg_mesh}
 
 

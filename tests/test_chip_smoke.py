@@ -89,7 +89,7 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     out = str(tmp_path / "smoke")
     try:
         rc = smoke.main(["--rehearsal", "--legs",
-                         "titanic,higgs,multiclass,serve,kernels",
+                         "titanic,higgs,multiclass,free_text,serve,kernels",
                          "--rows", "4000",
                          "--big-rows", "3000", "--out", out])
     finally:
@@ -100,7 +100,7 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     assert summary["ok"] is True and summary["rehearsal"] is True
     assert summary["claim"] is None
     assert list(summary["legs"]) == ["titanic", "higgs", "multiclass",
-                                     "serve", "kernels"]
+                                     "free_text", "serve", "kernels"]
     for leg in summary["legs"].values():
         assert leg["ok"] and leg["asserted"]
     assert summary["legs"]["titanic"]["best"].startswith(
@@ -108,6 +108,10 @@ def test_rehearsal_runs_every_single_device_leg(smoke, tmp_path,
     assert summary["legs"]["higgs"]["grid_points"] == 14
     multi = summary["legs"]["multiclass"]["sweep_run_counters"]
     assert multi["sweepLoopFallbacks"] == 0 and multi["sweepHostSyncs"] == 1
+    text = summary["legs"]["free_text"]
+    assert text["vector_width"] == 1028 and text["rows"] == 4000
+    assert text["sweep_run_counters"]["feTextPythonRows"] == 20
+    assert text["sweep_run_counters"]["feHashPerRowFallbacks"] == 0
     assert summary["legs"]["serve"]["frame_sizes"] == [1, 7, 64, 256]
     assert set(summary["native_libraries"]) == {"texthash", "shist",
                                                 "dictenc"}

@@ -191,6 +191,67 @@ def test_memory_guard_falls_back(monkeypatch):
     _summaries_equal(s1, s2)
 
 
+@pytest.mark.parametrize("fit_intercept,standardize,chunk", [
+    (True, True, 512), (True, True, 5000), (False, True, 1024),
+    (True, False, 1536)])
+def test_newton_in_place_equals_the_copying_trainer(fit_intercept,
+                                                    standardize, chunk):
+    """``_newton_in_place`` (folds as row weightings of ONE matrix, walked
+    in row chunks with a shorter last one, the Hessian in bfloat16) ends
+    where ``_train_logistic_newton`` ends on each fold's gathered rows."""
+    import jax
+    from transmogrifai_tpu.models import linear as L
+    rng = np.random.default_rng(0)
+    n, d, k = 5000, 37, 3
+    X = (rng.normal(size=(n, d)) * rng.uniform(0.5, 30, size=d)
+         + 10 * rng.normal(size=d)).astype(np.float32)
+    if not standardize:
+        X = ((X - X.mean(0)) / X.std(0)).astype(np.float32)
+    beta = rng.normal(size=d) / np.sqrt(d)
+    y = (((X - X.mean(0)) / X.std(0)) @ beta + rng.normal(size=n)
+         > 0).astype(np.float32)
+    fold = rng.integers(0, k, size=n)
+    w = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+    wf = np.stack([np.where(fold != f, w, 0.0) for f in range(k)])
+    rp = jnp.asarray([0.001, 0.01, 0.1, 0.2], jnp.float32)
+    kw = dict(fit_intercept=fit_intercept, standardize=standardize)
+    Ws, bs = L._newton_in_place(jnp.asarray(X), jnp.asarray(y),
+                                jnp.asarray(wf), rp, chunk=chunk, **kw)
+    assert Ws.shape == (k, 4, d, 2) and bs.shape == (k, 4, 2)
+    for f in range(k):
+        rows = np.nonzero(fold != f)[0]
+        W0, b0, _ = jax.vmap(lambda r: L._train_logistic_newton(
+            jnp.asarray(X[rows]), jnp.asarray(y[rows]),
+            jnp.asarray(w[rows]), r, **kw))(rp)
+        scale = float(jnp.max(jnp.abs(W0)))
+        assert float(jnp.max(jnp.abs(Ws[f] - W0))) < 1e-4 * scale
+        assert float(jnp.max(jnp.abs(bs[f] - b0))) < 1e-4
+
+
+def test_newton_points_stay_stacked_where_copies_do_not_fit(monkeypatch):
+    """A matrix whose gathered folds and their copies do not fit the
+    budget keeps its Newton points on the stacked path, trained in place
+    (the sweep and the winner's cold refit), with the metrics of the
+    copying trainer; only where not even a row chunk fits does the family
+    take the loop."""
+    from transmogrifai_tpu.models import linear as L
+    frame = _frame(seed=11)
+    sweep_counters.reset()
+    s1 = _train(_binary_selector(), frame).selector_summary()
+    in_place = []
+    real = L._newton_in_place
+    monkeypatch.setattr(L, "_newton_copies_fit", lambda k, n, d: False)
+    monkeypatch.setattr(L, "_newton_in_place", lambda *a, **kw: (
+        in_place.append(a[2].shape[0]), real(*a, **kw))[1])
+    sweep_counters.reset()
+    s2 = _train(_binary_selector(), frame).selector_summary()
+    c2 = sweep_counters.to_json()
+    assert c2["OpLogisticRegression_0"]["mode"] == "fold_stacked", c2
+    assert sweep_counters.run_to_json().get("sweepLoopFallbacks", 0) == 0
+    assert 3 in in_place                    # the sweep's folds as weightings
+    _summaries_equal(s1, s2, tol=1e-5)
+
+
 class CrashOnce(OpLinearSVC):
     """Simulates a mid-sweep crash (NOT an isolated candidate failure):
     KeyboardInterrupt escapes the per-family isolation by design."""

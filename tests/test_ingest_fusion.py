@@ -576,6 +576,31 @@ def test_frame_fingerprint_sensitivity():
     assert fr.frame_fingerprint(f1) != fr.frame_fingerprint(f4)
 
 
+@pytest.mark.parametrize("other", [
+    ["ab", "c", None, ""],          # a character moved across a row boundary
+    ["a", "bc", "", None],          # the null and the empty string swapped
+    ["a", "bc", None, "", None],    # one more null
+    ["a", "bC", None, ""],          # another character
+    ["a", "b\u00e7", None, ""],     # not ASCII
+    ["a", 7.5, None, ""],           # an object that is no string
+])
+def test_frame_fingerprint_tells_string_columns_apart(other):
+    """A column of strings hashes its text end to end with each value's
+    length and null flag: what tells the values apart row by row still
+    tells the frames apart, and objects that are not strings take the
+    per-row path."""
+    def frame(values):
+        return fr.HostFrame({"t": fr.HostColumn(
+            ft.Text, np.array(values, dtype=object))})
+    base = ["a", "bc", None, ""]
+    assert fr.frame_fingerprint(frame(base)) == fr.frame_fingerprint(
+        frame(list(base)))
+    assert fr.frame_fingerprint(frame(base)) != fr.frame_fingerprint(
+        frame(other))
+    assert fr.frame_fingerprint(frame(other)) == fr.frame_fingerprint(
+        frame(list(other)))
+
+
 def test_generate_frame_resolves_schema_once_per_reader(monkeypatch):
     """The satellite fix: HostColumn.builder (the kind dispatch) runs
     once per (reader, feature), however many chunks stream through."""

@@ -69,13 +69,19 @@ def _vectorize(table, config: dict) -> np.ndarray:
     return np.asarray(out[vec.name].values, np.float32)
 
 
-@pytest.mark.parametrize("per_row_fallback", [False, True])
-def test_vector_equals_the_reference_to_the_bit(monkeypatch,
-                                                per_row_fallback):
+@pytest.mark.parametrize("odd_rows", [False, True])
+def test_vector_equals_the_reference_to_the_bit(odd_rows):
+    """With ``odd_rows`` a hashed column of values seen once each holds a
+    few rows that are not ASCII: the column takes the native pass, those
+    rows alone the Python tokenizer, and no column the per-row loop."""
     config = _config(64)
     table = _table(2000, config)
-    if per_row_fallback:  # 64 x 40: every hashed column but c5 is past it
-        monkeypatch.setattr(smart_text, "_UNIQUE_TABLE_CAP", 64 * 120)
+    if odd_rows:
+        free = np.array([f"Item_{i:05d} sold-by {i % 7}" for i in range(2000)],
+                        dtype=object)
+        free[[3, 500, 1999]] = ["crème brûlée", "naïve  café", "5€ “ok”"]
+        free[10] = None
+        table.cats["c3"] = free
     profiler.reset(app_name="test")
     got = _vectorize(table, config)
     fit = reference.fit_fe(table, reference.fe_settings(config))
@@ -86,13 +92,11 @@ def test_vector_equals_the_reference_to_the_bit(monkeypatch,
     assert any(v is None for v in table.cats["c1"])
     assert got.shape == want.shape
     assert np.array_equal(got, want)
-    fallbacks = sweep_counters.run_to_json()["feHashPerRowFallbacks"]
+    counters = sweep_counters.run_to_json()
+    assert counters["feHashPerRowFallbacks"] == 0
+    assert counters["feDistinctValues"] > 0
     # fit-time transform and the read-back each fill the columns once
-    assert (fallbacks > 0) == per_row_fallback
-    hashed = sum(1 for t in fit.treatments.values() if t[0] == "hash")
-    assert sweep_counters.run_to_json()["feDistinctValues"] > 0
-    if per_row_fallback:
-        assert 2 <= fallbacks <= 2 * hashed         # the narrow ones fit
+    assert odd_rows == (counters["feTextPythonRows"] >= 2 * 3)
 
 
 @pytest.fixture(scope="module")
@@ -244,21 +248,37 @@ def test_device_fill_equals_host_fill_to_the_bit(tracked):
     assert got[:, :64].max() == 1.0 and got[:, -3:].sum() > 0
 
 
-def test_device_fill_leaves_free_text_and_objects_to_the_host():
-    """A value of more slots than the per-row table carries, and a column
-    holding objects that are not strings, fill on the host; the executor
+def test_device_fill_takes_free_text_and_leaves_objects_to_the_host():
+    """A value of more slots than the per-row table carries fills on the
+    device from row-ordered entries; a column holding objects that are not
+    strings fills on the host, row by row, and is counted; the executor
     takes whichever the stage gives."""
     from transmogrifai_tpu.dag import DagExecutor
     cols = _text_columns()
     long_text = cols["words"].copy()
     long_text[0] = " ".join(f"w{i}" for i in range(40))
     model, data_ = _text_stage({"words": long_text}, num_hash_features=64)
-    assert model.device_output_column(data_) is None
+    dev = model.device_output_column(data_)
+    assert dev is not None
+    assert np.array_equal(np.asarray(dev.values),
+                          model.output_column(data_).values)
     name = model.get_output().name
     out = DagExecutor().apply_layer(data_, [model])
-    assert name in out.host and name not in out.device
+    assert name in out.device and name not in out.host
+    class Wrapped:
+        """Not a string, though the row path can tokenize it."""
+
+        def __init__(self, text):
+            self.text = text
+
+        def lower(self):
+            return self.text.lower()
+
+        def __len__(self):
+            return len(self.text)
+
     mixed = cols["ids"].copy()
-    mixed[3] = 7.5
+    mixed[3] = Wrapped("Seen-Once twice TWICE")
     model2, data2 = _text_stage({"ids": cols["ids"]}, num_hash_features=64)
     from transmogrifai_tpu import frame as fr
     from transmogrifai_tpu.pipeline_data import PipelineData
@@ -266,6 +286,11 @@ def test_device_fill_leaves_free_text_and_objects_to_the_host():
     odd = PipelineData(fr.HostFrame(
         {"ids": fr.HostColumn(ft.Text, mixed)}), {})
     assert model2.device_output_column(odd) is None
+    profiler.reset(app_name="test")
+    by_row = model2.output_column(odd).values
+    assert sweep_counters.run_to_json()["feHashPerRowFallbacks"] == 1
+    assert np.array_equal(by_row[3], model2.transform_row(mixed[3]))
+    assert by_row[3, :64].sum() == 4.0 and by_row[3, 64] == 21.0
     # and where it can, the vector never exists on the host
     name2 = model2.get_output().name
     out2 = DagExecutor().apply_layer(data2, [model2])

@@ -410,13 +410,37 @@ class HostFrame:
 # Identity + accounting helpers (round 14: device-frame cache)
 # ---------------------------------------------------------------------------
 
+def _hash_strings(h, v: np.ndarray) -> bool:
+    """Feed an object column of strings and ``None`` to ``h`` with no
+    Python a row: the values end to end (a block of rows at a time), their
+    lengths and the null mask. False, with ``h`` untouched, where the
+    column holds anything else."""
+    null = np.equal(v, None)
+    if null.all():
+        return False
+    text = np.where(null, "", v) if null.any() else v
+    try:
+        lengths = np.frompyfunc(str.__len__, 1, 1)(text).astype(np.int64)
+    except TypeError:
+        return False
+    h.update(b"str")
+    for lo in range(0, len(text), 4096):
+        h.update("".join(text[lo:lo + 4096].tolist()).encode(
+            "utf-8", "surrogatepass"))
+    h.update(lengths.tobytes())
+    h.update(null.tobytes())
+    return True
+
+
 def frame_fingerprint(frame: "HostFrame") -> str:
     """Content fingerprint of a host frame: column names, feature types,
     and the FULL value/mask bytes (blake2b). This keys the device-frame
     cache, so it must be collision-safe in practice — numeric columns hash
-    at memory bandwidth; object columns (strings/maps) hash per-row reprs,
-    the same order of work dict-encoding them costs. Two frames with equal
-    fingerprints produce identical device columns."""
+    at memory bandwidth; a column of strings and nulls hashes its text end
+    to end with each value's length and null flag beside it (which together
+    determine every value), in bulk; other object columns (maps, lists)
+    hash per-row reprs. Two frames with equal fingerprints produce
+    identical device columns."""
     import hashlib
     h = hashlib.blake2b(digest_size=16)
     for name in sorted(frame.names()):
@@ -425,11 +449,11 @@ def frame_fingerprint(frame: "HostFrame") -> str:
         h.update(col.ftype.__name__.encode())
         v = col.values
         h.update(str(v.shape).encode())
-        if v.dtype == object:
+        if v.dtype == object and not _hash_strings(h, v):
             for x in v:
                 h.update(repr(x).encode())
                 h.update(b"\x1f")
-        else:
+        elif v.dtype != object:
             h.update(np.ascontiguousarray(v).tobytes())
         if col.mask is not None:
             h.update(np.ascontiguousarray(col.mask).tobytes())

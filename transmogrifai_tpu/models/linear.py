@@ -59,6 +59,14 @@ def _standardize_stats(X, w):
 _X_PRECISION = jax.lax.Precision.HIGH
 
 
+#: a fitted model's score of a row is an exact product: the rows are raw
+#: (a text length of thousands is no bfloat16 number, and its weight is not
+#: small), and one bfloat16 pass moved a served probability by up to 8e-2
+#: against the same weights applied in float32 (chip runs, PR 33); the
+#: product is as wide as the classes, so the passes cost nothing
+_SCORE_PRECISION = "highest"
+
+
 def _lane_stats(X, wf, standardize: bool):
     """Per weight row of ``wf [F, n]``: the weight sum, the weighted mean
     and deviation of every column, and which columns vary at all under it;
@@ -290,6 +298,137 @@ def _train_logistic_newton(X, y, w, reg_param, *, n_iter: int = 15,
     return W, b, jnp.float32(0.0)
 
 
+#: bytes of one row chunk's working set in ``_newton_in_place`` (the
+#: chunk's standardized rows and every lane's weighted copy of them)
+_NEWTON_CHUNK_BYTES = 256 << 20
+
+
+def _newton_chunk_rows(n: int, d: int, lanes: int) -> int:
+    """Rows a chunk of ``_newton_in_place``: ``_NEWTON_CHUNK_BYTES`` of one
+    float32 and ``lanes`` bfloat16 rows of ``d + 1`` columns, a multiple of
+    512, the whole matrix where it is smaller."""
+    rows = _NEWTON_CHUNK_BYTES // ((d + 1) * (4 + 2 * lanes))
+    return int(min(n, max(512, rows // 512 * 512)))
+
+
+def _newton_copies_fit(k: int, n: int, d: int) -> bool:
+    """Whether ``_train_logistic_newton`` has room for its three
+    matrix-sized arrays a fold (the fold's rows, their standardized copy
+    with the ones column, the Hessian build's weighted copy) inside the
+    stacked budget. Under a mesh they are sharded, and the in-place form
+    (a scan over row chunks of ONE device's matrix) does not apply."""
+    from transmogrifai_tpu.parallel import mesh as pmesh
+    from transmogrifai_tpu.utils.devicewatch import stacked_hbm_budget
+    return (pmesh.current_mesh() is not None
+            or 3.0 * 4.0 * k * n * max(d, 1) <= stacked_hbm_budget())
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "n_iter",
+                                             "fit_intercept", "standardize"))
+def _newton_in_place(X, y, wf, reg_param, *, chunk: int, n_iter: int = 15,
+                     fit_intercept: bool, standardize: bool):
+    """``_train_logistic_newton`` for a matrix too large to copy: ``k`` row
+    weightings of the resident ``X [n, d]`` (``wf [k, n]``: a fold is a
+    weighting, 0 on the rows it leaves out) x ``g`` strengths
+    (``reg_param [g]``) as ``k x g`` lanes of one damped Newton iteration.
+    A step walks ``X`` in chunks of ``chunk`` rows. A chunk is centred and
+    scaled ONCE, by the moments of all rows, with a ones column beside it
+    (``Xb``); a lane's own standardization is the map ``T`` from that to
+    its weighting's moments (a diagonal and one row), so its margins are
+    ``Xb @ (T uv)``, its gradient ``T^T (Xb^T r)`` and its Hessian
+    ``T^T (A^T A) T``, ``A`` the chunk's rows scaled by the root of their
+    curvature. Margins and gradient are exact products (``HIGHEST``: they
+    decide where the iteration ends); ``A^T A`` runs in bfloat16, one pass
+    of the MXU, positive semi-definite by construction (it decides only how
+    fast the iteration gets there). No array of the matrix's size is made.
+    Returns original-space ``(Ws [k, g, d, 2], bs [k, g, 2])``."""
+    n, d = X.shape
+    k, g = wf.shape[0], reg_param.shape[0]
+    lanes = k * g                                # fold-major: f * g + j
+    exact = jax.lax.Precision.HIGHEST
+    whole, tail = divmod(n, chunk)
+
+    def over_chunks(add, acc):
+        """``add(acc, lo, size)`` over every chunk of rows, the last one
+        shorter."""
+        acc = jax.lax.fori_loop(
+            0, whole, lambda i, a: add(a, i * chunk, chunk), acc)
+        return add(acc, whole * chunk, tail) if tail else acc
+
+    def rows_of(a, lo, size, axis=0):
+        return jax.lax.dynamic_slice_in_dim(a, lo, size, axis=axis)
+
+    wsum = jnp.maximum(jnp.sum(wf, axis=1), 1.0)
+    if standardize:
+        center, scale = _standardize_stats(X, jnp.ones(n, X.dtype))
+
+        def add_moments(acc, lo, size):
+            # about the mean of all rows, which a weighting's own mean is
+            # close to: the second moment loses nothing to the offset
+            Xc, w = rows_of(X, lo, size) - center, rows_of(wf, lo, size, 1)
+            return (acc[0] + jnp.matmul(w, Xc, precision=exact),
+                    acc[1] + jnp.matmul(w, Xc * Xc, precision=exact))
+        s1, s2 = over_chunks(add_moments, (jnp.zeros((k, d)),) * 2)
+        off = s1 / wsum[:, None]
+        sd = jnp.sqrt(jnp.maximum(s2 / wsum[:, None] - off * off, 1e-12))
+        mu, sd = center + off, jnp.where(sd < 1e-6, 1.0, sd)
+    else:
+        center, scale = jnp.zeros(d), jnp.ones(d)
+        mu, sd = jnp.zeros((k, d)), jnp.ones((k, d))
+    # T [k, d+1, d+1]: [Xs_f, 1] = [Xg, 1] @ T_f
+    T = jax.vmap(lambda m, s: jnp.zeros((d + 1, d + 1)).at[
+        jnp.arange(d), jnp.arange(d)].set(scale / s).at[d, :d].set(
+        (center - m) / s).at[d, d].set(1.0))(mu, sd)
+    T = jnp.repeat(T, g, axis=0)                              # [lanes, ..]
+    lam = jnp.tile(reg_param * 0.5, k)[:, None]
+    penalty_mask = jnp.ones(d + 1).at[-1].set(0.0)  # intercept unpenalized
+    w_lane = jnp.repeat(wf / wsum[:, None], g, axis=0)        # [lanes, n]
+
+    def add_rows(V, acc, lo, size):
+        Xb = jnp.concatenate([(rows_of(X, lo, size) - center) / scale,
+                              jnp.ones((size, 1), X.dtype)], axis=1)
+        w = rows_of(w_lane, lo, size, 1).T                    # [c, lanes]
+        p = jax.nn.sigmoid(jnp.matmul(Xb, V, precision=exact))
+        r = w * (p - rows_of(y, lo, size)[:, None])
+        root = jnp.sqrt(w * jnp.maximum(p * (1.0 - p), 1e-6))
+        grams = []
+        for lane in range(lanes):
+            A = (Xb * root[:, lane:lane + 1]).astype(jnp.bfloat16)
+            grams.append(jnp.matmul(A.T, A,
+                                    preferred_element_type=jnp.float32))
+        return (acc[0] + jnp.matmul(Xb.T, r, precision=exact),
+                acc[1] + jnp.stack(grams))
+
+    def step(uv, _):
+        V = jnp.einsum("lde,le->dl", T, uv, precision=exact)
+        acc = over_chunks(
+            functools.partial(add_rows, V),
+            (jnp.zeros((d + 1, lanes), jnp.float32),
+             jnp.zeros((lanes, d + 1, d + 1), jnp.float32)))
+        grad = jnp.einsum("lde,dl->le", T, acc[0], precision=exact) \
+            + lam * penalty_mask * uv
+        H = jnp.einsum("lda,lde,leb->lab", T, acc[1], T, precision=exact)
+        # Levenberg damping, as in ``_train_logistic_newton``
+        H = H + (lam * penalty_mask + 1e-4)[..., None] \
+            * jnp.eye(d + 1, dtype=jnp.float32)
+        delta = jax.vmap(functools.partial(
+            jax.scipy.linalg.solve, assume_a="pos"))(H, grad)
+        if not fit_intercept:
+            delta = delta.at[:, -1].set(0.0)
+        new = uv - delta
+        ok = jnp.all(jnp.isfinite(new), axis=-1, keepdims=True)
+        return jnp.where(ok, new, uv), 0.0
+
+    uv, _ = jax.lax.scan(step, jnp.zeros((lanes, d + 1), jnp.float32), None,
+                         length=n_iter)
+    uv = uv.reshape(k, g, d + 1)
+    # margin space -> equivalent 2-column softmax weights, unstandardized
+    half = uv[..., :d] / 2.0 / sd[:, None]
+    b_half = uv[..., d] / 2.0 - jnp.sum(mu[:, None] * half, axis=-1)
+    return (jnp.stack([-half, half], axis=-1),
+            jnp.stack([-b_half, b_half], axis=-1))
+
+
 def _shard_candidates(*arrs):
     """Shard the leading (candidate/grid) axis over the mesh "model" axis
     when one is active — the grid sweep then runs 2-D parallel: rows over
@@ -399,7 +538,8 @@ class LinearClassificationModel(PredictionModel):
 
     def device_apply(self, params, col: fr.VectorColumn) -> fr.PredictionColumn:
         W, b = params
-        z = col.values @ W + b
+        with jax.default_matmul_precision(_SCORE_PRECISION):
+            z = col.values @ W + b
         if z.shape[1] == 1:  # margin-only binary (SVC)
             z = jnp.concatenate([-z, z], axis=1)
         prob = jax.nn.softmax(z, axis=-1) if self.probabilistic \
@@ -451,7 +591,8 @@ class LinearRegressionModel(PredictionModel):
 
     def device_apply(self, params, col: fr.VectorColumn) -> fr.PredictionColumn:
         W, b = params
-        yhat = col.values @ W + b
+        with jax.default_matmul_precision(_SCORE_PRECISION):
+            yhat = col.values @ W + b
         n = yhat.shape[0]
         empty = jnp.zeros((n, 0), jnp.float32)
         return fr.PredictionColumn(yhat, empty, empty)
@@ -751,12 +892,45 @@ class OpLogisticRegression(_LinearPredictor):
     def fit_arrays(self, X, y, w, params):
         params = {**self.params, **params}
         if self._newton_ok(params, X.shape[1], self._n_classes(y)):
-            W, b, _ = _train_logistic_newton(
-                X, y, w, jnp.float32(params["reg_param"]),
-                fit_intercept=bool(params["fit_intercept"]),
-                standardize=bool(params["standardization"]))
-            return self._make_model(W, b)
+            Ws, bs = self._newton_points(
+                X, y, w, jnp.asarray([params["reg_param"]], jnp.float32),
+                bool(params["fit_intercept"]),
+                bool(params["standardization"]))
+            return self._make_model(Ws[0], bs[0])
         return super().fit_arrays(X, y, w, params)
+
+    def _newton_points(self, X, y, w, rp, fit_b: bool, std_b: bool):
+        """The Newton points ``rp [g]`` on one matrix ``X [n, d]`` under
+        the row weights ``w [n]``: ``(Ws [g, d, 2], bs [g, 2])``, on copies
+        of the matrix where they fit the budget, else in place."""
+        from transmogrifai_tpu.utils import flops
+        n, d = (int(v) for v in X.shape)
+        g = int(rp.shape[0])
+        # per Newton step: z/grad matvecs 4n(d+1) + Hessian build
+        # 2n(d+1)^2 + dense solve (2/3)(d+1)^3
+        flops.add("linear", g * 15 * (
+            4.0 * n * (d + 1) + 2.0 * n * (d + 1) ** 2
+            + (2.0 / 3.0) * (d + 1) ** 3))
+        if _newton_copies_fit(1, n, d):
+            Ws, bs, _ = jax.vmap(lambda r: _train_logistic_newton(
+                X, y, w, r, fit_intercept=fit_b, standardize=std_b))(rp)
+            return Ws, bs
+        Ws, bs = _newton_in_place(
+            X, y, w[None], rp, chunk=_newton_chunk_rows(n, d, g),
+            fit_intercept=fit_b, standardize=std_b)
+        return Ws[0], bs[0]
+
+    @staticmethod
+    def _by_flags(merged, idxs):
+        """``((fit_intercept, standardization), [grid indices])`` groups of
+        the points ``idxs``: those flags are compile-time constants, so a
+        group is one program and no point trains with another's."""
+        groups: dict[tuple[bool, bool], list[int]] = {}
+        for i in idxs:
+            groups.setdefault((bool(merged[i]["fit_intercept"]),
+                               bool(merged[i]["standardization"])),
+                              []).append(i)
+        return groups.items()
 
     def grid_fit_arrays(self, X, y, w, grid):
         if not grid:
@@ -769,27 +943,11 @@ class OpLogisticRegression(_LinearPredictor):
             return super().grid_fit_arrays(X, y, w, grid)
         adam_idx = [i for i in range(len(grid)) if i not in set(newton_idx)]
         models: list = [None] * len(grid)
-        # Newton points vmapped over reg_param, one program per distinct
-        # (fit_intercept, standardization) combo — those flags are static
-        # and must not silently inherit the first grid point's values
-        by_flags: dict[tuple[bool, bool], list[int]] = {}
-        for i in newton_idx:
-            key = (bool(merged[i]["fit_intercept"]),
-                   bool(merged[i]["standardization"]))
-            by_flags.setdefault(key, []).append(i)
-        for (fit_b, std_b), idxs in by_flags.items():
+        for (fit_b, std_b), idxs in self._by_flags(merged, newton_idx):
             rp = jnp.asarray([merged[i]["reg_param"] for i in idxs],
                              jnp.float32)
             rp, = _shard_candidates(rp)
-            Ws, bs, _ = jax.vmap(lambda r: _train_logistic_newton(
-                X, y, w, r, fit_intercept=fit_b, standardize=std_b))(rp)
-            from transmogrifai_tpu.utils import flops
-            n, d = X.shape
-            # per Newton step: z/grad matvecs 4n(d+1) + Hessian build
-            # 2n(d+1)^2 + dense solve (2/3)(d+1)^3
-            flops.add("linear", len(idxs) * 15 * (
-                4.0 * n * (d + 1) + 2.0 * n * (d + 1) ** 2
-                + (2.0 / 3.0) * (d + 1) ** 3))
+            Ws, bs = self._newton_points(X, y, w, rp, fit_b, std_b)
             for j, i in enumerate(idxs):
                 models[i] = self._make_model(Ws[j], bs[j])
         if adam_idx:
@@ -809,12 +967,7 @@ class OpLogisticRegression(_LinearPredictor):
         from transmogrifai_tpu.utils.profiling import sweep_counters
         k, n, d = (int(v) for v in X.shape)
         parts, order = [], []
-        by_flags: dict[tuple[bool, bool], list[int]] = {}
-        for i in newton_idx:
-            key = (bool(merged[i]["fit_intercept"]),
-                   bool(merged[i]["standardization"]))
-            by_flags.setdefault(key, []).append(i)
-        for (fit_b, std_b), idxs in by_flags.items():
+        for (fit_b, std_b), idxs in self._by_flags(merged, newton_idx):
             rp = jnp.asarray([merged[i]["reg_param"] for i in idxs],
                              jnp.float32)
             if not pmesh.fold_axis_on_model(k):
@@ -862,16 +1015,43 @@ class OpLogisticRegression(_LinearPredictor):
             order.extend(adam_idx)
         return _merge_grid_parts(parts, order)
 
+    def _newton_lanes(self, batch, merged, newton_idx):
+        """The Newton points of a grid over the folds of ``batch`` as row
+        weightings of its resident matrix (``_newton_in_place``), one
+        program per (fit_intercept, standardization) combo. Returns
+        ``(parts, order)`` for ``_merge_grid_parts``."""
+        from transmogrifai_tpu.utils import flops
+        n, d, k = int(batch.X.shape[0]), batch.d, batch.k
+        parts, order = [], []
+        for (fit_b, std_b), idxs in self._by_flags(merged, newton_idx):
+            rp = jnp.asarray([merged[i]["reg_param"] for i in idxs],
+                             jnp.float32)
+            parts.append(_newton_in_place(
+                batch.X, batch.y, batch.fold_weights(), rp,
+                chunk=_newton_chunk_rows(n, d, k * len(idxs)),
+                fit_intercept=fit_b, standardize=std_b))
+            # every row of the matrix is read under every fold's weighting
+            flops.add("linear", k * len(idxs) * 15 * (
+                4.0 * n * (d + 1) + 2.0 * n * (d + 1) ** 2
+                + (2.0 / 3.0) * (d + 1) ** 3))
+            order.extend(idxs)
+        return parts, order
+
     def _batch_params(self, batch, grid, n_classes: int):
-        """The Adam points train in place; the Newton points (their
-        Hessian wants each fold's own standardized matrix, and exist only
-        up to ``_NEWTON_MAX_D`` columns) read the gathered folds."""
+        """The Adam points train in place. The Newton points (they exist
+        only up to ``_NEWTON_MAX_D`` columns) read the gathered folds,
+        each fold's own standardized matrix, where those and their copies
+        fit the budget, and otherwise train in place too, a chunk of rows
+        at a time."""
         merged, newton_idx, adam_idx = self._newton_split(
             grid, batch.d, n_classes)
         if not newton_idx:
             return super()._batch_params(batch, grid, n_classes)
-        parts, order = self._newton_folds(*batch.training_folds(), merged,
-                                          newton_idx)
+        if _newton_copies_fit(batch.k, batch.n_tr, batch.d):
+            parts, order = self._newton_folds(*batch.training_folds(),
+                                              merged, newton_idx)
+        else:
+            parts, order = self._newton_lanes(batch, merged, newton_idx)
         if adam_idx:
             parts.append(super()._batch_params(
                 batch, [grid[i] for i in adam_idx], n_classes))
@@ -882,8 +1062,16 @@ class OpLogisticRegression(_LinearPredictor):
         need = super().fold_stack_bytes(batch, grid)
         if self._newton_split(grid, batch.d, 2)[1]:
             # the gathered training folds, their standardized copy and the
-            # Hessian build's weighted copy
-            need += 4.0 * batch.k * batch.n_tr * max(batch.d, 1) * 3.0
+            # Hessian build's weighted copy; where those do not fit, one
+            # row chunk's working set and the lanes' Hessians
+            copies = 4.0 * batch.k * batch.n_tr * max(batch.d, 1) * 3.0
+            lanes = batch.k * max(len(grid), 1)
+            rows = _newton_chunk_rows(int(batch.X.shape[0]), batch.d, lanes)
+            in_place = (batch.d + 1) * (
+                2.0 * rows * (4 + 2 * lanes)
+                + 3.0 * 4.0 * lanes * (batch.d + 1))
+            need += (copies if _newton_copies_fit(
+                batch.k, batch.n_tr, batch.d) else in_place)
         return need
 
     def refit_winner(self, X, y, w, params, *, warm=None, lane=None,

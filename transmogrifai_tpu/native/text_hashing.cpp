@@ -5,10 +5,13 @@
 // host-side equivalent tokenizes ASCII word runs and hashes with zlib's
 // CRC-32 — bit-identical to Python's zlib.crc32, so the Python row path and
 // this columnar path agree exactly (the OpTransformerSpec parity contract).
-// Non-ASCII columns stay on the Python/regex path (dispatch in hashing.py).
+// What is not ASCII stays on the Python/regex path (dispatch in hashing.py: a
+// column for the dense entry points, a row for hash_tokens_entries).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <vector>
 
 namespace {
 
@@ -41,10 +44,11 @@ inline bool is_word(unsigned char c) {
 
 // The ONE tokenizer loop: every entry point routes through this so the
 // word-character set, lowercase rule, and 4096-byte token cap cannot drift
-// between consumers. emit(row, crc) fires once per token.
-template <class Emit>
+// between consumers. emit(row, crc) fires once per token, end_row(row) once
+// a row after its last token.
+template <class Emit, class EndRow>
 inline void scan_tokens(const char* buf, const int64_t* offsets, int64_t n,
-                        int32_t lowercase, Emit&& emit) {
+                        int32_t lowercase, Emit&& emit, EndRow&& end_row) {
     init_crc();
     unsigned char tok[4096];
     for (int64_t r = 0; r < n; ++r) {
@@ -62,7 +66,14 @@ inline void scan_tokens(const char* buf, const int64_t* offsets, int64_t n,
                 t = 0;
             }
         }
+        end_row(r);
     }
+}
+
+template <class Emit>
+inline void scan_tokens(const char* buf, const int64_t* offsets, int64_t n,
+                        int32_t lowercase, Emit&& emit) {
+    scan_tokens(buf, offsets, n, lowercase, emit, [](int64_t) {});
 }
 
 }  // namespace
@@ -95,6 +106,48 @@ void hash_tokens_hist(const char* buf, const int64_t* offsets, int64_t n,
                 [&](int64_t, uint32_t h) {
                     hist[h % (uint32_t)num_bins] += 1.0;
                 });
+}
+
+// A column's entries that are not zero, in row order: row r owns
+// row_entries[r] (slot, count) pairs, its distinct slots ascending, written
+// one row after another into slot_out / count_out (capacity cap: a row of
+// L bytes holds at most (L + 1) / 2 tokens, and no more distinct slots than
+// num_bins). No [n, bins] block is made. Returns the pairs written and
+// leaves the column's token count in *tokens_out; -1 if cap was too small.
+int64_t hash_tokens_entries(const char* buf, const int64_t* offsets,
+                            int64_t n, int32_t num_bins, int32_t lowercase,
+                            int32_t* row_entries, int32_t* slot_out,
+                            int32_t* count_out, int64_t cap,
+                            int64_t* tokens_out) {
+    std::vector<int32_t> counts((size_t)num_bins, 0);
+    std::vector<int32_t> touched;
+    touched.reserve(256);
+    int64_t written = 0, tokens = 0;
+    bool overflow = false;
+    scan_tokens(
+        buf, offsets, n, lowercase,
+        [&](int64_t, uint32_t h) {
+            int32_t b = (int32_t)(h % (uint32_t)num_bins);
+            if (counts[b]++ == 0) touched.push_back(b);
+            ++tokens;
+        },
+        [&](int64_t r) {
+            const int64_t k = (int64_t)touched.size();
+            row_entries[r] = (int32_t)k;
+            if (overflow || written + k > cap) {
+                overflow = true;
+            } else {
+                std::sort(touched.begin(), touched.end());
+                for (int32_t b : touched) {
+                    slot_out[written] = b;
+                    count_out[written++] = counts[b];
+                }
+            }
+            for (int32_t b : touched) counts[b] = 0;
+            touched.clear();
+        });
+    *tokens_out = tokens;
+    return overflow ? -1 : written;
 }
 
 }  // extern "C"

@@ -41,21 +41,13 @@ __all__ = ["TextStats", "SmartTextVectorizer", "SmartTextModel",
 from transmogrifai_tpu.utils.dict_encode import \
     scan_column as _scan_column  # shared object-column scanner
 
-#: hash treatments fall back to the per-row loop when the per-unique
-#: table (uniques x num_hash_features) would exceed this many floats
-#: (true free text — no repetition to exploit)
-_UNIQUE_TABLE_CAP = 64_000_000
-
-
-def _over_table_cap(n_unique: int, num_hash_features: int) -> bool:
-    """Whether a per-unique token-count table would pass the memory cap."""
-    return n_unique * num_hash_features > _UNIQUE_TABLE_CAP
-
-
-#: the device fill (``SmartTextModel.device_output_column``) carries a
-#: value's occupied hash slots as columns of a per-row table; a value past
-#: this many (free text, not an id) sends the stage to the host fill
+#: the per-value device fill (``_dense_from_entries``) carries a value's
+#: occupied hash slots as columns of a per-row table; a column whose values
+#: occupy more (text, not an id) fills from per-row entries instead
 _DEVICE_FILL_MAX_SLOTS = 8
+
+#: rows the repetition scan adds to its set of seen values at a time
+_SCAN_BLOCK = 8192
 
 
 @functools.partial(jax.jit, static_argnames="layout")
@@ -79,6 +71,69 @@ def _dense_from_entries(idx, val, layout):
         g += groups
         off += width
     return out
+
+
+def entry_piece(n_rows: int) -> int:
+    """Entries a call of ``_fill_text_entries`` takes: a power of two set by
+    the ROWS alone (eight a row, between 2**12 and 2**22). A column's
+    entries go up in as many whole pieces as hold them, the last filled up,
+    so that no compiled shape follows how many tokens a table drew."""
+    return 1 << min(max((8 * max(n_rows, 1) - 1).bit_length(), 12), 22)
+
+
+@functools.partial(jax.jit, static_argnames="width")
+def _fill_text_entries_block(length, null, width):
+    """A hashed column's zeroed ``[n, width]`` block with what follows the
+    hash slots written: the text length and the null indicator, where
+    given (a ``[n, 0]`` operand stands for one not tracked)."""
+    with jax.named_scope("fe.text_fill"):
+        n = length.shape[0]
+        tail = jnp.concatenate([length.astype(jnp.float32),
+                                null.astype(jnp.float32)], axis=1)
+        return jnp.concatenate(
+            [jnp.zeros((n, width - tail.shape[1]), jnp.float32), tail],
+            axis=1)
+
+
+def _fill_text_entries(block, row_start, slot, count, base):
+    """One piece of a column's entries written into its block: entry ``e``
+    of the piece is entry ``base + e`` of the column, whose row is the
+    number of row boundaries ``row_start[1:n]`` at or before it; entries
+    past the column's last fall outside the block and are dropped. The
+    (row, slot) positions are distinct, so the scatter sets and need not
+    add; that they also ascend is NOT promised to XLA: on the TPU
+    ``indices_are_sorted`` misplaces two-dimensional positions (chip probe,
+    PR 33). Counts are whole numbers: equal to the host fill to the bit."""
+    with jax.named_scope("fe.text_fill"):
+        n, piece = block.shape[0], slot.shape[0]
+        at = row_start[1:-1] - base
+        marks = jnp.zeros(piece, jnp.int32).at[
+            jnp.where(at < 0, piece, at)].add(1, mode="drop")
+        row = jnp.sum(at < 0) + jnp.cumsum(marks)
+        e = base + jnp.arange(piece, dtype=jnp.int32)
+        row = jnp.where(e < row_start[-1], row, n)
+        return block.at[row, slot.astype(jnp.int32)].set(
+            count.astype(jnp.float32), mode="drop", unique_indices=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _fill_text_entries_program():
+    """``_fill_text_entries`` jitted, the block donated where the backend
+    aliases buffers (each piece is then written in place)."""
+    donate = () if jax.default_backend() == "cpu" else (0,)
+    return jax.jit(_fill_text_entries, donate_argnums=donate)
+
+
+def _fill_text_block(length, null, row_start, *pieces, width: int):
+    """A hashed column's ``[n, width]`` block from its device operands
+    (``SmartTextModel._text_block_operands``): the zeroed block with its
+    length and null columns, then one call of the ONE compiled
+    ``_fill_text_entries`` a piece (``slot``, ``count``, ``base``)."""
+    block = _fill_text_entries_block(length, null, width=width)
+    for i in range(0, len(pieces), 3):
+        block = _fill_text_entries_program()(block, row_start,
+                                             *pieces[i:i + 3])
+    return block
 
 
 def _dict_encode_span(vals: np.ndarray, column: str):
@@ -109,24 +164,15 @@ def pivot_slot_fill(out: np.ndarray, off: int, cats, codes: np.ndarray,
         out[null_mask, off + k + 1] = 1.0
 
 
-def hashed_unique_table(vocab, num_hash_features: int):
-    """[uniques, H] token-count table for a vocab, or None when the table
-    would blow the memory cap (caller falls back to the per-row loop)."""
-    if _over_table_cap(len(vocab), num_hash_features):
-        return None
-    uvecs = np.zeros((len(vocab), num_hash_features), np.float32)
-    for u, v in enumerate(vocab):
-        for tok in tokenize(v):
-            uvecs[u, hash_token(tok, num_hash_features)] += 1.0
-    return uvecs
-
-
-def hashed_unique_slots(vocab, num_hash_features: int):
+def hashed_unique_slots(vocab, num_hash_features: int,
+                        max_slots: Optional[int] = None):
     """Per distinct value, the hash slots its tokens fall into and how many
     tokens fall into each: ``(starts [uniques + 1], slots, counts)``, value
     ``u`` owning ``slots[starts[u]:starts[u + 1]]`` (distinct within a
-    value). The sparse form of :func:`hashed_unique_table`: an id-like
-    value is one slot, not a row of ``num_hash_features`` floats."""
+    value): an id-like value is one slot, not a row of
+    ``num_hash_features`` floats. None at the first value that occupies
+    more than ``max_slots`` slots, where that is given: the caller then has
+    no use for the rest."""
     starts = np.zeros(len(vocab) + 1, np.int64)
     slots: list[int] = []
     counts: list[int] = []
@@ -135,6 +181,8 @@ def hashed_unique_slots(vocab, num_hash_features: int):
         for tok in tokenize(v):
             s = hash_token(tok, num_hash_features)
             per[s] = per.get(s, 0) + 1
+        if max_slots is not None and len(per) > max_slots:
+            return None
         slots.extend(per)
         counts.extend(per.values())
         starts[u + 1] = len(slots)
@@ -177,6 +225,38 @@ def hashed_slot_fill(out: np.ndarray, off: int, vocab, codes: np.ndarray,
     order = np.argsort(r_idx, kind="stable")  # a merge of sorted runs
     out[r_idx[order], np.concatenate(c_idx)[order]] = \
         np.concatenate(vals)[order]
+
+
+def _distinct_exceed(vals: np.ndarray, null_mask: np.ndarray,
+                     limit: int) -> tuple[bool, int]:
+    """Whether a column of strings holds more than ``limit`` distinct
+    values, and how many the pass had seen when it knew: it adds
+    ``_SCAN_BLOCK`` rows at a time to a set and stops once the set passes
+    ``limit``; no vocabulary of the column is built."""
+    seen: set = set()
+    for s in range(0, len(vals), _SCAN_BLOCK):
+        block = vals[s:s + _SCAN_BLOCK]
+        seen.update(block[~null_mask[s:s + _SCAN_BLOCK]].tolist())
+        if len(seen) > limit:
+            return True, len(seen)
+    return False, len(seen)
+
+
+def _values_repeat(vals: np.ndarray, null_mask: np.ndarray,
+                   column: str = "") -> bool:
+    """Whether a hashed column's values repeat: at most half of its values
+    are distinct, so that work a distinct value (a dictionary code, slots
+    hashed once a value) is at most half of work a row. Decided from the
+    column itself, exactly: the pass stops as soon as the distinct values
+    seen pass half of the column's values (a column of free text after half
+    of its rows). Under a ``fe.scan`` span."""
+    from transmogrifai_tpu.utils.tracing import recorder
+    t0 = time.time()
+    distinct, seen = _distinct_exceed(vals, null_mask,
+                                      int((~null_mask).sum()) // 2)
+    recorder.add("fe.scan", t0, time.time(), column=column, rows=len(vals),
+                 seen=seen, repeats=not distinct)
+    return not distinct
 
 
 @dataclass
@@ -254,10 +334,12 @@ class SmartTextVectorizer(Estimator):
             col = data.host_col(name)
             if not self.detect_names:
                 # vectorized stats (the Criteo hot path: 26 columns x 10M+
-                # rows): one native dict-encode pass + a bincount replaces
-                # n per-row TextStats.add() calls. Final-state equivalent:
-                # overflow iff total uniques exceed the cap, counts over
-                # all values otherwise.
+                # rows), final-state equivalent to n per-row
+                # TextStats.add() calls: overflow iff the distinct values
+                # exceed the cap, found by a pass that stops there (no
+                # vocabulary of a column of free text is built); counts
+                # over all values otherwise, by one native dict-encode
+                # pass + a bincount.
                 vals = np.asarray(col.values, dtype=object)
                 null_mask, all_str = _scan_column(vals)
                 nulls = int(null_mask.sum())
@@ -273,15 +355,14 @@ class SmartTextVectorizer(Estimator):
                     stats = TextStats(max_cardinality=self.max_cardinality)
                     for v in col.values:
                         stats.add(v)
+                elif non_null and _distinct_exceed(
+                        vals, null_mask, self.max_cardinality)[0]:
+                    stats.overflowed = True     # TextStats's overflow
                 elif non_null:
                     codes, vocab = _dict_encode_span(vals, name)
-                    if len(vocab) > self.max_cardinality:
-                        stats.overflowed = True
-                    else:
-                        counts = np.bincount(codes[codes >= 0],
-                                             minlength=len(vocab))
-                        stats.counts = {v: int(c)
-                                        for v, c in zip(vocab, counts)}
+                    counts = np.bincount(codes[codes >= 0],
+                                         minlength=len(vocab))
+                    stats.counts = {v: int(c) for v, c in zip(vocab, counts)}
                 name_hits = 0
             else:
                 stats = TextStats(max_cardinality=self.max_cardinality)
@@ -396,11 +477,12 @@ class SmartTextModel(HostTransformer):
 
     def _fill_column(self, out: np.ndarray, offset: int, t: dict,
                      values, n: int, column: str = "") -> None:
-        """Columnar treatment fill — exact per-row (_fill_row) semantics,
-        vectorized for the Criteo-scale categorical path: one native
-        dict-encode pass per column, then per-UNIQUE work (category slot /
-        hashed token counts) gathered back by code. Python cost is
-        O(uniques), not O(rows)."""
+        """Columnar treatment fill, exact per-row (``_fill_row``)
+        semantics with no Python a row: a pivot and a hashed column whose
+        values repeat take one native dict-encode pass and per-UNIQUE work
+        (category slot / hashed token counts) gathered back by code; a
+        hashed column whose values do not repeat takes one native
+        tokenize-and-hash pass (``_tokenize_column``)."""
         kind = t["kind"]
         if kind == "sensitive":
             return
@@ -410,14 +492,28 @@ class SmartTextModel(HostTransformer):
             if self.track_nulls:
                 out[:, offset] = null_mask.astype(np.float32)
             return
+        from transmogrifai_tpu.utils.profiling import sweep_counters
+        from transmogrifai_tpu.utils.tracing import span
         if not all_str:
             # non-string objects: the encoder's vocab is stringified and
             # would mis-route category matching — exact per-row semantics
+            if kind == "hash":
+                sweep_counters.count_run(fe_hash_fallbacks=1)
             for r in range(n):
                 self._fill_row(out[r], offset, t, values[r])
             return
-        from transmogrifai_tpu.utils.profiling import sweep_counters
-        from transmogrifai_tpu.utils.tracing import span
+        H = self.num_hash_features
+        if kind == "hash" and not _values_repeat(vals, null_mask, column):
+            e = self._tokenize_column(vals, null_mask, column)
+            rows = np.repeat(np.arange(n), np.diff(e.row_start))
+            out[rows, offset + e.slot] = e.count
+            pos = offset + H
+            if self.track_text_len:
+                out[:, pos] = e.length
+                pos += 1
+            if self.track_nulls:
+                out[:, pos] = null_mask
+            return
         codes, vocab = _dict_encode_span(vals, column)
         sweep_counters.count_run(fe_distinct_values=len(vocab))
         if kind == "pivot":
@@ -426,45 +522,47 @@ class SmartTextModel(HostTransformer):
                 pivot_slot_fill(out, offset, t["categories"], codes, vocab,
                                 null_mask, self.track_nulls)
             return
-        # hash
-        H = self.num_hash_features
-        over_cap = _over_table_cap(len(vocab), H)
         with span("fe.hash", column=column, rows=n, distinct=len(vocab),
-                  perRowFallback=over_cap):
-            if over_cap:  # exact per-row
-                sweep_counters.count_run(fe_hash_fallbacks=1)
-                for r in range(n):
-                    self._fill_row(out[r], offset, t, values[r])
-                return
+                  perRowFallback=False):
             hashed_slot_fill(out, offset, vocab, codes, null_mask, H,
                              self.track_text_len, self.track_nulls)
 
-    def _column_entries(self, t: dict, values, n: int, column: str):
-        """A column's entries that are not zero, per row: ``(idx, val)``,
-        each ``[n, groups]``, ``idx`` the position inside the column's
-        block or -1. ``_fill_column``'s semantics in the form the device
-        fill reads; None where only the host fill is exact or cheap
-        (objects that are not strings, a vocabulary past the table cap,
-        values of more than ``_DEVICE_FILL_MAX_SLOTS`` slots)."""
+    def _tokenize_column(self, vals: np.ndarray, null_mask: np.ndarray,
+                         column: str):
+        """A hashed column's :class:`ColumnEntries` in one native pass,
+        under a ``fe.tokenize`` span; the train's totals of tokens,
+        entries and rows that took the Python tokenizer are counted."""
+        from transmogrifai_tpu.ops.vectorizers.hashing import (
+            text_column_entries,
+        )
+        from transmogrifai_tpu.utils.profiling import sweep_counters
+        from transmogrifai_tpu.utils.tracing import recorder
+        t0 = time.time()
+        e = text_column_entries(vals, null_mask, self.num_hash_features)
+        recorder.add("fe.tokenize", t0, time.time(), column=column,
+                     rows=len(vals), tokens=e.tokens, entries=len(e.slot),
+                     pythonRows=e.python_rows)
+        sweep_counters.count_run(fe_text_tokens=e.tokens,
+                                 fe_text_entries=len(e.slot),
+                                 fe_text_python_rows=e.python_rows)
+        return e
+
+    def _value_table(self, t: dict, vals: np.ndarray, null_mask: np.ndarray,
+                     column: str):
+        """A pivoted column's, or a repeating hashed column's, entries per
+        row as the per-value device fill reads them: ``(idx, val)``, each
+        ``[n, groups]``, ``idx`` the position inside the column's block or
+        -1; gathered by dictionary code from per-value slots. None for a
+        hashed column one of whose values occupies more than
+        ``_DEVICE_FILL_MAX_SLOTS`` slots."""
         from transmogrifai_tpu.utils.profiling import sweep_counters
         from transmogrifai_tpu.utils.tracing import span
-        kind = t["kind"]
-        none = (np.zeros((n, 0), np.int32), np.zeros((n, 0), np.float32))
-        if kind == "sensitive":
-            return none
-        vals = np.asarray(values, dtype=object)
-        null_mask, all_str = _scan_column(vals)
+        n = len(vals)
         one = np.ones(n, np.float32)
-        if kind == "ignore":
-            if not self.track_nulls:
-                return none
-            return (np.where(null_mask, 0, -1).astype(np.int32)[:, None],
-                    one[:, None])
-        if not all_str:
-            return None
         codes, vocab = _dict_encode_span(vals, column)
+        sweep_counters.count_run(fe_distinct_values=len(vocab))
         at = np.where(null_mask, 0, codes)  # a safe index; nulls are masked
-        if kind == "pivot":
+        if t["kind"] == "pivot":
             with span("fe.pivot", column=column, rows=n,
                       distinct=len(vocab)):
                 cats = t["categories"]
@@ -474,18 +572,16 @@ class SmartTextModel(HostTransformer):
                                  dtype=np.int32)
                 null_at = k + 1 if self.track_nulls else -1
                 idx = np.where(null_mask, null_at, slots[at])
-            sweep_counters.count_run(fe_distinct_values=len(vocab))
             return idx.astype(np.int32)[:, None], one[:, None]
         H = self.num_hash_features
-        if _over_table_cap(len(vocab), H):
-            return None  # the host fill's per-row loop, counted there
         with span("fe.hash", column=column, rows=n, distinct=len(vocab),
                   perRowFallback=False):
-            starts, slots, counts = hashed_unique_slots(vocab, H)
+            hashed = hashed_unique_slots(vocab, H, _DEVICE_FILL_MAX_SLOTS)
+            if hashed is None:
+                return None
+            starts, slots, counts = hashed
             per_value = np.diff(starts)
             width = int(per_value.max()) if per_value.size else 0
-            if width > _DEVICE_FILL_MAX_SLOTS:
-                return None
             # per-value tables [distinct, width], gathered by code
             tab_idx = np.full((max(len(vocab), 1), width), -1, np.int32)
             tab_val = np.zeros(tab_idx.shape, np.float32)
@@ -504,15 +600,66 @@ class SmartTextModel(HostTransformer):
             if self.track_nulls:
                 idx.append(np.where(null_mask, pos, -1)[:, None])
                 val.append(one[:, None])
-        sweep_counters.count_run(fe_distinct_values=len(vocab))
         return (np.concatenate(idx, axis=1).astype(np.int32),
                 np.concatenate(val, axis=1))
 
+    def _column_entries(self, t: dict, values, n: int, column: str):
+        """What the device fill reads of one column: ``(idx, val)`` tables
+        for the per-value fill (``_value_table``; an ignored column's null
+        flag; nothing for a sensitive one), a :class:`ColumnEntries` for a
+        hashed column whose values do not repeat or occupy more slots than
+        a table carries, None where only the host's per-row fill is exact
+        (objects that are not strings)."""
+        kind = t["kind"]
+        none = (np.zeros((n, 0), np.int32), np.zeros((n, 0), np.float32))
+        if kind == "sensitive":
+            return none
+        vals = np.asarray(values, dtype=object)
+        null_mask, all_str = _scan_column(vals)
+        if kind == "ignore":
+            if not self.track_nulls:
+                return none
+            return (np.where(null_mask, 0, -1).astype(np.int32)[:, None],
+                    np.ones((n, 1), np.float32))
+        if not all_str:
+            return None
+        table = None
+        if kind == "pivot" or _values_repeat(vals, null_mask, column):
+            table = self._value_table(t, vals, null_mask, column)
+        if table is None:
+            return self._tokenize_column(vals, null_mask, column)
+        return table
+
+    def _text_block_operands(self, e, n: int) -> list:
+        """Host operands of one column's text fill in the smallest encoding
+        that is exact: ``[length [n, 0 or 1] int32, null [n, 0 or 1] bool,
+        row_start [n + 1] int32]``, then ``slot`` (16 bits up to 65,536
+        slots), ``count`` (8 bits up to 255) and ``base`` a piece of
+        ``entry_piece(n)`` entries; the last piece is filled up with zeros,
+        which the program drops."""
+        small = e.count.max(initial=0) <= np.iinfo(np.uint8).max
+        count = e.count.astype(np.uint8 if small else np.int32)
+        piece = entry_piece(n)
+        length = e.length.astype(np.int32)[:, None]
+        null = e.null[:, None]
+        ops = [length if self.track_text_len else length[:, :0],
+               null if self.track_nulls else null[:, :0],
+               e.row_start.astype(np.int32)]
+        for base in range(0, len(count), piece):
+            for a in (e.slot[base:base + piece], count[base:base + piece]):
+                ops.append(np.concatenate(
+                    [a, np.zeros(piece - len(a), a.dtype)]))
+            ops.append(np.int32(base))
+        return ops
+
     def device_output_column(self, data):
         """The output vector filled ON the device from each row's entries
-        that are not zero (a slot a hashed id, a slot a pivoted value, the
-        text length, the null indicator): tens of numbers a row go up, not
-        a dense row of tens of kilobytes that the host first had to write.
+        that are not zero: tens of numbers a row go up, not a dense row of
+        kilobytes that the host first had to write. Pivots and hashed
+        columns whose values repeat go up as per-row tables of a few
+        (position, value) pairs (``_dense_from_entries``); a hashed column
+        whose values do not repeat goes up as its entries in row order, a
+        piece of ``entry_piece(n)`` at a time (``_fill_text_block``).
         Equal to ``host_apply`` to the bit (counts and indicators). None
         under a mesh and where a column needs the host fill."""
         from transmogrifai_tpu.parallel import mesh as pmesh
@@ -521,25 +668,46 @@ class SmartTextModel(HostTransformer):
         if pmesh.current_mesh() is not None:
             return None
         n = data.n_rows
-        layout, idx, val = [], [], []
+        # one (program, host operands) a block of the vector: consecutive
+        # per-value columns share a block, a column of row-ordered entries
+        # is a block of its own
+        blocks: list = []
+        tables: list = []           # the per-value columns not yet in a block
+
+        def close_tables():
+            if tables:
+                layout, idx, val = zip(*tables)
+                blocks.append((functools.partial(_dense_from_entries,
+                                                 layout=layout),
+                               [np.concatenate(idx, axis=1),
+                                np.concatenate(val, axis=1)]))
+                tables.clear()
+
         for t, name in zip(self.treatments, self.runtime_input_names()):
             entries = self._column_entries(
                 t, data.host_col(name).values, n, name)
             if entries is None:
                 return None
-            layout.append((self._width(t), entries[0].shape[1]))
-            idx.append(entries[0])
-            val.append(entries[1])
-        idx = np.concatenate(idx, axis=1) if idx else np.zeros((n, 0),
-                                                               np.int32)
-        val = np.concatenate(val, axis=1) if val else np.zeros((n, 0),
-                                                               np.float32)
-        nbytes = int(idx.nbytes + val.nbytes)
+            if isinstance(entries, tuple):
+                tables.append(((self._width(t), entries[0].shape[1]),
+                               *entries))
+                continue
+            if len(entries.slot) >= 2 ** 31:     # past int32 positions
+                return None
+            close_tables()
+            blocks.append((functools.partial(_fill_text_block,
+                                             width=self._width(t)),
+                           self._text_block_operands(entries, n)))
+        close_tables()
+        host = [a for _, ops in blocks for a in ops]
+        nbytes = int(sum(a.nbytes for a in host))
         with span("fe.upload", column=self.get_output().name, bytes=nbytes):
-            didx, dval = jax.device_put(idx), jax.device_put(val)
+            up = iter(jax.device_put(host))
         sweep_counters.count_run(fe_upload_bytes=nbytes)
+        out = [fill(*(next(up) for _ in ops)) for fill, ops in blocks] \
+            or [jnp.zeros((n, 0), jnp.float32)]
         return fr.VectorColumn(
-            _dense_from_entries(didx, dval, layout=tuple(layout)),
+            out[0] if len(out) == 1 else jnp.concatenate(out, axis=1),
             self._meta())
 
     def _meta(self) -> VectorMetadata:

@@ -42,6 +42,9 @@ _BIGRAM_RANGES = (
     (0x0E00, 0x0E7F),    # Thai
 )
 
+#: below this character no token needs the per-character script walk
+_FIRST_BIGRAM_CHAR = chr(min(lo for lo, _ in _BIGRAM_RANGES))
+
 #: per-language stopword profiles (removal; detection rides ops/lang.py)
 STOP_WORDS: dict[str, frozenset] = {
     "en": frozenset("the a an and or of to in is are was were be been i you "
@@ -116,6 +119,10 @@ def simple_tokenize(text: str, lowercase: bool = True,
         text = text.lower()
     out = []
     for tok in _WORD_RE.findall(text):
+        if max(tok) < _FIRST_BIGRAM_CHAR:   # no space-less script in it
+            if len(tok) >= min_token_length:
+                out.append(tok)
+            continue
         start = 0
         while start < len(tok):
             is_cjk = _needs_bigrams(tok[start])

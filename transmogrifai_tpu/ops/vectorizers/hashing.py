@@ -15,8 +15,10 @@ behavioral contract, bin distribution quality is.
 
 from __future__ import annotations
 
+import os
 import re
 import zlib
+from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import numpy as np
@@ -30,7 +32,8 @@ from transmogrifai_tpu.vector_metadata import (
 )
 
 __all__ = ["TextHashingVectorizer", "DeviceTextHashingVectorizer",
-           "hash_token", "encode_ascii_rows"]
+           "hash_token", "encode_ascii_rows", "ColumnEntries",
+           "text_column_entries"]
 
 _TOKEN_RE = re.compile(r"[^\W_]+", re.UNICODE)
 
@@ -65,6 +68,13 @@ def _native():
                 np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
             ]
             lib.hash_tokens_hist.restype = None
+            i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+            lib.hash_tokens_entries.argtypes = [
+                ctypes.c_char_p, i64p, ctypes.c_int64, ctypes.c_int32,
+                ctypes.c_int32, i32p, i32p, i32p, ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_int64),
+            ]
+            lib.hash_tokens_entries.restype = ctypes.c_int64
         _native_lib = lib
     return _native_lib
 
@@ -95,6 +105,131 @@ def encode_ascii_rows(values) -> Optional[tuple[bytes, np.ndarray, int]]:
         parts.append(b)
         lens[r + 1] = len(b)
     return b"".join(parts), np.cumsum(lens).astype(np.int64), nulls
+
+
+@dataclass
+class ColumnEntries:
+    """A text column's hashed token counts that are not zero, in row order:
+    row ``r`` owns ``slot[row_start[r]:row_start[r + 1]]`` (distinct within
+    the row, ascending) and the counts beside them; ``length`` is each
+    string's length in characters (0 for a null) and ``null`` the null
+    flag. ``tokens`` counts the column's tokens, ``python_rows`` the rows
+    that took the Python tokenizer."""
+    row_start: np.ndarray
+    slot: np.ndarray
+    count: np.ndarray
+    length: np.ndarray
+    null: np.ndarray
+    tokens: int
+    python_rows: int
+
+
+#: rows a native call tokenizes at a time: a chunk's joined text stays a
+#: few megabytes, and the chunks run side by side (ctypes drops the GIL)
+_ENTRY_CHUNK_ROWS = 2048
+
+
+def _python_row_entries(text: str, num_bins: int) -> tuple[dict, int]:
+    """``({slot: count}, tokens)`` of one string by the row path's own
+    ``tokenize`` / ``hash_token``."""
+    per: dict[int, int] = {}
+    toks = tokenize(text)
+    for tok in toks:
+        b = hash_token(tok, num_bins)
+        per[b] = per.get(b, 0) + 1
+    return per, len(toks)
+
+
+def text_column_entries(vals: np.ndarray, null: np.ndarray,
+                        num_bins: int) -> Optional[ColumnEntries]:
+    """The :class:`ColumnEntries` of an object column of strings (``null``
+    marks its ``None``s) in ONE native pass: every eligible row is
+    tokenized and hashed in C++ (``hash_tokens_entries``), a chunk of rows
+    a call, with no Python a row. Eligible means what
+    :func:`encode_ascii_rows` means, a ROW: a row that is not ASCII or is
+    longer than ``_NATIVE_MAX_LEN`` goes through ``tokenize`` /
+    ``hash_token`` alone and is counted in ``python_rows``; the rest of its
+    column stays native. Equal to the row path to the bit. None where the
+    column holds objects that are not strings."""
+    n = len(vals)
+    present = ~null
+    try:
+        chars = np.frompyfunc(len, 1, 1)(vals[present]).astype(np.int64)
+        ascii_ = np.frompyfunc(str.isascii, 1, 1)(vals[present]).astype(bool)
+    except TypeError:
+        return None
+    length = np.zeros(n, np.int64)
+    length[present] = chars
+    lib = _native()
+    native = np.zeros(n, bool)
+    if lib is not None:
+        native[present] = ascii_ & (chars <= _NATIVE_MAX_LEN)
+    per_row = np.zeros(n, np.int32)
+    narrow = np.uint16 if num_bins <= 1 << 16 else np.int32
+    slots, counts, tokens = [], [], 0
+    if native.any():
+        import ctypes
+        import threading
+        from concurrent.futures import ThreadPoolExecutor
+        nbytes = np.where(native, length, 0)
+        text = np.where(native, vals, "")
+        starts = np.arange(0, n, _ENTRY_CHUNK_ROWS)
+        # a row of L bytes holds at most (L + 1) / 2 tokens, and no more
+        # distinct slots than there are bins
+        caps = np.add.reduceat(np.minimum((nbytes + 1) // 2, num_bins),
+                               starts)
+        scratch = threading.local()   # a worker's output pages, touched once
+
+        def chunk(i: int):
+            s = int(starts[i])
+            e = min(s + _ENTRY_CHUNK_ROWS, n)
+            if not hasattr(scratch, "slot"):
+                scratch.slot = np.empty(max(int(caps.max()), 1), np.int32)
+                scratch.count = np.empty_like(scratch.slot)
+            buf = "".join(text[s:e].tolist()).encode("ascii")
+            offsets = np.zeros(e - s + 1, np.int64)
+            np.cumsum(nbytes[s:e], out=offsets[1:])
+            toks = ctypes.c_int64(0)
+            wrote = lib.hash_tokens_entries(
+                buf, offsets, e - s, num_bins, 1, per_row[s:e],
+                scratch.slot, scratch.count, int(caps[i]),
+                ctypes.byref(toks))
+            assert wrote >= 0, "hash_tokens_entries: capacity too small"
+            # a native row is at most _NATIVE_MAX_LEN characters: 16 bits
+            return (scratch.slot[:wrote].astype(narrow),
+                    scratch.count[:wrote].astype(np.uint16), toks.value)
+
+        with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) \
+                as pool:
+            for slot, count, toks in pool.map(chunk, range(len(starts))):
+                slots.append(slot)
+                counts.append(count)
+                tokens += toks
+    slot = np.concatenate(slots) if slots else np.zeros(0, narrow)
+    count = np.concatenate(counts) if counts else np.zeros(0, np.uint16)
+    python_rows = np.nonzero(present & ~native)[0]
+    if python_rows.size:
+        py_slot, py_count = [], []
+        for r in python_rows:
+            per, toks = _python_row_entries(vals[r], num_bins)
+            per_row[r] = len(per)
+            for b, c in sorted(per.items()):
+                py_slot.append(b)
+                py_count.append(c)
+            tokens += toks
+        is_py = np.zeros(n, bool)
+        is_py[python_rows] = True
+        owner = np.repeat(is_py, per_row)
+        native_slot, native_count = slot, count
+        # a row of any length may count one token past 16 bits
+        slot = np.empty(owner.size, narrow)
+        count = np.empty(owner.size, np.int32)
+        slot[~owner], count[~owner] = native_slot, native_count
+        slot[owner], count[owner] = py_slot, py_count
+    row_start = np.zeros(n + 1, np.int64)
+    np.cumsum(per_row, out=row_start[1:])
+    return ColumnEntries(row_start, slot, count, length, null, int(tokens),
+                         int(python_rows.size))
 
 
 def hash_token(token: str, num_bins: int) -> int:

@@ -482,6 +482,23 @@ class GeolocationMapVectorizer(_MapVectorizerBase):
 # smart text maps
 # ---------------------------------------------------------------------------
 
+#: a key's hashed values fall back to the per-row fill when the per-unique
+#: table (uniques x num_hash_features) would exceed this many floats
+_UNIQUE_TABLE_CAP = 64_000_000
+
+
+def _hashed_unique_table(vocab, num_hash_features: int):
+    """``[uniques, H]`` token-count table for a vocab, or None when the
+    table would pass the memory cap (the caller fills row by row)."""
+    if len(vocab) * num_hash_features > _UNIQUE_TABLE_CAP:
+        return None
+    uvecs = np.zeros((len(vocab), num_hash_features), np.float32)
+    for u, v in enumerate(vocab):
+        for tok in tokenize(v):
+            uvecs[u, hash_token(tok, num_hash_features)] += 1.0
+    return uvecs
+
+
 class _SmartTextMapModel(_KeyedModelBase):
     in_types = (ft.TextMap,)
 
@@ -529,9 +546,7 @@ class _SmartTextMapModel(_KeyedModelBase):
         slot gather / per-unique hashed table — one implementation for the
         scalar and map paths); non-string values and over-cap hash vocabs
         fall back to the exact per-row fill."""
-        from transmogrifai_tpu.ops.smart_text import (
-            hashed_unique_table, pivot_slot_fill,
-        )
+        from transmogrifai_tpu.ops.smart_text import pivot_slot_fill
         from transmogrifai_tpu.utils.dict_encode import (
             dict_encode, scan_column,
         )
@@ -542,7 +557,7 @@ class _SmartTextMapModel(_KeyedModelBase):
         if all_str:
             codes, vocab = dict_encode(vals)
             if t["kind"] != "pivot":
-                uvecs = hashed_unique_table(vocab, self.num_hash_features)
+                uvecs = _hashed_unique_table(vocab, self.num_hash_features)
         if not all_str or (t["kind"] != "pivot" and uvecs is None):
             # non-strings (stringified encoding would skew matching) or an
             # over-cap hash vocab (table would not fit): exact per-row

@@ -456,34 +456,11 @@ class ModelSelector(Estimator):
 
     @staticmethod
     def _stacked_hbm_budget() -> float:
-        """Byte budget for one family's stacked fold batch.
-        ``TRANSMOGRIFAI_SWEEP_HBM_BUDGET`` overrides; otherwise half the
-        reported memory limit from the shared ``utils/devicewatch.py``
-        census — summed across ALL local devices when a mesh is active
-        (the stacked batch shards over it), but device 0's alone without
-        one (un-meshed, the batch lands on a single device and an N-
-        device sum would admit N×-too-large programs) — or 4 GiB when
-        the backend exposes none (CPU)."""
-        import os
-        env = os.environ.get("TRANSMOGRIFAI_SWEEP_HBM_BUDGET")
-        if env:
-            return float(env)
-        try:
-            from transmogrifai_tpu.parallel import mesh as pmesh
-            from transmogrifai_tpu.utils.devicewatch import (
-                device_memory_census,
-            )
-            census = device_memory_census()
-            if pmesh.current_mesh() is not None:
-                limit = float(census["bytesLimit"])
-            else:
-                devices = census["devices"]
-                limit = float(devices[0]["bytesLimit"]) if devices else 0.0
-            if limit > 0:
-                return 0.5 * limit
-        except Exception:  # failure-ok: memory-stats probe; conservative default
-            pass
-        return float(4 << 30)
+        """Byte budget for one family's stacked fold batch
+        (``utils/devicewatch.py::stacked_hbm_budget``, which the families
+        that choose between a gathered and an in-place form read too)."""
+        from transmogrifai_tpu.utils.devicewatch import stacked_hbm_budget
+        return stacked_hbm_budget()
 
     def _stacked_fits_memory(self, batch, est, grid) -> bool:
         """HBM guard for one family's fold-stacked unit: what the family

@@ -137,6 +137,32 @@ def device_memory_census() -> dict:
     return out
 
 
+def stacked_hbm_budget() -> float:
+    """Byte budget for one family's stacked fold batch.
+    ``TRANSMOGRIFAI_SWEEP_HBM_BUDGET`` overrides; otherwise half the
+    reported memory limit from the census — summed across ALL local
+    devices when a mesh is active (the stacked batch shards over it), but
+    device 0's alone without one (un-meshed, the batch lands on a single
+    device and an N-device sum would admit N×-too-large programs) — or
+    4 GiB when the backend exposes none (CPU)."""
+    env = os.environ.get("TRANSMOGRIFAI_SWEEP_HBM_BUDGET")
+    if env:
+        return float(env)
+    try:
+        from transmogrifai_tpu.parallel import mesh as pmesh
+        census = device_memory_census()
+        if pmesh.current_mesh() is not None:
+            limit = float(census["bytesLimit"])
+        else:
+            devices = census["devices"]
+            limit = float(devices[0]["bytesLimit"]) if devices else 0.0
+        if limit > 0:
+            return 0.5 * limit
+    except Exception:  # failure-ok: memory-stats probe; conservative default
+        pass
+    return float(4 << 30)
+
+
 def device_memory() -> tuple[int, int]:
     """``(bytes_in_use, peak_bytes_in_use)`` summed across all local
     devices — the signature ``utils.profiling`` and ``utils.tracing``

@@ -54,8 +54,11 @@ def ensure_checkpoint_dir(path: str, what: str) -> bool:
 
 
 def atomic_json_dump(doc: Any, path: str, **json_kw) -> None:
-    """Write ``doc`` as json to ``path`` atomically (tmp + rename)."""
-    tmp = path + ".tmp"
+    """Write ``doc`` as json to ``path`` atomically (tmp + rename). The
+    tmp name is the writer's own: two processes replacing one document
+    (a rolling promotion) must not rename each other's half-written file
+    away."""
+    tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "w") as fh:
         json.dump(doc, fh, **json_kw)
     os.replace(tmp, path)

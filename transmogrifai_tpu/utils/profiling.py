@@ -466,6 +466,12 @@ class SweepCounters:
         self.fe_distinct_values = 0
         self.fe_hash_fallbacks = 0
         self.fe_upload_bytes = 0
+        #: hashed columns whose values do not repeat (free text): tokens
+        #: hashed, (row, slot) entries they made, and rows that took the
+        #: Python tokenizer (not ASCII, or past the native row length)
+        self.fe_text_tokens = 0
+        self.fe_text_entries = 0
+        self.fe_text_python_rows = 0
         #: ``models/trees.py::predict_tree`` traces that kept a per-row
         #: gather (a frame wider, or a tree deeper, than its
         #: compare-and-select lookups take)
@@ -487,6 +493,9 @@ class SweepCounters:
         self.fe_distinct_values = 0
         self.fe_hash_fallbacks = 0
         self.fe_upload_bytes = 0
+        self.fe_text_tokens = 0
+        self.fe_text_entries = 0
+        self.fe_text_python_rows = 0
         self.tree_gather_walks = 0
         self.loop_fallbacks = {}
         self._compiles_at_reset = compile_telemetry.family_compiles()
@@ -514,7 +523,8 @@ class SweepCounters:
     def count_run(self, *, host_syncs: int = 0, async_families: int = 0,
                   refit_warm_starts: int = 0, operand_bytes: int = 0,
                   fe_distinct_values: int = 0, fe_hash_fallbacks: int = 0,
-                  fe_upload_bytes: int = 0,
+                  fe_upload_bytes: int = 0, fe_text_tokens: int = 0,
+                  fe_text_entries: int = 0, fe_text_python_rows: int = 0,
                   tree_gather_walks: int = 0,
                   loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
@@ -529,6 +539,9 @@ class SweepCounters:
         self.fe_distinct_values += int(fe_distinct_values)
         self.fe_hash_fallbacks += int(fe_hash_fallbacks)
         self.fe_upload_bytes += int(fe_upload_bytes)
+        self.fe_text_tokens += int(fe_text_tokens)
+        self.fe_text_entries += int(fe_text_entries)
+        self.fe_text_python_rows += int(fe_text_python_rows)
         self.tree_gather_walks += int(tree_gather_walks)
         if loop_fallback is not None:
             self.loop_fallbacks[loop_fallback] = \
@@ -552,6 +565,9 @@ class SweepCounters:
                 "feDistinctValues": self.fe_distinct_values,
                 "feHashPerRowFallbacks": self.fe_hash_fallbacks,
                 "feUploadBytes": self.fe_upload_bytes,
+                "feTextTokens": self.fe_text_tokens,
+                "feTextEntries": self.fe_text_entries,
+                "feTextPythonRows": self.fe_text_python_rows,
                 "treeGatherWalks": self.tree_gather_walks,
                 "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
                 "sweepLoopFallbackReasons": dict(self.loop_fallbacks)}
