@@ -37,11 +37,14 @@ native engines with one device-resident histogram learner (SURVEY §2.7 P5):
 Random forests grow CART-style regression trees on bootstrap (Poisson)
 weights with per-tree feature subsampling; for classification the leaf holds
 the class-probability estimate (variance-reduction splits ~ gini for binary).
+On the sorted engine a forest round carries only the rows its bootstrap drew
+(``drawn_rows``, ``forest_rows_carried``).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -185,6 +188,9 @@ _MAX_HIST_NODES = 1024
 #: block one-hot contraction runs the same level in ~80 ms and its cost is
 #: INDEPENDENT of the node count, so deep levels stop needing chunking.
 _SORT_BLOCK = 256
+#: the TPU's lane width: the narrowest block ``_level_block`` lays out
+#: where a node's mean rows allow one of 32 slots or more
+_LANES = 128
 #: byte budget for the materialized one-hot chunk ([blocks, C, d, B] bf16)
 _SORT_OH_BUDGET = 192 * 1024 * 1024
 #: row threshold above which single-device fits switch to the sorted path
@@ -469,6 +475,28 @@ def _segment_sums(vals_sorted, counts):
     return jnp.where(counts > 0, upper - lower, 0.0)
 
 
+def _level_block(n: int, N: int, block: int) -> int:
+    """Slots a block of a level with ``N`` nodes over ``n`` rows: a power
+    of two no wider than ``block`` and about half a node's mean rows (a
+    node's segment is padded to whole blocks, ``N * C`` slots a level),
+    but never 32 or 64: a block narrower than the TPU's 128 lanes costs
+    ``_sorted_hist`` four to five times a slot what a full one does, more
+    than its padding saves. ``n`` is the rows the grower is given (a
+    forest's drawn rows), not the table's. Chosen on the chip (v5e, PR 34;
+    one depth-12 forest tree over ``covtype_multi``'s 229,888 drawn rows of
+    348,606 x 54 under the sweep's 3-fold ``vmap``, its validation scoring
+    included; ms, ``hist`` alone in brackets). The rule before, half the
+    mean rows down to 8: by the drawn rows 550.7 a tree, level 10 in
+    blocks of 64 56.44 (45.17), level 11 in blocks of 32 72.95 (56.72); by
+    the table's rows 533.1, level 10 in blocks of 128 27.99 (13.87), level
+    11 in blocks of 64 74.72 (58.47), level 9 in blocks of 256 21.55
+    against 18.64 in blocks of 128. This rule: 491.4 a tree, level 11 in
+    blocks of 128 42.82 (22.14). ``hist`` costs 24 to 45 ns a slot at 128
+    and 256, 153 at 64, 192 at 32; all rows under the old rule 663.4."""
+    C = min(block, _pow2_at_most(max(n // (2 * N), 8)))
+    return _LANES if _LANES // 4 <= C < _LANES else C
+
+
 def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
                       n_bins: int, reg_lambda, gamma, min_child_weight,
                       block: int = _SORT_BLOCK, data_axis=None):
@@ -515,7 +543,7 @@ def _grow_tree_sorted(Xb, grad, hess, feat_mask, *, max_depth: int,
         # their own scope in the ops' metadata: a device trace splits the
         # grower's time by level and phase (``tree.L<level>/<phase>``)
         N = 2 ** level
-        C = min(block, _pow2_at_most(max(n // (2 * N), 8)))
+        C = _level_block(n, N, block)
         with device_scope(f"tree.L{level}"):
             with device_scope("gather"):
                 layout = _sorted_layout(counts, n, C)
@@ -806,14 +834,81 @@ def predict_tree(Xb, feats, bins, leaf_values):
 # boosting / forest training loops
 # ---------------------------------------------------------------------------
 
+def forest_rows_carried(n: int, subsample: float, *, bootstrap: bool,
+                        hist: str, data_axis=None) -> int:
+    """THE static count of rows a round of ``train_ensemble`` can hand its
+    grower, of the ``n`` it is given. A forest round on the sorted engine
+    of one shard carries only the rows its Poisson(``subsample``) bootstrap
+    drew: a row of weight 0 adds zeros to every histogram and leaf sum and
+    decides no split, and at rate 1 that is ``exp(-1)`` = 36.8% of every
+    slot the grower would move, at every level of every tree. The live
+    count is binomial (deviation under ``0.49 * sqrt(n)``), so the cap
+    stands more than 30 deviations above its mean, rounded up to whole
+    ``_SORT_BLOCK`` blocks; whether a forest's draws hold it is counted,
+    not assumed (``drawn_rows``). Everything else carries ``n``: boosting
+    (every row's prediction feeds the next round), the scatter engine
+    (small fits, CPU, GSPMD), a shard of ``train_ensemble_sharded`` (its
+    live count differs a shard), and a table so small that the cap is
+    ``n`` (under about 2,000 rows)."""
+    if not (bootstrap and hist == "sorted" and data_axis is None):
+        return n
+    cap = math.ceil((1.0 - math.exp(-subsample)) * n + 16.0 * math.sqrt(n))
+    return min(n, -(-cap // _SORT_BLOCK) * _SORT_BLOCK)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "n_rounds", "seed",
+                                             "subsample"))
+def forest_draws(*, n: int, n_rounds: int, seed: int, subsample: float):
+    """[n_rounds, n] int32: the Poisson bootstrap draw of each round of a
+    forest over ``n`` rows, by the keys ``train_ensemble``'s rounds use. A
+    function of its static arguments alone."""
+    def draw(key):
+        return jax.random.poisson(jax.random.split(key)[0], subsample, (n,))
+
+    return jax.lax.map(draw, jax.random.split(jax.random.PRNGKey(seed),
+                                              n_rounds))
+
+
+@jax.jit
+def _most_drawn(draws):
+    """The largest count of drawn rows (positive weight) over the rounds."""
+    return jnp.max(jnp.sum(draws > 0, axis=1))
+
+
+@functools.lru_cache(maxsize=None)
+def _draws_hold(n: int, n_rounds: int, seed: int, subsample: float,
+                n_cap: int) -> bool:
+    """Whether no round of ``forest_draws`` draws more than ``n_cap`` rows:
+    counted on the device and pulled ONCE a process and key (the one host
+    sync the mechanism costs, in a process's first train of a shape)."""
+    return int(_most_drawn(forest_draws(
+        n=n, n_rounds=n_rounds, seed=seed, subsample=subsample))) <= n_cap
+
+
+def drawn_rows(n: int, *, n_rounds: int, seed: int, subsample: float,
+               hist: str, data_axis=None):
+    """The ``draws`` to hand ``train_ensemble`` / ``train_score_stacked``
+    for a forest over ``n`` rows, or ``None`` where its rounds carry all
+    rows: the engine, the axis or the table's size rule compaction out
+    (``forest_rows_carried``), or some round draws more rows than the cap
+    holds (30 deviations out: the forest then grows on all rows, by the
+    program it always had, so no drawn row is ever dropped)."""
+    n_cap = forest_rows_carried(n, subsample, bootstrap=True, hist=hist,
+                                data_axis=data_axis)
+    if n_cap == n or not _draws_hold(n, n_rounds, seed, subsample, n_cap):
+        return None
+    return forest_draws(n=n, n_rounds=n_rounds, seed=seed,
+                        subsample=subsample)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "n_rounds", "max_depth", "n_bins", "n_out", "loss", "seed",
     "bootstrap", "subsample", "colsample", "max_hist_nodes",
     "hist", "data_axis"))
-def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
-                   n_out: int, loss: str, learning_rate, reg_lambda, gamma,
-                   min_child_weight, subsample, colsample, base_score,
-                   bootstrap: bool, seed: int,
+def train_ensemble(Xb, y, w, draws=None, *, n_rounds: int, max_depth: int,
+                   n_bins: int, n_out: int, loss: str, learning_rate,
+                   reg_lambda, gamma, min_child_weight, subsample,
+                   colsample, base_score, bootstrap: bool, seed: int,
                    max_hist_nodes: int = _MAX_HIST_NODES,
                    hist: str = "scatter", data_axis=None):
     """Train a whole ensemble in one scanned program.
@@ -821,14 +916,37 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
     loss: 'logistic' (n_out=1), 'softmax' (n_out=K one-vs-all), 'squared'.
     bootstrap=True grows independent trees on Poisson(1) row weights from
     the base margin (random forest); otherwise rounds are boosted.
+
+    ``draws`` (``drawn_rows``: a forest on the sorted engine whose draws
+    the cap holds) makes a round carry only the rows its bootstrap drew:
+    the rows of positive weight are compacted to the front of ``n_cap``
+    slots (``forest_rows_carried``) once a round, before the round's
+    ``n_out`` trees grow, and the grower sees ``n_cap`` rows (the slots
+    past the live count weigh 0, as dead rows did). The draws are an
+    argument no batch axis reaches, so under ``train_score_stacked``'s
+    ``vmap``s the indices are computed once a round a program and each
+    fold gathers its own rows by them. Without ``draws`` a forest draws
+    inside the program and moves all ``n`` rows, as every other ensemble
+    does. Draws the cap does not hold (``drawn_rows`` hands none out) give
+    NaN leaves, never a forest of fewer rows.
     """
     n, d = Xb.shape
     key0 = jax.random.PRNGKey(seed)
+    n_cap = n
+    if draws is not None:
+        n_cap = forest_rows_carried(n, subsample, bootstrap=bootstrap,
+                                    hist=hist, data_axis=data_axis)
+        if n_cap == n or draws.shape != (n_rounds, n):
+            raise ValueError(
+                f"draws {draws.shape}: for a forest on the sorted engine of "
+                f"one shard over rows the cap is under ({n_cap} of {n}), "
+                f"one draw a round ({n_rounds})")
 
-    def margins_zero():
-        return jnp.broadcast_to(base_score, (n, n_out)).astype(jnp.float32)
+    def margins_zero(rows=n):
+        return jnp.broadcast_to(base_score, (rows, n_out)
+                                ).astype(jnp.float32)
 
-    def grads(margin):
+    def grads(margin, y=y):
         if loss == "logistic":
             p = jax.nn.sigmoid(margin[:, 0])
             return (p - y)[:, None], (p * (1 - p))[:, None]
@@ -844,9 +962,11 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
             return margin - t, jnp.ones_like(margin)
         return margin - y[:, None], jnp.ones_like(margin)
 
-    def one_round(carry, key):
+    def one_round(carry, key_draw):
         margin = carry
-        g, h = grads(margin)
+        key, draw = key_draw if draws is not None else (key_draw, None)
+        if draw is None:  # else a function of the gathered labels, below
+            g, h = grads(margin)
         k_rows, k_cols = jax.random.split(key)
         if data_axis is not None:
             # distributed: row-sampling draws must be INDEPENDENT per
@@ -854,7 +974,9 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
             # below must stay IDENTICAL across shards (k_cols unfolded)
             k_rows = jax.random.fold_in(k_rows,
                                         jax.lax.axis_index(data_axis))
-        if bootstrap:
+        if draw is not None:
+            rw = draw.astype(jnp.float32)
+        elif bootstrap:
             rw = jax.random.poisson(k_rows, subsample, (n,)).astype(jnp.float32)
         elif subsample < 1.0:
             rw = (jax.random.uniform(k_rows, (n,)) < subsample
@@ -867,35 +989,62 @@ def train_ensemble(Xb, y, w, *, n_rounds: int, max_depth: int, n_bins: int,
         fmask = jnp.where(jnp.sum(fmask) < 1.0, jnp.ones(d, jnp.float32),
                           fmask)
 
-        def grow_one(gk, hk):
-            return grow_tree(Xb, gk * rw, hk * rw, fmask,
-                             max_depth=max_depth, n_bins=n_bins,
-                             reg_lambda=reg_lambda, gamma=gamma,
-                             min_child_weight=min_child_weight,
-                             max_hist_nodes=max_hist_nodes, hist=hist,
-                             data_axis=data_axis)
+        def grow_round(Xb, g, h, rw):
+            def grow_one(gk, hk):
+                return grow_tree(Xb, gk * rw, hk * rw, fmask,
+                                 max_depth=max_depth, n_bins=n_bins,
+                                 reg_lambda=reg_lambda, gamma=gamma,
+                                 min_child_weight=min_child_weight,
+                                 max_hist_nodes=max_hist_nodes, hist=hist,
+                                 data_axis=data_axis)
 
-        if n_out == 1:  # the one-output programs as they always were
-            feats, bins, leaves, gains, preds = jax.vmap(
-                grow_one, in_axes=(1, 1))(g, h)
-        else:
+            if n_out == 1:  # the one-output programs as they always were
+                return jax.vmap(grow_one, in_axes=(1, 1))(g, h)
             # the K one-vs-all trees of a round share nothing but the rows:
             # grown one after another inside the program (a ``vmap`` over
             # them multiplies the grower's temporaries, and on the TPU its
             # compile time, by K, and one chip runs them in turn anyway)
-            feats, bins, leaves, gains, preds = jax.lax.map(
-                lambda gh: grow_one(*gh), (g.T, h.T))
+            return jax.lax.map(lambda gh: grow_one(*gh), (g.T, h.T))
+
         # feats/bins: tuples of [n_out, 2^level]; leaves [n_out, 2^depth];
         # preds [n_out, n] come from the grower's final node assignment
         # (no re-descent)
-        if bootstrap:
-            new_margin = margin  # forest trees are independent
-        else:
-            new_margin = margin + learning_rate * preds.T
-        return new_margin, ((feats, bins, leaves), jnp.sum(gains, axis=0))
+        if draw is None:
+            feats, bins, leaves, gains, preds = grow_round(Xb, g, h, rw)
+            if bootstrap:
+                new_margin = margin  # forest trees are independent
+            else:
+                new_margin = margin + learning_rate * preds.T
+            return new_margin, ((feats, bins, leaves),
+                                jnp.sum(gains, axis=0))
+
+        # a forest round on the rows its bootstrap drew: their positions are
+        # one cumsum and one unique-index scatter that no batch axis reaches
+        with device_scope("tree.compact"):
+            live = draw > 0
+            pos = _long_cumsum(live.astype(jnp.int32))
+            n_live = pos[-1]
+            # dead rows (and live ones past a cap that does not hold them)
+            # get DISTINCT out-of-range slots and drop
+            slot = jnp.where(live, pos - 1,
+                             n_cap + jnp.arange(n, dtype=jnp.int32))
+            rows = jnp.zeros(n_cap, jnp.int32).at[slot].set(
+                jnp.arange(n, dtype=jnp.int32), mode="drop",
+                unique_indices=True)
+            held = jnp.arange(n_cap, dtype=jnp.int32) < n_live
+            rw_c = jnp.where(held, rw[rows], 0.0)
+            # a forest's margin is the base score in every round, so the
+            # gradients are a function of the gathered labels
+            g_c, h_c = grads(margins_zero(n_cap), y[rows])
+            Xb_c = Xb[rows]
+        feats, bins, leaves, gains, _ = grow_round(Xb_c, g_c, h_c, rw_c)
+        leaves = jnp.where(n_live <= n_cap, leaves, jnp.nan)
+        return margin, ((feats, bins, leaves), jnp.sum(gains, axis=0))
 
     keys = jax.random.split(key0, n_rounds)
-    _, (trees, gains) = jax.lax.scan(one_round, margins_zero(), keys)
+    _, (trees, gains) = jax.lax.scan(
+        one_round, margins_zero(),
+        keys if draws is None else (keys, draws))
     # trees: pytree with leading [n_rounds] axis; gains: [n_rounds, d]
     return trees, jnp.sum(gains, axis=0)
 
@@ -935,7 +1084,8 @@ def train_ensemble_sharded(ctx, Xb, y, w, **kw):
 @functools.partial(jax.jit, static_argnames=(
     "n_rounds", "max_depth", "n_bins", "loss", "subsample",
     "colsample", "bootstrap", "seed", "hist", "forest_margin", "n_out"))
-def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
+def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw,
+                        draws=None, *,
                         n_rounds: int, max_depth: int, n_bins: int,
                         loss: str, subsample, colsample,
                         bootstrap: bool, seed: int, hist: str,
@@ -954,7 +1104,8 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
     (host-computed with the loop path's exact f32/f64 arithmetic —
     ``tree_stack_fold_bases`` — so stacked-vs-loop parity stays bitwise);
     ``lr/lam/gam/mcw``: ``[L]`` per-lane hyperparameter scalars riding as
-    batched operands. The fold axis is the outer ``vmap``, lanes the
+    batched operands; ``draws``: a forest's ``drawn_rows`` (every fold and
+    lane grows on the rows of the one draw a round), else ``None``. The fold axis is the outer ``vmap``, lanes the
     inner one, so the existing ``lax.scan``-over-rounds grower batches:
     the sorted engine's one-hot contraction gains MXU batch dims
     (node-count-independent, the extra axis feeds the systolic array),
@@ -969,7 +1120,7 @@ def train_score_stacked(Xb, y, w, Xva, base, lr, lam, gam, mcw, *,
     def fold_fn(Xb_k, y_k, w_k, Xva_k, base_k):
         def lane_fn(lr_i, lam_i, gam_i, mcw_i):
             trees, _gains = train_ensemble(
-                Xb_k, y_k, w_k, n_rounds=n_rounds, max_depth=max_depth,
+                Xb_k, y_k, w_k, draws, n_rounds=n_rounds, max_depth=max_depth,
                 n_bins=n_bins, n_out=n_out, loss=loss, learning_rate=lr_i,
                 reg_lambda=lam_i, gamma=gam_i, min_child_weight=mcw_i,
                 subsample=subsample, colsample=colsample,
@@ -1229,6 +1380,25 @@ class _TreePredictor(Predictor):
             edges = self._edges_of(X, max_bins)
             return edges, bin_data(X, edges), max_bins
 
+    def _forest_draws(self, n: int, trees: int, *, n_rounds: int, seed: int,
+                      subsample: float, hist_mode: str):
+        """The ``draws`` of a forest program about to be dispatched
+        (``drawn_rows``; ``None`` for any other program), counted where it
+        is dispatched, from static shapes (a warm process does not
+        retrace): ``n`` rows a tree given and the rows a round carries,
+        times the program's ``trees``. The analytic FLOPs and the
+        lane-memory estimate beside the call sites stay by all rows."""
+        if not self.bootstrap:
+            return None
+        from transmogrifai_tpu.utils.profiling import sweep_counters
+        draws = drawn_rows(n, n_rounds=n_rounds, seed=seed,
+                           subsample=subsample, hist=hist_mode)
+        carried = n if draws is None else forest_rows_carried(
+            n, subsample, bootstrap=True, hist=hist_mode)
+        sweep_counters.count_run(forest_rows_total=n * trees,
+                                 forest_rows_carried=carried * trees)
+        return draws
+
     def fit_arrays(self, X, y, w, params, _binned=None, _lnb=None):
         params = {self._ALIASES.get(k, k): v for k, v in params.items()}
         p = {**self.default_params, **params}
@@ -1240,7 +1410,7 @@ class _TreePredictor(Predictor):
             edges, Xb = _binned[0], _binned[1]
         else:
             edges, Xb, _ = self._binned(X, int(p["max_bins"]))
-        subsample = p["subsample"] if not self.bootstrap else 1.0
+        subsample = float(p["subsample"]) if not self.bootstrap else 1.0
         from transmogrifai_tpu.utils import flops
         n, d = int(Xb.shape[0]), int(Xb.shape[1])
         depth, rounds, B = int(p["max_depth"]), int(p["num_rounds"]), \
@@ -1264,6 +1434,9 @@ class _TreePredictor(Predictor):
             per_tree = sum(5.0 * n * d + 4.0 * n + 12.0 * (2 ** lv) * d * B
                            for lv in range(depth))
         flops.add("tree", rounds * n_out * per_tree)
+        draws = self._forest_draws(n, rounds * n_out, n_rounds=rounds,
+                                   seed=int(p["seed"]), subsample=subsample,
+                                   hist_mode=hist_mode)
         ens_kw = dict(
             n_rounds=int(p["num_rounds"]), max_depth=int(p["max_depth"]),
             n_bins=int(p["max_bins"]), n_out=n_out, loss=loss,
@@ -1271,7 +1444,7 @@ class _TreePredictor(Predictor):
             reg_lambda=jnp.float32(p["reg_lambda"]),
             gamma=jnp.float32(p["gamma"]),
             min_child_weight=jnp.float32(p["min_child_weight"]),
-            subsample=float(subsample),
+            subsample=subsample,
             colsample=float(p["colsample"]),
             base_score=jnp.float32(base),
             bootstrap=self.bootstrap, seed=int(p["seed"]))
@@ -1281,7 +1454,7 @@ class _TreePredictor(Predictor):
                                                   **ens_kw)
         else:
             trees, gains = train_ensemble(
-                Xb, y, w, max_hist_nodes=_MAX_HIST_NODES,
+                Xb, y, w, draws, max_hist_nodes=_MAX_HIST_NODES,
                 hist=hist_mode, **ens_kw)
         model = TreeEnsembleModel(
             kind=self.kind, n_out=n_out,
@@ -1506,10 +1679,14 @@ class _TreePredictor(Predictor):
                            + 12.0 * (2 ** lv) * d * B
                            for lv in range(depth))
         flops.add("tree", k * L * rounds * n_out * per_tree)
+        subsample = 1.0 if self.bootstrap else float(p0["subsample"])
+        draws = self._forest_draws(n_tr, k * L * rounds * n_out,
+                                   n_rounds=rounds, seed=int(p0["seed"]),
+                                   subsample=subsample, hist_mode=hist_mode)
         return train_score_stacked(
-            Xb, y, w, Xva, bases, lrs, lams, gams, mcws,
+            Xb, y, w, Xva, bases, lrs, lams, gams, mcws, draws,
             n_rounds=rounds, max_depth=depth, n_bins=B, loss=loss,
-            subsample=1.0 if self.bootstrap else float(p0["subsample"]),
+            subsample=subsample,
             colsample=float(p0["colsample"]), bootstrap=self.bootstrap,
             seed=int(p0["seed"]), hist=hist_mode,
             forest_margin=self.bootstrap and self.kind.endswith("classifier"),

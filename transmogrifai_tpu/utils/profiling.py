@@ -479,6 +479,11 @@ class SweepCounters:
         #: families (or tree lane groups) that left the fold-stacked path
         #: for the per-fold loop, by the reason the selector observed
         self.loop_fallbacks: dict = {}
+        #: forest programs dispatched: rows times trees they were given,
+        #: and rows times trees their growers carried (the rows a round's
+        #: bootstrap can draw: ``models/trees.py::forest_rows_carried``)
+        self.forest_rows_total = 0
+        self.forest_rows_carried = 0
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -498,6 +503,8 @@ class SweepCounters:
         self.fe_text_python_rows = 0
         self.tree_gather_walks = 0
         self.loop_fallbacks = {}
+        self.forest_rows_total = 0
+        self.forest_rows_carried = 0
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -526,11 +533,13 @@ class SweepCounters:
                   fe_upload_bytes: int = 0, fe_text_tokens: int = 0,
                   fe_text_entries: int = 0, fe_text_python_rows: int = 0,
                   tree_gather_walks: int = 0,
+                  forest_rows_total: int = 0, forest_rows_carried: int = 0,
                   loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
         overlapped families, warm-started refits, operand copies, the
         host string work that fed the sweep, tree walks traced with a
-        per-row gather, and (``loop_fallback``: the reason) one unit that
+        per-row gather, the rows a dispatched forest program was given
+        and carried, and (``loop_fallback``: the reason) one unit that
         left the stacked path for the per-fold loop."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
@@ -543,6 +552,8 @@ class SweepCounters:
         self.fe_text_entries += int(fe_text_entries)
         self.fe_text_python_rows += int(fe_text_python_rows)
         self.tree_gather_walks += int(tree_gather_walks)
+        self.forest_rows_total += int(forest_rows_total)
+        self.forest_rows_carried += int(forest_rows_carried)
         if loop_fallback is not None:
             self.loop_fallbacks[loop_fallback] = \
                 self.loop_fallbacks.get(loop_fallback, 0) + 1
@@ -558,6 +569,10 @@ class SweepCounters:
     def run_to_json(self) -> dict:
         """The run-level one-sync counters (separate from the per-family
         ``to_json`` map so existing consumers keep their shape)."""
+        # a run that dispatched no forest program reports no forest rows
+        forest = ({"forestRowsTotal": self.forest_rows_total,
+                   "forestRowsCarried": self.forest_rows_carried}
+                  if self.forest_rows_total else {})
         return {"sweepHostSyncs": self.sweep_host_syncs,
                 "asyncFamilies": self.async_families,
                 "refitWarmStarts": self.refit_warm_starts,
@@ -570,7 +585,8 @@ class SweepCounters:
                 "feTextPythonRows": self.fe_text_python_rows,
                 "treeGatherWalks": self.tree_gather_walks,
                 "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
-                "sweepLoopFallbackReasons": dict(self.loop_fallbacks)}
+                "sweepLoopFallbackReasons": dict(self.loop_fallbacks),
+                **forest}
 
 
 sweep_counters = SweepCounters()
