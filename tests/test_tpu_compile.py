@@ -57,3 +57,50 @@ def test_newton_in_place_makes_no_copy_of_the_matrix(one_chip, k, g):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 4.0e9      # the padded matrix
     assert memory.temp_size_in_bytes < 1.0e9, memory.temp_size_in_bytes
+
+
+def _broadcasts(text):
+    """(dims, minor-to-major layout) of every broadcast in compiled text."""
+    import re
+    pat = re.compile(r"\[([0-9,]+)\]\{([0-9,]+)[^}]*\} broadcast\(")
+    for m in pat.finditer(text):
+        yield ([int(x) for x in m.group(1).split(",")],
+               [int(x) for x in m.group(2).split(",")])
+
+
+@pytest.mark.parametrize("k,n", [(3, 174_303), (1, 58_101)],
+                         ids=["validation", "holdout"])
+def test_tree_walk_keeps_rows_on_the_lanes(one_chip, monkeypatch, k, n):
+    """``covtype_multi``'s depth-12 forest walked under the sweep's fold
+    and lane ``vmap``s (1 lane, 3 rounds, 7 classes, 54 int8 columns) over
+    its validation folds and its holdout, row counts that are no whole
+    number of 128-lane tiles: every comparison of a row's node with a
+    level table of 128 entries or more keeps the ROWS minor. Over the
+    unpadded rows the compiler put the table axis there (4,467 ms against
+    253 ms a validation walk on the v5e)."""
+    from transmogrifai_tpu.models import trees
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rounds, classes, depth, d = 3, 7, 12, 54
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    lead = (k, 1, rounds, classes)
+    stack = (tuple(arg(lead + (2 ** lv,), jnp.int32) for lv in range(depth)),
+             tuple(arg(lead + (2 ** lv,), jnp.int32) for lv in range(depth)),
+             arg(lead + (2 ** depth,), jnp.float32))
+
+    def program(Xva, forest):
+        def fold_fn(X_k, forest_k):
+            def lane_fn(t):
+                return trees.predict_ensemble(
+                    X_k, t, n_out=classes, learning_rate=1.0,
+                    base_score=0.0, bootstrap=True).T
+            return jax.vmap(lane_fn)(forest_k)
+        return jax.vmap(fold_fn)(Xva, forest)
+
+    text = jax.jit(program).trace(arg((k, n, d), jnp.int8), stack).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    walked = (n, n + (-n % 128))
+    compared = [(dims, layout) for dims, layout in _broadcasts(text)
+                if len(dims) == 6 and dims[4] >= 128 and dims[5] in walked]
+    assert len(compared) >= depth - 7 + 1   # levels 7-11 and the leaves
+    assert all(layout[0] == 5 for _, layout in compared), compared[:3]

@@ -933,29 +933,44 @@ def _bits(a):
     return a.view({2: np.int16, 4: np.int32}[a.dtype.itemsize])
 
 
-@pytest.mark.parametrize("d", [28, 300], ids=["narrow", "wide"])
-@pytest.mark.parametrize("depth", [1, 3, 6, 12, 13])
-def test_select_walk_is_the_gather_walk(depth, d, as_tpu):
+def _pads(jaxpr):
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "pad"]
+
+
+#: (depth, columns, rows) of the walks compared with the gather walk: every
+#: depth and width at a row count that is a multiple of nothing, and the
+#: row counts about one tile of the TPU's lanes at ``covtype_multi``'s depth
+_WALKS = [(depth, d, 1237) for depth in (1, 3, 6, 12, 13) for d in (28, 300)] \
+    + [(12, 28, n) for n in (1, 127, 128, 129)]
+
+
+@pytest.mark.parametrize("depth,d,n", _WALKS,
+                         ids=[f"depth{t}-d{d}-n{n}" for t, d, n in _WALKS])
+def test_select_walk_is_the_gather_walk(depth, d, n, as_tpu):
     """The walk whose lookups compare against whole tables reaches the
     gather walk's leaf for every row and returns that leaf's bits (a
-    ``-0.0`` leaf included): nodes that do not split, a row count that is
-    a multiple of nothing, both sides of the width threshold
-    (``_SELECT_MAX_WIDTH``: wider frames gather the code) and of the table
-    threshold (``_SELECT_MAX_NODES``: depth 13's leaves are gathered)."""
+    ``-0.0`` leaf included): nodes that do not split, both sides of the
+    width threshold (``_SELECT_MAX_WIDTH``: wider frames gather the code)
+    and of the table threshold (``_SELECT_MAX_NODES``: depth 13's leaves
+    are gathered), and row counts in and out of whole 128-row tiles: the
+    walk pads its rows to whole tiles with one ``pad`` and none where they
+    are whole, and ``treeWalkPaddedRows`` reads the rows it added."""
     import jax
     from tree_reference import gather_walk
     from transmogrifai_tpu.models import trees
     from transmogrifai_tpu.utils.profiling import sweep_counters
-    rng = np.random.default_rng(100 * depth + d)
-    n, B = 1237, 64
+    rng = np.random.default_rng(100 * depth + d + n)
+    B = 64
     Xb = jnp.asarray(rng.integers(0, B, size=(n, d)).astype(np.int32))
     tree = _random_trees(rng, (), depth, d, B)
     sweep_counters.reset()
     jaxpr = jax.make_jaxpr(_fresh(trees.predict_tree))(Xb, *tree)
     all_selected = d <= trees._SELECT_MAX_WIDTH \
         and 2 ** depth <= trees._SELECT_MAX_NODES
-    assert sweep_counters.run_to_json()["treeGatherWalks"] == \
-        (0 if all_selected else 1)
+    run = sweep_counters.run_to_json()
+    assert run["treeGatherWalks"] == (0 if all_selected else 1)
+    assert run["treeWalkPaddedRows"] == -n % 128
+    assert len(_pads(jaxpr.jaxpr)) == (1 if n % 128 else 0)
     assert (not _row_long_gathers(jaxpr.jaxpr, n)) == all_selected
     got = jax.jit(_fresh(trees.predict_tree))(Xb, *tree)
     want = jax.jit(gather_walk)(Xb, *tree)
@@ -963,19 +978,47 @@ def test_select_walk_is_the_gather_walk(depth, d, as_tpu):
     assert (_bits(want) == _bits(np.float32(-0.0))).any()
 
 
-@pytest.mark.parametrize("case", ["int16_tables", "int8_codes",
-                                  "bf16_leaves", "three_classes"])
-def test_select_walk_of_other_operands(case, as_tpu, monkeypatch):
+def test_select_walk_leaves_mesh_rows_unpadded(as_tpu, mesh8):
+    """Rows sharded over a mesh's data axis are walked as they are: a pad
+    to whole tiles would reshard them."""
+    import jax
+    from tree_reference import gather_walk
+    from transmogrifai_tpu.models import trees
+    from transmogrifai_tpu.parallel.mesh import shard_rows
+    from transmogrifai_tpu.utils.profiling import sweep_counters
+    rng = np.random.default_rng(5)
+    n, d, B = 1240, 28, 64
+    Xb = shard_rows(jnp.asarray(rng.integers(0, B, size=(n, d)), jnp.int32))
+    tree = _random_trees(rng, (), 12, d, B)
+    sweep_counters.reset()
+    jaxpr = jax.make_jaxpr(_fresh(trees.predict_tree))(Xb, *tree)
+    assert not _pads(jaxpr.jaxpr)
+    assert sweep_counters.run_to_json()["treeWalkPaddedRows"] == 0
+    got = jax.jit(_fresh(trees.predict_tree))(Xb, *tree)
+    np.testing.assert_array_equal(
+        _bits(got), _bits(jax.jit(gather_walk)(Xb, *tree)))
+
+
+_OPERANDS = [(case, 515) for case in ("int16_tables", "int8_codes",
+                                      "bf16_leaves", "three_classes")] \
+    + [("three_classes", n) for n in (1, 127, 128, 129, 1237)]
+
+
+@pytest.mark.parametrize("case,n", _OPERANDS,
+                         ids=[f"{c}-n{n}" for c, n in _OPERANDS])
+def test_select_walk_of_other_operands(case, n, as_tpu, monkeypatch):
     """What the serving rungs and the sweep hand the walk: int16 tables
     (compared after promotion), int8 codes, bfloat16 leaves (returned as
     they are), and a ``[rounds, classes]`` stack through
     ``predict_ensemble`` (against the same ensemble over the gather
-    walk)."""
+    walk) at row counts in and out of whole 128-row tiles: the rows, not
+    batched over rounds or classes, are padded once."""
     import jax
     from tree_reference import gather_walk
     from transmogrifai_tpu.models import trees
-    rng = np.random.default_rng(3)
-    n, d, B, depth = 515, 28, 64, 5
+    from transmogrifai_tpu.utils.profiling import sweep_counters
+    rng = np.random.default_rng(3 + n)
+    d, B, depth = 28, 64, 5
     Xb = rng.integers(0, B, size=(n, d)).astype(
         np.int8 if case == "int8_codes" else np.int32)
     lead = (4, 3) if case == "three_classes" else ()
@@ -987,6 +1030,12 @@ def test_select_walk_of_other_operands(case, as_tpu, monkeypatch):
     if case == "three_classes":
         kw = dict(n_out=3, learning_rate=jnp.float32(0.3),
                   base_score=jnp.float32(0.1), bootstrap=False)
+        sweep_counters.reset()
+        jaxpr = jax.make_jaxpr(
+            lambda X, t: trees.predict_ensemble(X, t, **kw))(
+                Xb, (feats, bins, leaves))
+        assert sweep_counters.run_to_json()["treeWalkPaddedRows"] == -n % 128
+        assert len(_pads(jaxpr.jaxpr)) == (1 if n % 128 else 0)
         got = jax.jit(lambda X, t: trees.predict_ensemble(X, t, **kw))(
             Xb, (feats, bins, leaves))
         monkeypatch.setattr(trees, "predict_tree", gather_walk)

@@ -106,6 +106,11 @@ def quantile_bin_edges_device(X, *, max_bins: int):
 _COUNT_MAX_EDGES = 4096
 _SELECT_MAX_WIDTH = 256
 _SELECT_MAX_NODES = 4096
+#: the TPU's lane width: the row tile a walk that compares against whole
+#: tables scores (``predict_tree``), and the narrowest block
+#: ``_level_block`` lays out where a node's mean rows allow one of 32
+#: slots or more
+_LANES = 128
 
 
 def _compares_all(size: int, cap: int) -> bool:
@@ -116,7 +121,13 @@ def _compares_all(size: int, cap: int) -> bool:
     whatever the table holds, so on a TPU everything up to ``cap`` is
     compared; XLA:CPU gathers fast and MATERIALISES the ``[size, rows]``
     comparison (cpu, PR 30: 304 MB and 215 ms against 3 ms for 18 depth-12
-    trees over 1,000 rows), so off the TPU nothing is."""
+    trees over 1,000 rows), so off the TPU nothing is. The compared form
+    wants its rows in whole tiles of ``_LANES``: over a row count that is
+    not, XLA:TPU lays every table of 128 entries or more with the TABLE
+    axis on the lanes and each row's sum becomes a reduction across them,
+    so ``predict_tree`` pads its rows (v5e: 4,467 ms against 253 ms for
+    ``covtype_multi``'s depth-12 validation walk at 174,303 and 174,336
+    rows)."""
     return size <= cap and jax.default_backend() == "tpu"
 
 
@@ -188,9 +199,6 @@ _MAX_HIST_NODES = 1024
 #: block one-hot contraction runs the same level in ~80 ms and its cost is
 #: INDEPENDENT of the node count, so deep levels stop needing chunking.
 _SORT_BLOCK = 256
-#: the TPU's lane width: the narrowest block ``_level_block`` lays out
-#: where a node's mean rows allow one of 32 slots or more
-_LANES = 128
 #: byte budget for the materialized one-hot chunk ([blocks, C, d, B] bf16)
 _SORT_OH_BUDGET = 192 * 1024 * 1024
 #: row threshold above which single-device fits switch to the sorted path
@@ -800,23 +808,39 @@ def predict_tree(Xb, feats, bins, leaf_values):
     form ``_compares_all`` gives for its table's size: ``_select`` (the
     leaf through its bits), or the per-row gather. The node a row reaches
     is decided by integer comparisons in either, so the forms agree to the
-    bit. ``treeGatherWalks`` counts the traces that kept a gather."""
+    bit. ``treeGatherWalks`` counts the traces that kept a gather.
+
+    Where any table is compared, the rows are padded with code-0 rows to
+    whole tiles of ``_LANES`` (``_compares_all`` says why), walked, and
+    cut off before the leaves are returned: code 0 is a bin and every node
+    index stays inside its table, so a pad row walks like any other.
+    ``Xb`` is not batched over rounds or classes, so under
+    ``predict_ensemble`` the pad is made once. ``treeWalkPaddedRows`` sums
+    the rows added over the traces. Rows sharded over a mesh's data axis
+    are walked as they are: a pad would reshard them."""
+    from transmogrifai_tpu.parallel.mesh import num_data_shards
+    from transmogrifai_tpu.utils.profiling import sweep_counters
     n, d = Xb.shape
     select_code = _compares_all(d, _SELECT_MAX_WIDTH)
     select_leaf = _compares_all(leaf_values.shape[0], _SELECT_MAX_NODES)
+    select_node = [_compares_all(f.shape[0], _SELECT_MAX_NODES) for f in feats]
     if not (select_code and select_leaf):  # the level tables are shorter
-        from transmogrifai_tpu.utils.profiling import sweep_counters
         sweep_counters.count_run(tree_gather_walks=1)
+    pad = -n % _LANES if num_data_shards() == 1 and (
+        select_code or select_leaf or any(select_node)) else 0
+    if pad:
+        Xb = jnp.pad(Xb, ((0, pad), (0, 0)))
+        sweep_counters.count_run(tree_walk_padded_rows=pad)
     if select_code:
         codes = Xb.T.astype(jnp.int32)  # [d, n]: rows on the lanes
     else:
-        rows = jnp.arange(n)
-    node = jnp.zeros(n, dtype=jnp.int32)
+        rows = jnp.arange(n + pad)
+    node = jnp.zeros(n + pad, dtype=jnp.int32)
     for level in range(len(feats)):
         with device_scope(f"L{level}"):
             f_tab = feats[level].astype(jnp.int32)
             b_tab = bins[level].astype(jnp.int32)
-            if _compares_all(f_tab.shape[0], _SELECT_MAX_NODES):
+            if select_node[level]:
                 f, b = _select(f_tab, node), _select(b_tab, node)
             else:
                 f, b = f_tab[node], b_tab[node]
@@ -826,8 +850,10 @@ def predict_tree(Xb, feats, bins, leaf_values):
             node = node * 2 + jnp.where(go_left, 0, 1).astype(jnp.int32)
     with device_scope("leaf"):
         if select_leaf:
-            return _select_float(leaf_values, node)
-        return leaf_values[node]
+            leaves = _select_float(leaf_values, node)
+        else:
+            leaves = leaf_values[node]
+    return leaves[:n] if pad else leaves
 
 
 # ---------------------------------------------------------------------------

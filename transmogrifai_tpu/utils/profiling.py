@@ -473,6 +473,10 @@ class SweepCounters:
         #: gather (a frame wider, or a tree deeper, than its
         #: compare-and-select lookups take)
         self.tree_gather_walks = 0
+        #: rows ``predict_tree`` added to whole tiles of the TPU's lanes
+        #: before a walk that compares against whole tables, summed over
+        #: its traces
+        self.tree_walk_padded_rows = 0
         #: families (or tree lane groups) that left the fold-stacked path
         #: for the per-fold loop, by the reason the selector observed
         self.loop_fallbacks: dict = {}
@@ -497,6 +501,7 @@ class SweepCounters:
         self.fe_text_entries = 0
         self.fe_text_python_rows = 0
         self.tree_gather_walks = 0
+        self.tree_walk_padded_rows = 0
         self.loop_fallbacks = {}
         self.forest_rows_total = 0
         self.forest_rows_carried = 0
@@ -528,13 +533,14 @@ class SweepCounters:
                   fe_text_tokens: int = 0,
                   fe_text_entries: int = 0, fe_text_python_rows: int = 0,
                   tree_gather_walks: int = 0,
+                  tree_walk_padded_rows: int = 0,
                   forest_rows_total: int = 0, forest_rows_carried: int = 0,
                   loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
         overlapped families, warm-started refits, operand copies, the
         host string work that fed the sweep, tree walks traced with a
-        per-row gather, the rows a dispatched forest program was given
-        and carried, and (``loop_fallback``: the reason) one unit that
+        per-row gather and the rows tree walks padded, the rows a
+        dispatched forest program was given and carried, and (``loop_fallback``: the reason) one unit that
         left the stacked path for the per-fold loop."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
@@ -545,6 +551,7 @@ class SweepCounters:
         self.fe_text_entries += int(fe_text_entries)
         self.fe_text_python_rows += int(fe_text_python_rows)
         self.tree_gather_walks += int(tree_gather_walks)
+        self.tree_walk_padded_rows += int(tree_walk_padded_rows)
         self.forest_rows_total += int(forest_rows_total)
         self.forest_rows_carried += int(forest_rows_carried)
         if loop_fallback is not None:
@@ -578,6 +585,7 @@ class SweepCounters:
                 "feTextEntries": self.fe_text_entries,
                 "feTextPythonRows": self.fe_text_python_rows,
                 "treeGatherWalks": self.tree_gather_walks,
+                "treeWalkPaddedRows": self.tree_walk_padded_rows,
                 "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
                 "sweepLoopFallbackReasons": dict(self.loop_fallbacks),
                 "gcCollections": {str(g): n for g, n in
