@@ -243,7 +243,11 @@ def test_warm_refit_regression_metric_parity(monkeypatch):
     """The warm-started (fold-averaged init, donated buffers) winner
     refit reproduces the cold serial refit's train/holdout metrics within
     the artifact-gated 1e-5 on a converged convex sweep, and counts in
-    refitWarmStarts."""
+    refitWarmStarts. Least squares is solved from its Grams up to
+    ``_GRAM_MAX_D`` columns, and a solved point needs no warm start: the
+    descent and its warm refit are what a wider matrix takes, here with
+    the cap at 0."""
+    monkeypatch.setattr(OpLinearRegression, "_GRAM_MAX_D", 0)
     frame = _reg_frame(seed=3)
 
     def make_sel():

@@ -104,3 +104,20 @@ def test_tree_walk_keeps_rows_on_the_lanes(one_chip, monkeypatch, k, n):
                 if len(dims) == 6 and dims[4] >= 128 and dims[5] in walked]
     assert len(compared) >= depth - 7 + 1   # levels 7-11 and the leaves
     assert all(layout[0] == 5 for _, layout in compared), compared[:3]
+
+
+@pytest.mark.parametrize("k,g", [(3, 8), (1, 1)], ids=["sweep", "refit"])
+def test_gram_folds_makes_no_copy_of_the_matrix(one_chip, k, g):
+    """``taxi_duration_train``'s least squares (the sweep's 3 fold
+    weightings x 8 points, a linear winner's refit) over the 1,312,780 x 9
+    training split: one pass of row chunks, so the compiler plans a chunk's
+    working set beside the matrix, not a weighted copy of it a fold, and
+    accepts the lanes' Cholesky and coordinate-descent loops."""
+    from transmogrifai_tpu.models import linear
+    n, d = 1_312_780, 9
+    arg = lambda *shape: _row_major(shape, one_chip)  # noqa: E731
+    compiled = linear._gram_folds.lower(
+        arg(n, d), arg(n), arg(k, n), arg(g), arg(g),
+        chunk=linear._gram_chunk_rows(n, d, k)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes

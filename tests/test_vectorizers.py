@@ -13,6 +13,7 @@ from transmogrifai_tpu.ops.vectorizers import (
     OneHotVectorizer, RealVectorizer, SetVectorizer, TextHashingVectorizer,
     VectorsCombiner,
 )
+from transmogrifai_tpu.ops.vectorizers.dates import TIME_PERIODS
 from transmogrifai_tpu.pipeline_data import PipelineData
 from transmogrifai_tpu.types import feature_types as ft
 from transmogrifai_tpu.vector_metadata import NULL_INDICATOR, OTHER
@@ -143,6 +144,27 @@ def test_date_unit_circle():
     # 6am = quarter turn: sin=1, cos=0
     np.testing.assert_allclose(vec[0], [1.0, 0.0, 0.0], atol=1e-5)
     np.testing.assert_allclose(vec[1], [0.0, 0.0, 1.0], atol=1e-5)
+
+
+@pytest.mark.parametrize("period", sorted(TIME_PERIODS))
+def test_date_unit_circle_keeps_the_phase_of_2016(period):
+    """Epoch milliseconds of 2016 are 131 s apart in float32; the device's
+    (sin, cos) agree with the float64 ``transform_row`` (the serving path)
+    to 1e-5 for every period, over whole seconds of the half year and the
+    odd millisecond."""
+    rng = np.random.default_rng(2016)
+    ms = rng.integers(1_451_606_400_000, 1_467_331_200_000, size=4096)
+    ms[:4] = [1_451_606_400_000, 1_451_692_799_999, 1_459_468_800_001,
+              1_467_331_199_999]
+    host = fr.HostFrame.from_dict({"d": (ft.DateTime, ms.tolist())})
+    feats = FeatureBuilder.from_frame(host)
+    stage = DateToUnitCircleVectorizer(time_period=period)
+    out = feats["d"].transform_with(stage)
+    data, fitted, _ = _fit_one(host, out)
+    got = np.asarray(data.device_col(out.name).values, np.float64)
+    want = np.stack([stage.transform_row(int(v)) for v in ms])
+    np.testing.assert_allclose(got[:, :2], want[:, :2], rtol=0, atol=1e-5)
+    assert np.all(got[:, 2] == 0.0)
 
 
 def test_transmogrify_end_to_end_mixed_types():

@@ -229,27 +229,55 @@ class HostColumn:
 # Device columns (JAX pytrees)
 # ---------------------------------------------------------------------------
 
+#: milliseconds in a day: a date column's ``day_parts`` split
+MS_PER_DAY = 86_400_000
+
+
+def day_parts(ms: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(whole days since the epoch, milliseconds into the day)`` of epoch
+    milliseconds, each as float32 holds it: the days exactly (to year
+    47,900), the milliseconds to 4 ms. Epoch milliseconds themselves are
+    2^17 ms = 131 s apart in float32 at 2016."""
+    ms = np.asarray(ms, np.float64)
+    days = np.floor(ms / MS_PER_DAY)
+    return (days.astype(np.float32),
+            (ms - days * MS_PER_DAY).astype(np.float32))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class NumericColumn:
-    """float32 values + float32 {0,1} mask. Missing slots hold 0 in values."""
+    """float32 values + float32 {0,1} mask. Missing slots hold 0 in values.
+
+    A Date/DateTime column (epoch milliseconds) also carries ``day_parts``,
+    ``(days f32[n], ms_of_day f32[n])`` from :func:`day_parts`, for what
+    needs the time of day (``DateToUnitCircleVectorizer``); every other
+    column has none, and flattens as it always did."""
 
     values: jax.Array  # f32[n]
     mask: jax.Array    # f32[n]
+    day_parts: Optional[tuple] = None
 
     def tree_flatten(self):
-        return (self.values, self.mask), None
+        if self.day_parts is None:
+            return (self.values, self.mask), None
+        return (self.values, self.mask, *self.day_parts), "day_parts"
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        if aux is None:
+            return cls(*children)
+        return cls(children[0], children[1], tuple(children[2:]))
 
     @staticmethod
     def from_host(col: HostColumn) -> "NumericColumn":
+        vals = np.where(col.mask, col.values, 0.0)
+        parts = None
+        if col.kind in ("date", "datetime"):
+            parts = tuple(jnp.asarray(p) for p in day_parts(vals))
         return NumericColumn(
-            jnp.asarray(np.where(col.mask, col.values, 0.0), dtype=jnp.float32),
-            jnp.asarray(col.mask, dtype=jnp.float32),
-        )
+            jnp.asarray(vals, dtype=jnp.float32),
+            jnp.asarray(col.mask, dtype=jnp.float32), parts)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -1173,7 +1173,10 @@ def predict_ensemble(Xb, trees, *, n_out: int, learning_rate, base_score,
     """[n, n_out] margins of a stacked ensemble (``trees``: per-level
     ``[R, n_out, 2**level]`` tables and ``[R, n_out, 2**depth]`` leaves):
     ``predict_tree`` over rounds and classes, under the device scope
-    ``tree.predict`` (its levels read ``tree.predict/L<level>``)."""
+    ``tree.predict`` (its levels read ``tree.predict/L<level>``). A forest
+    is the mean of its trees: a regression forest's caller adds the base
+    its trees grew from (their leaves are residuals of the label's mean,
+    ``_TreePredictor._stacked_base_mode``)."""
     walk = functools.partial(predict_tree, Xb)
     with device_scope("tree.predict"):
         preds = jax.vmap(jax.vmap(walk))(*trees)  # [R, n_out, n]
@@ -1244,6 +1247,8 @@ class TreeEnsembleModel(PredictionModel):
             bootstrap=self.is_forest)  # [n, n_out]
         n = out.shape[0]
         if not self.is_classifier:
+            if self.is_forest:      # its trees fit residuals of the base
+                out = out + base
             empty = jnp.zeros((n, 0), jnp.float32)
             return fr.PredictionColumn(out[:, 0], empty, empty)
         if self.is_forest:
@@ -1382,9 +1387,10 @@ class _TreePredictor(Predictor):
         """How the fold x grid-stacked program derives each fold's base
         score IN-PROGRAM — must mirror ``_loss_and_nout``'s base exactly
         (the stacked-vs-loop parity contract), so overrides pair with it:
-        ``"mean"`` = fold label mean (squared losses, forests included —
-        forests trained on a mean base fit residuals whose base is never
-        re-added at predict, the established semantics), ``"logodds"`` =
+        ``"mean"`` = fold label mean (squared losses, forests included:
+        a regression forest's trees fit residuals of that mean, which every
+        prediction of it adds back: ``TreeEnsembleModel.device_apply``,
+        ``tree_stack_scores``, ``grid_predict_scores``), ``"logodds"`` =
         log-odds of the fold's positive rate, ``"zero"`` = 0 (forest
         classifiers, and one-vs-all boosting past two classes)."""
         if loss == "squared":
@@ -1573,7 +1579,12 @@ class _TreePredictor(Predictor):
             return None
         lrs = jnp.asarray([m.learning_rate for m in models], jnp.float32)
         bases = jnp.asarray([m.base_score for m in models], jnp.float32)
-        return jax.vmap(score_one)(stacked, lrs, bases)
+        scores = jax.vmap(score_one)(stacked, lrs, bases)
+        if m0.is_forest and not m0.is_classifier:
+            # a regression forest's base, added as the stacked sweep adds
+            # it (``tree_stack_scores``): one rounding, the same in both
+            return scores + bases[:, None]
+        return scores
 
     # -- fold x grid-stacked sweep (round 8) ---------------------------------
     def tree_stack_groups(self, grid):
@@ -1709,14 +1720,19 @@ class _TreePredictor(Predictor):
         draws = self._forest_draws(n_tr, k * L * rounds * n_out,
                                    n_rounds=rounds, seed=int(p0["seed"]),
                                    subsample=subsample, hist_mode=hist_mode)
-        return train_score_stacked(
+        classifier = self.kind.endswith("classifier")
+        scores = train_score_stacked(
             Xb, y, w, Xva, bases, lrs, lams, gams, mcws, draws,
             n_rounds=rounds, max_depth=depth, n_bins=B, loss=loss,
             subsample=subsample,
             colsample=float(p0["colsample"]), bootstrap=self.bootstrap,
             seed=int(p0["seed"]), hist=hist_mode,
-            forest_margin=self.bootstrap and self.kind.endswith("classifier"),
-            n_out=n_out)
+            forest_margin=self.bootstrap and classifier, n_out=n_out)
+        if self.bootstrap and not classifier:
+            # a regression forest's trees fit residuals of the fold's mean;
+            # its prediction adds the mean back
+            return scores + bases[:, None, None]
+        return scores
 
     # -- winner refit (round 9) ----------------------------------------------
     def refit_winner(self, X, y, w, params, *, warm=None, lane=None,

@@ -5,8 +5,13 @@ Parity: reference ``core/.../stages/impl/feature/DateToUnitCircleTransformer
 (HourOfDay, DayOfWeek, DayOfMonth, DayOfYear, HourOfWeek, MonthOfYear,
 WeekOfMonth, WeekOfYear), so midnight and 23:59 are neighbors.
 
-TPU-first: the phase extraction is pure modular arithmetic on epoch millis,
-jittable and fused — no calendar library on the hot path. Month-anchored
+TPU-first: the phase extraction is pure modular arithmetic, jittable and
+fused — no calendar library on the hot path. Epoch milliseconds are 131 s
+apart in float32 at 2016, so a date column reaches the device as whole days
+since the epoch and milliseconds into the day as well (``frame.day_parts``),
+and the phase is taken from those: the days modulo the period in int32 (every
+period is a whole number of milliseconds, a ratio of small integers in
+days), then the time of day in float32. Month-anchored
 periods (DayOfMonth, MonthOfYear, WeekOfMonth) use the mean Gregorian month
 (30.436875 days); the cyclic encoding is phase-accurate to within leap-drift,
 which is what the model consumes. Missing dates encode as the circle center
@@ -15,6 +20,7 @@ which is what the model consumes. Missing dates encode as the circle center
 
 from __future__ import annotations
 
+from fractions import Fraction
 from typing import Optional
 
 import jax.numpy as jnp
@@ -50,6 +56,30 @@ TIME_PERIODS: dict[str, tuple[float, float]] = {
 }
 
 
+def _in_days(period: str) -> tuple[int, int, int]:
+    """``(num, den, offset)`` of a period: its modulus is ``num / den``
+    days and its offset ``offset / den`` days, all whole numbers."""
+    modulus, offset = TIME_PERIODS[period]
+    span = Fraction(round(modulus), fr.MS_PER_DAY)
+    shift = Fraction(round(offset), fr.MS_PER_DAY) * span.denominator
+    if shift.denominator != 1:
+        raise ValueError(f"{period}: offset is no whole number of 1/"
+                         f"{span.denominator} days")
+    return span.numerator, span.denominator, int(shift)
+
+
+def _phase_of_parts(period: str, days, ms_of_day):
+    """The phase in ``[0, 2 pi)`` of ``frame.day_parts``: the days modulo
+    the period's ``num / den`` days exactly, in int32 (to day 1,342,177 at
+    ``den`` 1,600), then the time of day added in float32 (one period
+    spans at most 146,097 such units: 1e-7 of a turn)."""
+    num, den, shift = _in_days(period)
+    whole = jnp.mod(days.astype(jnp.int32) * den + shift, num)
+    turn = whole.astype(jnp.float32) + ms_of_day * (den / fr.MS_PER_DAY)
+    turn = jnp.where(turn >= num, turn - num, turn)
+    return turn / num * (2.0 * np.pi)
+
+
 class DateToUnitCircleVectorizer(DeviceTransformer):
     """N date inputs -> [sin, cos][, null] per input."""
 
@@ -73,7 +103,8 @@ class DateToUnitCircleVectorizer(DeviceTransformer):
     def device_apply(self, params, *cols: fr.NumericColumn) -> fr.VectorColumn:
         pieces = []
         for c in cols:
-            theta = self._phase(c.values)
+            theta = (self._phase(c.values) if c.day_parts is None
+                     else _phase_of_parts(self.time_period, *c.day_parts))
             pieces.append((jnp.sin(theta) * c.mask)[:, None])
             pieces.append((jnp.cos(theta) * c.mask)[:, None])
             if self.track_nulls:

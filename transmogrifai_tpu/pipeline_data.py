@@ -68,10 +68,9 @@ def _upload_rows(arr):
 
 @jax.jit
 def _split_columns(dvals, dmasks):
-    k = dvals.shape[1]
     dmasks = dmasks.astype(jnp.float32)
-    return (tuple(dvals[:, i] for i in range(k)),
-            tuple(dmasks[:, i] for i in range(k)))
+    return (tuple(dvals[:, i] for i in range(dvals.shape[1])),
+            tuple(dmasks[:, i] for i in range(dmasks.shape[1])))
 
 
 class PipelineData:
@@ -172,13 +171,18 @@ class PipelineData:
         from transmogrifai_tpu.utils.profiling import OpStep, profiler
         from transmogrifai_tpu.utils.tracing import span
         n = len(pending[0][1].values)
+        # a date column's exact day parts ride as two more value columns
+        dates = [i for i, (_, c) in enumerate(pending)
+                 if c.kind in ("date", "datetime")]
         # float32 values and uint8 masks go up
         with profiler.phase(OpStep.DATA_READING_AND_FILTERING), \
                 span("ingest.numeric", columns=len(pending), rows=n,
-                     bytes=5 * n * len(pending)):
+                     bytes=5 * n * len(pending) + 8 * n * len(dates)):
+            filled = [np.where(c.mask, c.values, 0.0) for _, c in pending]
             vals = np.stack(
-                [np.where(c.mask, c.values, 0.0).astype(np.float32)
-                 for _, c in pending], axis=1)
+                [v.astype(np.float32) for v in filled]
+                + [p for i in dates for p in fr.day_parts(filled[i])],
+                axis=1)
             # masks travel as uint8 (4x fewer bytes over the link) and
             # widen to f32 on device inside _split_columns
             masks = np.stack([c.mask.astype(np.uint8) for _, c in pending],
@@ -192,8 +196,12 @@ class PipelineData:
             # split into per-column arrays inside ONE jitted program — k
             # eager `dvals[:, i]` slices would pay k dispatches
             cols_v, cols_m = _split_columns(dvals, dmasks)
+            parts = {i: (cols_v[len(pending) + 2 * j],
+                         cols_v[len(pending) + 2 * j + 1])
+                     for j, i in enumerate(dates)}
             for i, (name, _) in enumerate(pending):
-                self.device[name] = fr.NumericColumn(cols_v[i], cols_m[i])
+                self.device[name] = fr.NumericColumn(cols_v[i], cols_m[i],
+                                                     parts.get(i))
 
     @staticmethod
     def _encode_text(col: fr.HostColumn) -> fr.CodesColumn:
@@ -228,8 +236,16 @@ class PipelineData:
 
     # -- updates -------------------------------------------------------------
     def with_host_cols(self, new: Mapping[str, fr.HostColumn]) -> "PipelineData":
-        return PipelineData(self.host.with_columns(new), self.device,
-                            n_rows_logical=self._n_logical)
+        out = PipelineData(self.host.with_columns(new), self.device,
+                           n_rows_logical=self._n_logical)
+        # a text column's codes hold while its host values do: a stage
+        # that adds host columns does not make the next one encode again
+        stale = set(new) & set(self._codes_cache)
+        out._codes_cache = ({n: c for n, c in self._codes_cache.items()
+                             if n not in stale} if stale
+                            else self._codes_cache)
+        out._row_mask = self._row_mask
+        return out
 
     def with_device_cols(self, new: Mapping[str, Any]) -> "PipelineData":
         dev = dict(self.device)
@@ -277,8 +293,10 @@ class PipelineData:
         dev = {}
         for n, c in self.device.items():
             if isinstance(c, fr.NumericColumn):
-                dev[n] = fr.NumericColumn(_shard(c.values[jidx]),
-                                          _shard(c.mask[jidx]))
+                dev[n] = fr.NumericColumn(
+                    _shard(c.values[jidx]), _shard(c.mask[jidx]),
+                    None if c.day_parts is None
+                    else tuple(_shard(p[jidx]) for p in c.day_parts))
             elif isinstance(c, fr.VectorColumn):
                 dev[n] = fr.VectorColumn(_shard(c.values[jidx]), c.metadata)
             elif isinstance(c, fr.CodesColumn):

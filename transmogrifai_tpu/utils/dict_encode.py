@@ -42,6 +42,13 @@ def _check_trailing_nul(pvals: np.ndarray, fixed: np.ndarray) -> None:
     NULs, so they are exempt from the comparison."""
     if len(pvals) == 0:
         return
+    try:
+        # the common case in one C pass: strings that hold no NUL at all
+        # lose none (a Python call a row took 0.3 s a million rows)
+        if "\x00" not in "".join(pvals.tolist()):
+            return
+    except TypeError:       # not all strings: compare them one by one
+        pass
 
     def _len(v):
         return len(v) if isinstance(v, (str, bytes)) else -1

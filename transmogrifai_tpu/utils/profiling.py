@@ -485,6 +485,10 @@ class SweepCounters:
         #: bootstrap can draw: ``models/trees.py::forest_rows_carried``)
         self.forest_rows_total = 0
         self.forest_rows_carried = 0
+        #: linear lanes (row weighting x grid point, or one warm refit)
+        #: trained by ``models/linear.py::_linear_descent``, the Adam
+        #: descent, and not solved
+        self.linear_descent_lanes = 0
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -505,6 +509,7 @@ class SweepCounters:
         self.loop_fallbacks = {}
         self.forest_rows_total = 0
         self.forest_rows_carried = 0
+        self.linear_descent_lanes = 0
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -535,13 +540,15 @@ class SweepCounters:
                   tree_gather_walks: int = 0,
                   tree_walk_padded_rows: int = 0,
                   forest_rows_total: int = 0, forest_rows_carried: int = 0,
+                  linear_descent_lanes: int = 0,
                   loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
         overlapped families, warm-started refits, operand copies, the
         host string work that fed the sweep, tree walks traced with a
         per-row gather and the rows tree walks padded, the rows a
-        dispatched forest program was given and carried, and (``loop_fallback``: the reason) one unit that
-        left the stacked path for the per-fold loop."""
+        dispatched forest program was given and carried, the linear lanes
+        that took the Adam descent, and (``loop_fallback``: the reason) one
+        unit that left the stacked path for the per-fold loop."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
@@ -554,6 +561,7 @@ class SweepCounters:
         self.tree_walk_padded_rows += int(tree_walk_padded_rows)
         self.forest_rows_total += int(forest_rows_total)
         self.forest_rows_carried += int(forest_rows_carried)
+        self.linear_descent_lanes += int(linear_descent_lanes)
         if loop_fallback is not None:
             self.loop_fallbacks[loop_fallback] = \
                 self.loop_fallbacks.get(loop_fallback, 0) + 1
@@ -586,6 +594,7 @@ class SweepCounters:
                 "feTextPythonRows": self.fe_text_python_rows,
                 "treeGatherWalks": self.tree_gather_walks,
                 "treeWalkPaddedRows": self.tree_walk_padded_rows,
+                "linearDescentLanes": self.linear_descent_lanes,
                 "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
                 "sweepLoopFallbackReasons": dict(self.loop_fallbacks),
                 "gcCollections": {str(g): n for g, n in

@@ -12,6 +12,8 @@ transformer DAG (layer-fused jit programs), ``evaluate()`` runs evaluators,
 
 from __future__ import annotations
 
+import functools
+
 import json
 import os
 from typing import Any, Callable, Iterable, Optional, Sequence
@@ -457,8 +459,9 @@ class WorkflowModel:
         self.dag = dag
         self.executor = executor or DagExecutor()
         self.blocklisted = list(blocklisted)
-        #: bounded-bin label histogram captured at train time (ModelInsights)
-        self.label_distribution = label_distribution
+        #: bounded-bin label histogram of the training label (ModelInsights):
+        #: a dict, or the function that makes it on first read
+        self._label_distribution = label_distribution
         #: RawFeatureFilterResults (or None) — exclusion reasons incl.
         #: per-key map blocklists, surfaced in summary/ModelInsights
         self.raw_filter_results = raw_filter_results
@@ -467,6 +470,12 @@ class WorkflowModel:
         #: host->device re-transfer at scoring time
         from transmogrifai_tpu.ingest_fusion import DeviceFrameCache
         self._frame_cache = DeviceFrameCache()
+
+    @property
+    def label_distribution(self) -> Optional[dict]:
+        if callable(self._label_distribution):
+            self._label_distribution = self._label_distribution()
+        return self._label_distribution
 
     # -- scoring -------------------------------------------------------------
     def _ingest_frame(self, reader_or_frame) -> fr.HostFrame:
@@ -712,11 +721,12 @@ def _frame_up_to(data, raw_features, dag) -> fr.HostFrame:
     return fr.HostFrame(cols, data.host.key)
 
 
-def _label_distribution(frame: fr.HostFrame, raw_features) -> Optional[dict]:
+def _label_distribution(frame: fr.HostFrame, raw_features):
     """Bounded-memory label histogram (reference: StreamingHistogram fed by
-    the regression label; here for any numeric response)."""
-    from transmogrifai_tpu.utils.streaming_histogram import StreamingHistogram
-
+    the regression label; here for any numeric response), as a function
+    that makes it from the label's values taken now: a histogram of a
+    real-valued label takes a second per million rows, and a train whose
+    model is never asked for it does not pay that."""
     for f in raw_features:
         if not f.is_response or f.name not in frame.columns:
             continue
@@ -728,16 +738,20 @@ def _label_distribution(frame: fr.HostFrame, raw_features) -> Optional[dict]:
         mask = getattr(col, "mask", None)
         if mask is not None:
             vals = vals[np.asarray(mask, bool)]
-        h = StreamingHistogram(max_bins=100).update_all(vals)
-        d = h.to_json()
-        d["name"] = f.name
-        d["count"] = int(np.isfinite(vals).sum())
-        if d["count"]:
-            d["mean"] = float(np.nanmean(vals))
-            d["min"] = float(np.nanmin(vals))
-            d["max"] = float(np.nanmax(vals))
-        return d
+        return functools.partial(_label_histogram, f.name, vals.copy())
     return None
+
+
+def _label_histogram(name: str, vals: np.ndarray) -> dict:
+    from transmogrifai_tpu.utils.streaming_histogram import StreamingHistogram
+    d = StreamingHistogram(max_bins=100).update_all(vals).to_json()
+    d["name"] = name
+    d["count"] = int(np.isfinite(vals).sum())
+    if d["count"]:
+        d["mean"] = float(np.nanmean(vals))
+        d["min"] = float(np.nanmin(vals))
+        d["max"] = float(np.nanmax(vals))
+    return d
 
 
 def _apply_blocklist(result_features: Sequence[FeatureLike],
