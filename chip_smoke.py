@@ -20,7 +20,8 @@ multiclass selector: no unit leaves the stacked sweep, no tree walk gathers),
 the vector filled on the device and equal to the plain reference's to the bit),
 ``serve`` (the higgs winner behind a real localhost endpoint, JSON + binary
 frames against the row-path oracle), ``kernels`` (each Pallas kernel through
-its public stage, compiled, against its XLA twin), ``mesh`` (the higgs leg
+its public stage, compiled, against its XLA twin; the linear descent's
+one-pass gradient against the ``highest`` product), ``mesh`` (the higgs leg
 under two mesh shapes; runs when >= 4 devices are visible).
 
 A bare run asserts ``jax.default_backend() == "tpu"`` before any work and exits
@@ -73,6 +74,14 @@ FRAME_SIZES = (1, 7, 64, 256)
 #: Tree winners involve no matmul and agree to ~1e-6. The leg also checks
 #: that this tolerance REJECTS a reply paired with another row's oracle.
 SERVE_TOL = 5e-2
+
+#: the linear descent's one-pass step (``models/linear.py::_onepass_terms``):
+#: its gradient against the ``highest`` product's, as a share of the
+#: gradient's largest entry. Three bfloat16 products (``HIGH``) read about
+#: 5e-6 there on the v5e, ONE bfloat16 pass about 2e-3, and the fold metrics
+#: of ``criteo_ctr`` do not tell the two apart; the leg also checks that this
+#: tolerance REJECTS XLA's one-pass product.
+ONEPASS_GRAD_TOL = 5e-5
 
 
 class Leg:
@@ -606,8 +615,62 @@ def leg_kernels(leg: Leg, out: str, ctx: dict) -> None:
     leg.info["hashing_width"] = int(got.shape[1])
     del got, ref
 
+    # 3. the linear descent's one-pass step -> models/linear.py
+    _onepass_precision(leg, min(n, 20_483), on_tpu)
+
     leg.info["interpret"] = not on_tpu
     leg.info["peak_bytes_in_use"] = _peaks()
+
+
+def _onepass_precision(leg: Leg, n: int, on_tpu: bool) -> None:
+    """The one-pass step's softmax gradient over ``n x 2,048`` raw columns
+    of offset 5 and 24 lanes of fold weights, against the same gradient by
+    ``jax.grad`` at ``highest`` and at one bfloat16 pass: a kernel whose
+    split into bfloat16 halves the compiler folded into ONE pass passes
+    every CPU test (interpret mode multiplies exactly) and the criteo
+    cell's limits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from transmogrifai_tpu.models import linear
+    d, L, C = 2_048, 24, 2
+    rng = np.random.default_rng(12)
+    X = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32) * 3.0 + 5.0)
+    y = jnp.asarray(rng.integers(0, C, n).astype(np.float32))
+    w = jnp.asarray((rng.uniform(size=(L, n)) > 1 / 3).astype(np.float32))
+    center, scale = linear._standardize_stats(X, jnp.ones(n, jnp.float32))
+    inv = 1.0 / scale
+    We = jnp.asarray(rng.normal(size=(L, d, C)).astype(np.float32)) * 0.02
+    off = jnp.zeros((L, C), jnp.float32)
+    rows = linear._onepass_rows_of(w, y, jnp.zeros(L), jnp.ones(L))
+    hot = jax.nn.one_hot(y.astype(jnp.int32), C, axis=0)[:, None, :]
+
+    def xla_grad(precision):
+        def data(We):
+            z = jnp.einsum("nd,ldc->cln", (X - center) * inv, We,
+                           precision=precision) + off.T[:, :, None]
+            return -jnp.sum(jax.nn.log_softmax(z, axis=0) * hot * w)
+        return jax.jit(jax.grad(data))(We)
+
+    def terms(We):
+        return linear._onepass_terms(X, center, inv, We, off, *rows,
+                                     loss_kind="softmax")[1]
+
+    want = xla_grad("highest")
+    scale_g = float(jnp.max(jnp.abs(want)))
+    gap = {name: float(jnp.max(jnp.abs(g - want))) / scale_g
+           for name, g in (("onepass", jax.jit(terms)(We)),
+                           ("xla_high", xla_grad("high")),
+                           ("xla_one_bf16_pass", xla_grad("default")))}
+    leg.info["onepass_grad_gap"] = gap
+    leg.info["onepass_rows"] = n
+    leg.check(gap["onepass"] <= ONEPASS_GRAD_TOL,
+              f"one-pass gradient within {ONEPASS_GRAD_TOL} of highest")
+    if on_tpu:
+        leg.check(gap["xla_one_bf16_pass"] > ONEPASS_GRAD_TOL,
+                  "the one-pass tolerance rejects one bfloat16 pass")
+        leg.check(_lowers_to_custom_call(jax.jit(terms), We),
+                  "one-pass step lowers to a tpu_custom_call")
 
 
 # -- mesh ---------------------------------------------------------------------

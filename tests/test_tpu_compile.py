@@ -121,3 +121,95 @@ def test_gram_folds_makes_no_copy_of_the_matrix(one_chip, k, g):
         chunk=linear._gram_chunk_rows(n, d, k)).compile()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes
+
+
+def _custom_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("k,g,loss,C", [(3, 8, "softmax", 2),
+                                        (3, 4, "hinge", 2),
+                                        (1, 1, "softmax", 2)],
+                         ids=["lr", "svc", "refit"])
+def test_onepass_descent_makes_no_copy_of_the_matrix(one_chip, monkeypatch,
+                                                     k, g, loss, C):
+    """``criteo_ctr``'s Adam lanes over the 108,000 x 8,960 training split
+    (logistic regression's 8 points x 3 folds x 2 classes, the hinge
+    family's 4 x 3, a winner's one lane): every step is the one-pass
+    kernel, which Mosaic accepts inside the VMEM it asks for, beside no
+    copy of the 3.9 GB matrix."""
+    from transmogrifai_tpu.models import linear
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d = 108_000, 8_960
+    arg = lambda *shape: _row_major(shape, one_chip)  # noqa: E731
+    assert linear._one_pass_ok(arg(n, d), k * g,
+                               C if loss == "softmax" else 1)
+    compiled = linear._train_linear.lower(
+        arg(n, d), arg(n), arg(k, n), arg(g), arg(g), loss_kind=loss,
+        n_classes=C, max_iter=200, fit_intercept=True,
+        standardize=True).compile()
+    assert _custom_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("d,g,loss,C,kernels", [
+    (37_120, 4, "hinge", 2, 1), (37_248, 4, "hinge", 2, 0),
+    (8_960, 8, "softmax", 17, 1), (8_960, 8, "softmax", 18, 0)],
+    ids=["columns_fit", "columns_past", "classes_fit", "classes_past"])
+def test_onepass_kernel_fits_its_vmem_or_is_not_taken(one_chip, monkeypatch,
+                                                      d, g, loss, C, kernels):
+    """Both sides of the VMEM the kernel may ask for, as
+    ``_onepass_vmem_bytes`` bounds it: the widest matrix the hinge
+    family's 4 x 3 lanes take the kernel over and one 128-column tile more,
+    the most classes a softmax of 8 x 3 lanes takes it for over
+    ``criteo_ctr``'s 8,960 columns and one class more. Where the bound
+    lets the kernel in, Mosaic accepts it inside that VMEM; one step past,
+    the program holds no kernel and compiles as XLA's."""
+    from transmogrifai_tpu.models import linear
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, k = 4_096, 3
+    arg = lambda *shape: _row_major(shape, one_chip)  # noqa: E731
+    compiled = linear._train_linear.lower(
+        arg(n, d), arg(n), arg(k, n), arg(g), arg(g), loss_kind=loss,
+        n_classes=C, max_iter=200, fit_intercept=True,
+        standardize=True).compile()
+    assert _custom_calls(compiled) == kernels
+
+
+@pytest.mark.parametrize("loss,C", [("softmax", 2), ("hinge", 1)])
+def test_onepass_kernel_takes_a_width_of_no_whole_tiles(one_chip, monkeypatch,
+                                                        loss, C):
+    """``amazon_polarity_text``'s 900,000 x 1,027 training split, its Adam
+    points' 4 x 3 lanes: a block of rows spans all 1,027 columns, no whole
+    number of 128-lane tiles, and the kernel copies nothing of the matrix.
+    (The whole descent there also plans the column-major copy its folds'
+    moments take, ``_lane_stats``, as the XLA program does.)"""
+    from transmogrifai_tpu.models import linear
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, L = 900_000, 1_027, 12
+    arg = lambda *shape: _row_major(shape, one_chip)  # noqa: E731
+
+    def terms(X, center, inv_scale, We, off, w, y, y_mean, y_sd):
+        return linear._onepass_terms(
+            X, center, inv_scale, We, off,
+            *linear._onepass_rows_of(w, y, y_mean, y_sd), loss_kind=loss)
+    compiled = jax.jit(terms).trace(
+        arg(n, d), arg(d), arg(d), arg(L, d, C), arg(L, C), arg(L, n),
+        arg(n), arg(L), arg(L)).lower(lowering_platforms=("tpu",)).compile()
+    assert _custom_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_narrow_descent_keeps_the_xla_program(one_chip, monkeypatch):
+    """``higgs_zoo``'s 28 columns (480,000 training rows a fold batch's
+    split, its L1 points' 4 x 3 lanes) stay below the gate's width: the
+    program the gate picks holds no kernel."""
+    from transmogrifai_tpu.models import linear
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d = 480_000, 28
+    arg = lambda *shape: _row_major(shape, one_chip)  # noqa: E731
+    compiled = linear._train_linear.lower(
+        arg(n, d), arg(n), arg(3, n), arg(4), arg(4), loss_kind="softmax",
+        n_classes=2, max_iter=200, fit_intercept=True,
+        standardize=True).compile()
+    assert _custom_calls(compiled) == 0

@@ -81,6 +81,214 @@ def _lane_stats(X, wf, standardize: bool):
     return wsum, mu, sd, varies.astype(X.dtype)
 
 
+#: the one-pass step (``_onepass_terms``) serves matrices this wide and
+#: wider: the narrowest width timed on the v5e, where the kernel's step
+#: took a third of the XLA program's (PERF.md, section 6); the tables of
+#: 28 and 54 columns keep the XLA program
+_ONEPASS_MIN_D = 256
+#: bytes of the float32 rows of one block of the one-pass step, and the
+#: most rows a block takes (the lanes' per-row terms of a narrow matrix's
+#: block would outgrow VMEM)
+_ONEPASS_BLOCK_BYTES = 8 << 20
+_ONEPASS_MAX_ROWS = 4096
+#: lanes of the one-pass step pad to whole bfloat16 sublane tiles
+_ONEPASS_LANE_TILE = 16
+#: VMEM the one-pass kernel asks Mosaic for, of the v5e's 128 MiB
+_ONEPASS_VMEM_LIMIT = 100 << 20
+#: bytes of VMEM the kernel takes an element of its block of rows, of its
+#: lanes' weights (``P x d``) and of their per-row terms (``P x b``), the
+#: columns padded to whole 128-lane tiles: at or above what Mosaic asked
+#: for on the v5e at 29 shapes of 256 to 65,536 columns and 16 to 1,440
+#: lanes x classes (PERF.md, section 6)
+_VMEM_PER_ROW_ELEMENT = 20
+_VMEM_PER_WEIGHT = 16
+_VMEM_PER_TERM = 8
+
+
+def _one_pass_ok(X, lanes: int, outputs: int) -> bool:
+    """Whether a descent of ``lanes`` lanes of ``outputs`` columns each
+    over ``X`` takes the one-pass step: on a TPU, off a mesh (row-sharded
+    ``X`` keeps the XLA program), from ``_ONEPASS_MIN_D`` columns, and
+    where the kernel fits its VMEM (``_onepass_vmem_bytes``)."""
+    from transmogrifai_tpu.parallel import mesh as pmesh
+    n, d = (int(s) for s in X.shape)
+    return (jax.default_backend() == "tpu" and pmesh.current_mesh() is None
+            and d >= _ONEPASS_MIN_D
+            and _onepass_vmem_bytes(n, d, lanes, outputs)
+            <= _ONEPASS_VMEM_LIMIT)
+
+
+def _onepass_vmem_bytes(n: int, d: int, lanes: int, outputs: int) -> int:
+    """An upper bound on the VMEM the one-pass kernel asks for: its block
+    of rows (two buffers) with their centred and split copies, the lanes'
+    split weights with the gradient's accumulator, and the lanes' per-row
+    terms. It grows with the columns x lanes x classes and, past 16,384
+    columns, with a block that cannot shrink below one 128-row tile
+    (``tests/test_tpu_compile.py`` compiles both sides of the limit)."""
+    b, dp = _onepass_rows(n, d), -(-d // 128) * 128
+    P = outputs * -(-lanes // _ONEPASS_LANE_TILE) * _ONEPASS_LANE_TILE
+    return (_VMEM_PER_ROW_ELEMENT * b * dp + _VMEM_PER_WEIGHT * P * dp
+            + _VMEM_PER_TERM * P * b)
+
+
+def _onepass_rows(n: int, d: int) -> int:
+    """Rows of a block of the one-pass step: ``_ONEPASS_BLOCK_BYTES`` of
+    float32 rows in whole 128-row tiles, at least one tile and no more
+    tiles than ``n`` holds whole."""
+    rows = min(_ONEPASS_BLOCK_BYTES // (4 * d), _ONEPASS_MAX_ROWS)
+    return int(max(128, min(rows // 128 * 128, n // 128 * 128)))
+
+
+def _split_bf16(a):
+    """``a`` as the sum of two bfloat16 arrays: its high part, rounded to
+    the nearest even on the float32 number's bits (so exact in bfloat16),
+    and the rest, rounded. Split by a pair of conversions inside the
+    kernel, the products read as one bfloat16 pass on the v5e, 2e-3 off
+    a gradient where XLA's ``HIGH`` read 2e-5; split so, 5e-6."""
+    bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+    bits = bits + jnp.uint32(0x7FFF) + ((bits >> 16) & jnp.uint32(1))
+    hi = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                      jnp.float32)
+    return hi.astype(jnp.bfloat16), (a - hi).astype(jnp.bfloat16)
+
+
+def _onepass_block(x, center, inv_scale, w_cat, off, w_rows, y, y_mean, y_sd,
+                   *, loss_kind: str, lanes: int):
+    """One block of rows of the one-pass step. ``x [b, d]`` raw rows,
+    centred and scaled here; ``w_cat [2P, d]`` the lanes' weights split in
+    bfloat16 (the high parts over the low ones), ``P = C x lanes`` rows
+    class-major (row ``c * lanes + l``); ``off [P, 1]``; ``w_rows [lanes,
+    b]`` each lane's row weights; ``y [1, b]``;
+    ``y_mean``/``y_sd [lanes, 1]`` the squared loss's target moments.
+    Each product is three bfloat16 products summed in float32
+    (``Precision.HIGH``), two MXU passes over the rows: the low half of the
+    lanes rides the high half's pass. Returns the block's share of the
+    weight gradient ``[P, d]``, of the margins' gradient ``[P, b]`` (the
+    bias gradient once summed over rows) and of the loss ``[lanes, b]``."""
+    f32 = jnp.float32
+    x_hi, x_lo = _split_bf16((x - center) * inv_scale)
+    P = w_cat.shape[0] // 2
+    nt = (((1,), (1,)), ((), ()))
+    z2 = jax.lax.dot_general(w_cat, x_hi, nt, preferred_element_type=f32)
+    z = z2[:P] + z2[P:] + jax.lax.dot_general(
+        w_cat[:P], x_lo, nt, preferred_element_type=f32) + off    # [P, b]
+    if loss_kind == "softmax":
+        zs = [z[c * lanes:(c + 1) * lanes] for c in range(P // lanes)]
+        top = functools.reduce(jnp.maximum, zs)
+        es = [jnp.exp(zc - top) for zc in zs]
+        total = functools.reduce(jnp.add, es)
+        hot = [(y == c).astype(f32) for c in range(len(zs))]
+        loss = top + jnp.log(total) - functools.reduce(
+            jnp.add, [h * zc for h, zc in zip(hot, zs)])
+        dz = jnp.concatenate([e / total - h for e, h in zip(es, hot)])
+        r = jnp.concatenate([w_rows] * len(zs)) * dz
+    elif loss_kind == "hinge":
+        sign = 2.0 * y - 1.0
+        margin = 1.0 - sign * z
+        loss = jnp.maximum(0.0, margin)
+        r = w_rows * jnp.where(margin > 0.0, -sign, 0.0)
+    else:  # squared, against the lane's standardized target
+        diff = z - (y - y_mean) / y_sd
+        loss = 0.5 * diff * diff
+        r = w_rows * diff
+    r_hi, r_lo = _split_bf16(r)
+    g2 = jnp.dot(jnp.concatenate([r_hi, r_lo]), x_hi,
+                 preferred_element_type=f32)
+    g = g2[:P] + g2[P:] + jnp.dot(r_hi, x_lo, preferred_element_type=f32)
+    return g, r, loss * w_rows
+
+
+def _onepass_terms(X, center, inv_scale, We, off, w_rows, y_row, y_mean,
+                   y_sd, *, loss_kind: str):
+    """The data term of every lane of ``_linear_descent`` and its gradient
+    from ONE read of ``X [n, d]``: a Pallas kernel walks the whole
+    ``_onepass_rows`` blocks in order, each loaded into VMEM once for the
+    lanes' margins, loss derivative and ``Xs^T r``, with the gradient
+    accumulated in VMEM over the grid; the rows past the last whole block
+    (all of them, in a table of fewer than 128) take the same
+    ``_onepass_block`` in XLA. ``We [L, d, C]`` and ``off
+    [L, C]`` are the lanes' weights on the centred and scaled matrix and
+    their offsets; ``w_rows [Lp, n]``, ``y_mean``/``y_sd [Lp, 1]`` come
+    lane-padded from ``_onepass_rows_of``. Returns the row-weighted sums
+    ``(loss [L], dWe [L, d, C], db [L, C])``, each lane's still to be
+    divided by its weight sum. Whole-number weights sum exactly, as in
+    ``objective``: where a fold's labels are exactly balanced, its
+    intercept's gradient at zero is exactly 0, and Adam would make a whole
+    step of any rounding left there."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, d = X.shape
+    L, _, C = We.shape
+    Lp = w_rows.shape[0]
+    P = C * Lp
+    WT = jnp.zeros((C, Lp, d), jnp.float32).at[:, :L].set(
+        jnp.transpose(We, (2, 0, 1))).reshape(P, d)
+    w_cat = jnp.concatenate(_split_bf16(WT))
+    offT = jnp.zeros((C, Lp), jnp.float32).at[:, :L].set(off.T).reshape(P, 1)
+    center, inv_scale = center.reshape(1, d), inv_scale.reshape(1, d)
+    block = functools.partial(_onepass_block, loss_kind=loss_kind, lanes=Lp)
+    b = _onepass_rows(n, d)
+    whole = n // b
+
+    def kernel(x_ref, c_ref, s_ref, w_ref, off_ref, wr_ref, y_ref, ym_ref,
+               ysd_ref, g_ref, r_ref, l_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            g_ref[...] = jnp.zeros_like(g_ref)
+            r_ref[...] = jnp.zeros_like(r_ref)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        g, r, loss = block(x_ref[...], c_ref[...], s_ref[...], w_ref[...],
+                           off_ref[...], wr_ref[...], y_ref[...],
+                           ym_ref[...], ysd_ref[...])
+        g_ref[...] += g
+        r_ref[...] += r
+        l_ref[...] += loss
+
+    fixed = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))  # noqa: E731
+    rows = lambda lead: pl.BlockSpec((lead, b), lambda i: (0, i))  # noqa: E731
+    if not whole:   # a table of fewer rows than one block is all tail
+        g = jnp.zeros((P, d), jnp.float32)
+        bias, loss = jnp.zeros(P, jnp.float32), jnp.zeros(Lp, jnp.float32)
+    else:
+        g, r, lw = pl.pallas_call(
+            kernel, grid=(whole,),
+            in_specs=[pl.BlockSpec((b, d), lambda i: (i, 0)), fixed((1, d)),
+                      fixed((1, d)), fixed((2 * P, d)), fixed((P, 1)),
+                      rows(Lp), rows(1), fixed((Lp, 1)), fixed((Lp, 1))],
+            out_specs=[fixed((P, d)), fixed((P, b)), fixed((Lp, b))],
+            out_shape=[jax.ShapeDtypeStruct((P, d), jnp.float32),
+                       jax.ShapeDtypeStruct((P, b), jnp.float32),
+                       jax.ShapeDtypeStruct((Lp, b), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_ONEPASS_VMEM_LIMIT),
+            interpret=jax.default_backend() != "tpu",
+        )(X, center, inv_scale, w_cat, offT, w_rows, y_row, y_mean, y_sd)
+        bias, loss = jnp.sum(r, axis=1), jnp.sum(lw, axis=1)
+    if n > whole * b:
+        lo = whole * b
+        gt, rt, lt = block(X[lo:], center, inv_scale, w_cat, offT,
+                           w_rows[:, lo:], y_row[:, lo:], y_mean, y_sd)
+        g, bias, loss = g + gt, bias + jnp.sum(rt, axis=1), \
+            loss + jnp.sum(lt, axis=1)
+    dWe = jnp.transpose(g.reshape(C, Lp, d)[:, :L], (1, 2, 0))
+    return loss[:L], dWe, bias.reshape(C, Lp)[:, :L].T
+
+
+def _onepass_rows_of(w, y, y_mean, y_sd):
+    """The one-pass step's row operands, made once a descent: each lane's
+    row weights ``w [L, n]`` and the squared loss's target moments ``[L]``,
+    padded with empty lanes to whole ``_ONEPASS_LANE_TILE`` lanes, and the
+    labels as one row."""
+    pad = -w.shape[0] % _ONEPASS_LANE_TILE
+
+    def lane(a, fill):
+        return jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
+    return (lane(w, 0.0), y[None, :], lane(y_mean[:, None], 0.0),
+            lane(y_sd[:, None], 1.0))
+
+
 def _linear_descent(X, y, wf, reg_param, elastic_net, W_init, b_init, *,
                     loss_kind: str, n_classes: int, max_iter: int,
                     fit_intercept: bool, standardize: bool):
@@ -104,11 +312,15 @@ def _linear_descent(X, y, wf, reg_param, elastic_net, W_init, b_init, *,
     ~max_iter/10 from 0, so raw targets of large mean or scale would
     under-fit. ``W_init``/``b_init`` (``[F*G, d, C]``/``[F*G, C]``, in
     ORIGINAL feature space) warm-start the descent; ``None`` starts from
-    zero. Returns original-space ``(W [F, G, d, C], b [F, G, C], last loss
-    [F, G])``."""
+    zero. Where ``_one_pass_ok`` holds, a step's data term and gradient
+    come from ONE read of ``X`` (``_onepass_terms``); it is asked while the
+    program is traced, and jax traces afresh for arguments of another
+    sharding, as a row-sharded ``X`` under a mesh is. Returns
+    original-space ``(W [F, G, d, C], b [F, G, C], last loss [F, G])``."""
     n, d = X.shape
     F, G = wf.shape[0], reg_param.shape[0]
     C = n_classes if loss_kind == "softmax" else 1
+    one_pass = _one_pass_ok(X, F * G, C)
     wsum_f, mu_f, sd_f, live_f = _lane_stats(X, wf, standardize)
     if standardize:
         center, scale = _standardize_stats(X, jnp.ones(n, X.dtype))
@@ -148,11 +360,38 @@ def _linear_descent(X, y, wf, reg_param, elastic_net, W_init, b_init, *,
         else:  # squared, against the lane's standardized target
             per_row = 0.5 * (z[0] - (y - ym[:, None]) / ysd[:, None]) ** 2
         data_loss = jnp.sum(per_row * w, axis=1) / wsum
-        l2 = 0.5 * jnp.sum(W ** 2, axis=(1, 2))
-        l1 = jnp.sum(jnp.abs(W), axis=(1, 2))
-        lane_loss = data_loss + reg * ((1.0 - en) * l2 + en * l1)
+        lane_loss = data_loss + penalty(W)
         # lanes share nothing but X: the sum's gradient is each lane's own
         return jnp.sum(lane_loss), lane_loss
+
+    def penalty(W):
+        l2 = 0.5 * jnp.sum(W ** 2, axis=(1, 2))
+        l1 = jnp.sum(jnp.abs(W), axis=(1, 2))
+        return reg * ((1.0 - en) * l2 + en * l1)
+
+    if one_pass:
+        rows_op = _onepass_rows_of(w, y, ym, ysd)
+
+    def one_pass_value_and_grad(params):
+        """``objective``'s lane losses and gradient, its data term from
+        ``_onepass_terms`` and by the chain rule through ``We`` and
+        ``off``, its penalty by ``jax.value_and_grad``."""
+        W, b = params
+        lift = (center - mu) / sd
+        We = W * (scale / sd)[:, :, None]
+        off = b + jnp.einsum("ld,ldc->lc", lift, W)
+        data_loss, dWe, db = _onepass_terms(
+            X, center, inv_scale, We, off, *rows_op, loss_kind=loss_kind)
+        data_loss, dWe, db = (data_loss / wsum, dWe / wsum[:, None, None],
+                              db / wsum[:, None])
+
+        def summed_penalty(W):
+            lanes = penalty(W)
+            return jnp.sum(lanes), lanes
+        (_, pen), dpen = jax.value_and_grad(summed_penalty, has_aux=True)(W)
+        dW = dWe * (scale / sd)[:, :, None] + lift[:, :, None] \
+            * db[:, None, :] + dpen
+        return data_loss + pen, (dW, db)
 
     # columns and target centered by the same weights: the squared loss's
     # fit-space intercept is 0 whatever W is, and its gradient at 0 is
@@ -171,8 +410,11 @@ def _linear_descent(X, y, wf, reg_param, elastic_net, W_init, b_init, *,
 
     def step(carry, _):
         params, opt_state = carry
-        (_, lane_loss), grads = jax.value_and_grad(
-            objective, has_aux=True)(params)
+        if one_pass:
+            lane_loss, grads = one_pass_value_and_grad(params)
+        else:
+            (_, lane_loss), grads = jax.value_and_grad(
+                objective, has_aux=True)(params)
         # a column constant under the lane's weighting has no gradient; what
         # the centred product leaves there is rounding, which Adam would
         # scale up to whole steps
@@ -609,8 +851,10 @@ def _run_grid(X, y, wf, grid: Sequence[dict], defaults: dict, kw: dict):
     # per Adam step: forward z = X@W (2ndC) + backward grads (~4ndC)
     flops.add("linear", int(wf.shape[0]) * len(grid) * kw["max_iter"]
               * 6.0 * int(n) * int(d) * C)
+    lanes = int(wf.shape[0]) * len(grid)
     sweep_counters.count_run(
-        linear_descent_lanes=int(wf.shape[0]) * len(grid))
+        linear_descent_lanes=lanes,
+        linear_onepass_lanes=lanes if _one_pass_ok(X, lanes, C) else 0)
     return _train_linear(X, y, wf, rp, en, **kw)
 
 
@@ -1001,10 +1245,12 @@ class _LinearPredictor(Predictor):
         p = {**self.params, **params}
         if warm is None or lane is None:
             return self.fit_arrays(X, y, w, p), False
-        sweep_counters.count_run(linear_descent_lanes=1)
         Ws, bs = warm
         W_init = jnp.mean(jnp.asarray(Ws, jnp.float32)[:, int(lane)],
                           axis=0)
+        sweep_counters.count_run(
+            linear_descent_lanes=1,
+            linear_onepass_lanes=int(_one_pass_ok(X, 1, W_init.shape[-1])))
         b_init = jnp.mean(jnp.asarray(bs, jnp.float32)[:, int(lane)],
                           axis=0)
         kw = self._static_kw(p, self._n_classes(y))

@@ -489,6 +489,9 @@ class SweepCounters:
         #: trained by ``models/linear.py::_linear_descent``, the Adam
         #: descent, and not solved
         self.linear_descent_lanes = 0
+        #: those of them whose every Adam step read the matrix once
+        #: (``models/linear.py::_onepass_terms``)
+        self.linear_onepass_lanes = 0
         #: the telemetry's process-lifetime per-family compile counts when
         #: this run began
         self._compiles_at_reset: dict = {}
@@ -510,6 +513,7 @@ class SweepCounters:
         self.forest_rows_total = 0
         self.forest_rows_carried = 0
         self.linear_descent_lanes = 0
+        self.linear_onepass_lanes = 0
         self._compiles_at_reset = compile_telemetry.family_compiles()
 
     def compiles(self, name: str) -> int:
@@ -541,14 +545,16 @@ class SweepCounters:
                   tree_walk_padded_rows: int = 0,
                   forest_rows_total: int = 0, forest_rows_carried: int = 0,
                   linear_descent_lanes: int = 0,
+                  linear_onepass_lanes: int = 0,
                   loop_fallback: Optional[str] = None) -> None:
         """Run-level accounting (see class docstring): settle barriers,
         overlapped families, warm-started refits, operand copies, the
         host string work that fed the sweep, tree walks traced with a
         per-row gather and the rows tree walks padded, the rows a
         dispatched forest program was given and carried, the linear lanes
-        that took the Adam descent, and (``loop_fallback``: the reason) one
-        unit that left the stacked path for the per-fold loop."""
+        that took the Adam descent and those of them that took its one-pass
+        step, and (``loop_fallback``: the reason) one unit that left the
+        stacked path for the per-fold loop."""
         self.sweep_host_syncs += host_syncs
         self.async_families += async_families
         self.refit_warm_starts += refit_warm_starts
@@ -562,6 +568,7 @@ class SweepCounters:
         self.forest_rows_total += int(forest_rows_total)
         self.forest_rows_carried += int(forest_rows_carried)
         self.linear_descent_lanes += int(linear_descent_lanes)
+        self.linear_onepass_lanes += int(linear_onepass_lanes)
         if loop_fallback is not None:
             self.loop_fallbacks[loop_fallback] = \
                 self.loop_fallbacks.get(loop_fallback, 0) + 1
@@ -595,6 +602,7 @@ class SweepCounters:
                 "treeGatherWalks": self.tree_gather_walks,
                 "treeWalkPaddedRows": self.tree_walk_padded_rows,
                 "linearDescentLanes": self.linear_descent_lanes,
+                "linearOnePassLanes": self.linear_onepass_lanes,
                 "sweepLoopFallbacks": sum(self.loop_fallbacks.values()),
                 "sweepLoopFallbackReasons": dict(self.loop_fallbacks),
                 "gcCollections": {str(g): n for g, n in

@@ -559,7 +559,10 @@ def _app_collectors(reg: PromRegistry) -> None:
              "futures until the single settle)"),
             ("refit_warm_starts", "refit_warm_starts",
              "winner refits warm-started from sweep state (stacked fold "
-             "parameters / reused tree bin codes)")):
+             "parameters / reused tree bin codes)"),
+            ("linear_onepass_lanes", "linear_onepass_lanes",
+             "linear descent lanes whose every Adam step read the matrix "
+             "once")):
         reg.register(f"transmogrifai_sweep_{name}_total", "counter", help_,
                      lambda a=attr: [({}, getattr(sc, a))])
 
